@@ -53,19 +53,17 @@ def _tree_util():
     return jax.tree_util
 
 
-def _device_count_if_initialized() -> Optional[int]:
-    """``jax.device_count()`` ONLY when a backend is already live.
-    ``device_count`` initializes the platform as a side effect —
-    unacceptable from a process that is merely checkpointing host
-    arrays (backend bring-up can block on real-hardware probes)."""
-    try:
-        from jax._src import xla_bridge
-        if not xla_bridge.backends_are_initialized():
-            return None
+def _device_count_of(flat) -> Optional[int]:
+    """``jax.device_count()`` when the ``(path, leaf)`` list holds
+    device-placed leaves: the process that owns them already runs a
+    backend. None for host-only trees — the count means nothing
+    there, and a process that only checkpoints numpy arrays should
+    not start a backend to learn it."""
+    if any(getattr(leaf, 'sharding', None) is not None
+           for _, leaf in flat):
         import jax
         return jax.device_count()
-    except Exception:  # pylint: disable=broad-except
-        return None
+    return None
 
 
 def saved_device_count(lineage_dir: str) -> Optional[int]:
@@ -274,7 +272,7 @@ class NativeCheckpointManager:
                 f'(first few: {missing[:5]}); was it saved from a '
                 'different model/optimizer configuration?')
         restored = tree_util.tree_unflatten(treedef, out)
-        device_count = _device_count_if_initialized()
+        device_count = _device_count_of(flat)
         self.last_restore = {
             'step': step,
             'bytes_read': stats['bytes_read'],
@@ -393,11 +391,7 @@ class NativeCheckpointManager:
         flat, _ = tree_util.tree_flatten_with_path(state)
         # Recorded in the merged manifest so a restore onto a
         # different mesh can tell it is a resize (elastic resume).
-        # None for host-only trees: device count is meaningless
-        # there, and asking jax for it would force BACKEND INIT in
-        # checkpoint-only processes that never touch a device (a
-        # hang on boxes whose TPU plugin probes real hardware).
-        self._snapshot_device_count = _device_count_if_initialized()
+        self._snapshot_device_count = _device_count_of(flat)
         payload = []
         for path, leaf in flat:
             key = format_lib.key_str(path)

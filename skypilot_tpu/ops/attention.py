@@ -37,6 +37,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from skypilot_tpu import tpu_logging
+
+logger = tpu_logging.init_logger(__name__)
+
 # Default flash tile sizes; env-overridable for block-size sweeps on
 # new chips/shapes without touching call sites (read at import).
 _DEFAULT_BLOCK_Q = int(os.environ.get('SKYTPU_FLASH_BLOCK_Q', '512'))
@@ -58,11 +62,16 @@ _LOG2E = 1.4426950408889634
 _STAT_SUBLANES = 8
 
 
+# pallas_call names: the trace reduction and the lowered-step check
+# (recipes/finetune.py) find the kernels by these.
+KERNEL_NAMES = ('flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv')
+
+
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == 'tpu'
-    except Exception:  # pylint: disable=broad-except
-        return False
+    """Whether the default backend is a TPU. A backend that cannot
+    start raises here (JAX's own error): guessing "not on TPU" would
+    silently train on the XLA reference path."""
+    return jax.default_backend() == 'tpu'
 
 
 # ---------------------------------------------------------------------
@@ -485,6 +494,7 @@ def _fwd_pallas(q, k, v, cos=None, sin=None, *, scale, causal,
                                  jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[0],
     )(*inputs)
     return out, lse
 
@@ -537,6 +547,7 @@ def _bwd_pallas(q, k, v, out, lse, do, cos=None, sin=None, *, scale,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         interpret=interpret,
+        name=KERNEL_NAMES[1],
     )(*dq_inputs)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
@@ -573,6 +584,7 @@ def _bwd_pallas(q, k, v, out, lse, do, cos=None, sin=None, *, scale,
             jax.ShapeDtypeStruct((b, hkv, s, d), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[2],
     )(*dkv_inputs)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -737,6 +749,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                min(block_q_bwd, t),
                                min(block_k_bwd, s), interpret)
         return out.transpose(0, 2, 1, 3)
+    if use_pallas:
+        # Trace-time, so once per compiled shape: on the chip the
+        # choice of path must show in the log, not only in the speed.
+        logger.warning(
+            'flash_attention: XLA reference path for q=%s k=%s — '
+            'lengths must be >= 128 and divisible by the blocks '
+            '(fwd %dx%d, bwd %dx%d)', q.shape, k.shape, block_q,
+            block_k, block_q_bwd, block_k_bwd)
     if rope_angles is not None:
         q = apply_rope(q, rope_angles)
         k = apply_rope(k, rope_angles)

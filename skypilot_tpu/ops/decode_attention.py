@@ -77,10 +77,9 @@ def _use_pallas(which: str = '') -> bool:
         return False
     if which and os.environ.get(f'SKYTPU_NO_PALLAS_{which}') == '1':
         return False  # per-kernel kill-switch (ATTN / WRITE)
-    try:
-        return jax.default_backend() == 'tpu'
-    except RuntimeError:
-        return False
+    # No except: a backend that cannot start is JAX's error to raise,
+    # not a reason to answer "not on TPU".
+    return jax.default_backend() == 'tpu'
 
 
 # ---------------------------------------------------------------------

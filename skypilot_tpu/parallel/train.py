@@ -294,6 +294,35 @@ def make_ring_attention_impl(mesh: Mesh, axis_name: str = 'sp'):
     return impl
 
 
+def make_flash_attention_impl(mesh: Mesh):
+    """attn_impl for a multi-device mesh without sequence parallelism:
+    the default fused-RoPE flash attention under shard_map, batch over
+    the data axes and heads over 'tp'. XLA cannot partition a Mosaic
+    custom call, so inside the auto-sharded jit the Pallas kernels
+    must be handed per-device blocks explicitly; attention is
+    independent per (batch row, KV head), so no collective is needed.
+    KV heads shard over 'tp' with their query groups — the same split
+    ``param_sharding_rules`` gives wq/wk/wv."""
+    from jax import shard_map
+
+    tp = mesh.shape['tp']
+    spec = P(('dp', 'fsdp', 'ep'), None, 'tp', None)
+    # check_vma off: pallas_call's outputs carry no varying-axes
+    # annotation for the checker to follow.
+    fn = shard_map(llama.default_attn_impl(), mesh=mesh,
+                   in_specs=(spec, spec, spec, P()), out_specs=spec,
+                   check_vma=False)
+
+    def impl(q, k, v, angles):
+        if k.shape[2] % tp:
+            raise ValueError(
+                f'n_kv_heads={k.shape[2]} is not divisible by tp={tp}: '
+                'flash attention shards KV heads over the tp axis')
+        return fn(q, k, v, angles)
+
+    return impl
+
+
 def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
                      state_shardings: TrainState,
                      optimizer: Optional[
@@ -309,7 +338,9 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
 
     When the mesh has an ``sp`` axis > 1, activations shard their
     sequence dim over it and attention runs as ring attention
-    (long-context: per-device memory stays O(T / sp)). A ``pp`` axis
+    (long-context: per-device memory stays O(T / sp)). Any other
+    multi-device mesh runs flash attention under shard_map
+    (``make_flash_attention_impl``). A ``pp`` axis
     > 1 runs the layer stack as a GPipe pipeline
     (``parallel/pipeline.py``) with ``pipeline_microbatches``
     microbatches (default 2*pp)."""
@@ -319,7 +350,12 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
 
     use_sp = mesh.shape.get('sp', 1) > 1
     use_pp = mesh.shape.get('pp', 1) > 1
-    attn_impl = make_ring_attention_impl(mesh) if use_sp else None
+    if use_sp:
+        attn_impl = make_ring_attention_impl(mesh)
+    elif mesh.size > 1 and not use_pp:
+        attn_impl = make_flash_attention_impl(mesh)
+    else:
+        attn_impl = None  # one device: the model's own default
     act_sharding = NamedSharding(
         mesh, P(('dp', 'fsdp', 'ep'), 'sp', None)) if use_sp else None
 

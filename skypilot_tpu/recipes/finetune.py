@@ -18,6 +18,7 @@ the v6e README):
         --lora-rank 16 --checkpoint-dir /checkpoints
 """
 import argparse
+import json
 import os
 import time
 
@@ -87,8 +88,6 @@ def _elastic_design(lineage_dir, n_now, global_batch):
     designed shape (NEXT_BEST_SHAPE only resizes recoveries), so
     recording (devices, batch) when the file is absent on a
     non-resized run captures the design exactly."""
-    import json
-
     path = os.path.join(lineage_dir, 'design.json')
     try:
         with open(path, encoding='utf-8') as f:
@@ -133,15 +132,32 @@ def data_iterator(args, vocab_size, rng):
                                dtype=np.int32)
 
 
+def _flash_kernel_census(step_fn, state, batch) -> dict:
+    """How many times each Pallas flash kernel appears in the lowered
+    train step (the module XLA compiles; the layer scan holds each
+    once). All zero means attention lowered to the XLA reference —
+    the log says so instead of leaving it to the step time."""
+    from skypilot_tpu.ops import attention as attention_ops
+    text = step_fn.lower(state, batch).as_text()
+    return {name: text.count(f'kernel_name = "{name}"')
+            for name in attention_ops.KERNEL_NAMES}
+
+
 def main():
     args = parse_args()
 
+    from skypilot_tpu.utils import jax_runtime
+    jax_runtime.configure_compile_cache()
     from skypilot_tpu import callbacks
     from skypilot_tpu.parallel import distributed
     distributed.initialize()  # no-op single-host
 
     import jax
     import jax.numpy as jnp
+
+    if jax.process_index() == 0:
+        print(jax_runtime.device_line(jax_runtime.device_facts()),
+              flush=True)
 
     from skypilot_tpu.models import llama
     from skypilot_tpu.parallel import (MeshConfig, auto_mesh_config,
@@ -247,6 +263,15 @@ def main():
     for step in range(start_step, args.steps):
         batch_np = next(batches)
         batch = {'tokens': jnp.asarray(batch_np)}
+        if step == start_step and jax.process_index() == 0:
+            wq = state.params['layers']['wq']
+            print('train_step ' + json.dumps({
+                'kernels': _flash_kernel_census(step_fn.inner, state,
+                                                batch),
+                'wq': wq.shape,
+                'wq_shard': wq.addressable_shards[0].data.shape,
+                'wq_devices': len(wq.sharding.device_set),
+            }), flush=True)
         callbacks.step_begin()
         state, metrics = step_fn(state, batch)
         jax.block_until_ready(metrics['loss'])
@@ -268,6 +293,7 @@ def main():
         ckpt.close()
     publisher.close()
     if jax.process_index() == 0:
+        print(f'runtime {json.dumps(jax_runtime.runtime_facts())}')
         print('finetune done.')
 
 

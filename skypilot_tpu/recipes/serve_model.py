@@ -194,11 +194,16 @@ def main():
                      'not supported yet: the engine cache is '
                      'unsharded and would replicate per device')
 
+    from skypilot_tpu.utils import jax_runtime
+    jax_runtime.configure_compile_cache()
+
     import jax
     import jax.numpy as jnp
 
     from skypilot_tpu.models import decode, llama
 
+    device = jax_runtime.device_facts()
+    print(jax_runtime.device_line(device), flush=True)
     config = llama.get_config(args.model)
     ckpt_params = None
     if args.checkpoint_dir:
@@ -457,7 +462,12 @@ def main():
 
         def do_GET(self):  # noqa: N802
             if self.path == '/':
-                self._json({'status': 'ok', 'model': args.model})
+                # Readiness, plus what this replica runs on and what
+                # it has compiled and allocated so far — a probe
+                # reply alone shows whether it is the chip.
+                self._json({'status': 'ok', 'model': args.model,
+                            'device': device,
+                            'runtime': jax_runtime.runtime_facts()})
             else:
                 self._json({'error': 'not found'}, 404)
 
@@ -738,7 +748,10 @@ def main():
         while req.out.get() is not None:
             pass
     server = ThreadingHTTPServer(('0.0.0.0', args.port), Handler)
-    print(f'serve_model ready on :{args.port} (model {args.model})')
+    print(f'serve_model ready on :{args.port} (model {args.model}, '
+          f'platform={device["platform"]} '
+          f'device_kind={device["device_kind"]!r} '
+          f'devices={device["device_count"]})', flush=True)
     server.serve_forever()
 
 
