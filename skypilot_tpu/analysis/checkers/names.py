@@ -26,7 +26,11 @@ from skypilot_tpu.analysis import docs_contract
 OBS_DOC = 'observability.md'
 RES_DOC = 'resilience.md'
 
-_SPAN_FUNCS_SUFFIX = ('.span', '.record_span', '.emit_span', '._span')
+# ``.phase`` names (loop phases on the profiler's clock) share the
+# span-name contract; they are documented in their own sub-table,
+# which is also checked in the reverse direction.
+_SPAN_FUNCS_SUFFIX = ('.span', '.record_span', '.emit_span', '._span',
+                      '.phase')
 _SPAN_FUNCS_BARE = ('record_span', 'emit_span')
 _SPAN_NAME_RE = re.compile(r'[a-z0-9_.]+\Z')
 _METRIC_NAME_RE = re.compile(r'skytpu_[a-z0-9_]+\Z')
@@ -168,7 +172,10 @@ def collect_fault_sites(repo: 'core.RepoContext'
 class SpanNameContractChecker(core.Checker):
     rule = 'span-name-contract'
     description = ('Every literal span name emitted in-tree is '
-                   'backticked in docs/observability.md.')
+                   'backticked in docs/observability.md; the loop '
+                   "phases' sub-table is checked both ways.")
+
+    PHASE_SECTION = "### On the profiler's clock"
 
     def check_repo(self, repo: 'core.RepoContext'
                    ) -> Iterable['core.Finding']:
@@ -190,6 +197,23 @@ class SpanNameContractChecker(core.Checker):
                     'from the docs/observability.md span-name '
                     'contract table — span names are stable API '
                     'exactly like metric names')
+        if repo.partial_package_scan:
+            # Partial scan: skip the documented⇒emitted direction
+            # (see MetricNameContractChecker).
+            return
+        sect = docs_contract.section(doc, self.PHASE_SECTION)
+        if sect is None:
+            # No such sub-table (a fixture, or a doc that documents
+            # no loop phase): nothing to hold against the code.
+            return
+        documented = docs_contract.table_col0(
+            sect, r'[a-z0-9_]+\.[a-z0-9_.]+')
+        for name in sorted(documented - set(emitted)):
+            yield core.Finding(
+                self.rule, f'docs/{OBS_DOC}', 1, 1,
+                f'loop phase `{name}` is documented in the '
+                f'"{self.PHASE_SECTION}" table but emitted nowhere '
+                'in skypilot_tpu/ — stale contract row')
 
 
 class MetricNameContractChecker(core.Checker):

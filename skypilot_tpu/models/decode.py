@@ -97,7 +97,8 @@ def _dequant_kv(q: jax.Array, scale: Optional[jax.Array],
     into the consumer, so HBM reads stay int8-sized."""
     if scale is None:
         return q
-    return q.astype(dtype) * scale[..., None].astype(dtype)
+    with jax.named_scope('kv_dequant'):
+        return q.astype(dtype) * scale[..., None].astype(dtype)
 
 
 def _masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -314,11 +315,12 @@ def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
     float32 accumulation, cast by the caller. Per-row math only — a
     row's output is independent of its batch-mates, which is the
     mixed-vs-alone exactness contract the adapter tests assert."""
-    a = a_slots[adapter_idx]                        # [B, d, R]
-    bm = b_slots[adapter_idx]                       # [B, R, out]
-    hf = h.astype(jnp.float32)
-    mid = jnp.einsum('btd,bdr->btr', hf, a)
-    return jnp.einsum('btr,bro->bto', mid, bm)
+    with jax.named_scope('lora_delta'):
+        a = a_slots[adapter_idx]                    # [B, d, R]
+        bm = b_slots[adapter_idx]                   # [B, R, out]
+        hf = h.astype(jnp.float32)
+        mid = jnp.einsum('btd,bdr->btr', hf, a)
+        return jnp.einsum('btr,bro->bto', mid, bm)
 
 
 def forward_paged(params: Params, tokens: jax.Array, pools,
@@ -407,9 +409,10 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
             ks = vs = None
         h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                             config.norm_offset)
-        q = _mm(h, lp['wq'])
-        k = _mm(h, lp['wk'])
-        v = _mm(h, lp['wv'])
+        with jax.named_scope('qkv_proj'):
+            q = _mm(h, lp['wq'])
+            k = _mm(h, lp['wk'])
+            v = _mm(h, lp['wv'])
         if ad is not None:
             # Adapter attach mirrors the engine's decode/verify
             # twins exactly (same helper, same q/v points) — prefill
@@ -441,11 +444,12 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
         # merged scatter after the layer scan (same split as
         # forward_cached — full-pool ys per layer would rewrite the
         # whole pool every chunk).
-        kc = kc.at[gw].set(k_rows[0])
-        vc = vc.at[gw].set(v_rows[0])
-        if quantized:
-            ks = ks.at[gw].set(ks_rows[0])
-            vs = vs.at[gw].set(vs_rows[0])
+        with jax.named_scope('kv_write'):
+            kc = kc.at[gw].set(k_rows[0])
+            vc = vc.at[gw].set(v_rows[0])
+            if quantized:
+                ks = ks.at[gw].set(ks_rows[0])
+                vs = vs.at[gw].set(vs_rows[0])
         kd = _dequant_kv(da.paged_gather(kc, gr[None]),
                          None if ks is None
                          else da.paged_gather(ks, gr[None]), k.dtype)
@@ -466,21 +470,24 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
                            k[0][relc][None], kd)
             vd = jnp.where(in_chunk[None, :, None, None],
                            v[0][relc][None], vd)
-        attn = _masked_attention(q, kd, vd, q_pos=start,
-                                 kv_len=start + real_len,
-                                 scale=hd ** -0.5)
-        xc = xc + _mm(attn.reshape(1, t, nh * hd), lp['wo'])
+        with jax.named_scope('prefill_attention'):
+            attn = _masked_attention(q, kd, vd, q_pos=start,
+                                     kv_len=start + real_len,
+                                     scale=hd ** -0.5)
+        with jax.named_scope('o_proj'):
+            xc = xc + _mm(attn.reshape(1, t, nh * hd), lp['wo'])
         h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
                             config.norm_offset)
-        if config.n_experts:
-            moe_out, _ = llama._moe_mlp(config, h, lp)
-            xc = xc + moe_out
-        else:
-            gate = llama.mlp_act(config)(
-                _mm(h, lp['w_gate']).astype(jnp.float32)
-            ).astype(h.dtype)
-            up = _mm(h, lp['w_up'])
-            xc = xc + _mm(gate * up, lp['w_down'])
+        with jax.named_scope('mlp'):
+            if config.n_experts:
+                moe_out, _ = llama._moe_mlp(config, h, lp)
+                xc = xc + moe_out
+            else:
+                gate = llama.mlp_act(config)(
+                    _mm(h, lp['w_gate']).astype(jnp.float32)
+                ).astype(h.dtype)
+                up = _mm(h, lp['w_up'])
+                xc = xc + _mm(gate * up, lp['w_down'])
         return xc, ((k_rows[0], v_rows[0], ks_rows[0], vs_rows[0])
                     if quantized else (k_rows[0], v_rows[0]))
 

@@ -272,8 +272,10 @@ def paged_gather(pool_flat: jax.Array,
     """Gather rows' logical KV views out of a flattened pool:
     pool_flat [num_blocks * block_size, ...] indexed by the
     precomputed flat indices from ``kv_pool.read_indices``
-    ([B, S_pad] -> [B, S_pad, ...])."""
-    return jnp.take(pool_flat, gather_idx, axis=0)
+    ([B, S_pad] -> [B, S_pad, ...]). Scoped ``paged_gather`` in the
+    compiled program's ``op_name`` metadata."""
+    with jax.named_scope('paged_gather'):
+        return jnp.take(pool_flat, gather_idx, axis=0)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -311,11 +313,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     vd = paged_gather(v_pool, gidx)
     if k_scale is not None:
         dtype = q.dtype
-        kd = kd.astype(dtype) * paged_gather(
-            k_scale, gidx)[..., None].astype(dtype)
-        vd = vd.astype(dtype) * paged_gather(
-            v_scale, gidx)[..., None].astype(dtype)
-    return decode_attention(q, kd, vd, lengths, scale)
+        with jax.named_scope('kv_dequant'):
+            kd = kd.astype(dtype) * paged_gather(
+                k_scale, gidx)[..., None].astype(dtype)
+            vd = vd.astype(dtype) * paged_gather(
+                v_scale, gidx)[..., None].astype(dtype)
+    with jax.named_scope('decode_attention'):
+        return decode_attention(q, kd, vd, lengths, scale)
 
 
 def _reference_verify_attention(q, k, v, lengths, scale):
@@ -373,11 +377,13 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     vd = paged_gather(v_pool, gidx)
     if k_scale is not None:
         dtype = q.dtype
-        kd = kd.astype(dtype) * paged_gather(
-            k_scale, gidx)[..., None].astype(dtype)
-        vd = vd.astype(dtype) * paged_gather(
-            v_scale, gidx)[..., None].astype(dtype)
-    return _reference_verify_attention(q, kd, vd, lengths, scale)
+        with jax.named_scope('kv_dequant'):
+            kd = kd.astype(dtype) * paged_gather(
+                k_scale, gidx)[..., None].astype(dtype)
+            vd = vd.astype(dtype) * paged_gather(
+                v_scale, gidx)[..., None].astype(dtype)
+    with jax.named_scope('decode_attention'):
+        return _reference_verify_attention(q, kd, vd, lengths, scale)
 
 
 # ---------------------------------------------------------------------

@@ -384,6 +384,14 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     each step's token per row, keyed ``(seed, position)``, with the
     grammar mask gathered in-jit by traced index.
 
+    The ``jax.named_scope``s here and in the functions this calls
+    (``qkv_proj``, ``lora_delta``, ``kv_write``, ``paged_gather``,
+    ``kv_dequant``, ``decode_attention``, ``o_proj``, ``mlp``,
+    ``sampler``; the verify and prefill twins carry the same) name
+    the program's parts in ``op_name=`` of the compiled text, which
+    is the only place the chip's trace lets them be looked up; they
+    change HLO metadata and nothing else.
+
     Returns (out_tokens [B, num_steps], caches, new_pos).
     """
     from skypilot_tpu.ops import decode_attention as da
@@ -422,9 +430,10 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             lp, kc, vc, ks, vs, ad = scanned
             h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                                 config.norm_offset)
-            q = _mm(h, lp['wq'])
-            k = _mm(h, lp['wk'])
-            v = _mm(h, lp['wv'])
+            with jax.named_scope('qkv_proj'):
+                q = _mm(h, lp['wq'])
+                k = _mm(h, lp['wk'])
+                v = _mm(h, lp['wv'])
             if ad is not None:
                 q = q + _lora_gather_delta(
                     h, ad['wq_a'], ad['wq_b'],
@@ -451,26 +460,29 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             # sees the new row (the caller-visible pool update is the
             # single merged scatter per token after the layer scan,
             # same split as decode_steps_rows).
-            kc = kc.at[widx].set(k_rows[:, 0])
-            vc = vc.at[widx].set(v_rows[:, 0])
-            if ks is not None:
-                ks = ks.at[widx].set(ks_rows[:, 0])
-                vs = vs.at[widx].set(vs_rows[:, 0])
+            with jax.named_scope('kv_write'):
+                kc = kc.at[widx].set(k_rows[:, 0])
+                vc = vc.at[widx].set(v_rows[:, 0])
+                if ks is not None:
+                    ks = ks.at[widx].set(ks_rows[:, 0])
+                    vs = vs.at[widx].set(vs_rows[:, 0])
             attn = da.paged_decode_attention(
                 q[:, 0], kc, vc, block_tables, cur_ + 1, hd ** -0.5,
                 block_size, k_scale=ks, v_scale=vs)[:, None]
-            xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
+            with jax.named_scope('o_proj'):
+                xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
             h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
                                 config.norm_offset)
-            if config.n_experts:
-                moe_out, _ = llama._moe_mlp(config, h, lp)
-                xc = xc + moe_out
-            else:
-                gate = llama.mlp_act(config)(
-                    _mm(h, lp['w_gate']).astype(jnp.float32)
-                ).astype(h.dtype)
-                up = _mm(h, lp['w_up'])
-                xc = xc + _mm(gate * up, lp['w_down'])
+            with jax.named_scope('mlp'):
+                if config.n_experts:
+                    moe_out, _ = llama._moe_mlp(config, h, lp)
+                    xc = xc + moe_out
+                else:
+                    gate = llama.mlp_act(config)(
+                        _mm(h, lp['w_gate']).astype(jnp.float32)
+                    ).astype(h.dtype)
+                    up = _mm(h, lp['w_up'])
+                    xc = xc + _mm(gate * up, lp['w_down'])
             return (xc, cur_), (
                 k_rows[:, 0], v_rows[:, 0],
                 None if ks_rows is None else ks_rows[:, 0],
@@ -493,17 +505,19 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             logits = (x @ llama.output_head(cparams, config))
         else:
             logits = _mm(x, cparams['lm_head'])
-        if sampling is None:
-            nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
-        else:
-            # Counter-keyed per-row sampling at position ``cur`` —
-            # the row's draw never depends on batch neighbors
-            # (serve/sampling/prng.py batch-invariance contract).
-            allowed = sample_lib.gather_masks(sampling['mask_table'],
-                                              sampling['mask_idx'])
-            nxt = sample_lib.sample_rows(
-                logits[:, -1], sampling['temps'], sampling['top_ps'],
-                sampling['seeds'], cur, allowed)
+        with jax.named_scope('sampler'):
+            if sampling is None:
+                nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
+            else:
+                # Counter-keyed per-row sampling at position ``cur``
+                # — the row's draw never depends on batch neighbors
+                # (serve/sampling/prng.py batch-invariance contract).
+                allowed = sample_lib.gather_masks(
+                    sampling['mask_table'], sampling['mask_idx'])
+                nxt = sample_lib.sample_rows(
+                    logits[:, -1], sampling['temps'],
+                    sampling['top_ps'], sampling['seeds'], cur,
+                    allowed)
         # Inactive rows: hold the last token and do NOT advance, so
         # their next (scratch-redirected) write stays parked.
         nxt = jnp.where(active, nxt, tok)
@@ -699,9 +713,10 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         lp, kc, vc, ks, vs, ad = scanned
         h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                             config.norm_offset)
-        q = _mm(h, lp['wq'])
-        k = _mm(h, lp['wk'])
-        v = _mm(h, lp['wv'])
+        with jax.named_scope('qkv_proj'):
+            q = _mm(h, lp['wq'])
+            k = _mm(h, lp['wk'])
+            v = _mm(h, lp['wv'])
         if ad is not None:
             # Same row-gathered LoRA attach as the decode twin —
             # verify MUST apply the identical delta or speculation
@@ -732,26 +747,29 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         # pool update is the merged scatter after the layer scan —
         # same split as the decode twin). Padded lanes collide
         # harmlessly on the scratch slot.
-        kc = kc.at[wflat].set(k_rows.reshape(b * width, nkv, hd))
-        vc = vc.at[wflat].set(v_rows.reshape(b * width, nkv, hd))
-        if ks is not None:
-            ks = ks.at[wflat].set(ks_rows.reshape(b * width, nkv))
-            vs = vs.at[wflat].set(vs_rows.reshape(b * width, nkv))
+        with jax.named_scope('kv_write'):
+            kc = kc.at[wflat].set(k_rows.reshape(b * width, nkv, hd))
+            vc = vc.at[wflat].set(v_rows.reshape(b * width, nkv, hd))
+            if ks is not None:
+                ks = ks.at[wflat].set(ks_rows.reshape(b * width, nkv))
+                vs = vs.at[wflat].set(vs_rows.reshape(b * width, nkv))
         attn = da.paged_verify_attention(
             q, kc, vc, block_tables, pos + 1, hd ** -0.5,
             block_size, k_scale=ks, v_scale=vs)       # [B, W, Hq, hd]
-        xc = xc + _mm(attn.reshape(b, width, nh * hd), lp['wo'])
+        with jax.named_scope('o_proj'):
+            xc = xc + _mm(attn.reshape(b, width, nh * hd), lp['wo'])
         h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
                             config.norm_offset)
-        if config.n_experts:
-            moe_out, _ = llama._moe_mlp(config, h, lp)
-            xc = xc + moe_out
-        else:
-            gate = llama.mlp_act(config)(
-                _mm(h, lp['w_gate']).astype(jnp.float32)
-            ).astype(h.dtype)
-            up = _mm(h, lp['w_up'])
-            xc = xc + _mm(gate * up, lp['w_down'])
+        with jax.named_scope('mlp'):
+            if config.n_experts:
+                moe_out, _ = llama._moe_mlp(config, h, lp)
+                xc = xc + moe_out
+            else:
+                gate = llama.mlp_act(config)(
+                    _mm(h, lp['w_gate']).astype(jnp.float32)
+                ).astype(h.dtype)
+                up = _mm(h, lp['w_up'])
+                xc = xc + _mm(gate * up, lp['w_down'])
         return xc, (
             k_rows.reshape(b * width, nkv, hd),
             v_rows.reshape(b * width, nkv, hd),
@@ -773,18 +791,20 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         logits = (x @ llama.output_head(cparams, config))
     else:
         logits = _mm(x, cparams['lm_head'])
-    if sampling is None:
-        preds = logits.argmax(-1).astype(jnp.int32)   # [B, W]
-    else:
-        # Target realizations drawn with the keys plain decode
-        # would use at each position — the maximal-coupling half of
-        # the speculative-sampling rule (serve/sampling/accept.py).
-        allowed = sample_lib.gather_masks(sampling['mask_table'],
-                                          sampling['mask_idx'])
-        preds = sample_lib.verify_targets(
-            logits, sampling['temps'], sampling['top_ps'],
-            sampling['seeds'], pos, allowed)          # [B, W]
-    accepted = accept_tokens(tokens, preds, n_real)   # [B]
+    with jax.named_scope('sampler'):
+        if sampling is None:
+            preds = logits.argmax(-1).astype(jnp.int32)   # [B, W]
+        else:
+            # Target realizations drawn with the keys plain decode
+            # would use at each position — the maximal-coupling half
+            # of the speculative-sampling rule
+            # (serve/sampling/accept.py).
+            allowed = sample_lib.gather_masks(sampling['mask_table'],
+                                              sampling['mask_idx'])
+            preds = sample_lib.verify_targets(
+                logits, sampling['temps'], sampling['top_ps'],
+                sampling['seeds'], pos, allowed)          # [B, W]
+        accepted = accept_tokens(tokens, preds, n_real)   # [B]
     live = n_real > 0
     new_pos = jnp.where(live, pos + accepted + 1, pos)
     new_tok = jnp.where(
@@ -1045,6 +1065,44 @@ def _engine_metrics():
             'Prompt + resume tokens held by the pending queue — '
             'the currency of the max_queued_tokens admission '
             'bound.'),
+        # The scheduler iteration as the unit of account. Unlabelled
+        # on purpose: each is one row of the docs table and one
+        # number to a reader that sums a family over its labels.
+        'iterations': reg.counter(
+            'skytpu_batch_iterations_total',
+            'Scheduler passes that did work (ran a prefill chunk '
+            'or a decode / verify dispatch).'),
+        'iteration_seconds': reg.counter(
+            'skytpu_batch_iteration_seconds_total',
+            'Wall time of the passes that did work, loop top to '
+            'the end of the gauges; passes that end in wake.wait '
+            'are left out.'),
+        'host_gap_seconds': reg.counter(
+            'skytpu_batch_host_gap_seconds_total',
+            'Time inside those passes from the return of a '
+            'blocking device_get (decode, verify, first token) to '
+            'the next enqueue of a prefill, decode, verify, '
+            'first-token or block-copy program. A lower bound of '
+            'device idle: launch latency, the return of device_get '
+            'and the small block-table updates enqueued inside '
+            'the gap are not in it (a half to three quarters of a '
+            'trace\'s idle on the v5e).'),
+        'prefill_chunks': reg.counter(
+            'skytpu_batch_prefill_chunks_total',
+            'Prefill-chunk dispatches (forward_paged calls).'),
+        'prefill_tokens': reg.counter(
+            'skytpu_batch_prefill_tokens_total',
+            'Real prompt (or resume) tokens in those chunks.'),
+        'prefill_bucket_tokens': reg.counter(
+            'skytpu_batch_prefill_bucket_tokens_total',
+            'Tokens those chunks were charged, bucket padding '
+            'included: attempts, against the useful count in '
+            'prefill_tokens_total.'),
+        'decode_dispatches': reg.counter(
+            'skytpu_batch_decode_dispatches_total',
+            'Decode and verify dispatches (decode_steps_paged + '
+            'verify_step_paged calls); decode_tokens_total over it '
+            'is the tokens a dispatch yields.'),
     }
 
 
@@ -1353,6 +1411,12 @@ class BatchingEngine:
         # interleaving contract is asserted against this in tests.
         self.events: 'collections.deque' = collections.deque(
             maxlen=4096)
+        # The iteration account (skytpu_batch_iteration*/host_gap):
+        # _gap_open is the perf_counter instant since which the
+        # device is known to have nothing queued (None while work is
+        # in flight or the loop is parked).
+        self._iter_n = 0
+        self._gap_open: Optional[float] = None
         self.wake = threading.Event()
         self._stop = False
         # Set on engine DEATH (never on clean close): submits after
@@ -2161,6 +2225,7 @@ class BatchingEngine:
                     self.pool.free([src])
                     self._unwind_admission(req, blocks)
                     return
+                self._mark_enqueue()
                 self.caches = self._copy_fn(
                     self.caches, jnp.asarray(src, jnp.int32),
                     jnp.asarray(got[0], jnp.int32))
@@ -2294,35 +2359,43 @@ class BatchingEngine:
             return 0
         bucket = self._chunk_bucket(t0 - off)
         real = min(t0 - off, bucket)
-        if self._prefill_t0[row] is None:
-            self._prefill_t0[row] = time.time()
-        # Slice the chunk straight out of prompt_ids/generated
-        # (the logical prompt is their concatenation, and generated
-        # is static while this row prefills) — concatenating the
-        # whole prompt per chunk would copy O(prompt) on the engine
-        # loop for every chunk of a long prompt.
-        n_p = len(req.prompt_ids)
-        if off + real <= n_p:
-            chunk = req.prompt_ids[off:off + real]
-        elif off >= n_p:
-            chunk = req.generated[off - n_p:off - n_p + real]
-        else:
-            chunk = (req.prompt_ids[off:] +
-                     req.generated[:off + real - n_p])
-        padded = chunk + [0] * (bucket - real)
-        chunk_tokens = jnp.asarray([padded], jnp.int32)
-        logits, self.caches = self._prefill_fn(
-            self.params, chunk_tokens, self.caches,
-            self.block_tables[row],
-            jnp.asarray(off, jnp.int32),
-            jnp.asarray(real, jnp.int32),
-            self.config, self.block_size,
-            *self._adapter_args([self.slot_adapter[row]]))
+        with trace_lib.phase('engine.prefill_chunk', row=row,
+                             bucket=bucket, real=real, offset=off):
+            if self._prefill_t0[row] is None:
+                self._prefill_t0[row] = time.time()
+            # Slice the chunk straight out of prompt_ids/generated
+            # (the logical prompt is their concatenation, and
+            # generated is static while this row prefills) —
+            # concatenating the whole prompt per chunk would copy
+            # O(prompt) on the engine loop for every chunk of a long
+            # prompt.
+            n_p = len(req.prompt_ids)
+            if off + real <= n_p:
+                chunk = req.prompt_ids[off:off + real]
+            elif off >= n_p:
+                chunk = req.generated[off - n_p:off - n_p + real]
+            else:
+                chunk = (req.prompt_ids[off:] +
+                         req.generated[:off + real - n_p])
+            padded = chunk + [0] * (bucket - real)
+            self._mark_enqueue()
+            chunk_tokens = jnp.asarray([padded], jnp.int32)
+            logits, self.caches = self._prefill_fn(
+                self.params, chunk_tokens, self.caches,
+                self.block_tables[row],
+                jnp.asarray(off, jnp.int32),
+                jnp.asarray(real, jnp.int32),
+                self.config, self.block_size,
+                *self._adapter_args([self.slot_adapter[row]]))
+        self._metrics['prefill_chunks'].inc()
+        self._metrics['prefill_tokens'].inc(real)
+        self._metrics['prefill_bucket_tokens'].inc(bucket)
         self.slot_off[row] = off + real
         self._prefill_chunks[row] += 1
         self.events.append(('prefill_chunk', row, off + real, t0))
         if self.slot_off[row] >= t0:
-            self._finish_prefill(row, logits)
+            with trace_lib.phase('engine.first_token', row=row):
+                self._finish_prefill(row, logits)
         return bucket
 
     def _run_prefill_chunks(self) -> bool:
@@ -2471,6 +2544,7 @@ class BatchingEngine:
             if req.grammar is not None:
                 allowed = jnp.asarray(
                     req.grammar.allowed(req.grammar_state))
+            self._mark_enqueue()
             first = int(jax.device_get(self._first_fn(
                 logits, jnp.asarray(req.temperature, jnp.float32),
                 jnp.asarray(req.top_p, jnp.float32),
@@ -2478,7 +2552,9 @@ class BatchingEngine:
                 jnp.asarray(t0 - 1, jnp.int32), allowed)))
         else:
             first = int(jax.device_get(logits)[0].argmax())
-        # The int() above synchronizes, so these are real wall times.
+        # The int() above synchronizes, so these are real wall times
+        # (and the device has nothing queued from here on).
+        self._gap_open = time.perf_counter()
         t_first = time.time()
         resumed = bool(req.generated)
         trace_lib.record_span('batch.prefill',
@@ -2634,35 +2710,68 @@ class BatchingEngine:
         del self.slot_blocks[row][keep:]
         self._set_table_row(row)
 
+    def _decode_rows(self) -> List[int]:
+        return [i for i in range(self.slots)
+                if self.slot_req[i] is not None
+                and self.slot_off[i] >= self.slot_total[i]]
+
     def _dispatch_decode(self) -> bool:
         """One whole-batch dispatch over every row whose prefill is
         complete: a VERIFY dispatch (``verify_step_paged``, width
         draft_k+1) when any row carries a live n-gram draft, the
         plain ``steps_per_dispatch`` decode scan otherwise — mixed
         batches verify and 1-token-decode in the same forward
-        (draft-less rows just pad their lanes to scratch)."""
-        def decode_rows():
-            return [i for i in range(self.slots)
-                    if self.slot_req[i] is not None
-                    and self.slot_off[i] >= self.slot_total[i]]
+        (draft-less rows just pad their lanes to scratch).
 
-        drafts = self._collect_drafts(decode_rows()) \
-            if self.speculative else {}
+        Three phases on the profiler's clock: ``engine.dispatch``
+        (host work up to the return of the enqueue),
+        ``engine.device_wait`` (the blocking ``device_get``) and
+        ``engine.emit`` (tokens to clients, retirement)."""
+        ready = self._decode_rows()
         n = self.steps
-        if any(self.slot_req[i] is not None
-               and self.slot_req[i].grammar is not None
-               for i in decode_rows()):
+        if any(self.slot_req[i].grammar is not None for i in ready):
             # Grammar masks advance HOST-side per emitted token — a
             # multi-step scan cannot re-mask between its steps, so
             # any constrained row forces 1-token dispatches (the
             # structured-decoding throughput cost; unconstrained
             # batches keep the full scan).
             n = 1
+        with trace_lib.phase('engine.dispatch', rows=len(ready),
+                             steps=n):
+            launched = self._launch_dispatch(ready, n)
+        if launched is None:
+            return False
+        active_rows, drafts, outputs, t_dispatch = launched
+        with trace_lib.phase('engine.device_wait',
+                             rows=len(active_rows),
+                             kind='verify' if drafts else 'decode'):
+            host = jax.device_get(outputs)
+        # device_get synchronizes: this is real decode wall time, and
+        # the device has nothing queued from here on.
+        self._gap_open = time.perf_counter()
+        dispatch_s = self._gap_open - t_dispatch
+        self._metrics['decode_dispatches'].inc()
+        with trace_lib.phase('engine.emit', rows=len(active_rows)):
+            if drafts:
+                self._finish_verify(active_rows, drafts, host,
+                                    dispatch_s)
+            else:
+                self._finish_decode(active_rows, n, host, dispatch_s)
+        return True
+
+    def _launch_dispatch(self, ready: List[int], n: int):
+        """Everything up to the return of the enqueue: drafts, block
+        growth, the ``active`` mask, then ``decode_steps_paged`` or
+        ``verify_step_paged``. Returns ``(active_rows, drafts,
+        device outputs, enqueue instant)``, or None when no row is
+        decode-ready."""
+        drafts = self._collect_drafts(ready) \
+            if self.speculative else {}
         # Grow allocations for this dispatch's writes up front;
         # exhaustion preempts the youngest request (possibly a row in
         # this very list, which then simply sits the dispatch out —
         # a preempted row's draft dies with it).
-        for i in decode_rows():
+        for i in ready:
             if self.slot_req[i] is None:
                 # Preempted by an earlier row's growth in this very
                 # loop — it sits the dispatch out.
@@ -2675,13 +2784,13 @@ class BatchingEngine:
                 need = max(need, len(drafts[i]) + 1)
             self._ensure_blocks(
                 i, min(self.slot_len[i] + need, self.max_seq))
-        active_rows = decode_rows()
+        active_rows = self._decode_rows()
         if not active_rows:
-            return False
+            return None
         drafts = {i: d for i, d in drafts.items()
                   if self.slot_req[i] is not None}
         if drafts:
-            return self._run_verify_dispatch(active_rows, drafts)
+            return self._launch_verify(active_rows, drafts)
         # On-demand profiling hook: one "step" per decode dispatch
         # (docs/observability.md, On-demand profiling).
         self._profiler.on_step()
@@ -2697,6 +2806,7 @@ class BatchingEngine:
              and self.slot_left[i] > 0
              for i in range(self.slots)], bool)
         t_dispatch = time.perf_counter()
+        self._mark_enqueue(t_dispatch)
         toks, self.caches, self.pos = self._step_fn(
             self.params, self.tokens, self.caches,
             self.block_tables, self.pos, active, self.config, n,
@@ -2707,11 +2817,12 @@ class BatchingEngine:
             if self.slot_left[i] > 0:
                 self.slot_len[i] = min(self.slot_len[i] + n,
                                        self.max_seq)
-        host_toks = jax.device_get(toks)
-        dispatch_s = time.perf_counter() - t_dispatch
+        return active_rows, drafts, toks, t_dispatch
+
+    def _finish_decode(self, active_rows: List[int], n: int,
+                       host_toks, dispatch_s: float) -> None:
+        """The emission tail of a plain decode dispatch."""
         if dispatch_s > 0:
-            # device_get synchronizes, so this is real decode wall
-            # time for len(active_rows) * n tokens.
             self._metrics['tok_s'].set(
                 len(active_rows) * n / dispatch_s)
         self.events.append(('decode', len(active_rows)))
@@ -2727,7 +2838,6 @@ class BatchingEngine:
                                          t_chunk_start, t_chunk_end)
         if emitted:
             self._metrics['tokens'].inc(emitted)
-        return True
 
     def _emit_tokens(self, row: int, toks, t_start: float,
                      t_end: float) -> int:
@@ -2773,8 +2883,8 @@ class BatchingEngine:
             self._refresh_mask_row(row)
         return row_emitted
 
-    def _run_verify_dispatch(self, active_rows: List[int],
-                             drafts: Dict[int, List[int]]) -> bool:
+    def _launch_verify(self, active_rows: List[int],
+                       drafts: Dict[int, List[int]]):
         """One speculative VERIFY dispatch: every decode-ready row
         rides the same ``verify_step_paged`` forward — rows with a
         draft verify draft+1 positions, draft-less rows decode their
@@ -2785,7 +2895,8 @@ class BatchingEngine:
         attended again, and whole blocks past the committed frontier
         are returned to the pool (``_trim_blocks``). Emission is
         ``preds[0..a]`` — exactly what plain greedy decode would
-        have produced, one forward at a time."""
+        have produced, one forward at a time. This half enqueues
+        the forward; ``_finish_verify`` commits and emits."""
         w = self.draft_k + 1
         toks = [[0] * w for _ in range(self.slots)]
         n_real = [0] * self.slots
@@ -2800,6 +2911,7 @@ class BatchingEngine:
             n_real[i] = 1 + len(d)
         self._profiler.on_step()
         t_dispatch = time.perf_counter()
+        self._mark_enqueue(t_dispatch)
         preds, accepted, self.pos, self.tokens, self.caches = \
             self._verify_fn(
                 self.params, jnp.asarray(toks, jnp.int32),
@@ -2807,8 +2919,15 @@ class BatchingEngine:
                 jnp.asarray(n_real, jnp.int32), self.config, w,
                 self.block_size, *self._adapter_args(),
                 sampling=self._verify_sampling_args(toks, n_real))
-        host_preds, host_acc = jax.device_get((preds, accepted))
-        dispatch_s = time.perf_counter() - t_dispatch
+        return active_rows, drafts, (preds, accepted), t_dispatch
+
+    def _finish_verify(self, active_rows: List[int],
+                       drafts: Dict[int, List[int]], host,
+                       dispatch_s: float) -> None:
+        """Commit and emit a verify dispatch: per row the accepted
+        span of ``preds``, the adaptive draft length, the blocks past
+        the committed frontier back to the pool."""
+        host_preds, host_acc = host
         t_chunk_end = time.time()
         t_chunk_start = t_chunk_end - dispatch_s
         emitted = 0
@@ -2879,7 +2998,6 @@ class BatchingEngine:
                             accepted_total))
         if emitted:
             self._metrics['tokens'].inc(emitted)
-        return True
 
     def _sweep_overload(self) -> None:
         """Iteration-boundary enforcement of cancellation and
@@ -3114,8 +3232,35 @@ class BatchingEngine:
         except BaseException as e:  # pylint: disable=broad-except
             self._fail_all(e)
 
+    def _mark_enqueue(self, now: Optional[float] = None) -> None:
+        """A device program is about to be enqueued: close the host
+        gap that the last blocking ``device_get`` opened, and count
+        it. (A parked loop has no gap open, so adds nothing.)"""
+        if self._gap_open is not None:
+            self._metrics['host_gap_seconds'].inc(
+                (time.perf_counter() if now is None else now)
+                - self._gap_open)
+            self._gap_open = None
+
     def _loop_inner(self) -> None:
         while not self._stop:
+            if not self._iterate():
+                with trace_lib.phase('engine.idle_wait'):
+                    self.wake.wait(timeout=0.5)
+                self.wake.clear()
+
+    def _iterate(self) -> bool:
+        """One pass of the scheduler: sweep, admit, prefill chunks
+        under the token budget, one decode / verify dispatch, gauges.
+        Returns whether it did work (ran a chunk or a dispatch). The
+        ``engine.*`` phases (docs/observability.md) partition the
+        pass on the profiler's clock; the ``skytpu_batch_iteration*``
+        / ``host_gap`` counters account for it on the host's, and
+        count only passes that did work."""
+        t_top = time.perf_counter()
+        self._iter_n += 1
+        with trace_lib.phase('engine.iteration', n=self._iter_n,
+                             queued=len(self.pending)):
             if faults_lib.fire('serve.stall'):
                 # Chaos drill (docs/resilience.md): stall the
                 # scheduler iteration regardless of armed kind so
@@ -3124,12 +3269,27 @@ class BatchingEngine:
                 # then abort them typed and reclaim their blocks.
                 time.sleep(float(os.environ.get(
                     'SKYTPU_SERVE_STALL_SECONDS', '1.0')))
-            self._sweep_overload()
-            self._poll_adapter_loads()
-            self._admit_pending()
-            progressed = self._run_prefill_chunks()
+            with trace_lib.phase('engine.sweep'):
+                self._sweep_overload()
+                self._poll_adapter_loads()
+            with trace_lib.phase('engine.admit',
+                                 queued=len(self.pending)):
+                self._admit_pending()
+            with trace_lib.phase('engine.prefill'):
+                progressed = self._run_prefill_chunks()
             ran = self._dispatch_decode()
-            self._set_gauges()
-            if not progressed and not ran:
-                self.wake.wait(timeout=0.5)
-                self.wake.clear()
+            with trace_lib.phase('engine.gauges'):
+                self._set_gauges()
+            t_end = time.perf_counter()
+        if not (progressed or ran):
+            # Parking in wake.wait: an idle engine accrues nothing.
+            self._gap_open = None
+            return False
+        if self._gap_open is not None:
+            # The gap runs on into the next pass: account for this
+            # pass's part of it now.
+            self._mark_enqueue(t_end)
+            self._gap_open = t_end
+        self._metrics['iterations'].inc()
+        self._metrics['iteration_seconds'].inc(t_end - t_top)
+        return True

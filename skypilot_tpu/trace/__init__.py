@@ -7,6 +7,7 @@ Public surface:
     trace.context_env()            # env stamp for child processes
     trace.format_traceparent() / trace.parse_traceparent(header)
     trace.record_span(...)         # explicit-timestamp emission
+    trace.phase('engine.admit')    # loop phase on the profiler's clock
     trace.collect                  # driver-side assembly/rendering
 """
 from skypilot_tpu.trace import collect
@@ -16,7 +17,8 @@ from skypilot_tpu.trace.tracer import (ENV_CONTEXT, TRACEPARENT_HEADER,
                                        component, context_env,
                                        current, emit_span, enabled,
                                        format_traceparent,
-                                       parse_traceparent, record_span,
+                                       parse_traceparent, phase,
+                                       record_span,
                                        reset_current, reset_sink,
                                        sample_root, set_component,
                                        set_current, sink_dir, span)
@@ -25,7 +27,7 @@ __all__ = [
     'ENV_CONTEXT', 'TRACEPARENT_HEADER', 'Span', 'SpanContext',
     'attach', 'child_context', 'chrome_export', 'collect',
     'component', 'context_env', 'current', 'emit_span', 'enabled',
-    'format_traceparent', 'parse_traceparent',
+    'format_traceparent', 'parse_traceparent', 'phase',
     'record_span', 'reset_current', 'reset_sink', 'sample_root',
     'set_component', 'set_current', 'sink_dir', 'span',
 ]

@@ -46,11 +46,17 @@ included) additionally lands in the in-process Chrome-trace buffer —
 not a second.
 
 ``SKYTPU_TRACE=0`` disables sink writes entirely.
+
+Loop phases (:func:`phase`) are a different tree on a different
+clock: ``jax.profiler.TraceAnnotation`` spans in the profiler's own
+trace, beside the device's operations, recorded only while a profiler
+session is open. They never reach the sink or the ambient context.
 """
 import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, NamedTuple, Optional
@@ -514,3 +520,24 @@ def record_span(name: str, start: float, end: float,
         'pid': os.getpid(),
     })
     return ctx
+
+
+# -- loop phases (profiler clock) -------------------------------------
+
+_NO_PHASE = contextlib.nullcontext()
+
+
+def phase(name: str, **attrs: Any):
+    """A phase of a host loop as a span on the PROFILER's clock
+    (context manager): ``jax.profiler.TraceAnnotation('skytpu.' +
+    name, **attrs)``, written into the same ``.xplane.pb`` as the
+    device's operations while a profiler session is open (``xsky
+    profile``, ``perf.run --trace 1``) and costing a flag test
+    otherwise. ``attrs`` are taken at entry. A process that has not
+    imported jax gets a null context: this module never imports it.
+    Nothing goes to the jsonl sink or the ambient request context —
+    request spans and loop phases are separate trees."""
+    jax = sys.modules.get('jax')
+    if jax is None:
+        return _NO_PHASE
+    return jax.profiler.TraceAnnotation('skytpu.' + name, **attrs)

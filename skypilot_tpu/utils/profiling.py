@@ -32,6 +32,7 @@ import glob
 import gzip
 import json
 import os
+import re
 import tempfile
 import time
 from typing import Any, Dict, Iterator, List, NamedTuple, Optional
@@ -65,11 +66,18 @@ def _trace_files(trace_dir: str) -> List[str]:
         recursive=True))
 
 
+# A loop, branch or call: its device event spans the operations
+# inside it, which the same track lists too.
+_CONTAINER_OP = re.compile(r'%?(while|conditional|call)\b')
+
+
 def summarize_trace(trace_dir: str, top: int = 25,
                     device_only: bool = True) -> List[OpTime]:
     """Aggregate complete ('X') trace events by op name, descending
     total duration. ``device_only`` keeps TPU/GPU tracks and drops
-    host threads."""
+    host threads. Container operations on device tracks (``while``,
+    ``conditional``, ``call``) are left out: their time is their
+    children's, and counting both would count it twice."""
     files = _trace_files(trace_dir)
     if not files:
         raise FileNotFoundError(
@@ -87,8 +95,10 @@ def summarize_trace(trace_dir: str, top: int = 25,
             if ev.get('ph') != 'X':
                 continue
             pname = pids.get(ev.get('pid'), '')
-            if device_only and ('TPU' not in pname and
-                                'GPU' not in pname.upper()):
+            on_device = 'TPU' in pname or 'GPU' in pname.upper()
+            if device_only and not on_device:
+                continue
+            if on_device and _CONTAINER_OP.match(ev['name']):
                 continue
             a = agg[ev['name']]
             a[0] += ev.get('dur', 0) / 1e3  # us -> ms

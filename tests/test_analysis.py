@@ -267,6 +267,34 @@ class TestSeededViolations:
             assert len(findings) >= 2, (rule, messages)
 
 
+    def test_loop_phase_names_are_held_both_ways(self, tmp_path):
+        """``trace.phase`` names (loop phases on the profiler's
+        clock) share the span-name contract: an emitted phase must be
+        documented, and a row of the phases' own sub-table must be
+        emitted somewhere."""
+        files = {'loop.py': '''
+            from skypilot_tpu import trace as trace_lib
+            def f():
+                with trace_lib.phase('engine.secret', rows=1):
+                    with trace_lib.phase('engine.known'):
+                        pass
+        '''}
+        docs = {'observability.md': (
+            "# obs\n## Span-name contract\n| `launch` | x |\n"
+            "### On the profiler's clock (`trace.phase`)\n"
+            "| Span | Around |\n|---|---|\n"
+            "| `engine.known` | x |\n| `engine.ghost` | x |\n"
+            "\n# Bench gate\n| `bench.ghost` | not in the table |\n")}
+        findings = run_fixture(tmp_path, 'span-name-contract', files,
+                               docs)
+        messages = ' | '.join(f.message for f in findings)
+        assert len(findings) == 2, messages
+        assert 'engine.secret' in messages
+        assert 'engine.ghost' in messages
+        assert 'engine.known' not in messages
+        assert 'bench.ghost' not in messages
+
+
 # ---------------------------------------------------------------------
 # 3a. Suppression syntax.
 # ---------------------------------------------------------------------

@@ -817,8 +817,28 @@ class TestSpanNameContractLint:
                          'batch.queue_wait', 'batch.first_token',
                          'jobs.submit', 'jobs.recovery', 'ckpt.save',
                          'train.step', 'agent.rpc', 'agent.run',
-                         'job.run', 'serve.up'):
+                         'job.run', 'serve.up',
+                         # loop phases (trace.phase), profiler clock
+                         'engine.iteration', 'engine.admit',
+                         'engine.prefill_chunk', 'engine.first_token',
+                         'engine.dispatch', 'engine.device_wait',
+                         'engine.emit', 'engine.idle_wait'):
             assert expected in emitted, expected
+
+    def test_documented_loop_phases_are_emitted(self):
+        """The reverse direction, for the sub-table of phases on the
+        profiler's clock: its eleven rows are what the engine's loop
+        emits, no more and no fewer."""
+        from skypilot_tpu.analysis import docs_contract
+        doc = open(os.path.join(os.path.dirname(_pkg_dir()), 'docs',
+                                'observability.md')).read()
+        sect = docs_contract.section(
+            doc, name_checkers.SpanNameContractChecker.PHASE_SECTION)
+        documented = docs_contract.table_col0(sect, r'engine\.[a-z_]+')
+        emitted = {n for n in name_checkers.collect_span_names(
+            _loaded_repo()) if n.startswith('engine.')}
+        assert len(documented) == 11, sorted(documented)
+        assert documented == emitted
 
 
 class TestMetricNameContractLint:
