@@ -93,8 +93,16 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def _dequant_kv(q: jax.Array, scale: Optional[jax.Array],
                 dtype) -> jax.Array:
-    """Lazy dequant right before attention — XLA fuses the multiply
-    into the consumer, so HBM reads stay int8-sized."""
+    """Dequantise a [B, S, Hkv] -scaled int8 view to ``dtype`` for
+    the chunk paths (prefill chunk, multi-token append), whose
+    attention takes float K/V. NOT fused into the consumer on the
+    v5e: PR 25's chip trace shows the decode step's use of this
+    (``convert_multiply_fusion bf16[24,4096,8,128]``) materialised,
+    a quarter of that program's time, so since PR 26 the decode and
+    verify steps read int8 views through
+    ``ops.decode_attention.view_attention`` instead; the prefill
+    chunk's one-row view (4 MB a layer) still comes through here
+    (ROADMAP S5)."""
     if scale is None:
         return q
     with jax.named_scope('kv_dequant'):
@@ -157,40 +165,42 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
     k = attention_ops.apply_rope(k, angles)
 
     # The caller persists only the NEW rows ([B, t, ...]) into the
-    # [L, ...] cache after the layer scan; the slice updates below
-    # exist solely so attention reads this step's keys — emitting the
-    # full updated [B, S] slice as scan output would write the entire
-    # cache to fresh buffers every decoded token (~1 GB/token at 8B,
-    # measured ~3.3 ms of the r3 TPOT).
-    if k_scale is not None:
+    # [L, ...] cache after the layer scan; ``updated`` below exists
+    # solely so the float paths' attention reads this step's keys —
+    # emitting the full updated [B, S] slice as scan output would
+    # write the entire cache to fresh buffers every decoded token
+    # (~1 GB/token at 8B, measured ~3.3 ms of the r3 TPOT).
+    quantized = k_scale is not None
+    if quantized:
         k_rows, ks_rows = _quantize_kv(k)
         v_rows, vs_rows = _quantize_kv(v)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k_rows,
-                                               (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v_rows,
-                                               (0, pos, 0, 0))
-        k_scale = jax.lax.dynamic_update_slice(k_scale, ks_rows,
-                                               (0, pos, 0))
-        v_scale = jax.lax.dynamic_update_slice(v_scale, vs_rows,
-                                               (0, pos, 0))
     else:
         k_rows, v_rows = k, v
         ks_rows = vs_rows = None
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k,
-                                               (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v,
-                                               (0, pos, 0, 0))
 
-    if t == 1 or not prefill:
-        kd = _dequant_kv(k_cache, k_scale, k.dtype)
-        vd = _dequant_kv(v_cache, v_scale, v.dtype)
-    if t == 1:
+    def updated(cache, rows):
+        return jax.lax.dynamic_update_slice(
+            cache, rows, (0, pos) + (0,) * (cache.ndim - 2))
+
+    from skypilot_tpu.ops import decode_attention as da
+    if t == 1 and quantized:
+        # int8 decode step: the cache is read as int8 and the new row
+        # goes to attention as an operand (da.view_attention) — the
+        # paged engine's arithmetic, which the token-equality tests
+        # hold this path to.
+        lengths = jnp.full((b,), 0, jnp.int32) + pos
+        attn = da.view_attention(
+            q[:, 0], k_cache, v_cache, lengths, hd ** -0.5,
+            jnp.swapaxes(k_scale, 1, 2), jnp.swapaxes(v_scale, 1, 2),
+            new=(k_rows[:, 0], v_rows[:, 0], ks_rows[:, 0],
+                 vs_rows[:, 0]))[:, None]
+    elif t == 1:
         # Decode step: length-aware attention over the valid cache
         # prefix (Pallas when opted in, dense masked otherwise).
-        from skypilot_tpu.ops import decode_attention as da
         lengths = jnp.full((b,), 0, jnp.int32) + (pos + 1)
-        attn = da.decode_attention(q[:, 0], kd, vd,
-                                   lengths, hd ** -0.5)[:, None]
+        attn = da.decode_attention(
+            q[:, 0], updated(k_cache, k), updated(v_cache, v),
+            lengths, hd ** -0.5)[:, None]
     elif prefill:
         # Prefill at pos=0: the cache holds exactly this chunk, so
         # causal flash over the LOCAL q/k/v is the whole attention —
@@ -198,10 +208,15 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
         # logits (38 GB at T=4k, B=16, S=4.6k). The cache write
         # above may quantize; attention here reads the exact bf16
         # chunk (quantization error only enters later decode steps).
-        from skypilot_tpu.ops import attention as attention_ops
         attn = attention_ops.flash_attention(q, k, v, causal=True,
                                              scale=hd ** -0.5)
     else:
+        kd = _dequant_kv(updated(k_cache, k_rows),
+                         None if not quantized
+                         else updated(k_scale, ks_rows), k.dtype)
+        vd = _dequant_kv(updated(v_cache, v_rows),
+                         None if not quantized
+                         else updated(v_scale, vs_rows), v.dtype)
         attn = _masked_attention(q, kd, vd, q_pos=pos,
                                  kv_len=pos + t, scale=hd ** -0.5)
     x = x + _mm(attn.reshape(b, t, nh * hd), layer_params['wo'])

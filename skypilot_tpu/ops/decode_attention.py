@@ -42,6 +42,19 @@ idle either way, and no lane dim is ever sliced.
 
 Both entry points fall back to dense jnp references off-TPU (CPU
 tests, virtual meshes) and are numerically tested against them.
+
+The engine's own decode and verify steps go through
+``paged_decode_attention`` -> ``view_attention`` (plain XLA, one
+compiled program): the int8 pool is gathered block by block and read
+AS int8 — codes converted inside the two dots, the K scale applied
+to the scores and the V scale to the probabilities, this step's own
+row an operand, not a pool write. What the v5e's trace showed of the
+form before (PR 25: per-position gathers at 385 GB/s, a bf16 copy of
+the whole padded K and V view, a copy of the layer's pool slice for
+B new rows; 113.8 ms a step at 24 rows x 4,096) and of this one (PR
+26: 48 ms) is in PERF.md. It is still dense over the table width: a
+kernel that walks each row's own blocks is what is left (ROADMAP
+S2).
 """
 import functools
 from typing import Optional, Tuple
@@ -243,6 +256,14 @@ def _decode_attention_pallas(q, k, v, lengths, scale, block_s,
         acc, head_of[None, :, None, None], axis=2)[:, :, 0]
 
 
+def _pallas_takes(k: jax.Array) -> bool:
+    """Opted in, on TPU, and the [B, S, Hkv, hd] view meets the
+    kernel's chunk and lane divisibility."""
+    return (_use_pallas('ATTN') and k.shape[1] % _BLOCK_S == 0 and
+            k.shape[1] >= 2 * _BLOCK_S and
+            (k.shape[2] * k.shape[3]) % 128 == 0)
+
+
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lengths: jax.Array,
                      scale: float) -> jax.Array:
@@ -254,9 +275,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     HBM; elsewhere (or for lane-unaligned shapes) it falls back to
     the dense masked reference.
     """
-    hkv, hd = k.shape[2], k.shape[3]
-    if _use_pallas('ATTN') and k.shape[1] % _BLOCK_S == 0 and \
-            k.shape[1] >= 2 * _BLOCK_S and (hkv * hd) % 128 == 0:
+    if _pallas_takes(k):
         return _decode_attention_pallas(q, k, v, lengths, scale,
                                         _BLOCK_S)
     return _reference_decode_attention(q, k, v, lengths, scale)
@@ -269,121 +288,205 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def paged_gather(pool_flat: jax.Array,
                  gather_idx: jax.Array) -> jax.Array:
-    """Gather rows' logical KV views out of a flattened pool:
-    pool_flat [num_blocks * block_size, ...] indexed by the
-    precomputed flat indices from ``kv_pool.read_indices``
-    ([B, S_pad] -> [B, S_pad, ...]). Scoped ``paged_gather`` in the
+    """Gather rows' logical KV views out of a flattened pool,
+    position by position: pool_flat [num_blocks * block_size, ...]
+    indexed by the precomputed flat indices from
+    ``kv_pool.read_indices`` ([B, S_pad] -> [B, S_pad, ...]). The
+    prefill chunk's form (one row's view); the decode and verify
+    steps use ``gather_blocks``. Scoped ``paged_gather`` in the
     compiled program's ``op_name`` metadata."""
     with jax.named_scope('paged_gather'):
         return jnp.take(pool_flat, gather_idx, axis=0)
+
+
+def gather_blocks(pool: jax.Array,
+                  block_tables: jax.Array) -> jax.Array:
+    """Gather rows' logical KV views out of a pool, block by block:
+    pool [num_blocks, block_size, ...], block_tables [B, MB] ->
+    [B, MB * block_size, ...], the same values in the same order as
+    ``paged_gather(pool_flat, read_indices(block_tables))``, moved in
+    slices of a whole block (16 KB of int8 codes at block 16 x 8
+    heads x 128) where the per-position form moves 1 KB: on the v5e
+    the 24 x 4,096 view's per-position gather ran at 385 GB/s
+    (PR 25's trace), this one at 535-640 (PR 26's). Table entries
+    are always pool blocks, so the index is clipped, not checked."""
+    b, mb = block_tables.shape
+    with jax.named_scope('paged_gather'):
+        view = jnp.take(pool, block_tables, axis=0, mode='clip')
+    return view.reshape(b, mb * pool.shape[1], *pool.shape[2:])
+
+
+def gather_scales(scale_pool: jax.Array,
+                  block_tables: jax.Array) -> jax.Array:
+    """The int8 pool's scales for rows' views, laid out as the
+    scores take them: scale_pool [..., num_blocks, block_size, Hkv]
+    (any leading dims: the decode step passes every layer's at
+    once), block_tables [B, MB] -> float32 [..., B, Hkv, MB *
+    block_size]. A block's scales move as one lane-dense row of
+    block_size x Hkv values (256 B at 16 x 8) where the per-position
+    form moved 16 B slices."""
+    *lead, nb, bs, hkv = scale_pool.shape
+    b, mb = block_tables.shape
+    with jax.named_scope('paged_gather'):
+        rows = jnp.take(scale_pool.reshape(*lead, nb, bs * hkv),
+                        block_tables, axis=len(lead), mode='clip')
+    return jnp.swapaxes(
+        rows.reshape(*lead, b, mb * bs, hkv).astype(jnp.float32),
+        -1, -2)
+
+
+def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                   lengths: jax.Array, scale: float,
+                   k_scale: Optional[jax.Array] = None,
+                   v_scale: Optional[jax.Array] = None,
+                   new=None) -> jax.Array:
+    """Dense masked attention of the decode and verify steps over a
+    per-row view that may hold int8 codes, read as int8.
+
+    q [B, Hq, hd] (one position a row) or [B, W, Hq, hd] (the verify
+    window); k/v [B, S, Hkv, hd], floats, or int8 codes with
+    ``k_scale``/``v_scale`` [B, Hkv, S] (``gather_scales``' layout:
+    it is the scores', [B, W, Hkv, G, S]); lengths [B].
+
+    ``new`` = (k_new, v_new, ks_new, vs_new): this step's own K/V
+    rows ([B, Hkv, hd], or [B, W, Hkv, hd] for a window; scales
+    [B, (W,) Hkv] or None), in the view's type, handed over as an
+    operand instead of being written into the cache first. Then row
+    b attends view positions [0, lengths[b]) and, query j, new rows
+    [0, j]: whatever the view holds at lengths[b] and after (stale
+    rows of a recycled block) is masked. With ``new=None`` the rows
+    are in the view already and query j attends [0, lengths[b] + j),
+    as ``_reference_decode_attention`` does for one position.
+
+    An int8 view is never dequantised as a view: the codes are
+    converted in the dot's operand (exact in bf16), the K scale
+    multiplies the float32 scores and the V scale the float32
+    probabilities before their one cast to q's type; both sums
+    accumulate in float32. That is no coarser than the product
+    ``code x scale`` rounded to bf16 ahead of the dot, which is what
+    ran before PR 26 and what the chip's trace showed materialised:
+    a bf16 [B, S, Hkv, hd] copy of K and of V in every layer of
+    every step, over a quarter of the decode program's time. In the
+    program compiled for the v5e both converts now sit inside the
+    two ``convolution`` fusions, which take the s8 view as operand.
+    """
+    single = q.ndim == 3
+    if single:
+        q = q[:, None]
+        if new is not None:
+            new = tuple(None if r is None else r[:, None]
+                        for r in new)
+    b, w, hq, hd = q.shape
+    s, hkv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, w, hkv, hq // hkv, hd)
+
+    def scores(keys, key_scale):
+        out = jnp.einsum('bwhgd,bshd->bwhgs', qg,
+                         keys.astype(q.dtype),
+                         preferred_element_type=jnp.float32)
+        if key_scale is not None:
+            with jax.named_scope('kv_dequant'):
+                out = out * key_scale.astype(
+                    jnp.float32)[:, None, :, None, :]
+        return out * scale
+
+    def weighted(probs, values, value_scale):
+        if value_scale is not None:
+            with jax.named_scope('kv_dequant'):
+                probs = probs * value_scale.astype(
+                    jnp.float32)[:, None, :, None, :]
+        return jnp.einsum('bwhgs,bshd->bwhgd', probs.astype(q.dtype),
+                          values.astype(q.dtype),
+                          preferred_element_type=jnp.float32)
+
+    j = jnp.arange(w)
+    span = lengths[:, None] + (j[None, :] if new is None else 0)
+    seen = jnp.arange(s)[None, None, :] < span[:, :, None]  # [B,W,S]
+    logits = jnp.where(seen[:, :, None, None, :],
+                       scores(k, k_scale), _NEG_INF)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    if new is not None:
+        k_new, v_new, ks_new, vs_new = new
+        if ks_new is not None:          # [B, W, Hkv] -> [B, Hkv, W]
+            ks_new = jnp.swapaxes(ks_new, 1, 2)
+            vs_new = jnp.swapaxes(vs_new, 1, 2)
+        own = jnp.where((j[None, :] <= j[:, None])[None, :, None,
+                                                   None, :],
+                        scores(k_new, ks_new), _NEG_INF)
+        top = jnp.maximum(top, jnp.max(own, axis=-1, keepdims=True))
+        p_own = jnp.exp(own - top)
+    p = jnp.exp(logits - top)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    if new is not None:
+        total = total + jnp.sum(p_own, axis=-1, keepdims=True)
+    out = weighted(p / total, v, v_scale)
+    if new is not None:
+        out = out + weighted(p_own / total, v_new, vs_new)
+    out = out.astype(q.dtype).reshape(b, w, hq, hd)
+    return out[:, 0] if single else out
+
+
+def _place_new(view, view_scale, new_rows, new_scale, lengths,
+               dtype):
+    """The float view with this step's row in place at
+    ``lengths[b]``: what the opt-in Pallas kernel, which takes one
+    float view and a length, is handed."""
+    if view_scale is not None:
+        view = view.astype(dtype) * jnp.swapaxes(
+            view_scale, 1, 2)[..., None].astype(dtype)
+        new_rows = new_rows.astype(dtype) * new_scale[
+            ..., None].astype(dtype)
+    hit = jnp.arange(view.shape[1])[None, :] == lengths[:, None]
+    return jnp.where(hit[:, :, None, None], new_rows[:, None], view)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array,
                            block_tables: jax.Array,
                            lengths: jax.Array, scale: float,
-                           block_size: int,
                            k_scale: Optional[jax.Array] = None,
-                           v_scale: Optional[jax.Array] = None
-                           ) -> jax.Array:
-    """Single-position decode attention over PAGED caches.
+                           v_scale: Optional[jax.Array] = None,
+                           new=None) -> jax.Array:
+    """Decode (q [B, Hq, hd]) and speculative-verify (q [B, W, Hq,
+    hd]: the row's current token plus its drafted continuation)
+    attention over PAGED caches.
 
-    q [B, Hq, hd]; k_pool/v_pool are one layer's flattened block
-    pool [num_blocks * block_size, Hkv, hd] (int8 codes with
-    ``k_scale``/``v_scale`` [num_blocks * block_size, Hkv] when the
-    pool is quantized); block_tables [B, MB] int32 maps row b's
-    logical block i to a pool block; lengths [B] — row b attends its
-    first ``lengths[b]`` logical positions.
+    k_pool/v_pool are a block pool [num_blocks, block_size, Hkv, hd]
+    (one layer's, or every layer's end to end with the table offset
+    to the layer: a layer's slice taken out of the stacked pool
+    first is a copy of the slice); block_tables [B, MB] int32 maps
+    row b's logical block i to a pool block. An int8 pool comes with
+    ``k_scale``/``v_scale``, the rows' scale views [B, Hkv, MB *
+    block_size] as ``gather_scales`` lays them out (which pool it
+    is, is decided at trace time from their presence). ``lengths``
+    [B] and ``new`` (this step's own rows, not yet in the pool) as
+    in ``view_attention``.
 
-    Gather-based: each row's blocks are gathered into the contiguous
-    [B, MB * block_size, Hkv, hd] view that ``decode_attention``
-    (length-aware Pallas on TPU, dense masked reference elsewhere)
-    already consumes — positions past ``lengths[b]`` gather
-    scratch/stale rows and are masked to -inf before the softmax, so
-    they contribute exactly 0 and the output is bit-identical to the
-    contiguous-cache path. The gather cost scales with the TABLE
-    WIDTH (the longest admissible request), not the pool allocation:
-    the pool holds many requests' blocks, but each row's view only
-    ever touches its own table.
+    Gather-based: each row's blocks are gathered whole
+    (``gather_blocks``) into the contiguous [B, MB * block_size,
+    Hkv, hd] view, codes as codes. Positions past a query's span
+    gather scratch/stale rows and are masked to -inf before the
+    softmax, so rejected-draft garbage and recycled blocks
+    contribute exactly 0. The gather cost scales with the TABLE WIDTH
+    (the longest admissible request), not the pool allocation and
+    not the rows' lengths: a kernel that walks each row's own blocks
+    is ROADMAP S2's remainder. The result equals the contiguous-cache
+    path's to float32 rounding (the new row's term is summed after
+    the view's, not in its place), not bit for bit; the engines'
+    token-for-token tests hold both to the same tokens.
     """
-    from skypilot_tpu.serve import kv_pool as kv_pool_lib
-
-    gidx = kv_pool_lib.read_indices(block_tables, block_size)
-    kd = paged_gather(k_pool, gidx)              # [B, S_pad, Hkv, hd]
-    vd = paged_gather(v_pool, gidx)
-    if k_scale is not None:
-        dtype = q.dtype
-        with jax.named_scope('kv_dequant'):
-            kd = kd.astype(dtype) * paged_gather(
-                k_scale, gidx)[..., None].astype(dtype)
-            vd = vd.astype(dtype) * paged_gather(
-                v_scale, gidx)[..., None].astype(dtype)
+    kd = gather_blocks(k_pool, block_tables)     # [B, S_pad, Hkv, hd]
+    vd = gather_blocks(v_pool, block_tables)
     with jax.named_scope('decode_attention'):
-        return decode_attention(q, kd, vd, lengths, scale)
-
-
-def _reference_verify_attention(q, k, v, lengths, scale):
-    """q [B, W, Hq, hd]; k/v [B, S, Hkv, hd]; lengths [B] — query
-    position j of row b attends keys [0, lengths[b] + j). This is
-    ``_reference_decode_attention`` widened for speculative VERIFY:
-    the W query positions of a row are the base token plus its
-    drafted continuation, so the mask is the single-position length
-    mask plus an intra-draft causal stagger (+j per query). The
-    contraction pattern per (row, position) is identical to the
-    single-position path, so a verify over the TRUE next tokens
-    reproduces plain decode's logits."""
-    b, w, hq, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    groups = hq // hkv
-    qg = q.reshape(b, w, hkv, groups, hd)
-    logits = jnp.einsum('bwhgd,bshd->bwhgs', qg, k,
-                        preferred_element_type=jnp.float32) * scale
-    span = lengths[:, None] + jnp.arange(w)[None, :]      # [B, W]
-    mask = (jnp.arange(s)[None, None, :] <
-            span[:, :, None])                             # [B, W, S]
-    logits = jnp.where(mask[:, :, None, None, :], logits, _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum('bwhgs,bshd->bwhgd', probs.astype(v.dtype), v)
-    return out.reshape(b, w, hq, hd)
-
-
-def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array,
-                           block_tables: jax.Array,
-                           lengths: jax.Array, scale: float,
-                           block_size: int,
-                           k_scale: Optional[jax.Array] = None,
-                           v_scale: Optional[jax.Array] = None
-                           ) -> jax.Array:
-    """Multi-position decode attention over PAGED caches — the
-    speculative-decoding VERIFY widening of
-    ``paged_decode_attention``: q carries W positions per row (the
-    row's current token plus its drafted continuation, KV already
-    written into the row's blocks), and query j of row b attends its
-    first ``lengths[b] + j`` logical positions (intra-draft causal).
-
-    q [B, W, Hq, hd]; k_pool/v_pool one layer's flattened pool
-    [num_blocks * block_size, Hkv, hd] (+ int8 scales); block_tables
-    [B, MB]; lengths [B] is the BASE length (the j=0 query's valid
-    prefix, self included). Reuses the exact gather/mask math of the
-    single-position path: positions past a query's span gather
-    scratch/stale rows and are masked to -inf, so rejected-draft
-    garbage and recycled blocks contribute exactly 0.
-    """
-    from skypilot_tpu.serve import kv_pool as kv_pool_lib
-
-    gidx = kv_pool_lib.read_indices(block_tables, block_size)
-    kd = paged_gather(k_pool, gidx)              # [B, S_pad, Hkv, hd]
-    vd = paged_gather(v_pool, gidx)
-    if k_scale is not None:
-        dtype = q.dtype
-        with jax.named_scope('kv_dequant'):
-            kd = kd.astype(dtype) * paged_gather(
-                k_scale, gidx)[..., None].astype(dtype)
-            vd = vd.astype(dtype) * paged_gather(
-                v_scale, gidx)[..., None].astype(dtype)
-    with jax.named_scope('decode_attention'):
-        return _reference_verify_attention(q, kd, vd, lengths, scale)
+        if q.ndim == 3 and new is not None and _pallas_takes(kd):
+            k_new, v_new, ks_new, vs_new = new
+            return decode_attention(
+                q, _place_new(kd, k_scale, k_new, ks_new, lengths,
+                              q.dtype),
+                _place_new(vd, v_scale, v_new, vs_new, lengths,
+                           q.dtype), lengths + 1, scale)
+        return view_attention(q, kd, vd, lengths, scale, k_scale,
+                              v_scale, new)
 
 
 # ---------------------------------------------------------------------

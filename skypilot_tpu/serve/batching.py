@@ -237,41 +237,37 @@ def decode_steps_rows(params: Params, tokens: jax.Array,
             v = v.reshape(b, 1, nkv, hd)
             q = _rope_rows(q, angles)
             k = _rope_rows(k, angles)
-            # The in-layer cache update exists ONLY so this step's
-            # attention sees the new row; the caller persists the
-            # rows with one merged write per token (emitting full
-            # updated slices as scan outputs rewrote the entire
-            # cache per token — measured ~1.6 ms/token at 1B b16,
-            # the same pathology fixed in models/decode.py).
+            # The caller persists the new rows with one merged write
+            # per token (emitting full updated slices as scan
+            # outputs rewrote the entire cache per token — measured
+            # ~1.6 ms/token at 1B b16, the same pathology fixed in
+            # models/decode.py).
+            from skypilot_tpu.ops import decode_attention as da
             if ks is not None:
-                # int8 KV: quantize the new row, one-hot write codes
-                # AND scales, dequant lazily at the attention read
-                # (XLA fuses; HBM reads stay int8-sized).
+                # int8 KV: quantize the new row and hand it to
+                # attention beside the cache, which is read as int8
+                # (da.view_attention: no in-layer write, no
+                # dequantised copy) — the same arithmetic as the
+                # paged twin, which the token-equality tests hold
+                # this path to.
                 k_rows, ks_rows = decode._quantize_kv(k)
                 v_rows, vs_rows = decode._quantize_kv(v)
-                hit = (jnp.arange(kc.shape[1])[None, :] ==
-                       cur_[:, None])                    # [B, S]
-                kc = jnp.where(hit[:, :, None, None],
-                               k_rows[:, 0][:, None], kc)
-                vc = jnp.where(hit[:, :, None, None],
-                               v_rows[:, 0][:, None], vc)
-                ks = jnp.where(hit[:, :, None],
-                               ks_rows[:, 0][:, None], ks)
-                vs = jnp.where(hit[:, :, None],
-                               vs_rows[:, 0][:, None], vs)
+                attn = da.view_attention(
+                    q[:, 0], kc, vc, cur_, hd ** -0.5,
+                    jnp.swapaxes(ks, 1, 2), jnp.swapaxes(vs, 1, 2),
+                    new=(k_rows[:, 0], v_rows[:, 0], ks_rows[:, 0],
+                         vs_rows[:, 0]))[:, None]
             else:
-                # Per-row cache write: Pallas windowed write when
-                # opted in; otherwise the one-hot full-cache where()
-                # (the JetStream trick to avoid XLA's unvectorized
+                # Per-row cache write so this step's attention sees
+                # the new row: Pallas windowed write when opted in;
+                # otherwise the one-hot full-cache where() (the
+                # JetStream trick to avoid XLA's unvectorized
                 # scatter).
-                from skypilot_tpu.ops import decode_attention as da
                 k_rows, v_rows = k, v
                 ks_rows = vs_rows = None
                 kc, vc = da.cache_write(kc, vc, k[:, 0], v[:, 0],
                                         cur_)
-            kd = decode._dequant_kv(kc, ks, k.dtype)
-            vd = decode._dequant_kv(vc, vs, v.dtype)
-            attn = _attend_rows(q, kd, vd, cur_, hd ** -0.5)
+                attn = _attend_rows(q, kc, vc, cur_, hd ** -0.5)
             xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
             h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
                                 config.norm_offset)
@@ -349,6 +345,37 @@ def decode_steps_rows(params: Params, tokens: jax.Array,
 _lora_gather_delta = decode.lora_gather_delta
 
 
+def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
+    """[L, NB * bs, ...] -> every layer's blocks as ONE pool
+    [L * NB, bs, ...], which layer l reads through its block table
+    offset by l * NB: a layer's slice taken out of the stacked pool
+    first (a scanned input, or an index) is a copy of the slice,
+    75 MB of K and of V a layer at 4,561 blocks."""
+    return flat.reshape(-1, block_size, *flat.shape[2:])
+
+
+def _scale_views(k_scale, v_scale, block_tables: jax.Array,
+                 block_size: int):
+    """Every layer's K and V scales for the rows' views
+    (``decode_attention.gather_scales``), gathered OUTSIDE the layer
+    scan and scanned as ONE array [L, 2, B, Hkv, S] float32 (201 MB
+    at 32 x 24 x 8 x 4,096; 2.7 ms of a 48 ms decode step); None for
+    a bf16 pool. k_scale/v_scale are the flat [L, NB * bs, Hkv]
+    pools. Timed on the v5e (PERF.md, PR 26): scale pools read inside
+    the layer scan cost 7-120 ms a step more — an array of 37-100 MB
+    that rides the layer loop is placed in the compiler's on-chip
+    memory space and evicted and fetched back in every layer (two
+    [L, B, Hkv, S] arrays: 55 ms a step), and the pools'
+    [.., 16, 8] tail reshapes to blocks by a copy (172 ms)."""
+    from skypilot_tpu.ops import decode_attention as da
+    if k_scale is None:
+        return None
+    return jnp.stack([
+        da.gather_scales(
+            sp.reshape(sp.shape[0], -1, block_size, sp.shape[-1]),
+            block_tables) for sp in (k_scale, v_scale)], axis=1)
+
+
 def decode_steps_paged(params: Params, tokens: jax.Array,
                        caches, block_tables: jax.Array,
                        pos: jax.Array, active: jax.Array,
@@ -365,9 +392,15 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
 
     Attention per layer is the gather-based
     ``ops.decode_attention.paged_decode_attention``: row b's logical
-    view is gathered out of the pool and masked to its own length,
-    so recycled-block garbage past the length contributes exactly 0.
-    Writes go through ``kv_pool.write_index`` — parked rows (inactive
+    view of positions [0, pos) is gathered out of the pool block by
+    block and masked to its own length, so recycled-block garbage
+    past the length contributes exactly 0; an int8 pool is read as
+    int8. This step's own K/V row reaches attention as an operand:
+    there is NO in-layer pool write (until PR 26 there was one, "so
+    this step's attention sees the new row"; the chip's trace showed
+    it copying the layer's whole pool slice, 2 x 75 MB in every layer
+    of every step). The pool is written once a token, after the layer
+    scan, through ``kv_pool.write_index`` — parked rows (inactive
     lanes) and overrun positions land in the scratch block, never in
     a block another request owns.
 
@@ -406,7 +439,8 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     b = tokens.shape[0]
     quantized = k_scale is not None  # static at trace
 
-    # Flat [NB * bs, ...] pool views — index math is 1-D flat-slot.
+    # Flat [NB * bs, ...] pool views — write index math is 1-D
+    # flat-slot; attention reads whole blocks (``_all_blocks``).
     kp = k_pool.reshape(nl, nb * bs, nkv, hd)
     vp = v_pool.reshape(nl, nb * bs, nkv, hd)
     ksp = k_scale.reshape(nl, nb * bs, nkv) if quantized else None
@@ -424,10 +458,10 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
 
         def layer(carry_x, scanned):
             xc, cur_ = carry_x
-            # None scale leaves (and a None adapter set) pass
-            # through lax.scan as empty pytrees — one unpack serves
-            # both cache dtypes and both adapter modes.
-            lp, kc, vc, ks, vs, ad = scanned
+            # None scale views (a bf16 pool) and a None adapter set
+            # pass through lax.scan as empty pytrees — one unpack
+            # serves both pool types and both adapter modes.
+            lp, li, sv, ad = scanned
             h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                                 config.norm_offset)
             with jax.named_scope('qkv_proj'):
@@ -450,25 +484,27 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             v = v.reshape(b, 1, nkv, hd)
             q = _rope_rows(q, angles)
             k = _rope_rows(k, angles)
-            if ks is not None:
+            if quantized:
                 k_rows, ks_rows = decode._quantize_kv(k)
                 v_rows, vs_rows = decode._quantize_kv(v)
             else:
                 k_rows, v_rows = k, v
                 ks_rows = vs_rows = None
-            # In-layer write exists ONLY so this step's attention
-            # sees the new row (the caller-visible pool update is the
-            # single merged scatter per token after the layer scan,
-            # same split as decode_steps_rows).
-            with jax.named_scope('kv_write'):
-                kc = kc.at[widx].set(k_rows[:, 0])
-                vc = vc.at[widx].set(v_rows[:, 0])
-                if ks is not None:
-                    ks = ks.at[widx].set(ks_rows[:, 0])
-                    vs = vs.at[widx].set(vs_rows[:, 0])
+            # No in-layer write: the layer's pool slice is a scanned
+            # input, so ``kc.at[widx].set`` copied the whole slice
+            # (75 MB of K and of V at 4,561 blocks, every layer of
+            # every step: 6 % of the step in PR 25's chip trace) for
+            # B new rows. Attention takes this step's rows as an
+            # operand beside the view of positions [0, cur); the one
+            # merged scatter after the layer scan persists them.
+            new = tuple(None if r is None else r[:, 0] for r in
+                        (k_rows, v_rows, ks_rows, vs_rows))
+            ks_view, vs_view = (None, None) if sv is None else sv
             attn = da.paged_decode_attention(
-                q[:, 0], kc, vc, block_tables, cur_ + 1, hd ** -0.5,
-                block_size, k_scale=ks, v_scale=vs)[:, None]
+                q[:, 0], _all_blocks(kp_all, bs),
+                _all_blocks(vp_all, bs), block_tables + li * nb,
+                cur_, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                new=new)[:, None]
             with jax.named_scope('o_proj'):
                 xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
             h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
@@ -483,22 +519,21 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
                     ).astype(h.dtype)
                     up = _mm(h, lp['w_up'])
                     xc = xc + _mm(gate * up, lp['w_down'])
-            return (xc, cur_), (
-                k_rows[:, 0], v_rows[:, 0],
-                None if ks_rows is None else ks_rows[:, 0],
-                None if vs_rows is None else vs_rows[:, 0])
+            return (xc, cur_), new
 
         (x, _), rows = jax.lax.scan(
             layer, (x, cur),
-            (cparams['layers'], kp_all, vp_all, ks_all, vs_all,
+            (cparams['layers'], jnp.arange(nl, dtype=jnp.int32),
+             _scale_views(ks_all, vs_all, block_tables, bs),
              adapters))
         # Persist the new rows: one merged scatter per token into the
         # carried (donated) flat pools.
-        kp_all = kp_all.at[:, widx].set(rows[0])
-        vp_all = vp_all.at[:, widx].set(rows[1])
-        if quantized:
-            ks_all = ks_all.at[:, widx].set(rows[2])
-            vs_all = vs_all.at[:, widx].set(rows[3])
+        with jax.named_scope('kv_write'):
+            kp_all = kp_all.at[:, widx].set(rows[0])
+            vp_all = vp_all.at[:, widx].set(rows[1])
+            if quantized:
+                ks_all = ks_all.at[:, widx].set(rows[2])
+                vs_all = vs_all.at[:, widx].set(rows[3])
         x = llama._rms_norm(x, cparams['final_norm'], config.norm_eps,
                             config.norm_offset)
         if config.tie_embeddings:
@@ -655,14 +690,16 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     first n_real[b] real — padded lanes write scratch and their
     outputs are ignored); caches/block_tables as in
     ``decode_steps_paged``. Drafted K/V is written into the row's
-    blocks UP FRONT (in-layer for same-forward visibility, one
-    merged scatter per layer stack after, same split as the decode
-    twin); a rejection later simply rolls the host-side ``pos`` back
+    blocks UP FRONT (one merged scatter after the layer scan; within
+    the forward the window's rows reach attention as an operand, as
+    in the decode twin); a rejection later simply rolls the
+    host-side ``pos`` back
     so the stale rows are never attended again — no block copying,
     no scatter-undo (the length-masked paged attention makes
     abandoning them free). Attention is
-    ``ops.decode_attention.paged_verify_attention`` with the
-    intra-draft causal mask (query j attends [0, pos+j]).
+    ``ops.decode_attention.paged_decode_attention`` in its
+    [B, W, ...] form with the intra-draft causal mask (query j
+    attends [0, pos+j]).
 
     Returns (preds [B, W] int32, accepted [B] int32, new_pos [B],
     new_tokens [B], caches): ``preds[b, j]`` is the target model's
@@ -697,6 +734,12 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     ksp = k_scale.reshape(nl, nb * bs, nkv) if quantized else None
     vsp = v_scale.reshape(nl, nb * bs, nkv) if quantized else None
 
+    # As in the decode twin: every layer's blocks as one pool read
+    # through tables offset by layer * NB, and the scale views of
+    # all layers gathered once, outside the layer scan.
+    kblocks, vblocks = _all_blocks(kp, bs), _all_blocks(vp, bs)
+    scale_views = _scale_views(ksp, vsp, block_tables, bs)
+
     positions = pos[:, None] + jnp.arange(width,
                                           dtype=jnp.int32)[None, :]
     angles = llama._rope_frequencies(
@@ -710,7 +753,7 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     wflat = widx.reshape(-1)
 
     def layer(xc, scanned):
-        lp, kc, vc, ks, vs, ad = scanned
+        lp, li, sv, ad = scanned
         h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                             config.norm_offset)
         with jax.named_scope('qkv_proj'):
@@ -736,26 +779,23 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         v = v.reshape(b, width, nkv, hd)
         q = _rope_verify(q, angles)
         k = _rope_verify(k, angles)
-        if ks is not None:
+        if quantized:
             k_rows, ks_rows = decode._quantize_kv(k)
             v_rows, vs_rows = decode._quantize_kv(v)
         else:
             k_rows, v_rows = k, v
             ks_rows = vs_rows = None
-        # In-layer write exists ONLY so this forward's attention
-        # sees the whole draft window causally (the caller-visible
-        # pool update is the merged scatter after the layer scan —
-        # same split as the decode twin). Padded lanes collide
-        # harmlessly on the scratch slot.
-        with jax.named_scope('kv_write'):
-            kc = kc.at[wflat].set(k_rows.reshape(b * width, nkv, hd))
-            vc = vc.at[wflat].set(v_rows.reshape(b * width, nkv, hd))
-            if ks is not None:
-                ks = ks.at[wflat].set(ks_rows.reshape(b * width, nkv))
-                vs = vs.at[wflat].set(vs_rows.reshape(b * width, nkv))
-        attn = da.paged_verify_attention(
-            q, kc, vc, block_tables, pos + 1, hd ** -0.5,
-            block_size, k_scale=ks, v_scale=vs)       # [B, W, Hq, hd]
+        # No in-layer write (it copied the layer's whole pool
+        # slice, as in the decode twin): the draft window's own rows
+        # go to attention as an operand, causally among themselves,
+        # beside the view of positions [0, pos); the merged scatter
+        # after the layer scan persists them. A padded lane's row is
+        # seen only by padded lanes, whose outputs are ignored.
+        ks_view, vs_view = (None, None) if sv is None else sv
+        attn = da.paged_decode_attention(
+            q, kblocks, vblocks, block_tables + li * nb, pos,
+            hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+            new=(k_rows, v_rows, ks_rows, vs_rows))   # [B, W, Hq, hd]
         with jax.named_scope('o_proj'):
             xc = xc + _mm(attn.reshape(b, width, nh * hd), lp['wo'])
         h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
@@ -779,12 +819,14 @@ def verify_step_paged(params: Params, tokens: jax.Array,
             else vs_rows.reshape(b * width, nkv))
 
     x, rows = jax.lax.scan(
-        layer, x, (cparams['layers'], kp, vp, ksp, vsp, adapters))
-    kp = kp.at[:, wflat].set(rows[0])
-    vp = vp.at[:, wflat].set(rows[1])
-    if quantized:
-        ksp = ksp.at[:, wflat].set(rows[2])
-        vsp = vsp.at[:, wflat].set(rows[3])
+        layer, x, (cparams['layers'], jnp.arange(nl, dtype=jnp.int32),
+                   scale_views, adapters))
+    with jax.named_scope('kv_write'):
+        kp = kp.at[:, wflat].set(rows[0])
+        vp = vp.at[:, wflat].set(rows[1])
+        if quantized:
+            ksp = ksp.at[:, wflat].set(rows[2])
+            vsp = vsp.at[:, wflat].set(rows[3])
     x = llama._rms_norm(x, cparams['final_norm'], config.norm_eps,
                         config.norm_offset)
     if config.tie_embeddings:

@@ -4,7 +4,7 @@ rollback, adaptive draft length, budget accounting, and the
 prefix-cache x speculation interaction (serve/batching.py
 verify_step_paged / propose_ngram_draft,
 serve/sampling/accept.accept_tokens,
-ops/decode_attention.paged_verify_attention,
+ops/decode_attention.paged_decode_attention ([B, W, ...] form),
 serve/kv_pool.verify_write_indices).
 
 The non-negotiable contract everywhere: spec-on == spec-off ==
