@@ -145,6 +145,8 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
     new_v_scale). Weight math mirrors ``_layer`` (models/llama.py)
     minus LoRA (serving uses merged weights —
     ``parallel/lora.merge_lora``)."""
+    llama.require_plain_stack(
+        config, 'decode._layer_cached (the contiguous-cache decode)')
     b, t, _ = x.shape
     nh, nkv, hd = (config.n_heads, config.n_kv_heads, config.head_dim)
 
@@ -317,6 +319,118 @@ def forward_cached(params: Params, tokens: jax.Array,
                            k_scale=new_ks, v_scale=new_vs)
 
 
+def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
+               attn: jax.Array, lp: Params) -> jax.Array:
+    """What follows attention in a layer of the three PAGED bodies
+    (``forward_paged``, ``batching.decode_steps_paged``,
+    ``batching.verify_step_paged``): the output projection and the
+    MLP, each added to the residual stream. ``xc`` [B, T, D]; ``attn``
+    [B, T, H * hd]. With ``config.sandwich_norms`` each branch's
+    output passes a norm of its own before the add
+    (``attn_out_norm``, ``mlp_out_norm``; scope ``branch_norm``)."""
+    def branch_norm(out, name):
+        if not config.sandwich_norms:
+            return out
+        with jax.named_scope('branch_norm'):
+            return llama._rms_norm(out, lp[name], config.norm_eps,
+                                   config.norm_offset)
+
+    with jax.named_scope('o_proj'):
+        xc = xc + branch_norm(_mm(attn, lp['wo']), 'attn_out_norm')
+    h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
+                        config.norm_offset)
+    with jax.named_scope('mlp'):
+        if config.n_experts:
+            out, _ = llama._moe_mlp(config, h, lp)
+        else:
+            gate = llama.mlp_act(config)(
+                _mm(h, lp['w_gate']).astype(jnp.float32)
+            ).astype(h.dtype)
+            up = _mm(h, lp['w_up'])
+            out = _mm(gate * up, lp['w_down'])
+        return xc + branch_norm(out, 'mlp_out_norm')
+
+
+def looped_stack(config: llama.LlamaConfig, cparams: Params,
+                 x: jax.Array, layer, adapters=None,
+                 last=lambda h: h):
+    """The layer stack of the three paged bodies: a scan of the
+    caller's ``layer`` over the stacked weights, run
+    ``config.loop_passes`` times over the same weights, closed by the
+    final norm after EVERY pass (the normed state goes on into the
+    next), and the exit gate's choice of the pass that is served.
+
+    ``layer(x, lp, entry, ad) -> (x, rows)`` is one layer on weights
+    ``lp`` (and its slice ``ad`` of ``adapters``, None without),
+    reading KV entry ``entry`` = pass x n_layers + layer and
+    returning its new K/V rows. ``last(h)`` cuts the normed state
+    down to the positions whose logits are wanted (a prefill chunk
+    wants one).
+
+    Every pass always runs for every row: a static batch cannot let
+    one row leave early. With ``config.exit_threshold`` = q the pass
+    served is chosen BY VALUE, per position: lam_t = sigmoid(w . h_t
+    + b) on the normed state; p_t = lam_t prod_{j<t} (1 - lam_j);
+    the first t < T whose p_1 + ... + p_t >= q, else T (float32; at
+    the published q = 1 that is the last pass unless a gate
+    saturates). Returns (hidden of the served pass at ``last``'s
+    positions, rows [loop_passes * n_layers, ...]).
+
+    One pass, no gate: one scan over the layers and the final norm
+    on ``last(x)``, the program every other model traced before this
+    function existed."""
+    def layer_scan(xc, first_entry):
+        def body(c, scanned):
+            lp, li, ad = scanned
+            return layer(c, lp, first_entry + li, ad)
+        return jax.lax.scan(
+            body, xc,
+            (cparams['layers'],
+             jnp.arange(config.n_layers, dtype=jnp.int32), adapters))
+
+    def final_norm(h):
+        return llama._rms_norm(h, cparams['final_norm'],
+                               config.norm_eps, config.norm_offset)
+
+    passes, q = config.loop_passes, config.exit_threshold
+    if passes == 1 and q is None:
+        x, rows = layer_scan(x, 0)
+        return final_norm(last(x)), rows
+
+    def one_pass(carry, t):
+        xc, survive, total, picked, served = carry
+        with jax.named_scope('loop_pass'):
+            xc, rows = layer_scan(xc, t * config.n_layers)
+            xc = final_norm(xc)
+        h = last(xc)
+        reached = t == passes - 1
+        if q is not None:
+            with jax.named_scope('exit_gate'):
+                lam = jax.nn.sigmoid(
+                    (h.astype(jnp.float32) @
+                     cparams['exit_gate_w'].astype(jnp.float32)
+                     )[..., 0] +
+                    cparams['exit_gate_b'].astype(jnp.float32)[0])
+                total = total + lam * survive
+                survive = survive * (1.0 - lam)
+                reached = reached | (total >= q)
+        take = ~picked & reached
+        served = jnp.where(take[..., None], h, served)
+        return (xc, survive, total, picked | take, served), rows
+
+    h0 = last(x)
+    lead = h0.shape[:-1]
+    (_, _, _, _, served), rows = jax.lax.scan(
+        one_pass,
+        (x, jnp.ones(lead, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(lead, bool), jnp.zeros_like(h0)),
+        jnp.arange(passes, dtype=jnp.int32))
+    # [passes, n_layers, ...] -> one row per KV entry.
+    rows = jax.tree.map(
+        lambda r: r.reshape(-1, *r.shape[2:]), rows)
+    return served, rows
+
+
 def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
                       b_slots: jax.Array,
                       adapter_idx: jax.Array) -> jax.Array:
@@ -352,7 +466,8 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     static bucket; their K/V writes are redirected to the scratch
     block and their logits discarded). ``pools`` is the engine's
     cache 4-tuple (k, v, k_scale, v_scale) with k/v
-    [L, num_blocks, block_size, Hkv, hd]; ``block_row`` [MB] int32 is
+    [entries, num_blocks, block_size, Hkv, hd]; ``block_row`` [MB]
+    int32 is
     THIS request's block table. ``start``/``real_len`` are traced
     scalars — one executable serves every chunk of every prompt at a
     given bucket T.
@@ -375,6 +490,10 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     seed greedy decoding); earlier chunks' are computed into the same
     cheap [1, 1, vocab] projection and ignored.
 
+    The pools' leading axis ``l`` counts KV entries, one for every
+    pass and layer (``kv_pool.KVBlockPool``); a looped configuration
+    runs the layers ``config.loop_passes`` times (``looped_stack``).
+
     Layer math MIRRORS ``_layer_cached`` (and ``forward_cached``'s
     scan) minus the cache layout — keep the four layer-body variants
     in sync; the engine's token-for-token-equality tests against
@@ -392,6 +511,7 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     quantized = k_scale_pool is not None
     l, nb, bs = k_pool.shape[:3]
     assert bs == block_size, (bs, block_size)
+    assert l == config.kv_entries, (l, config.kv_entries)
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     _, t = tokens.shape
 
@@ -416,12 +536,16 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     gr = kv_pool_lib.read_indices(block_row[None],
                                   block_size)[0]             # [S_pad]
 
-    def body(xc, scanned):
-        if quantized:
-            lp, kc, vc, ks, vs, ad = scanned
-        else:
-            lp, kc, vc, ad = scanned
-            ks = vs = None
+    def layer(xc, lp, entry, ad):
+        # This pass's and layer's KV entry: taken out of the stacked
+        # pools by index, as a scan over them would (at one pass the
+        # entry is the layer).
+        kc, vc, ks, vs = (
+            None if p is None else
+            jax.lax.dynamic_index_in_dim(
+                p, entry, 0, keepdims=False,
+                allow_negative_indices=False)
+            for p in (kp, vp, ksp, vsp))
         h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                             config.norm_offset)
         with jax.named_scope('qkv_proj'):
@@ -489,26 +613,17 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
             attn = _masked_attention(q, kd, vd, q_pos=start,
                                      kv_len=start + real_len,
                                      scale=hd ** -0.5)
-        with jax.named_scope('o_proj'):
-            xc = xc + _mm(attn.reshape(1, t, nh * hd), lp['wo'])
-        h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
-                            config.norm_offset)
-        with jax.named_scope('mlp'):
-            if config.n_experts:
-                moe_out, _ = llama._moe_mlp(config, h, lp)
-                xc = xc + moe_out
-            else:
-                gate = llama.mlp_act(config)(
-                    _mm(h, lp['w_gate']).astype(jnp.float32)
-                ).astype(h.dtype)
-                up = _mm(h, lp['w_up'])
-                xc = xc + _mm(gate * up, lp['w_down'])
+        xc = layer_tail(config, xc, attn.reshape(1, t, nh * hd), lp)
         return xc, ((k_rows[0], v_rows[0], ks_rows[0], vs_rows[0])
                     if quantized else (k_rows[0], v_rows[0]))
 
-    xs = ((cparams['layers'], kp, vp, ksp, vsp, adapters) if quantized
-          else (cparams['layers'], kp, vp, adapters))
-    x, rows = jax.lax.scan(body, x, xs)
+    # Project ONLY the chunk's last real position (start offsets make
+    # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
+    # materialization is the admission cost this path deletes.
+    x_last, rows = looped_stack(
+        config, cparams, x, layer, adapters,
+        last=lambda h: jnp.take(
+            h, jnp.maximum(real_len - 1, 0)[None], axis=1))  # [1,1,D]
     # Persist the chunk's rows with ONE scatter into the (donated)
     # flat pools.
     kp = kp.at[:, gw].set(rows[0])
@@ -516,14 +631,6 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     if quantized:
         ksp = ksp.at[:, gw].set(rows[2])
         vsp = vsp.at[:, gw].set(rows[3])
-
-    # Project ONLY the chunk's last real position (start offsets make
-    # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
-    # materialization is the admission cost this path deletes.
-    x_last = jnp.take(x, jnp.maximum(real_len - 1, 0)[None],
-                      axis=1)                              # [1, 1, D]
-    x_last = llama._rms_norm(x_last, cparams['final_norm'],
-                             config.norm_eps, config.norm_offset)
     if config.tie_embeddings:
         logits = (x_last @ llama.output_head(cparams, config)
                   ).astype(jnp.float32)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from skypilot_tpu import exceptions
 from skypilot_tpu.ops import attention as attention_ops
 
 Params = Dict[str, Any]
@@ -86,6 +87,23 @@ class LlamaConfig:
     # Coefficient on the load-balance aux loss (≈1.0 at perfect
     # balance; Switch Transformer's alpha).
     moe_aux_coef: float = 0.02
+    # ---- Looped layer stack (Ouro / LoopLM). The same ``n_layers``
+    # weights are run ``loop_passes`` times; pass t, layer l keeps
+    # its keys and values in a KV entry of its own, t * n_layers + l
+    # (``kv_entries``), and the final norm closes EVERY pass, the
+    # normed state going on into the next. Served by the paged
+    # engine's three layer bodies only; the dense bodies refuse
+    # (``require_plain_stack``). ----
+    loop_passes: int = 1
+    # A second RMSNorm on each branch's output, ahead of the residual
+    # add (leaves ``attn_out_norm`` / ``mlp_out_norm``).
+    sandwich_norms: bool = False
+    # Exit gate after each pass: lam_t = sigmoid(w . h_t + b) on the
+    # normed state; the pass served is the first whose cumulative
+    # exit probability reaches this threshold, else the last. None:
+    # no gate, the last pass is served. Every pass always runs for
+    # every row (static shapes); the gate selects by value.
+    exit_threshold: Optional[float] = None
 
     def __post_init__(self):
         unknown = set(self.remat_saves.split('+')) - {
@@ -97,12 +115,28 @@ class LlamaConfig:
         if self.mlp_activation not in ('silu', 'gelu_tanh'):
             raise ValueError(
                 f'unknown mlp_activation {self.mlp_activation!r}')
+        if self.loop_passes < 1:
+            raise ValueError(
+                f'loop_passes must be >= 1: {self.loop_passes}')
 
     @property
     def head_dim(self) -> int:
         if self.head_dim_override is not None:
             return self.head_dim_override
         return self.dim // self.n_heads
+
+    @property
+    def kv_entries(self) -> int:
+        """KV caches a token holds: one for every pass and layer.
+        The leading axis of the paged pool (serve/kv_pool.py)."""
+        return self.loop_passes * self.n_layers
+
+    @property
+    def plain_stack(self) -> bool:
+        """Each layer run once, no branch norms, no exit gate: what
+        every layer body of the repo computes."""
+        return (self.loop_passes == 1 and not self.sandwich_norms
+                and self.exit_threshold is None)
 
     def num_params(self) -> int:
         d, v, h = self.dim, self.vocab_size, self.ffn_hidden
@@ -115,8 +149,11 @@ class LlamaConfig:
             mlp + 2 * d)
         if self.qkv_bias:
             per_layer += (nh + 2 * nkv) * hd
+        if self.sandwich_norms:
+            per_layer += 2 * d
         head = 0 if self.tie_embeddings else v * d
-        return v * d + head + self.n_layers * per_layer + d
+        gate = 0 if self.exit_threshold is None else d + 1
+        return v * d + head + self.n_layers * per_layer + d + gate
 
     def num_active_params(self) -> int:
         """Params touched per token (== num_params for dense; for MoE
@@ -171,6 +208,16 @@ CONFIGS: Dict[str, LlamaConfig] = {
         name='mistral-7b', vocab_size=32000, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_hidden=14336,
         rope_theta=10000.0, max_seq_len=8192),
+    # Looped stack (HF ByteDance/Ouro-2.6B config.json: 48 layers run
+    # total_ut_steps = 4 times over shared weights, 16 heads of 128
+    # with no grouping, early_exit_threshold 1.0; published
+    # max_position_embeddings 65,536). KV a token: 192 entries x 2 x
+    # 16 x 128 B of int8 codes + 12,288 B of scales = 798,720 B.
+    'ouro-2.6b': LlamaConfig(
+        name='ouro-2.6b', vocab_size=49152, dim=2048, n_layers=48,
+        n_heads=16, n_kv_heads=16, ffn_hidden=5632,
+        rope_theta=1000000.0, norm_eps=1e-6, max_seq_len=4096,
+        loop_passes=4, sandwich_norms=True, exit_threshold=1.0),
     # MoE family: Mistral attention geometry + 8 routed experts, top-2
     # (HF mistralai/Mixtral-8x7B config.json).
     'mixtral-8x7b': LlamaConfig(
@@ -190,6 +237,12 @@ CONFIGS: Dict[str, LlamaConfig] = {
         name='tiny-moe', vocab_size=512, dim=128, n_layers=2,
         n_heads=4, n_kv_heads=2, ffn_hidden=256, max_seq_len=512,
         dtype=jnp.float32, remat=False, n_experts=4, moe_top_k=2),
+    'tiny-loop': LlamaConfig(
+        name='tiny-loop', vocab_size=512, dim=128, n_layers=2,
+        n_heads=4, n_kv_heads=4, ffn_hidden=256, max_seq_len=512,
+        rope_theta=1000000.0, norm_eps=1e-6, dtype=jnp.float32,
+        remat=False, loop_passes=4, sandwich_norms=True,
+        exit_threshold=1.0),
 }
 
 
@@ -198,6 +251,21 @@ def get_config(name: str, **overrides) -> LlamaConfig:
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg
+
+
+def require_plain_stack(config: LlamaConfig, where: str) -> None:
+    """The dense layer bodies (training forward, contiguous-cache
+    decode) run each layer once with two norms and serve the last
+    state: a looped / sandwich-normed / gated configuration must not
+    silently run as something else there."""
+    if not config.plain_stack:
+        raise exceptions.NotSupportedError(
+            f'{where} does not implement {config.name!r} '
+            f'(loop_passes={config.loop_passes}, sandwich_norms='
+            f'{config.sandwich_norms}, exit_threshold='
+            f'{config.exit_threshold}): only the paged engine '
+            f'(serve/batching.BatchingEngine; serve_model --slots N) '
+            f'runs a looped layer stack')
 
 
 # ---------------------------------------------------------------------
@@ -264,6 +332,14 @@ def init_params(config: LlamaConfig, key: jax.Array,
         params['layers']['bq'] = jnp.zeros((L, nh * hd), dtype)
         params['layers']['bk'] = jnp.zeros((L, nkv * hd), dtype)
         params['layers']['bv'] = jnp.zeros((L, nkv * hd), dtype)
+    if config.sandwich_norms:
+        params['layers']['attn_out_norm'] = norm_init((L, d))
+        params['layers']['mlp_out_norm'] = norm_init((L, d))
+    if config.exit_threshold is not None:
+        # A key of its own: the other leaves keep their seeds.
+        params['exit_gate_w'] = dense(jax.random.fold_in(key, 0x67),
+                                      (d, 1), d)
+        params['exit_gate_b'] = jnp.zeros((1,), dtype)
     if not config.tie_embeddings:
         params['lm_head'] = dense(k_out, (d, config.vocab_size), d)
     return params
@@ -313,6 +389,12 @@ def param_sharding_rules(config: LlamaConfig,
         rules['layers']['bq'] = P(pl, 'tp')
         rules['layers']['bk'] = P(pl, 'tp')
         rules['layers']['bv'] = P(pl, 'tp')
+    if config.sandwich_norms:
+        rules['layers']['attn_out_norm'] = P(pl, None)
+        rules['layers']['mlp_out_norm'] = P(pl, None)
+    if config.exit_threshold is not None:
+        rules['exit_gate_w'] = P(None, None)
+        rules['exit_gate_b'] = P(None)
     if not config.tie_embeddings:
         rules['lm_head'] = P(fs, 'tp')
     return rules
@@ -480,6 +562,7 @@ def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
     partial-manual shard_map), or None to skip them.
     ``act_spec``: the [B, T, D] activation PartitionSpec (so the MoE
     combine restores e.g. the 'sp' sequence sharding)."""
+    require_plain_stack(config, 'llama._layer (the dense forward)')
     b, t, d = x.shape
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
 

@@ -106,7 +106,7 @@ def init_quantized(config: llama.LlamaConfig, key: jax.Array,
         if 'norm' in name:
             return (jnp.zeros(sd.shape, dtype) if config.norm_offset
                     else jnp.ones(sd.shape, dtype))
-        if name in ('bq', 'bk', 'bv'):
+        if name in ('bq', 'bk', 'bv', 'exit_gate_b'):
             return jnp.zeros(sd.shape, dtype)
         # Same per-leaf fan-in rule as init_params' dense(): matmul
         # weights are [..., in, out] (fan_in = shape[-2]); the
@@ -148,8 +148,10 @@ def quantize_params_streamed(params: Params,
             out['layers'][name] = quantize(leaf)
         else:
             out['layers'][name] = cast(jnp.asarray(leaf))
-    for name in ('embed', 'final_norm'):
-        out[name] = cast(jnp.asarray(params[name]))
+    for name in params:
+        if name not in ('layers', 'lm_head'):
+            # embed, final_norm and the exit gate's two leaves.
+            out[name] = cast(jnp.asarray(params[name]))
     if 'lm_head' in params:
         out['lm_head'] = quantize(params['lm_head'])
     return out

@@ -378,7 +378,19 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         for r in new)
     b, w, hq, hd = q.shape
     s, hkv = k.shape[1], k.shape[2]
-    qg = q.reshape(b, w, hkv, hq // hkv, hd)
+    groups = hq // hkv
+    qg = q.reshape(b, w, hkv, groups, hd)
+    # One query row a KV head (no grouping, one position: 16 heads on
+    # 16 KV heads) makes each of the two dots a matrix-vector
+    # product, which the v5e's compiler computes elementwise over a
+    # float32 COPY of the whole view (``convert f32[B x MB, bs, Hkv,
+    # hd]`` of K and of V in every layer, four times the codes'
+    # bytes written and read back: PERF.md, PR 28). A second, zero
+    # query row keeps them matrix products that take the codes as
+    # codes; its output row is dropped below.
+    lone = w * groups == 1
+    if lone:
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, 1), (0, 0)))
 
     def scores(keys, key_scale):
         out = jnp.einsum('bwhgd,bshd->bwhgs', qg,
@@ -422,6 +434,8 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     out = weighted(p / total, v, v_scale)
     if new is not None:
         out = out + weighted(p_own / total, v_new, vs_new)
+    if lone:
+        out = out[:, :, :, :1]
     out = out.astype(q.dtype).reshape(b, w, hq, hd)
     return out[:, 0] if single else out
 

@@ -64,6 +64,11 @@ def main():
                              'admission bounds by actual usage and '
                              'the engine preempts-and-requeues on '
                              'exhaustion (engine.num_blocks)')
+    parser.add_argument('--max-seq', type=int, default=0,
+                        help='positions a decode row can reach (the '
+                             'block table\'s width: the decode step '
+                             'is dense over it); 0 takes the '
+                             'model\'s max_seq_len')
     parser.add_argument('--max-batched-tokens', type=int,
                         default=int(os.environ.get(
                             'SKYTPU_ENGINE_MAX_BATCHED_TOKENS',
@@ -205,6 +210,14 @@ def main():
     device = jax_runtime.device_facts()
     print(jax_runtime.device_line(device), flush=True)
     config = llama.get_config(args.model)
+    if not config.plain_stack and args.slots <= 0:
+        # The serial path is the dense layer body, which refuses a
+        # looped stack on the first request: say so at start-up.
+        parser.error(
+            f'--model {args.model} runs its layers '
+            f'{config.loop_passes} times over {config.kv_entries} KV '
+            f'entries, which only the batching engine implements: '
+            f'pass --slots N')
     ckpt_params = None
     if args.checkpoint_dir:
         from skypilot_tpu.data.checkpoint import CheckpointManager
@@ -304,7 +317,8 @@ def main():
                     f'a JSON list (token id -> string or null), got '
                     f'{type(grammar_vocab).__name__}')
         engine = BatchingEngine(
-            params, config, slots=args.slots, kv_int8=args.kv_int8,
+            params, config, slots=args.slots,
+            max_seq=args.max_seq or None, kv_int8=args.kv_int8,
             block_size=args.block_size,
             num_blocks=args.num_blocks or None,
             max_num_batched_tokens=args.max_batched_tokens,

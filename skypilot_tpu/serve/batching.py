@@ -11,7 +11,8 @@ free slots, and short requests never reserve long-request HBM.
 
 TPU-first design:
 - All shapes static: the engine owns a block pool
-  ``[L, num_blocks, block_size, Hkv, hd]`` (serve/kv_pool.py) plus
+  ``[E, num_blocks, block_size, Hkv, hd]`` (E KV entries, a pass and
+  a layer each: ``kv_pool.KVBlockPool`` says what that axis is) plus
   per-request block-table rows ``[B, max_blocks]``; decode is one
   jitted step for every batch/occupancy composition (block tables and
   occupancy are data, not shape).
@@ -202,6 +203,8 @@ def decode_steps_rows(params: Params, tokens: jax.Array,
 
     Returns (out_tokens [B, num_steps], caches, new_pos).
     """
+    llama.require_plain_stack(
+        config, 'decode_steps_rows (the contiguous-cache decode)')
     k_cache, v_cache, k_scale, v_scale = caches
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
@@ -346,27 +349,29 @@ _lora_gather_delta = decode.lora_gather_delta
 
 
 def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
-    """[L, NB * bs, ...] -> every layer's blocks as ONE pool
-    [L * NB, bs, ...], which layer l reads through its block table
-    offset by l * NB: a layer's slice taken out of the stacked pool
-    first (a scanned input, or an index) is a copy of the slice,
-    75 MB of K and of V a layer at 4,561 blocks."""
+    """[E, NB * bs, ...] -> every KV entry's blocks as ONE pool
+    [E * NB, bs, ...], which entry e (a pass and a layer,
+    ``kv_pool.KVBlockPool``) reads through its block table offset by
+    e * NB: an entry's slice taken out of the stacked pool first (a
+    scanned input, or an index) is a copy of the slice, 75 MB of K
+    and of V a layer at 4,561 blocks."""
     return flat.reshape(-1, block_size, *flat.shape[2:])
 
 
 def _scale_views(k_scale, v_scale, block_tables: jax.Array,
                  block_size: int):
-    """Every layer's K and V scales for the rows' views
+    """Every KV entry's K and V scales for the rows' views
     (``decode_attention.gather_scales``), gathered OUTSIDE the layer
-    scan and scanned as ONE array [L, 2, B, Hkv, S] float32 (201 MB
-    at 32 x 24 x 8 x 4,096; 2.7 ms of a 48 ms decode step); None for
-    a bf16 pool. k_scale/v_scale are the flat [L, NB * bs, Hkv]
-    pools. Timed on the v5e (PERF.md, PR 26): scale pools read inside
-    the layer scan cost 7-120 ms a step more — an array of 37-100 MB
-    that rides the layer loop is placed in the compiler's on-chip
-    memory space and evicted and fetched back in every layer (two
-    [L, B, Hkv, S] arrays: 55 ms a step), and the pools'
-    [.., 16, 8] tail reshapes to blocks by a copy (172 ms)."""
+    scan as ONE array [E, 2, B, Hkv, S] float32 (201 MB at 32 x 24 x
+    8 x 4,096; 2.7 ms of a 48 ms decode step) that the layer body
+    indexes by entry; None for a bf16 pool. k_scale/v_scale are the
+    flat [E, NB * bs, Hkv] pools. Timed on the v5e (PERF.md, PR 26):
+    scale pools read inside the layer scan cost 7-120 ms a step more
+    — an array of 37-100 MB that rides the layer loop is placed in
+    the compiler's on-chip memory space and evicted and fetched back
+    in every layer (two [L, B, Hkv, S] arrays: 55 ms a step), and
+    the pools' [.., 16, 8] tail reshapes to blocks by a copy
+    (172 ms)."""
     from skypilot_tpu.ops import decode_attention as da
     if k_scale is None:
         return None
@@ -387,8 +392,12 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     identical numerics: the per-row [S] slab is replaced by gathers
     and scatters through ``block_tables`` [B, MB] into the shared
     pool ``caches`` = (k, v, k_scale, v_scale) with k/v
-    [L, num_blocks, block_size, Hkv, hd] (int8 + bf16 scales
-    [L, num_blocks, block_size, Hkv] when quantized).
+    [E, num_blocks, block_size, Hkv, hd] (int8 + bf16 scales
+    [E, num_blocks, block_size, Hkv] when quantized; E as
+    ``kv_pool.KVBlockPool`` defines it). The layers run
+    ``config.loop_passes`` times over the same stacked weights
+    (``decode.looped_stack``: scopes ``loop_pass``, ``branch_norm``,
+    ``exit_gate``), pass t, layer l on entry t * n_layers + l.
 
     Attention per layer is the gather-based
     ``ops.decode_attention.paged_decode_attention``: row b's logical
@@ -430,8 +439,9 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     from skypilot_tpu.ops import decode_attention as da
 
     k_pool, v_pool, k_scale, v_scale = caches
-    nl, nb, bs = k_pool.shape[:3]
+    ne, nb, bs = k_pool.shape[:3]
     assert bs == block_size, (bs, block_size)
+    assert ne == config.kv_entries, (ne, config.kv_entries)
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
         params)
@@ -441,10 +451,10 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
 
     # Flat [NB * bs, ...] pool views — write index math is 1-D
     # flat-slot; attention reads whole blocks (``_all_blocks``).
-    kp = k_pool.reshape(nl, nb * bs, nkv, hd)
-    vp = v_pool.reshape(nl, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(nl, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(nl, nb * bs, nkv) if quantized else None
+    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
+    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
+    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
 
     def one_token(carry, _):
         tok, kp_all, vp_all, ks_all, vs_all, cur = carry
@@ -455,13 +465,11 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
         widx = kv_pool_lib.write_index(block_tables, cur,
                                        block_size)      # [B]
+        scale_views = _scale_views(ks_all, vs_all, block_tables, bs)
 
-        def layer(carry_x, scanned):
-            xc, cur_ = carry_x
-            # None scale views (a bf16 pool) and a None adapter set
-            # pass through lax.scan as empty pytrees — one unpack
-            # serves both pool types and both adapter modes.
-            lp, li, sv, ad = scanned
+        def layer(xc, lp, entry, ad):
+            # ``entry``: this pass's and layer's KV entry (at one
+            # pass, the layer); ``ad`` is None without adapters.
             h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                                 config.norm_offset)
             with jax.named_scope('qkv_proj'):
@@ -499,33 +507,21 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             # merged scatter after the layer scan persists them.
             new = tuple(None if r is None else r[:, 0] for r in
                         (k_rows, v_rows, ks_rows, vs_rows))
-            ks_view, vs_view = (None, None) if sv is None else sv
+            ks_view, vs_view = (None, None) if scale_views is None \
+                else jax.lax.dynamic_index_in_dim(
+                    scale_views, entry, 0, keepdims=False,
+                    allow_negative_indices=False)
             attn = da.paged_decode_attention(
                 q[:, 0], _all_blocks(kp_all, bs),
-                _all_blocks(vp_all, bs), block_tables + li * nb,
-                cur_, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                _all_blocks(vp_all, bs), block_tables + entry * nb,
+                cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
                 new=new)[:, None]
-            with jax.named_scope('o_proj'):
-                xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
-            h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
-                                config.norm_offset)
-            with jax.named_scope('mlp'):
-                if config.n_experts:
-                    moe_out, _ = llama._moe_mlp(config, h, lp)
-                    xc = xc + moe_out
-                else:
-                    gate = llama.mlp_act(config)(
-                        _mm(h, lp['w_gate']).astype(jnp.float32)
-                    ).astype(h.dtype)
-                    up = _mm(h, lp['w_up'])
-                    xc = xc + _mm(gate * up, lp['w_down'])
-            return (xc, cur_), new
+            xc = decode.layer_tail(
+                config, xc, attn.reshape(b, 1, nh * hd), lp)
+            return xc, new
 
-        (x, _), rows = jax.lax.scan(
-            layer, (x, cur),
-            (cparams['layers'], jnp.arange(nl, dtype=jnp.int32),
-             _scale_views(ks_all, vs_all, block_tables, bs),
-             adapters))
+        x, rows = decode.looped_stack(config, cparams, x, layer,
+                                      adapters)
         # Persist the new rows: one merged scatter per token into the
         # carried (donated) flat pools.
         with jax.named_scope('kv_write'):
@@ -534,8 +530,6 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             if quantized:
                 ks_all = ks_all.at[:, widx].set(rows[2])
                 vs_all = vs_all.at[:, widx].set(rows[3])
-        x = llama._rms_norm(x, cparams['final_norm'], config.norm_eps,
-                            config.norm_offset)
         if config.tie_embeddings:
             logits = (x @ llama.output_head(cparams, config))
         else:
@@ -563,10 +557,10 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
         one_token, (tokens, kp, vp, ksp, vsp, pos), None,
         length=num_steps)
     out_caches = (
-        kp.reshape(nl, nb, bs, nkv, hd),
-        vp.reshape(nl, nb, bs, nkv, hd),
-        ksp.reshape(nl, nb, bs, nkv) if quantized else None,
-        vsp.reshape(nl, nb, bs, nkv) if quantized else None)
+        kp.reshape(ne, nb, bs, nkv, hd),
+        vp.reshape(ne, nb, bs, nkv, hd),
+        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
+        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
     return toks.swapaxes(0, 1), out_caches, pos
 
 
@@ -720,8 +714,9 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     from skypilot_tpu.ops import decode_attention as da
 
     k_pool, v_pool, k_scale, v_scale = caches
-    nl, nb, bs = k_pool.shape[:3]
+    ne, nb, bs = k_pool.shape[:3]
     assert bs == block_size, (bs, block_size)
+    assert ne == config.kv_entries, (ne, config.kv_entries)
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
         params)
@@ -729,14 +724,14 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     b = tokens.shape[0]
     quantized = k_scale is not None  # static at trace
 
-    kp = k_pool.reshape(nl, nb * bs, nkv, hd)
-    vp = v_pool.reshape(nl, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(nl, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(nl, nb * bs, nkv) if quantized else None
+    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
+    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
+    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
 
-    # As in the decode twin: every layer's blocks as one pool read
-    # through tables offset by layer * NB, and the scale views of
-    # all layers gathered once, outside the layer scan.
+    # As in the decode twin: every entry's blocks as one pool read
+    # through tables offset by entry * NB, and the scale views of
+    # all entries gathered once, outside the layer scan.
     kblocks, vblocks = _all_blocks(kp, bs), _all_blocks(vp, bs)
     scale_views = _scale_views(ksp, vsp, block_tables, bs)
 
@@ -752,8 +747,7 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         block_tables, pos, n_real, width, block_size)  # [B, W]
     wflat = widx.reshape(-1)
 
-    def layer(xc, scanned):
-        lp, li, sv, ad = scanned
+    def layer(xc, lp, entry, ad):
         h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
                             config.norm_offset)
         with jax.named_scope('qkv_proj'):
@@ -791,25 +785,16 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         # beside the view of positions [0, pos); the merged scatter
         # after the layer scan persists them. A padded lane's row is
         # seen only by padded lanes, whose outputs are ignored.
-        ks_view, vs_view = (None, None) if sv is None else sv
+        ks_view, vs_view = (None, None) if scale_views is None \
+            else jax.lax.dynamic_index_in_dim(
+                scale_views, entry, 0, keepdims=False,
+                allow_negative_indices=False)
         attn = da.paged_decode_attention(
-            q, kblocks, vblocks, block_tables + li * nb, pos,
+            q, kblocks, vblocks, block_tables + entry * nb, pos,
             hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
             new=(k_rows, v_rows, ks_rows, vs_rows))   # [B, W, Hq, hd]
-        with jax.named_scope('o_proj'):
-            xc = xc + _mm(attn.reshape(b, width, nh * hd), lp['wo'])
-        h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
-                            config.norm_offset)
-        with jax.named_scope('mlp'):
-            if config.n_experts:
-                moe_out, _ = llama._moe_mlp(config, h, lp)
-                xc = xc + moe_out
-            else:
-                gate = llama.mlp_act(config)(
-                    _mm(h, lp['w_gate']).astype(jnp.float32)
-                ).astype(h.dtype)
-                up = _mm(h, lp['w_up'])
-                xc = xc + _mm(gate * up, lp['w_down'])
+        xc = decode.layer_tail(
+            config, xc, attn.reshape(b, width, nh * hd), lp)
         return xc, (
             k_rows.reshape(b * width, nkv, hd),
             v_rows.reshape(b * width, nkv, hd),
@@ -818,17 +803,13 @@ def verify_step_paged(params: Params, tokens: jax.Array,
             None if vs_rows is None
             else vs_rows.reshape(b * width, nkv))
 
-    x, rows = jax.lax.scan(
-        layer, x, (cparams['layers'], jnp.arange(nl, dtype=jnp.int32),
-                   scale_views, adapters))
+    x, rows = decode.looped_stack(config, cparams, x, layer, adapters)
     with jax.named_scope('kv_write'):
         kp = kp.at[:, wflat].set(rows[0])
         vp = vp.at[:, wflat].set(rows[1])
         if quantized:
             ksp = ksp.at[:, wflat].set(rows[2])
             vsp = vsp.at[:, wflat].set(rows[3])
-    x = llama._rms_norm(x, cparams['final_norm'], config.norm_eps,
-                        config.norm_offset)
     if config.tie_embeddings:
         logits = (x @ llama.output_head(cparams, config))
     else:
@@ -854,10 +835,10 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0],
         tokens[:, 0])
     out_caches = (
-        kp.reshape(nl, nb, bs, nkv, hd),
-        vp.reshape(nl, nb, bs, nkv, hd),
-        ksp.reshape(nl, nb, bs, nkv) if quantized else None,
-        vsp.reshape(nl, nb, bs, nkv) if quantized else None)
+        kp.reshape(ne, nb, bs, nkv, hd),
+        vp.reshape(ne, nb, bs, nkv, hd),
+        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
+        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
     return preds, accepted, new_pos, new_tok, out_caches
 
 
@@ -1140,6 +1121,17 @@ def _engine_metrics():
             'Tokens those chunks were charged, bucket padding '
             'included: attempts, against the useful count in '
             'prefill_tokens_total.'),
+        'loop_passes': reg.counter(
+            'skytpu_batch_loop_passes_total',
+            'Passes over the layer stack run for the tokens in '
+            'decode_tokens_total (config.loop_passes for each: every '
+            'pass runs for every row); over that family it is the '
+            'passes a token costs, 1 for a model whose layers run '
+            'once.'),
+        'kv_token_bytes': reg.gauge(
+            'skytpu_batch_kv_token_bytes',
+            'Resident KV bytes one cached token costs, codes and '
+            'scales over every KV entry (passes x layers).'),
         'decode_dispatches': reg.counter(
             'skytpu_batch_decode_dispatches_total',
             'Decode and verify dispatches (decode_steps_paged + '
@@ -1513,6 +1505,7 @@ class BatchingEngine:
         self._cache_bytes = self.pool.nbytes
         self._metrics['kv_bytes'].set(self._cache_bytes)
         self._metrics['kv_blocks_total'].set(self.pool.usable_blocks)
+        self._metrics['kv_token_bytes'].set(self.pool.token_bytes)
         from skypilot_tpu.utils import profiling as profiling_lib
         self._profiler = profiling_lib.StepProfiler('decode')
         self.thread = threading.Thread(target=self._loop, daemon=True)
@@ -2613,7 +2606,7 @@ class BatchingEngine:
         self.pos = self.pos.at[row].set(t0)
         self.tokens = self.tokens.at[row].set(first)
         self.slot_len[row] = t0
-        self._metrics['tokens'].inc()
+        self._count_tokens(1)
         req.out.put(first)
         req.generated.append(first)
         if req.grammar is not None:
@@ -2879,7 +2872,13 @@ class BatchingEngine:
             emitted += self._emit_tokens(i, host_toks[i][:n],
                                          t_chunk_start, t_chunk_end)
         if emitted:
-            self._metrics['tokens'].inc(emitted)
+            self._count_tokens(emitted)
+
+    def _count_tokens(self, n: int) -> None:
+        """``n`` tokens handed to clients, with the passes over the
+        layer stack that each cost."""
+        self._metrics['tokens'].inc(n)
+        self._metrics['loop_passes'].inc(n * self.config.loop_passes)
 
     def _emit_tokens(self, row: int, toks, t_start: float,
                      t_end: float) -> int:
@@ -3039,7 +3038,7 @@ class BatchingEngine:
         self.events.append(('verify', len(drafts), proposed_total,
                             accepted_total))
         if emitted:
-            self._metrics['tokens'].inc(emitted)
+            self._count_tokens(emitted)
 
     def _sweep_overload(self) -> None:
         """Iteration-boundary enforcement of cancellation and

@@ -7,9 +7,10 @@ reserved all 4608, and admission was bounded by whole free slabs.
 fragmentation gap. This module is the PagedAttention/vLLM answer,
 TPU-native: KV storage is ONE pool of fixed-size blocks
 
-    k/v:    [L, num_blocks, block_size, Hkv, hd]
-    scales: [L, num_blocks, block_size, Hkv]      (int8 pool only)
+    k/v:    [E, num_blocks, block_size, Hkv, hd]
+    scales: [E, num_blocks, block_size, Hkv]      (int8 pool only)
 
+(E = KV entries, one for every pass and layer: see ``KVBlockPool``)
 and each request holds a host-side list of block ids plus a device
 block-table row that maps its logical positions onto pool slots.
 Admission is then bounded by FREE BLOCKS (a token budget), not free
@@ -160,11 +161,22 @@ class KVBlockPool:
 
     ``caches`` is the engine-facing tuple
     ``(k, v, k_scale, v_scale)`` with k/v
-    ``[L, num_blocks, block_size, Hkv, hd]`` (int8 codes + bf16
-    scales ``[L, num_blocks, block_size, Hkv]`` when ``kv_int8``;
+    ``[E, num_blocks, block_size, Hkv, hd]`` (int8 codes + bf16
+    scales ``[E, num_blocks, block_size, Hkv]`` when ``kv_int8``;
     scales are None for a bf16 pool) — the same 4-tuple shape the
     decode step functions carry, so the pool arrays are donated
     through jit like the old slabs were.
+
+    THE LEADING AXIS, here and wherever the engine's docstrings
+    write the pool's shape: E = ``config.kv_entries`` =
+    ``loop_passes x n_layers`` KV entries. A model whose layers run
+    once has one entry a layer (E = L). A looped stack keeps the
+    keys and values of pass t, layer l at entry ``t * n_layers + l``
+    and shares none between passes, while ``params['layers']`` keeps
+    its ``n_layers`` leading axis. A block id names the same slot in
+    every entry, so allocation, prefix hashes, copy-on-write and
+    preemption are per block and know nothing of E; the bytes a
+    block (and so a token) costs scale with it (``token_bytes``).
     """
 
     def __init__(self, config: llama.LlamaConfig, num_blocks: int,
@@ -182,7 +194,7 @@ class KVBlockPool:
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.kv_int8 = kv_int8
-        shape = (config.n_layers, num_blocks, block_size,
+        shape = (config.kv_entries, num_blocks, block_size,
                  config.n_kv_heads, config.head_dim)
         if kv_int8:
             caches = (jnp.zeros(shape, jnp.int8),
@@ -256,6 +268,13 @@ class KVBlockPool:
     def block_bytes(self) -> float:
         """Resident bytes per block (codes + scales)."""
         return self.nbytes / self.num_blocks
+
+    @property
+    def token_bytes(self) -> float:
+        """Resident bytes one cached token costs, over every KV
+        entry (66,560 at 32 entries x 8 heads x 128 int8; 798,720 at
+        192 x 16 x 128)."""
+        return self.block_bytes / self.block_size
 
     def blocks_for(self, tokens: int) -> int:
         """Blocks needed to hold ``tokens`` positions."""
@@ -444,7 +463,7 @@ class KVBlockPool:
 
 def copy_pool_block(caches, src: jax.Array, dst: jax.Array):
     """Copy one block's content ``src`` -> ``dst`` across every
-    layer of the pool 4-tuple — the COPY-ON-WRITE primitive: a
+    KV entry of the pool 4-tuple — the COPY-ON-WRITE primitive: a
     partial-block prefix hit duplicates the cached block into a
     private one, then prefill overwrites from the first divergent
     token. ``src``/``dst`` are traced int32 scalars, so one jitted
