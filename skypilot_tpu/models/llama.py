@@ -551,36 +551,33 @@ def _moe_mlp(config: LlamaConfig, h: jax.Array, layer_params: Params,
     return out, aux
 
 
-def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
-           angles: jax.Array, attn_impl,
-           lora_params: Optional[Params] = None,
-           lora_scale: float = 1.0, mesh=None, act_spec=None):
-    """One transformer block. Returns (y, moe_aux_loss) — the aux is
-    0 for dense configs so the scan carry has one static shape.
-    ``mesh``: a concrete Mesh for the MoE sharding pins, or
-    ``AMBIENT_MESH`` to bind them to the ambient mesh (inside a
-    partial-manual shard_map), or None to skip them.
-    ``act_spec``: the [B, T, D] activation PartitionSpec (so the MoE
-    combine restores e.g. the 'sp' sequence sharding)."""
-    require_plain_stack(config, 'llama._layer (the dense forward)')
-    b, t, d = x.shape
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+_QKV_LEAVES = ('wq', 'wk', 'wv', 'bq', 'bk', 'bv')
+# LoRA factors by how the tp axis meets them (lora_sharding_rules):
+# the B factors are column-parallel like wq/wv, the A factors whole.
+_LORA_COLS = ('wq_b', 'wv_b')
+_LORA_WHOLE = ('wq_a', 'wv_a')
 
-    h = _rms_norm(x, layer_params['attn_norm'], config.norm_eps,
-                  config.norm_offset)
+
+def _qkv_proj(config: LlamaConfig, h: jax.Array, w: Params,
+              lora_params: Optional[Params], lora_scale: float):
+    """h [B, t, D] -> pre-rotation q, k, v [B, t, heads, head_dim]
+    for the heads whose columns ``w`` holds (all of them, or a tp
+    device's share inside a collective product)."""
+    b, t, _ = h.shape
+    hd = config.head_dim
     # ``matmul`` (not @): base projections may be int8-quantized
     # dicts — frozen-base QLoRA trains bf16 adapters over an int8
     # base that would not fit HBM in bf16 (8B on a 16 GB chip).
-    q = matmul(h, layer_params['wq'])
-    k = matmul(h, layer_params['wk'])
-    v = matmul(h, layer_params['wv'])
+    q = matmul(h, w['wq'])
+    k = matmul(h, w['wk'])
+    v = matmul(h, w['wv'])
     if config.qkv_bias:
-        q = q + layer_params['bq']
-        k = k + layer_params['bk']
-        v = v + layer_params['bv']
-    q = q.reshape(b, t, nh, hd)
-    k = k.reshape(b, t, nkv, hd)
-    v = v.reshape(b, t, nkv, hd)
+        q = q + w['bq']
+        k = k + w['bk']
+        v = v + w['bv']
+    q = q.reshape(b, t, -1, hd)
+    k = k.reshape(b, t, -1, hd)
+    v = v.reshape(b, t, -1, hd)
     if lora_params is not None:
         # LoRA on q/v projections (torchtune's default target set for
         # the reference recipe llm/llama-3_1-finetuning/lora.yaml).
@@ -588,8 +585,69 @@ def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
             lora_scale
         dv = ((h @ lora_params['wv_a']) @ lora_params['wv_b']) * \
             lora_scale
-        q = q + dq.reshape(b, t, nh, hd).astype(q.dtype)
-        v = v + dv.reshape(b, t, nkv, hd).astype(v.dtype)
+        q = q + dq.reshape(q.shape).astype(q.dtype)
+        v = v + dv.reshape(v.shape).astype(v.dtype)
+    return q, k, v
+
+
+def _mlp_up(config: LlamaConfig, h: jax.Array, w: Params) -> jax.Array:
+    """The gated MLP up to its down projection's input."""
+    # Save the PRE-activation gate (its backward needs it anyway) and up:
+    # with these two named values kept, backward recomputes only
+    # elementwise ops here, not the two [d, ffn] matmuls. Separate
+    # names so remat_saves can keep just one of them when HBM is
+    # tight.
+    g_pre = checkpoint_name(matmul(h, w['w_gate']), 'mlp_gate')
+    up = checkpoint_name(matmul(h, w['w_up']), 'mlp_up')
+    gate = mlp_act(config)(g_pre.astype(jnp.float32)).astype(h.dtype)
+    return gate * up
+
+
+def _pick(tree: Params, names) -> Params:
+    return {n: tree[n] for n in names if n in tree}
+
+
+def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
+           angles: jax.Array, attn_impl,
+           lora_params: Optional[Params] = None,
+           lora_scale: float = 1.0, mesh=None, act_spec=None,
+           tp_overlap=None):
+    """One transformer block. Returns (y, moe_aux_loss) — the aux is
+    0 for dense configs so the scan carry has one static shape.
+    ``mesh``: a concrete Mesh for the MoE sharding pins, or
+    ``AMBIENT_MESH`` to bind them to the ambient mesh (inside a
+    partial-manual shard_map), or None to skip them.
+    ``act_spec``: the [B, T, D] activation PartitionSpec (so the MoE
+    combine restores e.g. the 'sp' sequence sharding).
+    ``tp_overlap``: a ``parallel.collective_matmul.TpOverlap`` when x
+    is sharded along the sequence over 'tp' (dense configs only): the
+    four tp products then run as collective matmuls, their transfers
+    beside them, and the norms and residual adds on a device's own
+    sequence block."""
+    require_plain_stack(config, 'llama._layer (the dense forward)')
+    b, t, d = x.shape
+
+    h = _rms_norm(x, layer_params['attn_norm'], config.norm_eps,
+                  config.norm_offset)
+    if tp_overlap is None:
+        q, k, v = _qkv_proj(config, h, layer_params, lora_params,
+                            lora_scale)
+    else:
+        # One gathered copy of h feeds all three products and the
+        # LoRA A factors.
+        lora_cols = lora_whole = None
+        if lora_params is not None:
+            lora_cols = _pick(lora_params, _LORA_COLS)
+            lora_whole = _pick(lora_params, _LORA_WHOLE)
+
+        def qkv_of_block(h_blk, cols, whole):
+            w, lora_b = cols
+            lora = None if lora_b is None else {**lora_b, **whole}
+            return _qkv_proj(config, h_blk, w, lora, lora_scale)
+
+        q, k, v = tp_overlap.gather_apply(
+            qkv_of_block, h,
+            (_pick(layer_params, _QKV_LEAVES), lora_cols), lora_whole)
     # RoPE is delegated to the attention impl: the Pallas kernels
     # rotate q/k blocks in VMEM (no separate f32 pass over HBM);
     # non-kernel impls (ring shards, XLA fallback) apply it via
@@ -598,8 +656,12 @@ def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
     k = checkpoint_name(k, 'qkv')
     v = checkpoint_name(v, 'qkv')
     attn = attn_impl(q, k, v, angles)
-    attn = attn.reshape(b, t, nh * hd)
-    x = x + matmul(attn, layer_params['wo'])
+    attn = attn.reshape(b, t, -1)
+    if tp_overlap is None:
+        x = x + matmul(attn, layer_params['wo'])
+    else:
+        x = x + tp_overlap.scatter_apply(matmul, attn,
+                                         layer_params['wo'])
 
     h = _rms_norm(x, layer_params['mlp_norm'], config.norm_eps,
                   config.norm_offset)
@@ -607,16 +669,14 @@ def _layer(config: LlamaConfig, x: jax.Array, layer_params: Params,
         moe_out, aux = _moe_mlp(config, h, layer_params, mesh=mesh,
                                 out_spec=act_spec)
         return x + moe_out, aux
-    # Save the PRE-activation gate (its backward needs it anyway) and up:
-    # with these two named values kept, backward recomputes only
-    # elementwise ops here, not the two [d, ffn] matmuls. Separate
-    # names so remat_saves can keep just one of them when HBM is
-    # tight.
-    g_pre = checkpoint_name(matmul(h, layer_params['w_gate']),
-                            'mlp_gate')
-    up = checkpoint_name(matmul(h, layer_params['w_up']), 'mlp_up')
-    gate = mlp_act(config)(g_pre.astype(jnp.float32)).astype(h.dtype)
-    x = x + matmul(gate * up, layer_params['w_down'])
+    if tp_overlap is None:
+        x = x + matmul(_mlp_up(config, h, layer_params),
+                       layer_params['w_down'])
+    else:
+        x = x + tp_overlap.gather_scatter_apply(
+            functools.partial(_mlp_up, config), matmul, h,
+            _pick(layer_params, ('w_gate', 'w_up')),
+            layer_params['w_down'])
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -677,7 +737,8 @@ def forward_hidden(params: Params, tokens: jax.Array,
                    lora: Optional[Params] = None,
                    lora_scale: float = 1.0,
                    activation_sharding=None,
-                   with_aux: bool = False, mesh=None):
+                   with_aux: bool = False, mesh=None,
+                   tp_overlap=None):
     """tokens [B, T] int32 -> final hidden states [B, T, D]
     (post-final-norm, compute dtype). With ``with_aux`` returns
     (hidden, moe_aux_loss) — the layer-mean load-balance loss
@@ -691,10 +752,21 @@ def forward_hidden(params: Params, tokens: jax.Array,
     activations — used by sequence parallelism to pin the T axis onto
     the 'sp' mesh axis (ring attention supplies the cross-shard
     communication).
+
+    ``tp_overlap``: a ``parallel.collective_matmul.TpOverlap`` from
+    ``build_train_step`` on a mesh with 'tp' > 1 (dense configs
+    only). Where T divides by tp the residual stream between the
+    blocks is sharded along the sequence over 'tp' and each layer's
+    tp products are collective matmuls (``_layer``); the hidden state
+    is gathered once for the head.
     """
     if attn_impl is None:
         attn_impl = default_attn_impl()
     _, t = tokens.shape
+    if tp_overlap is not None:
+        tp_overlap = tp_overlap.for_sequence(t)
+    if tp_overlap is not None:
+        activation_sharding = tp_overlap.seq_sharding
     if positions is None:
         positions = jnp.arange(t)
     angles = _rope_frequencies(config, positions)
@@ -719,7 +791,8 @@ def forward_hidden(params: Params, tokens: jax.Array,
                         mesh=mesh,
                         act_spec=(activation_sharding.spec
                                   if activation_sharding is not None
-                                  else None))
+                                  else None),
+                        tp_overlap=tp_overlap)
         return (y, aux_c + aux), None
 
     body = scan_body
@@ -739,6 +812,11 @@ def forward_hidden(params: Params, tokens: jax.Array,
 
     hidden = _rms_norm(x, cparams['final_norm'], config.norm_eps,
                        config.norm_offset)
+    if tp_overlap is not None:
+        # The head shards the vocabulary over tp and reads every
+        # position: one gather a step, not one a loss chunk.
+        hidden = jax.lax.with_sharding_constraint(
+            hidden, tp_overlap.whole_sharding)
     if with_aux:
         return hidden, aux / config.n_layers
     return hidden
@@ -907,7 +985,8 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
             lora: Optional[Params] = None,
             lora_scale: float = 1.0,
             attn_impl=None,
-            activation_sharding=None, mesh=None) -> jax.Array:
+            activation_sharding=None, mesh=None,
+            tp_overlap=None) -> jax.Array:
     """Causal LM cross-entropy over positions predicting
     ``tokens[:, 1:]`` (mask-aware if batch has 'loss_mask').
 
@@ -929,7 +1008,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
     hidden, moe_aux = forward_hidden(
         params, inputs, config, lora=lora, lora_scale=lora_scale,
         attn_impl=attn_impl, activation_sharding=activation_sharding,
-        with_aux=True, mesh=mesh)
+        with_aux=True, mesh=mesh, tp_overlap=tp_overlap)
     mask = shifted_loss_mask(batch, targets)
 
     # The head is frozen exactly when training LoRA adapters — skip

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.parallel import collective_matmul
 
 Params = llama.Params
 
@@ -343,7 +344,16 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
     (``make_flash_attention_impl``). A ``pp`` axis
     > 1 runs the layer stack as a GPipe pipeline
     (``parallel/pipeline.py``) with ``pipeline_microbatches``
-    microbatches (default 2*pp)."""
+    microbatches (default 2*pp).
+
+    When the mesh has ``tp`` > 1 (and neither ``sp`` nor ``pp``) and
+    the config is dense, the residual stream between the blocks is
+    sharded along the sequence over ``tp`` and each layer's four tp
+    products are collective matmuls whose transfers run beside them
+    (``parallel/collective_matmul.py``), in place of all-reduces that
+    run alone; a batch whose T does not divide by tp takes the
+    all-reduce path. Chosen by the mesh's shape alone; the gauge
+    ``skytpu_train_tp_overlapped_products`` says which was built."""
     if optimizer is None:
         optimizer = default_optimizer()
     is_lora = state_shardings.lora is not None
@@ -358,6 +368,12 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
         attn_impl = None  # one device: the model's own default
     act_sharding = NamedSharding(
         mesh, P(('dp', 'fsdp', 'ep'), 'sp', None)) if use_sp else None
+    tp_overlap = None
+    if (mesh.shape.get('tp', 1) > 1 and not use_sp and not use_pp
+            and not config.n_experts):
+        tp_overlap = collective_matmul.TpOverlap(mesh)
+    collective_matmul.overlapped_gauge().set(
+        collective_matmul.PRODUCTS_PER_LAYER if tp_overlap else 0)
 
     pp_loss = None
     pp_vg = None
@@ -390,7 +406,8 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
                     jax.lax.stop_gradient(state.params), batch, config,
                     lora=lora_p, lora_scale=lora_scale,
                     attn_impl=attn_impl,
-                    activation_sharding=act_sharding, mesh=mesh)
+                    activation_sharding=act_sharding, mesh=mesh,
+                    tp_overlap=tp_overlap)
 
             if pp_vg is not None:
                 loss, grads = pp_vg(state.params, state.lora, batch)
@@ -408,7 +425,8 @@ def build_train_step(config: llama.LlamaConfig, mesh: Mesh,
                     return pp_loss(params, batch)
                 return llama.loss_fn(
                     params, batch, config, attn_impl=attn_impl,
-                    activation_sharding=act_sharding, mesh=mesh)
+                    activation_sharding=act_sharding, mesh=mesh,
+                    tp_overlap=tp_overlap)
 
             if pp_vg is not None:
                 loss, grads = pp_vg(state.params, batch)
