@@ -61,12 +61,10 @@ def serve_main() -> dict:
     else:
         params = llama.init_params(config, jax.random.PRNGKey(0),
                                    dtype=jnp.bfloat16)
-    # Cache rounded to the Pallas decode kernel's chunk size so the
-    # (opt-in) length-aware attention path engages; the padding is
-    # never read. The block size comes from the kernel module — a
-    # hardcoded copy would silently divorce the bench from the
-    # kernel's engagement condition if _BLOCK_S changed.
-    from skypilot_tpu.ops.decode_attention import _BLOCK_S as blk
+    # Cache rounded up to a multiple of 512 positions, at least
+    # 1,024 (what this mode has always allocated; the padding is
+    # never read).
+    blk = 512
     max_seq = max(2 * blk, -(-(prompt_len + gen) // blk) * blk)
     # BENCH_MAX_SEQ: allocate a LARGER cache than the request needs —
     # the slack regime continuous batching lives in (slot caches are

@@ -1,9 +1,9 @@
 """serve-jit-prng: randomness in the serve plane's compiled steps
-comes ONLY from ``serve/sampling/``.
+comes ONLY from ``ops/sampling/``.
 
 The sampling subsystem's batch-invariance contract (docs/sampling.md)
 holds because every draw is keyed by ``(request_seed, absolute
-position)`` through ``serve/sampling/prng.row_key`` — a pure function
+position)`` through ``ops/sampling/prng.row_key`` — a pure function
 of the request, never of the batch. Any other PRNG construction
 inside a jitted serve step reintroduces exactly the failure modes the
 subsystem removed: a ``jax.random.PRNGKey``/``split`` chain advances
@@ -12,12 +12,15 @@ dispatch history), and host RNG (``random``, ``numpy.random``,
 ``os.urandom``, ``secrets``) inside a trace runs ONCE at trace time —
 every subsequent step silently reuses the first draw.
 
-Scope: ``serve/`` excluding ``serve/sampling/`` (the one module
-allowed to build counter-based keys). Like blocking-in-jit, the
-checker finds jit roots (decorator, ``partial(jax.jit, ...)``, and
-``jax.jit(fn)`` call forms) and walks the same-module call graph to a
-fixpoint, so a jitted step that reaches randomness through a local
-helper is still caught.
+Scope: ``serve/``, and the paged engine's model steps in
+``models/decode.py`` (``_STEP_ROOTS``: the scheduler jits them from
+``serve/batching.py``, and this checker sees one file at a time, so
+they are roots by name). ``ops/sampling/`` is the one package allowed
+to build counter-based keys and is outside the scope. Like
+blocking-in-jit, the checker finds jit roots (decorator,
+``partial(jax.jit, ...)``, and ``jax.jit(fn)`` call forms) and walks
+the same-module call graph to a fixpoint, so a jitted step that
+reaches randomness through a local helper is still caught.
 """
 import ast
 from typing import Dict, Iterable, List, Set, Tuple
@@ -25,7 +28,9 @@ from typing import Dict, Iterable, List, Set, Tuple
 from skypilot_tpu.analysis import core
 
 _SCOPE = 'serve/'
-_EXEMPT = 'serve/sampling/'
+_STEP_FILE = 'models/decode.py'
+_STEP_ROOTS = ('forward_paged', 'decode_steps_paged',
+               'verify_step_paged')
 _JIT_NAMES = ('jax.jit', 'jax.experimental.shard_map.shard_map')
 _JIT_SUFFIXES = ('.shard_map',)
 
@@ -51,21 +56,25 @@ class ServeJitPrngChecker(core.Checker):
     rule = 'serve-jit-prng'
     description = ('PRNG construction (jax.random.*, host RNG) '
                    'reachable inside jitted serve-plane steps outside '
-                   'serve/sampling/ — randomness there must flow '
+                   'ops/sampling/ — randomness there must flow '
                    'through the counter-based (seed, position) keys '
                    'or batch invariance breaks.')
 
     def check_file(self, ctx: 'core.FileContext'
                    ) -> Iterable['core.Finding']:
+        steps = ctx.rel.endswith(_STEP_FILE)
         in_scope = ctx.rel.startswith(_SCOPE) or f'/{_SCOPE}' in ctx.rel
-        exempt = ctx.rel.startswith(_EXEMPT) or f'/{_EXEMPT}' in ctx.rel
-        if not in_scope or exempt:
+        if not in_scope and not steps:
             return
         funcs: Dict[str, ast.AST] = {
             node.name: node for node in ast.walk(ctx.tree)
             if isinstance(node, (ast.FunctionDef,
                                  ast.AsyncFunctionDef))}
-        roots = self._jit_roots(ctx, funcs)
+        if steps:
+            roots = [(funcs[name], f'{name}, jitted by the engine')
+                     for name in _STEP_ROOTS if name in funcs]
+        else:
+            roots = self._jit_roots(ctx, funcs)
         if not roots:
             return
         graph: Dict[str, Set[str]] = {}
@@ -144,7 +153,7 @@ class ServeJitPrngChecker(core.Checker):
                         call.col_offset + 1,
                         f'{qual}() is reachable inside a jitted '
                         f'serve step ({via}) — serve-plane '
-                        'randomness must come from serve/sampling/ '
+                        'randomness must come from ops/sampling/ '
                         'counter-based (seed, position) keys; a key '
                         'chain or host RNG here breaks batch '
                         'invariance')

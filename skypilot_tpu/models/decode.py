@@ -16,8 +16,17 @@ TPU-first design:
   (``dynamic_update_slice``) and donated by the caller's jit.
 - Decode attention is a plain masked einsum: at q-length 1 the MXU
   tile is tiny either way and flash's block machinery buys nothing.
+
+The paged engine's three model steps live here too, below the
+scheduler that jits them (``serve/batching.py``): ``forward_paged``
+(a prefill chunk), ``decode_steps_paged`` and ``verify_step_paged``.
+Their layer is ``layer_head`` -> the body's own attention over its
+own view of the pool -> ``layer_tail``, under ``looped_stack``; the
+pool's format and index arithmetic are ``ops/decode_attention.py``'s.
+Nothing here imports the serve package, which is above it.
 """
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -25,6 +34,10 @@ import jax.numpy as jnp
 
 from skypilot_tpu.models import llama
 from skypilot_tpu.models.quant import matmul as _mm
+from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.ops import decode_attention as da
+from skypilot_tpu.ops.sampling import sample as sample_lib
+from skypilot_tpu.ops.sampling.accept import accept_tokens
 
 Params = Dict[str, Any]
 _NEG_INF = -1e30
@@ -162,7 +175,6 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
     q = q.reshape(b, t, nh, hd)
     k = k.reshape(b, t, nkv, hd)
     v = v.reshape(b, t, nkv, hd)
-    from skypilot_tpu.ops import attention as attention_ops
     q = attention_ops.apply_rope(q, angles)
     k = attention_ops.apply_rope(k, angles)
 
@@ -184,7 +196,6 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
         return jax.lax.dynamic_update_slice(
             cache, rows, (0, pos) + (0,) * (cache.ndim - 2))
 
-    from skypilot_tpu.ops import decode_attention as da
     if t == 1 and quantized:
         # int8 decode step: the cache is read as int8 and the new row
         # goes to attention as an operand (da.view_attention) — the
@@ -269,7 +280,6 @@ def forward_cached(params: Params, tokens: jax.Array,
 
     x = cparams['embed'][tokens]
     if config.scale_embeddings:
-        import math
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
 
     quantized = cache.quantized
@@ -319,11 +329,102 @@ def forward_cached(params: Params, tokens: jax.Array,
                            k_scale=new_ks, v_scale=new_vs)
 
 
+def rope(x: jax.Array, angles: jax.Array) -> jax.Array:
+    """Rotate-half RoPE of the three paged bodies: x [B, T, H, D];
+    angles float32 [..., T, D/2], broadcast over x's leading axes —
+    [T, D/2] for one request's chunk, [B, T, D/2] where each row
+    stands at positions of its own (T = 1 for a decode step). The
+    arithmetic is ``ops.attention.apply_rope``'s, which the training
+    path keeps for its one layout."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    # To x's rank in one step: leading axes angles lacks, and heads.
+    to_x = (None,) * (x.ndim - 1 - angles.ndim) + (..., None,
+                                                   slice(None))
+    cos = jnp.cos(angles)[to_x]
+    sin = jnp.sin(angles)[to_x]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+        axis=-1).astype(x.dtype)
+
+
+def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
+                      b_slots: jax.Array,
+                      adapter_idx: jax.Array) -> jax.Array:
+    """Per-row LoRA delta for mixed-adapter batches (the
+    S-LoRA/Punica gather, serve/adapters/): row ``b`` picks ITS
+    adapter's stacked factors by slot index and applies
+    ``(h @ A) @ B`` — one einsum pair serves every adapter in the
+    batch. ``h`` [B, T, d]; ``a_slots`` [C+1, d, R]; ``b_slots``
+    [C+1, R, out]; ``adapter_idx`` [B] int32, 0 = the reserved
+    all-zeros slot so base-model rows get a delta of exactly 0.
+    float32 accumulation, cast by the caller. Per-row math only — a
+    row's output is independent of its batch-mates, which is the
+    mixed-vs-alone exactness contract the adapter tests assert."""
+    with jax.named_scope('lora_delta'):
+        a = a_slots[adapter_idx]                    # [B, d, R]
+        bm = b_slots[adapter_idx]                   # [B, R, out]
+        hf = h.astype(jnp.float32)
+        mid = jnp.einsum('btd,bdr->btr', hf, a)
+        return jnp.einsum('btr,bro->bto', mid, bm)
+
+
+def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
+               ad, adapter_idx, angles: jax.Array, quantized: bool):
+    """What precedes attention in a layer of the three PAGED bodies
+    (``forward_paged``, ``decode_steps_paged``,
+    ``verify_step_paged``; ``layer_tail`` is its complement): the
+    attention norm, the three projections (scope ``qkv_proj``), the
+    row-gathered LoRA attach on q and v (``ad`` is this layer's slice
+    of the resident adapters, None without; every body MUST attach
+    the identical delta, or prefill would write KV that decode's
+    arithmetic does not imply and verify would accept drafts against
+    a different model), ``qkv_bias``, the reshape to heads, RoPE
+    (``rope``: ``angles`` in any of its layouts), and the new rows in
+    the pool's type.
+
+    ``xc`` [B, T, D]. Returns (q [B, T, H, hd], k, v [B, T, Hkv,
+    hd], rows): ``rows`` = (k_rows, v_rows, ks_rows, vs_rows) is what
+    the pool stores of k and v — int8 codes and bf16 scales [B, T,
+    Hkv] for a ``quantized`` pool, else k and v themselves and None
+    scales. What each body does with them differs and stays with the
+    body."""
+    b, t, _ = xc.shape
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
+                        config.norm_offset)
+    with jax.named_scope('qkv_proj'):
+        q = _mm(h, lp['wq'])
+        k = _mm(h, lp['wk'])
+        v = _mm(h, lp['wv'])
+    if ad is not None:
+        q = q + lora_gather_delta(
+            h, ad['wq_a'], ad['wq_b'], adapter_idx).astype(q.dtype)
+        v = v + lora_gather_delta(
+            h, ad['wv_a'], ad['wv_b'], adapter_idx).astype(v.dtype)
+    if config.qkv_bias:
+        q = q + lp['bq']
+        k = k + lp['bk']
+        v = v + lp['bv']
+    q = q.reshape(b, t, nh, hd)
+    k = k.reshape(b, t, nkv, hd)
+    v = v.reshape(b, t, nkv, hd)
+    q = rope(q, angles)
+    k = rope(k, angles)
+    if quantized:
+        k_rows, ks_rows = _quantize_kv(k)
+        v_rows, vs_rows = _quantize_kv(v)
+    else:
+        k_rows, v_rows = k, v
+        ks_rows = vs_rows = None
+    return q, k, v, (k_rows, v_rows, ks_rows, vs_rows)
+
+
 def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
                attn: jax.Array, lp: Params) -> jax.Array:
     """What follows attention in a layer of the three PAGED bodies
-    (``forward_paged``, ``batching.decode_steps_paged``,
-    ``batching.verify_step_paged``): the output projection and the
+    (``forward_paged``, ``decode_steps_paged``,
+    ``verify_step_paged``; ``layer_head`` is its complement): the
+    output projection and the
     MLP, each added to the residual stream. ``xc`` [B, T, D]; ``attn``
     [B, T, H * hd]. With ``config.sandwich_norms`` each branch's
     output passes a norm of its own before the add
@@ -431,25 +532,36 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
     return served, rows
 
 
-def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
-                      b_slots: jax.Array,
-                      adapter_idx: jax.Array) -> jax.Array:
-    """Per-row LoRA delta for mixed-adapter batches (the
-    S-LoRA/Punica gather, serve/adapters/): row ``b`` picks ITS
-    adapter's stacked factors by slot index and applies
-    ``(h @ A) @ B`` — one einsum pair serves every adapter in the
-    batch. ``h`` [B, T, d]; ``a_slots`` [C+1, d, R]; ``b_slots``
-    [C+1, R, out]; ``adapter_idx`` [B] int32, 0 = the reserved
-    all-zeros slot so base-model rows get a delta of exactly 0.
-    float32 accumulation, cast by the caller. Per-row math only — a
-    row's output is independent of its batch-mates, which is the
-    mixed-vs-alone exactness contract the adapter tests assert."""
-    with jax.named_scope('lora_delta'):
-        a = a_slots[adapter_idx]                    # [B, d, R]
-        bm = b_slots[adapter_idx]                   # [B, R, out]
-        hf = h.astype(jnp.float32)
-        mid = jnp.einsum('btd,bdr->btr', hf, a)
-        return jnp.einsum('btr,bro->bto', mid, bm)
+def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
+    """[E, NB * bs, ...] -> every KV entry's blocks as ONE pool
+    [E * NB, bs, ...], which entry e (a pass and a layer,
+    ``kv_pool.KVBlockPool``) reads through its block table offset by
+    e * NB: an entry's slice taken out of the stacked pool first (a
+    scanned input, or an index) is a copy of the slice, 75 MB of K
+    and of V a layer at 4,561 blocks."""
+    return flat.reshape(-1, block_size, *flat.shape[2:])
+
+
+def _scale_views(k_scale, v_scale, block_tables: jax.Array,
+                 block_size: int):
+    """Every KV entry's K and V scales for the rows' views
+    (``decode_attention.gather_scales``), gathered OUTSIDE the layer
+    scan as ONE array [E, 2, B, Hkv, S] float32 (201 MB at 32 x 24 x
+    8 x 4,096; 2.7 ms of a 48 ms decode step) that the layer body
+    indexes by entry; None for a bf16 pool. k_scale/v_scale are the
+    flat [E, NB * bs, Hkv] pools. Timed on the v5e (PERF.md, PR 26):
+    scale pools read inside the layer scan cost 7-120 ms a step more
+    — an array of 37-100 MB that rides the layer loop is placed in
+    the compiler's on-chip memory space and evicted and fetched back
+    in every layer (two [L, B, Hkv, S] arrays: 55 ms a step), and
+    the pools' [.., 16, 8] tail reshapes to blocks by a copy
+    (172 ms)."""
+    if k_scale is None:
+        return None
+    return jnp.stack([
+        da.gather_scales(
+            sp.reshape(sp.shape[0], -1, block_size, sp.shape[-1]),
+            block_tables) for sp in (k_scale, v_scale)], axis=1)
 
 
 def forward_paged(params: Params, tokens: jax.Array, pools,
@@ -467,8 +579,8 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     block and their logits discarded). ``pools`` is the engine's
     cache 4-tuple (k, v, k_scale, v_scale) with k/v
     [entries, num_blocks, block_size, Hkv, hd]; ``block_row`` [MB]
-    int32 is
-    THIS request's block table. ``start``/``real_len`` are traced
+    int32 is THIS request's block table. ``start``/``real_len`` are
+    traced
     scalars — one executable serves every chunk of every prompt at a
     given bucket T.
 
@@ -494,19 +606,20 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     pass and layer (``kv_pool.KVBlockPool``); a looped configuration
     runs the layers ``config.loop_passes`` times (``looped_stack``).
 
-    Layer math MIRRORS ``_layer_cached`` (and ``forward_cached``'s
-    scan) minus the cache layout — keep the four layer-body variants
-    in sync; the engine's token-for-token-equality tests against
-    ``greedy_generate`` are the drift alarm. int8 pools: within-chunk
-    attention reads the exact bf16 rows (spliced below), but a LATER
-    chunk reads earlier chunks' int8 round trip — exact equality with
-    the dense int8 path therefore holds for single-chunk prompts
-    (multi-chunk tracks closely; see the engine docstring caveat).
+    The layer is ``layer_head`` -> this body's own attention over
+    its own view -> ``layer_tail``, as in ``decode_steps_paged`` and
+    ``verify_step_paged``; what is written three times is what
+    differs (here: a chunk written, then gathered, with the chunk's
+    exact rows spliced in). The dense ``_layer_cached`` is still a
+    body of its own; ``tests/test_paged_bodies.py`` (the three agree
+    on one position) and the engine's token-for-token-equality tests
+    against ``greedy_generate`` are the drift alarm. int8 pools:
+    within-chunk attention reads the exact bf16 rows (spliced below),
+    but a LATER chunk reads earlier chunks' int8 round trip — exact
+    equality with the dense int8 path therefore holds for
+    single-chunk prompts (multi-chunk tracks closely; see the engine
+    docstring caveat).
     """
-    from skypilot_tpu.ops import attention as attention_ops
-    from skypilot_tpu.ops import decode_attention as da
-    from skypilot_tpu.serve import kv_pool as kv_pool_lib
-
     k_pool, v_pool, k_scale_pool, v_scale_pool = pools
     quantized = k_scale_pool is not None
     l, nb, bs = k_pool.shape[:3]
@@ -522,7 +635,6 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     angles = llama._rope_frequencies(config, positions)
     x = cparams['embed'][tokens]
     if config.scale_embeddings:
-        import math
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
 
     # Flat [NB * bs, ...] pool views; write/read index vectors are
@@ -531,10 +643,9 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     vp = v_pool.reshape(l, nb * bs, nkv, hd)
     ksp = k_scale_pool.reshape(l, nb * bs, nkv) if quantized else None
     vsp = v_scale_pool.reshape(l, nb * bs, nkv) if quantized else None
-    gw = kv_pool_lib.chunk_write_indices(block_row, start, real_len,
-                                         t, block_size)      # [T]
-    gr = kv_pool_lib.read_indices(block_row[None],
-                                  block_size)[0]             # [S_pad]
+    gw = da.chunk_write_indices(block_row, start, real_len, t,
+                                block_size)                  # [T]
+    gr = da.read_indices(block_row[None], block_size)[0]     # [S_pad]
 
     def layer(xc, lp, entry, ad):
         # This pass's and layer's KV entry: taken out of the stacked
@@ -546,38 +657,8 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
                 p, entry, 0, keepdims=False,
                 allow_negative_indices=False)
             for p in (kp, vp, ksp, vsp))
-        h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
-                            config.norm_offset)
-        with jax.named_scope('qkv_proj'):
-            q = _mm(h, lp['wq'])
-            k = _mm(h, lp['wk'])
-            v = _mm(h, lp['wv'])
-        if ad is not None:
-            # Adapter attach mirrors the engine's decode/verify
-            # twins exactly (same helper, same q/v points) — prefill
-            # under adapter X must write the SAME KV the decode math
-            # implies, or prefix-cache hits would change outputs.
-            q = q + lora_gather_delta(
-                h, ad['wq_a'], ad['wq_b'],
-                adapter_idx).astype(q.dtype)
-            v = v + lora_gather_delta(
-                h, ad['wv_a'], ad['wv_b'],
-                adapter_idx).astype(v.dtype)
-        if config.qkv_bias:
-            q = q + lp['bq']
-            k = k + lp['bk']
-            v = v + lp['bv']
-        q = q.reshape(1, t, nh, hd)
-        k = k.reshape(1, t, nkv, hd)
-        v = v.reshape(1, t, nkv, hd)
-        q = attention_ops.apply_rope(q, angles)
-        k = attention_ops.apply_rope(k, angles)
-        if quantized:
-            k_rows, ks_rows = _quantize_kv(k)
-            v_rows, vs_rows = _quantize_kv(v)
-        else:
-            k_rows, v_rows = k, v
-            ks_rows = vs_rows = None
+        q, k, v, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
+            config, xc, lp, ad, adapter_idx, angles, quantized)
         # In-layer write exists only so this chunk's attention sees
         # its own keys; the caller-visible pool update is the single
         # merged scatter after the layer scan (same split as
@@ -642,6 +723,313 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
         ksp.reshape(l, nb, bs, nkv) if quantized else None,
         vsp.reshape(l, nb, bs, nkv) if quantized else None)
     return logits[:, 0], new_pools
+
+
+def decode_steps_paged(params: Params, tokens: jax.Array,
+                       caches, block_tables: jax.Array,
+                       pos: jax.Array, active: jax.Array,
+                       config: llama.LlamaConfig,
+                       num_steps: int, block_size: int,
+                       adapters=None, adapter_idx=None,
+                       sampling=None):
+    """Decode ``num_steps`` tokens for every row at PER-ROW
+    positions, as one dispatch (inner ``lax.scan``), over the PAGED
+    pool: the step the engine (``serve/batching.py``) runs.
+
+    tokens [B] (each row's most recent token); pos [B] = next write
+    index per row; active [B] bool — inactive rows still compute
+    (static shapes) but their pos does not advance and their writes
+    keep landing on the same parked cell, so they cannot corrupt
+    anything. Rows read and write through ``block_tables`` [B, MB]
+    into the shared pool ``caches`` = (k, v, k_scale, v_scale) with
+    k/v [E, num_blocks, block_size, Hkv, hd] (int8 + bf16 scales
+    [E, num_blocks, block_size, Hkv] when quantized — int8 KV halves
+    the decode loop's dominant HBM stream; E as
+    ``kv_pool.KVBlockPool`` defines it). The layers run
+    ``config.loop_passes`` times over the same stacked weights
+    (``looped_stack``: scopes ``loop_pass``, ``branch_norm``,
+    ``exit_gate``), pass t, layer l on entry t * n_layers + l.
+
+    Attention per layer is the gather-based
+    ``ops.decode_attention.paged_decode_attention``: row b's logical
+    view of positions [0, pos) is gathered out of the pool block by
+    block and masked to its own length, so recycled-block garbage
+    past the length contributes exactly 0; an int8 pool is read as
+    int8. This step's own K/V row reaches attention as an operand:
+    there is NO in-layer pool write (until PR 26 there was one, "so
+    this step's attention sees the new row"; the chip's trace showed
+    it copying the layer's whole pool slice, 2 x 75 MB in every layer
+    of every step). The pool is written once a token, after the layer
+    scan, through ``da.write_index`` — parked rows (inactive
+    lanes) and overrun positions land in the scratch block, never in
+    a block another request owns.
+
+    Multi-adapter serving (serve/adapters/): ``adapters`` is the
+    resident set's stacked factor dict (leaves ``[L, C+1, ...]``,
+    scanned with the layer stack) and ``adapter_idx`` [B] maps each
+    row to its slot; row-gathered LoRA deltas attach to the q and v
+    projections (``layer_head``). ``adapters=None`` (a
+    distinct jit executable — None is an empty pytree) keeps the
+    adapterless math byte-identical to before.
+
+    ``sampling`` (ops/sampling/): None keeps the greedy argmax
+    executable byte-identical; otherwise a dict of TRACED per-row
+    knob arrays (``temps``/``top_ps``/``seeds`` [B]) plus the grammar
+    mask table (``mask_table`` [M, V] bool, ``mask_idx`` [B] — row 0
+    is all-allowed) and each step's next token is ``sample_rows``
+    keyed ``(seed, position)``; ``temperature <= 0`` rows still
+    reduce to the argmax.
+
+    The ``jax.named_scope``s here and in the functions this calls
+    (``qkv_proj``, ``lora_delta``, ``kv_write``, ``paged_gather``,
+    ``kv_dequant``, ``decode_attention``, ``o_proj``, ``mlp``,
+    ``sampler``; the verify and prefill twins carry the same) name
+    the program's parts in ``op_name=`` of the compiled text, which
+    is the only place the chip's trace lets them be looked up; they
+    change HLO metadata and nothing else.
+
+    Returns (out_tokens [B, num_steps], caches, new_pos).
+    """
+    k_pool, v_pool, k_scale, v_scale = caches
+    ne, nb, bs = k_pool.shape[:3]
+    assert bs == block_size, (bs, block_size)
+    assert ne == config.kv_entries, (ne, config.kv_entries)
+    cparams = jax.tree.map(
+        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
+        params)
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    b = tokens.shape[0]
+    quantized = k_scale is not None  # static at trace
+
+    # Flat [NB * bs, ...] pool views — write index math is 1-D
+    # flat-slot; attention reads whole blocks (``_all_blocks``).
+    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
+    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
+    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
+
+    def one_token(carry, _):
+        tok, kp_all, vp_all, ks_all, vs_all, cur = carry
+        angles = llama._rope_frequencies(
+            config, cur)[:, None]                       # [B, 1, hd/2]
+        x = cparams['embed'][tok][:, None]              # [B, 1, D]
+        if config.scale_embeddings:
+            x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
+        widx = da.write_index(block_tables, cur, block_size)  # [B]
+        scale_views = _scale_views(ks_all, vs_all, block_tables, bs)
+
+        def layer(xc, lp, entry, ad):
+            # ``entry``: this pass's and layer's KV entry (at one
+            # pass, the layer); ``ad`` is None without adapters.
+            q, _, _, rows = layer_head(
+                config, xc, lp, ad, adapter_idx, angles, quantized)
+            # No in-layer write: the layer's pool slice is a scanned
+            # input, so ``kc.at[widx].set`` copied the whole slice
+            # (75 MB of K and of V at 4,561 blocks, every layer of
+            # every step: 6 % of the step in PR 25's chip trace) for
+            # B new rows. Attention takes this step's rows as an
+            # operand beside the view of positions [0, cur); the one
+            # merged scatter after the layer scan persists them.
+            new = tuple(None if r is None else r[:, 0] for r in rows)
+            ks_view, vs_view = (None, None) if scale_views is None \
+                else jax.lax.dynamic_index_in_dim(
+                    scale_views, entry, 0, keepdims=False,
+                    allow_negative_indices=False)
+            attn = da.paged_decode_attention(
+                q[:, 0], _all_blocks(kp_all, bs),
+                _all_blocks(vp_all, bs), block_tables + entry * nb,
+                cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                new=new)[:, None]
+            xc = layer_tail(
+                config, xc, attn.reshape(b, 1, nh * hd), lp)
+            return xc, new
+
+        x, rows = looped_stack(config, cparams, x, layer, adapters)
+        # Persist the new rows: one merged scatter per token into the
+        # carried (donated) flat pools.
+        with jax.named_scope('kv_write'):
+            kp_all = kp_all.at[:, widx].set(rows[0])
+            vp_all = vp_all.at[:, widx].set(rows[1])
+            if quantized:
+                ks_all = ks_all.at[:, widx].set(rows[2])
+                vs_all = vs_all.at[:, widx].set(rows[3])
+        if config.tie_embeddings:
+            logits = (x @ llama.output_head(cparams, config))
+        else:
+            logits = _mm(x, cparams['lm_head'])
+        with jax.named_scope('sampler'):
+            if sampling is None:
+                nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
+            else:
+                # Counter-keyed per-row sampling at position ``cur``
+                # — the row's draw never depends on batch neighbors
+                # (ops/sampling/prng.py batch-invariance contract).
+                allowed = sample_lib.gather_masks(
+                    sampling['mask_table'], sampling['mask_idx'])
+                nxt = sample_lib.sample_rows(
+                    logits[:, -1], sampling['temps'],
+                    sampling['top_ps'], sampling['seeds'], cur,
+                    allowed)
+        # Inactive rows: hold the last token and do NOT advance, so
+        # their next (scratch-redirected) write stays parked.
+        nxt = jnp.where(active, nxt, tok)
+        new_cur = jnp.where(active, cur + 1, cur)
+        return (nxt, kp_all, vp_all, ks_all, vs_all, new_cur), nxt
+
+    (tok, kp, vp, ksp, vsp, pos), toks = jax.lax.scan(
+        one_token, (tokens, kp, vp, ksp, vsp, pos), None,
+        length=num_steps)
+    out_caches = (
+        kp.reshape(ne, nb, bs, nkv, hd),
+        vp.reshape(ne, nb, bs, nkv, hd),
+        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
+        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
+    return toks.swapaxes(0, 1), out_caches, pos
+
+
+def verify_step_paged(params: Params, tokens: jax.Array,
+                      caches, block_tables: jax.Array,
+                      pos: jax.Array, n_real: jax.Array,
+                      config: llama.LlamaConfig,
+                      width: int, block_size: int,
+                      adapters=None, adapter_idx=None,
+                      sampling=None):
+    """Batched multi-token VERIFY forward — the speculative twin of
+    ``decode_steps_paged``: instead of scanning ``num_steps`` single
+    tokens, ONE forward carries ``width`` = draft_k + 1 query
+    positions per row (the row's current token at ``pos[b]`` plus
+    its drafted continuation), so one weight read amortizes over up
+    to width accepted-and-emitted tokens — the bandwidth-bound
+    decode fix.
+
+    tokens [B, W] (row b's positions pos[b]..pos[b]+W-1, only the
+    first n_real[b] real — padded lanes write scratch and their
+    outputs are ignored); caches/block_tables as in
+    ``decode_steps_paged``. Drafted K/V is written into the row's
+    blocks UP FRONT (one merged scatter after the layer scan; within
+    the forward the window's rows reach attention as an operand, as
+    in the decode twin); a rejection later simply rolls the
+    host-side ``pos`` back so the stale rows are never attended
+    again — no block copying,
+    no scatter-undo (the length-masked paged attention makes
+    abandoning them free). Attention is
+    ``ops.decode_attention.paged_decode_attention`` in its
+    [B, W, ...] form with the intra-draft causal mask (query j
+    attends [0, pos+j]).
+
+    Returns (preds [B, W] int32, accepted [B] int32, new_pos [B],
+    new_tokens [B], caches): ``preds[b, j]`` is the target model's
+    token realization after position pos[b]+j — the argmax when
+    ``sampling`` is None, else ``sample_lib.verify_targets``'s
+    counter-keyed draw with the SAME key plain decode would use at
+    that position (``sampling`` also carries per-position grammar
+    masks, table [M, W, V] gathered by traced index). ``accepted``
+    is ``accept_tokens``'s per-row count (ops/sampling/accept.py
+    — the ONE acceptance implementation: the Chen et al. rejection
+    rule realized by maximal coupling, traced here so the
+    pos/tokens commit costs no extra host round-trips);
+    ``new_pos``/``new_tokens`` carry the committed frontier — pos
+    advances by accepted+1 for live rows (the ROLLBACK: rejected
+    positions simply stay past the new frontier) and parked rows
+    (n_real 0) are untouched.
+    """
+    k_pool, v_pool, k_scale, v_scale = caches
+    ne, nb, bs = k_pool.shape[:3]
+    assert bs == block_size, (bs, block_size)
+    assert ne == config.kv_entries, (ne, config.kv_entries)
+    cparams = jax.tree.map(
+        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
+        params)
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    b = tokens.shape[0]
+    quantized = k_scale is not None  # static at trace
+
+    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
+    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
+    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
+
+    # As in the decode twin: every entry's blocks as one pool read
+    # through tables offset by entry * NB, and the scale views of
+    # all entries gathered once, outside the layer scan.
+    kblocks, vblocks = _all_blocks(kp, bs), _all_blocks(vp, bs)
+    scale_views = _scale_views(ksp, vsp, block_tables, bs)
+
+    positions = pos[:, None] + jnp.arange(width,
+                                          dtype=jnp.int32)[None, :]
+    angles = llama._rope_frequencies(
+        config, positions.reshape(-1)).reshape(b, width, -1)
+    x = cparams['embed'][tokens]                   # [B, W, D]
+    if config.scale_embeddings:
+        x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
+    widx = da.verify_write_indices(
+        block_tables, pos, n_real, width, block_size)  # [B, W]
+    wflat = widx.reshape(-1)
+
+    def layer(xc, lp, entry, ad):
+        q, _, _, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
+            config, xc, lp, ad, adapter_idx, angles, quantized)
+        # No in-layer write (it copied the layer's whole pool
+        # slice, as in the decode twin): the draft window's own rows
+        # go to attention as an operand, causally among themselves,
+        # beside the view of positions [0, pos); the merged scatter
+        # after the layer scan persists them. A padded lane's row is
+        # seen only by padded lanes, whose outputs are ignored.
+        ks_view, vs_view = (None, None) if scale_views is None \
+            else jax.lax.dynamic_index_in_dim(
+                scale_views, entry, 0, keepdims=False,
+                allow_negative_indices=False)
+        attn = da.paged_decode_attention(
+            q, kblocks, vblocks, block_tables + entry * nb, pos,
+            hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+            new=(k_rows, v_rows, ks_rows, vs_rows))   # [B, W, Hq, hd]
+        xc = layer_tail(
+            config, xc, attn.reshape(b, width, nh * hd), lp)
+        return xc, (
+            k_rows.reshape(b * width, nkv, hd),
+            v_rows.reshape(b * width, nkv, hd),
+            None if ks_rows is None
+            else ks_rows.reshape(b * width, nkv),
+            None if vs_rows is None
+            else vs_rows.reshape(b * width, nkv))
+
+    x, rows = looped_stack(config, cparams, x, layer, adapters)
+    with jax.named_scope('kv_write'):
+        kp = kp.at[:, wflat].set(rows[0])
+        vp = vp.at[:, wflat].set(rows[1])
+        if quantized:
+            ksp = ksp.at[:, wflat].set(rows[2])
+            vsp = vsp.at[:, wflat].set(rows[3])
+    if config.tie_embeddings:
+        logits = (x @ llama.output_head(cparams, config))
+    else:
+        logits = _mm(x, cparams['lm_head'])
+    with jax.named_scope('sampler'):
+        if sampling is None:
+            preds = logits.argmax(-1).astype(jnp.int32)   # [B, W]
+        else:
+            # Target realizations drawn with the keys plain decode
+            # would use at each position — the maximal-coupling half
+            # of the speculative-sampling rule
+            # (ops/sampling/accept.py).
+            allowed = sample_lib.gather_masks(sampling['mask_table'],
+                                              sampling['mask_idx'])
+            preds = sample_lib.verify_targets(
+                logits, sampling['temps'], sampling['top_ps'],
+                sampling['seeds'], pos, allowed)          # [B, W]
+        accepted = accept_tokens(tokens, preds, n_real)   # [B]
+    live = n_real > 0
+    new_pos = jnp.where(live, pos + accepted + 1, pos)
+    new_tok = jnp.where(
+        live,
+        jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0],
+        tokens[:, 0])
+    out_caches = (
+        kp.reshape(ne, nb, bs, nkv, hd),
+        vp.reshape(ne, nb, bs, nkv, hd),
+        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
+        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
+    return preds, accepted, new_pos, new_tok, out_caches
 
 
 def decode_shardings(config: llama.LlamaConfig, mesh,

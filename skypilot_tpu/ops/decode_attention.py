@@ -1,108 +1,132 @@
-"""Length-aware decode attention + per-row cache writes (Pallas).
+"""The paged KV pool's format on the device, and attention over it.
 
-The serving decode hot loop previously attended with a dense masked
-einsum over the FULL static cache (``models/decode.py``
-``_masked_attention`` / ``serve/batching.py`` ``_attend_rows``): every
-generated token read all ``[B, S, Hkv, hd]`` of K and V from HBM and
-multiplied most of it by a -inf mask. At S >= 4k batched decode that
-masked junk dominates HBM traffic — decode is bandwidth-bound, so it
-directly sets TPOT.
+The format, stated here and nowhere else: a pool is
+``[num_blocks, block_size, Hkv, hd]`` a KV entry (int8 codes with
+bf16 scales ``[num_blocks, block_size, Hkv]``, or floats), a request
+maps its logical positions onto pool blocks through a block-table row
+``[MB]``, and logical position p of a row lives at
 
-This module provides length-aware Pallas alternatives (the reference
-delegates serving to vLLM/JetStream, whose paged/flash decode kernels
-play this role — ``llm/vllm/service.yaml``). NOTE: on the v5e used
-for this repo's benches, XLA's dense path won (see ``_use_pallas``);
-the kernels are opt-in via SKYTPU_PALLAS_DECODE=1 and the shipped
-serving bandwidth fix is the int8 KV cache (models/decode.py). Both
-kernels remain correctness-tested:
+    flat slot = block_table[p // block_size] * block_size
+                + p % block_size
 
-- ``decode_attention(q, k, v, lengths)``: a Pallas kernel that
-  streams ONLY the valid prefix of each row's cache HBM->VMEM with
-  double-buffered async DMA, chunk by chunk (flash-style online
-  softmax across chunks), skipping every block past ``lengths[b]``.
-  HBM reads scale with the ACTUAL context length, not the cache
-  allocation.
-- ``cache_write(k_cache, v_cache, k_new, v_new, pos)``: per-row
-  scatter of one new K/V position. The previous one-hot
-  ``jnp.where`` write (the "JetStream trick" to avoid XLA's scalar
-  scatter) rewrote the entire cache every layer — a second full
-  bandwidth pass; the Pallas version DMAs exactly one [Hkv*hd] row
-  per batch element in place (input/output aliased).
+Block 0 is the reserved SCRATCH block, never allocated
+(``serve/kv_pool.KVBlockPool`` is the host allocator): parked rows,
+padded prefill positions, padded or rejected draft lanes and
+positions past a table's capacity all write there, so a stale table
+entry can never corrupt a block that was recycled to another request.
 
-Mosaic alignment note: head_dim is 64 for 1B-class models, and VMEM
-lane tiling is 128 — per-head lane slices would be unaligned. The
-kernel therefore works on the flattened ``[S, Hkv*hd]`` cache view
-(lane dim 512+, aligned) with a BLOCK-DIAGONAL query matrix
-``[Hq, Hkv*hd]`` built outside the kernel: ``q_bd @ k_flat.T`` is
-exactly the per-head dot (zeros mask the foreign heads), and the
-``p @ v_flat`` accumulator carries every head's value block, from
-which the caller gathers each query head's own block. The extra MXU
-flops are ~Hkv x, but decode attention is HBM-bound — the MXU is
-idle either way, and no lane dim is ever sliced.
-
-Both entry points fall back to dense jnp references off-TPU (CPU
-tests, virtual meshes) and are numerically tested against them.
-
-The engine's own decode and verify steps go through
-``paged_decode_attention`` -> ``view_attention`` (plain XLA, one
-compiled program): the int8 pool is gathered block by block and read
-AS int8 — codes converted inside the two dots, the K scale applied
-to the scores and the V scale to the probabilities, this step's own
-row an operand, not a pool write. What the v5e's trace showed of the
-form before (PR 25: per-position gathers at 385 GB/s, a bf16 copy of
-the whole padded K and V view, a copy of the layer's pool slice for
-B new rows; 113.8 ms a step at 24 rows x 4,096) and of this one (PR
-26: 48 ms) is in PERF.md. It is still dense over the table width: a
-kernel that walks each row's own blocks is what is left (ROADMAP
-S2).
+- Write side: ``write_index`` (one position a row: the decode step),
+  ``verify_write_indices`` (a draft window a row),
+  ``chunk_write_indices`` (a prefill chunk of one request).
+- Read side: ``read_indices`` + ``paged_gather`` (position by
+  position: the prefill chunk's one-row view), ``gather_blocks`` and
+  ``gather_scales`` (block by block: the decode and verify steps).
+- Attention: the engine's decode and verify steps go through
+  ``paged_decode_attention`` -> ``view_attention`` (plain XLA, one
+  compiled program): the int8 pool is gathered block by block and
+  read AS int8 — codes converted inside the two dots, the K scale
+  applied to the scores and the V scale to the probabilities, this
+  step's own row an operand, not a pool write. What the v5e's trace
+  showed of the form before (PR 25: per-position gathers at 385 GB/s,
+  a bf16 copy of the whole padded K and V view, a copy of the layer's
+  pool slice for B new rows; 113.8 ms a step at 24 rows x 4,096) and
+  of this one (PR 26: 48 ms) is in PERF.md. It is still dense over
+  the table width: a kernel that walks each row's own blocks over
+  the int8 codes is what is left (ROADMAP S2b).
+  ``decode_attention`` is the plain masked form over a contiguous
+  float cache that ``models/decode._layer_cached`` calls.
 """
-import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 _NEG_INF = -1e30
 
-# KV positions streamed per DMA chunk. 512 keeps the double-buffered
-# scratch at 512*Hkv*hd*2B*2bufs*2(k,v) — ~2 MB for 1B-class models —
-# well inside a v5e core's ~16 MB more VMEM budget.
-_BLOCK_S = 512
-
-# Aligned read-modify-write window (rows) for the cache-write kernel:
-# Mosaic requires HBM sublane slices aligned to the memref tiling.
-_WRITE_WIN = 8
-
-
-def _use_pallas(which: str = '') -> bool:
-    """Opt-in (SKYTPU_PALLAS_DECODE=1), and only on TPU.
-
-    Measured on v5e (llama3.2-1b, B=16, S=4608, decode): the XLA
-    dense masked path sustains ~400 GB/s and 24.8 ms TPOT; these
-    kernels measured 26.8-30.8 ms — per-grid-step overhead exceeded
-    the bandwidth saved, at every occupancy tested. They stay
-    correctness-tested (interpret + on-chip token equality) for
-    hardware/toolchains where the tradeoff flips; the default serve
-    bandwidth win is the int8 KV cache instead (models/decode.py).
-    """
-    import os
-    if os.environ.get('SKYTPU_PALLAS_DECODE') != '1':
-        return False
-    if which and os.environ.get(f'SKYTPU_NO_PALLAS_{which}') == '1':
-        return False  # per-kernel kill-switch (ATTN / WRITE)
-    # No except: a backend that cannot start is JAX's error to raise,
-    # not a reason to answer "not on TPU".
-    return jax.default_backend() == 'tpu'
+# The reserved scratch block (see module docstring).
+SCRATCH_BLOCK = 0
 
 
 # ---------------------------------------------------------------------
-# Reference paths (CPU / tests / non-TPU backends)
+# Index arithmetic (pure, shape-static; used inside jitted steps)
 # ---------------------------------------------------------------------
 
 
-def _reference_decode_attention(q, k, v, lengths, scale):
-    """q [B, Hq, hd]; k/v [B, S, Hkv, hd]; lengths [B] — row b
-    attends keys [0, lengths[b])."""
+def read_indices(block_tables: jax.Array,
+                 block_size: int) -> jax.Array:
+    """Flat pool-slot indices for every logical position of every
+    row: block_tables [..., MB] int32 -> [..., MB * block_size].
+    Positions in unallocated tail blocks land in the scratch block —
+    callers mask them via their per-row lengths before softmax."""
+    offs = jnp.arange(block_size, dtype=jnp.int32)
+    flat = (block_tables[..., :, None] * block_size +
+            offs[None, :])
+    return flat.reshape(*block_tables.shape[:-1], -1)
+
+
+def write_index(block_tables: jax.Array, pos: jax.Array,
+                block_size: int) -> jax.Array:
+    """Flat pool-slot index for each row's next write:
+    block_tables [B, MB], pos [B] -> [B]. Positions at or past the
+    table's capacity are redirected to the scratch block (overrun
+    tokens of rows that finished mid-dispatch, parked lanes)."""
+    mb = block_tables.shape[-1]
+    blk = jnp.minimum(pos // block_size, mb - 1)
+    idx = (jnp.take_along_axis(block_tables, blk[:, None],
+                               axis=1)[:, 0] * block_size +
+           pos % block_size)
+    safe = (pos >= 0) & (pos < mb * block_size)
+    return jnp.where(safe, idx, SCRATCH_BLOCK * block_size)
+
+
+def verify_write_indices(block_tables: jax.Array, pos: jax.Array,
+                         n_real: jax.Array, width: int,
+                         block_size: int) -> jax.Array:
+    """Flat pool-slot indices for a speculative VERIFY dispatch:
+    row b writes ``width`` consecutive positions starting at
+    ``pos[b]`` (its current token plus drafted continuation), of
+    which only the first ``n_real[b]`` are real. Padded draft lanes
+    (j >= n_real[b]), parked rows (n_real 0) and positions past the
+    table capacity all redirect to the scratch block — a rejected or
+    padded draft can never touch a block another request owns.
+    block_tables [B, MB], pos/n_real [B] -> [B, width]."""
+    t = jnp.arange(width, dtype=jnp.int32)
+    p = pos[:, None] + t[None, :]                        # [B, W]
+    mb = block_tables.shape[-1]
+    blk = jnp.minimum(jnp.maximum(p, 0) // block_size, mb - 1)
+    idx = (jnp.take_along_axis(block_tables, blk, axis=1) *
+           block_size + jnp.maximum(p, 0) % block_size)
+    valid = ((t[None, :] < n_real[:, None]) & (p >= 0) &
+             (p < mb * block_size))
+    return jnp.where(valid, idx, SCRATCH_BLOCK * block_size)
+
+
+def chunk_write_indices(block_row: jax.Array, start: jax.Array,
+                        real_len: jax.Array, chunk: int,
+                        block_size: int) -> jax.Array:
+    """Flat pool-slot indices for a prefill chunk's ``chunk`` rows
+    written at positions [start, start+real_len): block_row [MB].
+    Padded positions (t >= real_len) go to the scratch block."""
+    t = jnp.arange(chunk, dtype=jnp.int32)
+    pos = start + t
+    mb = block_row.shape[0]
+    blk = jnp.minimum(pos // block_size, mb - 1)
+    idx = block_row[blk] * block_size + pos % block_size
+    valid = (t < real_len) & (pos < mb * block_size)
+    return jnp.where(valid, idx, SCRATCH_BLOCK * block_size)
+
+
+# ---------------------------------------------------------------------
+# Contiguous-cache decode attention
+# ---------------------------------------------------------------------
+
+
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     lengths: jax.Array, scale: float) -> jax.Array:
+    """Single-position decode attention over per-row valid prefixes
+    of a contiguous float cache: q [B, Hq, hd]; k/v [B, S, Hkv, hd];
+    lengths [B] int — row b attends keys [0, lengths[b]). Returns
+    [B, Hq, hd] in q.dtype. Dense over S with a length mask."""
     b, hq, hd = q.shape
     s, hkv = k.shape[1], k.shape[2]
     groups = hq // hkv
@@ -116,171 +140,6 @@ def _reference_decode_attention(q, k, v, lengths, scale):
     return out.reshape(b, hq, hd)
 
 
-def _reference_cache_write(k_cache, v_cache, k_new, v_new, pos):
-    """One-hot full-cache write (reads+writes the whole cache; kept
-    as the off-TPU fallback)."""
-    hit = jnp.arange(k_cache.shape[1])[None, :] == pos[:, None]
-    k_cache = jnp.where(hit[:, :, None, None], k_new[:, None],
-                        k_cache)
-    v_cache = jnp.where(hit[:, :, None, None], v_new[:, None],
-                        v_cache)
-    return k_cache, v_cache
-
-
-# ---------------------------------------------------------------------
-# Pallas decode attention
-# ---------------------------------------------------------------------
-
-
-def _decode_attn_kernel(lengths_ref, qbd_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *, block_s: int):
-    """Grid (B, S // block_s), row-major (the chunk index is the
-    FAST axis). Mosaic's BlockSpec pipeline streams the k/v chunks;
-    chunks past a row's valid length map to the last valid chunk
-    index (see index_map), so their copies are ELIDED — HBM reads
-    scale with the actual length. Online softmax accumulates in
-    scratch across chunk steps; the output block is written on the
-    row's last step."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    n_i = pl.num_programs(1)
-    length = jnp.maximum(lengths_ref[b], 1)
-    nblk = pl.cdiv(length, block_s)
-
-    @pl.when(i == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(i < nblk)
-    def _():
-        q_bd = qbd_ref[0]                          # [Hq, Hkv*hd]
-        kc = k_ref[0]                              # [BS, Hkv*hd]
-        vc = v_ref[0]
-
-        # Block-diagonal q makes this the per-head dot for every
-        # query head in ONE aligned matmul (docstring note). Operands
-        # stay bf16 (native MXU bf16 x bf16 -> f32); only the
-        # accumulators are f32.
-        logits = jax.lax.dot_general(
-            q_bd, kc,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [Hq, BS]
-
-        col = i * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_s), 1)
-        logits = jnp.where(col < length, logits, _NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(logits, -1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)                # [Hq, BS]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(vc.dtype), vc,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [Hq, Hkv*hd]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = m_new
-
-    @pl.when(i == n_i - 1)
-    def _():
-        o_ref[0] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=('scale', 'block_s', 'interpret'))
-def _decode_attention_pallas(q, k, v, lengths, scale, block_s,
-                             interpret=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hq, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    groups = hq // hkv
-    dflat = hkv * hd
-
-    # Block-diagonal queries: q_bd[h*G+g, h*hd : (h+1)*hd] = q[h*G+g],
-    # zeros elsewhere. Built in XLA (tiny), scaled here so the kernel
-    # skips the multiply.
-    head_of = jnp.arange(hq) // groups                     # [Hq]
-    lane_head = jnp.arange(dflat) // hd                    # [Dflat]
-    sel = (head_of[:, None] == lane_head[None, :])         # [Hq, Dflat]
-    q_tiled = jnp.tile(q, (1, 1, hkv))                     # [B,Hq,Dflat]
-    q_bd = jnp.where(sel[None], q_tiled,
-                     jnp.zeros_like(q_tiled)) * jnp.asarray(
-                         scale, q.dtype)
-
-    kernel = functools.partial(_decode_attn_kernel, block_s=block_s)
-
-    def kv_index(bi, i, lens):
-        # Chunks past this row's valid range repeat the last valid
-        # chunk index; the pipeline elides copies whose index did
-        # not change, so invalid chunks cost no HBM reads.
-        last = jnp.maximum(
-            jax.lax.div(jnp.maximum(lens[bi], 1) + block_s - 1,
-                        block_s) - 1, 0)
-        return (bi, jnp.minimum(i, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, s // block_s),
-        in_specs=[
-            pl.BlockSpec((1, hq, dflat), lambda bi, i, _: (bi, 0, 0)),
-            pl.BlockSpec((1, block_s, dflat), kv_index),
-            pl.BlockSpec((1, block_s, dflat), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, hq, dflat),
-                               lambda bi, i, _: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hq, 1), jnp.float32),      # running max
-            pltpu.VMEM((hq, 1), jnp.float32),      # running denom
-            pltpu.VMEM((hq, dflat), jnp.float32),  # accumulator
-        ],
-    )
-    acc = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, dflat), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), q_bd,
-      k.reshape(b, s, dflat), v.reshape(b, s, dflat))
-    # Each query head's output is its own head's value block.
-    acc = acc.reshape(b, hq, hkv, hd)
-    return jnp.take_along_axis(
-        acc, head_of[None, :, None, None], axis=2)[:, :, 0]
-
-
-def _pallas_takes(k: jax.Array) -> bool:
-    """Opted in, on TPU, and the [B, S, Hkv, hd] view meets the
-    kernel's chunk and lane divisibility."""
-    return (_use_pallas('ATTN') and k.shape[1] % _BLOCK_S == 0 and
-            k.shape[1] >= 2 * _BLOCK_S and
-            (k.shape[2] * k.shape[3]) % 128 == 0)
-
-
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     lengths: jax.Array,
-                     scale: float) -> jax.Array:
-    """Single-position decode attention over per-row valid prefixes.
-
-    q [B, Hq, hd]; k/v [B, S, Hkv, hd]; lengths [B] int — row b
-    attends keys [0, lengths[b]). Returns [B, Hq, hd] in q.dtype.
-    On TPU this streams only ceil(lengths/block) cache chunks from
-    HBM; elsewhere (or for lane-unaligned shapes) it falls back to
-    the dense masked reference.
-    """
-    if _pallas_takes(k):
-        return _decode_attention_pallas(q, k, v, lengths, scale,
-                                        _BLOCK_S)
-    return _reference_decode_attention(q, k, v, lengths, scale)
-
-
 # ---------------------------------------------------------------------
 # Paged (block-table-indirected) decode attention
 # ---------------------------------------------------------------------
@@ -291,7 +150,7 @@ def paged_gather(pool_flat: jax.Array,
     """Gather rows' logical KV views out of a flattened pool,
     position by position: pool_flat [num_blocks * block_size, ...]
     indexed by the precomputed flat indices from
-    ``kv_pool.read_indices`` ([B, S_pad] -> [B, S_pad, ...]). The
+    ``read_indices`` ([B, S_pad] -> [B, S_pad, ...]). The
     prefill chunk's form (one row's view); the decode and verify
     steps use ``gather_blocks``. Scoped ``paged_gather`` in the
     compiled program's ``op_name`` metadata."""
@@ -356,7 +215,7 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     [0, j]: whatever the view holds at lengths[b] and after (stale
     rows of a recycled block) is masked. With ``new=None`` the rows
     are in the view already and query j attends [0, lengths[b] + j),
-    as ``_reference_decode_attention`` does for one position.
+    as ``decode_attention`` does for one position.
 
     An int8 view is never dequantised as a view: the codes are
     converted in the dot's operand (exact in bf16), the K scale
@@ -440,20 +299,6 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, 0] if single else out
 
 
-def _place_new(view, view_scale, new_rows, new_scale, lengths,
-               dtype):
-    """The float view with this step's row in place at
-    ``lengths[b]``: what the opt-in Pallas kernel, which takes one
-    float view and a length, is handed."""
-    if view_scale is not None:
-        view = view.astype(dtype) * jnp.swapaxes(
-            view_scale, 1, 2)[..., None].astype(dtype)
-        new_rows = new_rows.astype(dtype) * new_scale[
-            ..., None].astype(dtype)
-    hit = jnp.arange(view.shape[1])[None, :] == lengths[:, None]
-    return jnp.where(hit[:, :, None, None], new_rows[:, None], view)
-
-
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array,
                            block_tables: jax.Array,
@@ -484,7 +329,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     contribute exactly 0. The gather cost scales with the TABLE WIDTH
     (the longest admissible request), not the pool allocation and
     not the rows' lengths: a kernel that walks each row's own blocks
-    is ROADMAP S2's remainder. The result equals the contiguous-cache
+    is ROADMAP S2b. The result equals the contiguous-cache
     path's to float32 rounding (the new row's term is summed after
     the view's, not in its place), not bit for bit; the engines'
     token-for-token tests hold both to the same tokens.
@@ -492,106 +337,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     kd = gather_blocks(k_pool, block_tables)     # [B, S_pad, Hkv, hd]
     vd = gather_blocks(v_pool, block_tables)
     with jax.named_scope('decode_attention'):
-        if q.ndim == 3 and new is not None and _pallas_takes(kd):
-            k_new, v_new, ks_new, vs_new = new
-            return decode_attention(
-                q, _place_new(kd, k_scale, k_new, ks_new, lengths,
-                              q.dtype),
-                _place_new(vd, v_scale, v_new, vs_new, lengths,
-                           q.dtype), lengths + 1, scale)
         return view_attention(q, kd, vd, lengths, scale, k_scale,
                               v_scale, new)
 
 
-# ---------------------------------------------------------------------
-# Pallas per-row cache write
-# ---------------------------------------------------------------------
-
-
-def _cache_write_kernel(pos_ref, knew_ref, vnew_ref, kwin_ref,
-                        vwin_ref, ko_ref, vo_ref):
-    """Grid (B,): the BlockSpec pipeline brings in the aligned
-    _WRITE_WIN-row cache window containing this row's write position
-    (dynamic block index from the prefetched positions), the kernel
-    overwrites the target row with a vector select, and the output
-    pipeline writes the window back. The rest of the cache is
-    preserved by input/output aliasing. ~2*WIN*Hkv*hd elements move
-    per row instead of a full-cache pass."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pos_ref[b]
-    row = p - (p // _WRITE_WIN) * _WRITE_WIN
-
-    # Extract this row's new K/V from the whole-[B, Dflat] block by
-    # masked reduction (dynamic sublane indexing is layout-hostile).
-    rowsel = jax.lax.broadcasted_iota(
-        jnp.int32, knew_ref.shape, 0) == b          # [B, Dflat]
-    knew = jnp.sum(jnp.where(rowsel, knew_ref[:], 0).astype(
-        jnp.float32), axis=0).astype(ko_ref.dtype)  # [Dflat]
-    vnew = jnp.sum(jnp.where(rowsel, vnew_ref[:], 0).astype(
-        jnp.float32), axis=0).astype(vo_ref.dtype)
-
-    sel = jax.lax.broadcasted_iota(
-        jnp.int32, kwin_ref.shape, 1) == row        # [1, W, Dflat]
-    ko_ref[:] = jnp.where(sel, knew[None, None], kwin_ref[:])
-    vo_ref[:] = jnp.where(sel, vnew[None, None], vwin_ref[:])
-
-
-@functools.partial(jax.jit, static_argnames=('interpret',))
-def _cache_write_pallas(k_cache, v_cache, k_new, v_new, pos,
-                        interpret=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, s, hkv, hd = k_cache.shape
-    dflat = hkv * hd
-    def win_index(bi, pos):
-        return (bi, pos[bi] // _WRITE_WIN, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            # New rows land in VMEM whole ([B, Dflat] is tiny); the
-            # kernel masks out its own row (a 1-sublane block would
-            # violate the (8, 128) block-divisibility rule).
-            pl.BlockSpec((b, dflat), lambda i, _: (0, 0)),
-            pl.BlockSpec((b, dflat), lambda i, _: (0, 0)),
-            pl.BlockSpec((1, _WRITE_WIN, dflat), win_index),
-            pl.BlockSpec((1, _WRITE_WIN, dflat), win_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _WRITE_WIN, dflat), win_index),
-            pl.BlockSpec((1, _WRITE_WIN, dflat), win_index),
-        ],
-    )
-    ko, vo = pl.pallas_call(
-        _cache_write_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, dflat), k_cache.dtype),
-            jax.ShapeDtypeStruct((b, s, dflat), v_cache.dtype),
-        ],
-        # Alias indices count ALL inputs incl. the scalar-prefetch
-        # arg: pos=0, k_new=1, v_new=2, k_cache=3, v_cache=4.
-        input_output_aliases={3: 0, 4: 1},
-        interpret=interpret,
-    )(pos.astype(jnp.int32),
-      k_new.reshape(b, dflat), v_new.reshape(b, dflat),
-      k_cache.reshape(b, s, dflat), v_cache.reshape(b, s, dflat))
-    return (ko.reshape(b, s, hkv, hd), vo.reshape(b, s, hkv, hd))
-
-
-def cache_write(k_cache: jax.Array, v_cache: jax.Array,
-                k_new: jax.Array, v_new: jax.Array,
-                pos: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Write one new K/V position per row: k/v_cache [B, S, Hkv, hd],
-    k/v_new [B, Hkv, hd], pos [B] int (row b writes index pos[b]).
-    Returns the updated caches (in-place on TPU via aliasing)."""
-    if _use_pallas('WRITE') and (k_cache.shape[2] *
-                                 k_cache.shape[3]) % 128 == 0:
-        return _cache_write_pallas(k_cache, v_cache, k_new, v_new,
-                                   pos)
-    return _reference_cache_write(k_cache, v_cache, k_new, v_new,
-                                  pos)

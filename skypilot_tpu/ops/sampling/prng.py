@@ -18,9 +18,9 @@ split chain to advance). Consequences, all load-bearing:
 
 Keys are derived with ``jax.random`` threefry machinery from TRACED
 seed/position arrays, so they live inside the jitted step functions —
-one executable serves every request. This module is the ONLY place in
-``serve/`` allowed to construct PRNG keys inside jitted code (the
-``serve-jit-prng`` skylint rule enforces it).
+one executable serves every request. This module is the ONLY place
+allowed to construct PRNG keys inside the serve plane's jitted steps
+(the ``serve-jit-prng`` skylint rule enforces it).
 """
 import jax
 import jax.numpy as jnp

@@ -17,9 +17,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from skypilot_tpu.serve.sampling import prng
+from skypilot_tpu.ops.sampling import prng
 
-# Matches serve/batching.py's _NEG_INF (finite: arithmetic on it stays
+# Matches models/decode.py's _NEG_INF (finite: arithmetic on it stays
 # NaN-free through softmax/cumsum).
 NEG_INF = -1e30
 

@@ -73,19 +73,19 @@ from skypilot_tpu import exceptions
 from skypilot_tpu import metrics as metrics_lib
 from skypilot_tpu import tpu_logging
 from skypilot_tpu import trace as trace_lib
-from skypilot_tpu.models import decode, llama
-from skypilot_tpu.models.quant import matmul as _mm
+from skypilot_tpu.models import llama
+from skypilot_tpu.models.decode import (decode_steps_paged,
+                                        forward_paged,
+                                        verify_step_paged)
+from skypilot_tpu.ops.sampling import sample as sample_lib
 from skypilot_tpu.resilience import faults as faults_lib
 from skypilot_tpu.serve import kv_pool as kv_pool_lib
 from skypilot_tpu.serve import prefix_hash
 from skypilot_tpu.serve.sampling import grammar as grammar_lib
-from skypilot_tpu.serve.sampling import sample as sample_lib
-from skypilot_tpu.serve.sampling.accept import accept_tokens
 
 logger = tpu_logging.init_logger(__name__)
 
 Params = Dict[str, Any]
-_NEG_INF = -1e30
 
 # Trailing window for the exported prefix hit-rate gauge — matches
 # the prefix-hit-ratio-low alert rule's evaluation window, so a
@@ -146,426 +146,8 @@ SPEC_MIN_DISPATCH_TOKENS = 4
 
 
 # ---------------------------------------------------------------------
-# Per-row decode primitives
-# ---------------------------------------------------------------------
-
-
-def _rope_rows(x: jax.Array, angles: jax.Array) -> jax.Array:
-    """Rotate-half RoPE for one token per row: x [B, 1, H, D],
-    angles [B, D/2] (each row at its OWN position)."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = jnp.cos(angles)[:, None, None, :]
-    sin = jnp.sin(angles)[:, None, None, :]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-        axis=-1).astype(x.dtype)
-
-
-def _attend_rows(q: jax.Array, k: jax.Array, v: jax.Array,
-                 pos: jax.Array, scale: float) -> jax.Array:
-    """q [B, 1, H, hd]; k/v [B, S, Hkv, hd]; pos [B] = the index the
-    current token was just written at. Row b attends keys [0, pos_b].
-    On TPU this is the length-aware Pallas kernel
-    (ops/decode_attention.py): HBM reads scale with each row's
-    actual context, not the cache allocation."""
-    from skypilot_tpu.ops import decode_attention as da
-    out = da.decode_attention(q[:, 0], k, v, pos + 1, scale)
-    return out[:, None]
-
-
-def decode_steps_rows(params: Params, tokens: jax.Array,
-                      caches, pos: jax.Array, active: jax.Array,
-                      config: llama.LlamaConfig,
-                      num_steps: int, sampling=None):
-    """Decode ``num_steps`` tokens for every row at PER-ROW
-    positions, as one dispatch (inner ``lax.scan``).
-
-    tokens [B] (each row's most recent token); ``caches`` =
-    (k_cache, v_cache, k_scale, v_scale) with k/v [L, B, S, Hkv, hd]
-    (int8 + bf16 scales [L, B, S, Hkv] when quantized — int8 KV
-    halves the decode loop's dominant HBM stream; scales are None
-    for a bf16 cache); pos [B] = next write index per row; active
-    [B] bool — inactive rows still compute (static shapes) but their
-    pos does not advance and their writes keep landing on the same
-    parked cell, so they cannot corrupt anything.
-
-    This is the CONTIGUOUS-cache variant (one [S] slab per row) —
-    the engine itself runs ``decode_steps_paged``, its block-table-
-    indirected twin with identical numerics.
-
-    ``sampling`` (serve/sampling/): None keeps the greedy argmax
-    path byte-identical to before; otherwise a dict of TRACED
-    per-row knob arrays (``temps``/``top_ps``/``seeds`` [B]) plus
-    the grammar mask table (``mask_table`` [M, V] bool,
-    ``mask_idx`` [B] — row 0 is all-allowed) and each step's next
-    token is ``sample_rows`` keyed ``(seed, position)``;
-    ``temperature <= 0`` rows still reduce to the argmax.
-
-    Returns (out_tokens [B, num_steps], caches, new_pos).
-    """
-    llama.require_plain_stack(
-        config, 'decode_steps_rows (the contiguous-cache decode)')
-    k_cache, v_cache, k_scale, v_scale = caches
-    cparams = jax.tree.map(
-        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
-        params)
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    b = tokens.shape[0]
-    quantized = k_scale is not None  # static at trace
-
-    def one_token(carry, _):
-        tok, kc_all, vc_all, ks_all, vs_all, cur = carry
-        angles = llama._rope_frequencies(config, cur)   # [B, hd/2]
-        x = cparams['embed'][tok][:, None]              # [B, 1, D]
-        if config.scale_embeddings:
-            import math
-            x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-
-        def layer(carry_x, scanned):
-            xc, cur_ = carry_x
-            # None scale leaves pass through lax.scan as empty
-            # pytrees — one unpack serves both cache dtypes.
-            lp, kc, vc, ks, vs = scanned
-            h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
-                                config.norm_offset)
-            q = _mm(h, lp['wq'])
-            k = _mm(h, lp['wk'])
-            v = _mm(h, lp['wv'])
-            if config.qkv_bias:
-                q = q + lp['bq']
-                k = k + lp['bk']
-                v = v + lp['bv']
-            q = q.reshape(b, 1, nh, hd)
-            k = k.reshape(b, 1, nkv, hd)
-            v = v.reshape(b, 1, nkv, hd)
-            q = _rope_rows(q, angles)
-            k = _rope_rows(k, angles)
-            # The caller persists the new rows with one merged write
-            # per token (emitting full updated slices as scan
-            # outputs rewrote the entire cache per token — measured
-            # ~1.6 ms/token at 1B b16, the same pathology fixed in
-            # models/decode.py).
-            from skypilot_tpu.ops import decode_attention as da
-            if ks is not None:
-                # int8 KV: quantize the new row and hand it to
-                # attention beside the cache, which is read as int8
-                # (da.view_attention: no in-layer write, no
-                # dequantised copy) — the same arithmetic as the
-                # paged twin, which the token-equality tests hold
-                # this path to.
-                k_rows, ks_rows = decode._quantize_kv(k)
-                v_rows, vs_rows = decode._quantize_kv(v)
-                attn = da.view_attention(
-                    q[:, 0], kc, vc, cur_, hd ** -0.5,
-                    jnp.swapaxes(ks, 1, 2), jnp.swapaxes(vs, 1, 2),
-                    new=(k_rows[:, 0], v_rows[:, 0], ks_rows[:, 0],
-                         vs_rows[:, 0]))[:, None]
-            else:
-                # Per-row cache write so this step's attention sees
-                # the new row: Pallas windowed write when opted in;
-                # otherwise the one-hot full-cache where() (the
-                # JetStream trick to avoid XLA's unvectorized
-                # scatter).
-                k_rows, v_rows = k, v
-                ks_rows = vs_rows = None
-                kc, vc = da.cache_write(kc, vc, k[:, 0], v[:, 0],
-                                        cur_)
-                attn = _attend_rows(q, kc, vc, cur_, hd ** -0.5)
-            xc = xc + _mm(attn.reshape(b, 1, nh * hd), lp['wo'])
-            h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
-                                config.norm_offset)
-            if config.n_experts:
-                # MoE routes per token — per-row positions are
-                # irrelevant to the dispatch, so the training-path
-                # expert MLP drops straight in (aux loss unused at
-                # inference).
-                moe_out, _ = llama._moe_mlp(config, h, lp)
-                xc = xc + moe_out
-            else:
-                gate = llama.mlp_act(config)(
-                    _mm(h, lp['w_gate']).astype(jnp.float32)
-                ).astype(h.dtype)
-                up = _mm(h, lp['w_up'])
-                xc = xc + _mm(gate * up, lp['w_down'])
-            return (xc, cur_), (
-                k_rows[:, 0], v_rows[:, 0],
-                None if ks_rows is None else ks_rows[:, 0],
-                None if vs_rows is None else vs_rows[:, 0])
-
-        (x, _), rows = jax.lax.scan(
-            layer, (x, cur),
-            (cparams['layers'], kc_all, vc_all, ks_all, vs_all))
-        # Persist the new rows with ONE merged elementwise select per
-        # token — XLA updates the carried cache buffers in place (no
-        # fresh ys allocation, no carry-aliasing copies).
-        hit = (jnp.arange(kc_all.shape[2])[None, :] ==
-               cur[:, None])                             # [B, S]
-        kc_all = jnp.where(hit[None, :, :, None, None],
-                           rows[0][:, :, None], kc_all)
-        vc_all = jnp.where(hit[None, :, :, None, None],
-                           rows[1][:, :, None], vc_all)
-        if quantized:
-            ks_all = jnp.where(hit[None, :, :, None],
-                               rows[2][:, :, None], ks_all)
-            vs_all = jnp.where(hit[None, :, :, None],
-                               rows[3][:, :, None], vs_all)
-        x = llama._rms_norm(x, cparams['final_norm'], config.norm_eps,
-                            config.norm_offset)
-        if config.tie_embeddings:
-            logits = (x @ llama.output_head(cparams, config))
-        else:
-            logits = _mm(x, cparams['lm_head'])
-        if sampling is None:
-            nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
-        else:
-            # Counter-keyed per-row sampling: the draw at position
-            # ``cur`` (the index of the token these logits consumed)
-            # depends only on the row's own (seed, position) — batch
-            # invariance (serve/sampling/prng.py).
-            allowed = sample_lib.gather_masks(sampling['mask_table'],
-                                              sampling['mask_idx'])
-            nxt = sample_lib.sample_rows(
-                logits[:, -1], sampling['temps'], sampling['top_ps'],
-                sampling['seeds'], cur, allowed)
-        # Inactive rows: hold the last token and do NOT advance, so
-        # their next write overwrites the same parked cell.
-        nxt = jnp.where(active, nxt, tok)
-        new_cur = jnp.where(active, cur + 1, cur)
-        return (nxt, kc_all, vc_all, ks_all, vs_all, new_cur), nxt
-
-    (tok, k_cache, v_cache, k_scale, v_scale, pos), toks = \
-        jax.lax.scan(
-            one_token,
-            (tokens, k_cache, v_cache, k_scale, v_scale, pos), None,
-            length=num_steps)
-    return (toks.swapaxes(0, 1),
-            (k_cache, v_cache, k_scale, v_scale), pos)
-
-
-# Row-gathered LoRA delta (serve/adapters/) — ONE implementation,
-# shared with the prefill path so all three jitted steps attach the
-# identical adapter math.
-_lora_gather_delta = decode.lora_gather_delta
-
-
-def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
-    """[E, NB * bs, ...] -> every KV entry's blocks as ONE pool
-    [E * NB, bs, ...], which entry e (a pass and a layer,
-    ``kv_pool.KVBlockPool``) reads through its block table offset by
-    e * NB: an entry's slice taken out of the stacked pool first (a
-    scanned input, or an index) is a copy of the slice, 75 MB of K
-    and of V a layer at 4,561 blocks."""
-    return flat.reshape(-1, block_size, *flat.shape[2:])
-
-
-def _scale_views(k_scale, v_scale, block_tables: jax.Array,
-                 block_size: int):
-    """Every KV entry's K and V scales for the rows' views
-    (``decode_attention.gather_scales``), gathered OUTSIDE the layer
-    scan as ONE array [E, 2, B, Hkv, S] float32 (201 MB at 32 x 24 x
-    8 x 4,096; 2.7 ms of a 48 ms decode step) that the layer body
-    indexes by entry; None for a bf16 pool. k_scale/v_scale are the
-    flat [E, NB * bs, Hkv] pools. Timed on the v5e (PERF.md, PR 26):
-    scale pools read inside the layer scan cost 7-120 ms a step more
-    — an array of 37-100 MB that rides the layer loop is placed in
-    the compiler's on-chip memory space and evicted and fetched back
-    in every layer (two [L, B, Hkv, S] arrays: 55 ms a step), and
-    the pools' [.., 16, 8] tail reshapes to blocks by a copy
-    (172 ms)."""
-    from skypilot_tpu.ops import decode_attention as da
-    if k_scale is None:
-        return None
-    return jnp.stack([
-        da.gather_scales(
-            sp.reshape(sp.shape[0], -1, block_size, sp.shape[-1]),
-            block_tables) for sp in (k_scale, v_scale)], axis=1)
-
-
-def decode_steps_paged(params: Params, tokens: jax.Array,
-                       caches, block_tables: jax.Array,
-                       pos: jax.Array, active: jax.Array,
-                       config: llama.LlamaConfig,
-                       num_steps: int, block_size: int,
-                       adapters=None, adapter_idx=None,
-                       sampling=None):
-    """Block-table-indirected twin of ``decode_steps_rows`` with
-    identical numerics: the per-row [S] slab is replaced by gathers
-    and scatters through ``block_tables`` [B, MB] into the shared
-    pool ``caches`` = (k, v, k_scale, v_scale) with k/v
-    [E, num_blocks, block_size, Hkv, hd] (int8 + bf16 scales
-    [E, num_blocks, block_size, Hkv] when quantized; E as
-    ``kv_pool.KVBlockPool`` defines it). The layers run
-    ``config.loop_passes`` times over the same stacked weights
-    (``decode.looped_stack``: scopes ``loop_pass``, ``branch_norm``,
-    ``exit_gate``), pass t, layer l on entry t * n_layers + l.
-
-    Attention per layer is the gather-based
-    ``ops.decode_attention.paged_decode_attention``: row b's logical
-    view of positions [0, pos) is gathered out of the pool block by
-    block and masked to its own length, so recycled-block garbage
-    past the length contributes exactly 0; an int8 pool is read as
-    int8. This step's own K/V row reaches attention as an operand:
-    there is NO in-layer pool write (until PR 26 there was one, "so
-    this step's attention sees the new row"; the chip's trace showed
-    it copying the layer's whole pool slice, 2 x 75 MB in every layer
-    of every step). The pool is written once a token, after the layer
-    scan, through ``kv_pool.write_index`` — parked rows (inactive
-    lanes) and overrun positions land in the scratch block, never in
-    a block another request owns.
-
-    Multi-adapter serving (serve/adapters/): ``adapters`` is the
-    resident set's stacked factor dict (leaves ``[L, C+1, ...]``,
-    scanned with the layer stack) and ``adapter_idx`` [B] maps each
-    row to its slot; row-gathered LoRA deltas attach to the q and v
-    projections (``_lora_gather_delta``). ``adapters=None`` (a
-    distinct jit executable — None is an empty pytree) keeps the
-    adapterless math byte-identical to before.
-
-    ``sampling``: as in ``decode_steps_rows`` — None keeps the
-    greedy argmax executable byte-identical; a knob dict samples
-    each step's token per row, keyed ``(seed, position)``, with the
-    grammar mask gathered in-jit by traced index.
-
-    The ``jax.named_scope``s here and in the functions this calls
-    (``qkv_proj``, ``lora_delta``, ``kv_write``, ``paged_gather``,
-    ``kv_dequant``, ``decode_attention``, ``o_proj``, ``mlp``,
-    ``sampler``; the verify and prefill twins carry the same) name
-    the program's parts in ``op_name=`` of the compiled text, which
-    is the only place the chip's trace lets them be looked up; they
-    change HLO metadata and nothing else.
-
-    Returns (out_tokens [B, num_steps], caches, new_pos).
-    """
-    from skypilot_tpu.ops import decode_attention as da
-
-    k_pool, v_pool, k_scale, v_scale = caches
-    ne, nb, bs = k_pool.shape[:3]
-    assert bs == block_size, (bs, block_size)
-    assert ne == config.kv_entries, (ne, config.kv_entries)
-    cparams = jax.tree.map(
-        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
-        params)
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    b = tokens.shape[0]
-    quantized = k_scale is not None  # static at trace
-
-    # Flat [NB * bs, ...] pool views — write index math is 1-D
-    # flat-slot; attention reads whole blocks (``_all_blocks``).
-    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
-    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
-
-    def one_token(carry, _):
-        tok, kp_all, vp_all, ks_all, vs_all, cur = carry
-        angles = llama._rope_frequencies(config, cur)   # [B, hd/2]
-        x = cparams['embed'][tok][:, None]              # [B, 1, D]
-        if config.scale_embeddings:
-            import math
-            x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-        widx = kv_pool_lib.write_index(block_tables, cur,
-                                       block_size)      # [B]
-        scale_views = _scale_views(ks_all, vs_all, block_tables, bs)
-
-        def layer(xc, lp, entry, ad):
-            # ``entry``: this pass's and layer's KV entry (at one
-            # pass, the layer); ``ad`` is None without adapters.
-            h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
-                                config.norm_offset)
-            with jax.named_scope('qkv_proj'):
-                q = _mm(h, lp['wq'])
-                k = _mm(h, lp['wk'])
-                v = _mm(h, lp['wv'])
-            if ad is not None:
-                q = q + _lora_gather_delta(
-                    h, ad['wq_a'], ad['wq_b'],
-                    adapter_idx).astype(q.dtype)
-                v = v + _lora_gather_delta(
-                    h, ad['wv_a'], ad['wv_b'],
-                    adapter_idx).astype(v.dtype)
-            if config.qkv_bias:
-                q = q + lp['bq']
-                k = k + lp['bk']
-                v = v + lp['bv']
-            q = q.reshape(b, 1, nh, hd)
-            k = k.reshape(b, 1, nkv, hd)
-            v = v.reshape(b, 1, nkv, hd)
-            q = _rope_rows(q, angles)
-            k = _rope_rows(k, angles)
-            if quantized:
-                k_rows, ks_rows = decode._quantize_kv(k)
-                v_rows, vs_rows = decode._quantize_kv(v)
-            else:
-                k_rows, v_rows = k, v
-                ks_rows = vs_rows = None
-            # No in-layer write: the layer's pool slice is a scanned
-            # input, so ``kc.at[widx].set`` copied the whole slice
-            # (75 MB of K and of V at 4,561 blocks, every layer of
-            # every step: 6 % of the step in PR 25's chip trace) for
-            # B new rows. Attention takes this step's rows as an
-            # operand beside the view of positions [0, cur); the one
-            # merged scatter after the layer scan persists them.
-            new = tuple(None if r is None else r[:, 0] for r in
-                        (k_rows, v_rows, ks_rows, vs_rows))
-            ks_view, vs_view = (None, None) if scale_views is None \
-                else jax.lax.dynamic_index_in_dim(
-                    scale_views, entry, 0, keepdims=False,
-                    allow_negative_indices=False)
-            attn = da.paged_decode_attention(
-                q[:, 0], _all_blocks(kp_all, bs),
-                _all_blocks(vp_all, bs), block_tables + entry * nb,
-                cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-                new=new)[:, None]
-            xc = decode.layer_tail(
-                config, xc, attn.reshape(b, 1, nh * hd), lp)
-            return xc, new
-
-        x, rows = decode.looped_stack(config, cparams, x, layer,
-                                      adapters)
-        # Persist the new rows: one merged scatter per token into the
-        # carried (donated) flat pools.
-        with jax.named_scope('kv_write'):
-            kp_all = kp_all.at[:, widx].set(rows[0])
-            vp_all = vp_all.at[:, widx].set(rows[1])
-            if quantized:
-                ks_all = ks_all.at[:, widx].set(rows[2])
-                vs_all = vs_all.at[:, widx].set(rows[3])
-        if config.tie_embeddings:
-            logits = (x @ llama.output_head(cparams, config))
-        else:
-            logits = _mm(x, cparams['lm_head'])
-        with jax.named_scope('sampler'):
-            if sampling is None:
-                nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
-            else:
-                # Counter-keyed per-row sampling at position ``cur``
-                # — the row's draw never depends on batch neighbors
-                # (serve/sampling/prng.py batch-invariance contract).
-                allowed = sample_lib.gather_masks(
-                    sampling['mask_table'], sampling['mask_idx'])
-                nxt = sample_lib.sample_rows(
-                    logits[:, -1], sampling['temps'],
-                    sampling['top_ps'], sampling['seeds'], cur,
-                    allowed)
-        # Inactive rows: hold the last token and do NOT advance, so
-        # their next (scratch-redirected) write stays parked.
-        nxt = jnp.where(active, nxt, tok)
-        new_cur = jnp.where(active, cur + 1, cur)
-        return (nxt, kp_all, vp_all, ks_all, vs_all, new_cur), nxt
-
-    (tok, kp, vp, ksp, vsp, pos), toks = jax.lax.scan(
-        one_token, (tokens, kp, vp, ksp, vsp, pos), None,
-        length=num_steps)
-    out_caches = (
-        kp.reshape(ne, nb, bs, nkv, hd),
-        vp.reshape(ne, nb, bs, nkv, hd),
-        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
-        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
-    return toks.swapaxes(0, 1), out_caches, pos
-
-
-# ---------------------------------------------------------------------
-# Speculative decoding: n-gram drafting + batched multi-token verify
+# Speculative decoding: n-gram drafting (the batched multi-token
+# verify is ``models/decode.verify_step_paged``)
 # ---------------------------------------------------------------------
 
 
@@ -651,195 +233,6 @@ def update_spec_k(cur_k: int, window, draft_k: int) -> int:
     if rate > SPEC_GROW_ABOVE and cur_k < draft_k:
         return min(draft_k, max(1, cur_k * 2))
     return cur_k
-
-
-def _rope_verify(x: jax.Array, angles: jax.Array) -> jax.Array:
-    """Rotate-half RoPE for a verify window: x [B, W, H, D],
-    angles [B, W, D/2] (each row's W positions at their own
-    offsets)."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-        axis=-1).astype(x.dtype)
-
-
-def verify_step_paged(params: Params, tokens: jax.Array,
-                      caches, block_tables: jax.Array,
-                      pos: jax.Array, n_real: jax.Array,
-                      config: llama.LlamaConfig,
-                      width: int, block_size: int,
-                      adapters=None, adapter_idx=None,
-                      sampling=None):
-    """Batched multi-token VERIFY forward — the speculative twin of
-    ``decode_steps_paged``: instead of scanning ``num_steps`` single
-    tokens, ONE forward carries ``width`` = draft_k + 1 query
-    positions per row (the row's current token at ``pos[b]`` plus
-    its drafted continuation), so one weight read amortizes over up
-    to width accepted-and-emitted tokens — the bandwidth-bound
-    decode fix.
-
-    tokens [B, W] (row b's positions pos[b]..pos[b]+W-1, only the
-    first n_real[b] real — padded lanes write scratch and their
-    outputs are ignored); caches/block_tables as in
-    ``decode_steps_paged``. Drafted K/V is written into the row's
-    blocks UP FRONT (one merged scatter after the layer scan; within
-    the forward the window's rows reach attention as an operand, as
-    in the decode twin); a rejection later simply rolls the
-    host-side ``pos`` back
-    so the stale rows are never attended again — no block copying,
-    no scatter-undo (the length-masked paged attention makes
-    abandoning them free). Attention is
-    ``ops.decode_attention.paged_decode_attention`` in its
-    [B, W, ...] form with the intra-draft causal mask (query j
-    attends [0, pos+j]).
-
-    Returns (preds [B, W] int32, accepted [B] int32, new_pos [B],
-    new_tokens [B], caches): ``preds[b, j]`` is the target model's
-    token realization after position pos[b]+j — the argmax when
-    ``sampling`` is None, else ``sample_lib.verify_targets``'s
-    counter-keyed draw with the SAME key plain decode would use at
-    that position (``sampling`` also carries per-position grammar
-    masks, table [M, W, V] gathered by traced index). ``accepted``
-    is ``accept_tokens``'s per-row count (serve/sampling/accept.py
-    — the ONE acceptance implementation: the Chen et al. rejection
-    rule realized by maximal coupling, traced here so the
-    pos/tokens commit costs no extra host round-trips);
-    ``new_pos``/``new_tokens`` carry the committed frontier — pos
-    advances by accepted+1 for live rows (the ROLLBACK: rejected
-    positions simply stay past the new frontier) and parked rows
-    (n_real 0) are untouched.
-    """
-    from skypilot_tpu.ops import decode_attention as da
-
-    k_pool, v_pool, k_scale, v_scale = caches
-    ne, nb, bs = k_pool.shape[:3]
-    assert bs == block_size, (bs, block_size)
-    assert ne == config.kv_entries, (ne, config.kv_entries)
-    cparams = jax.tree.map(
-        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
-        params)
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    b = tokens.shape[0]
-    quantized = k_scale is not None  # static at trace
-
-    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
-    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
-
-    # As in the decode twin: every entry's blocks as one pool read
-    # through tables offset by entry * NB, and the scale views of
-    # all entries gathered once, outside the layer scan.
-    kblocks, vblocks = _all_blocks(kp, bs), _all_blocks(vp, bs)
-    scale_views = _scale_views(ksp, vsp, block_tables, bs)
-
-    positions = pos[:, None] + jnp.arange(width,
-                                          dtype=jnp.int32)[None, :]
-    angles = llama._rope_frequencies(
-        config, positions.reshape(-1)).reshape(b, width, -1)
-    x = cparams['embed'][tokens]                   # [B, W, D]
-    if config.scale_embeddings:
-        import math
-        x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-    widx = kv_pool_lib.verify_write_indices(
-        block_tables, pos, n_real, width, block_size)  # [B, W]
-    wflat = widx.reshape(-1)
-
-    def layer(xc, lp, entry, ad):
-        h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
-                            config.norm_offset)
-        with jax.named_scope('qkv_proj'):
-            q = _mm(h, lp['wq'])
-            k = _mm(h, lp['wk'])
-            v = _mm(h, lp['wv'])
-        if ad is not None:
-            # Same row-gathered LoRA attach as the decode twin —
-            # verify MUST apply the identical delta or speculation
-            # would accept drafts against a different model.
-            q = q + _lora_gather_delta(
-                h, ad['wq_a'], ad['wq_b'],
-                adapter_idx).astype(q.dtype)
-            v = v + _lora_gather_delta(
-                h, ad['wv_a'], ad['wv_b'],
-                adapter_idx).astype(v.dtype)
-        if config.qkv_bias:
-            q = q + lp['bq']
-            k = k + lp['bk']
-            v = v + lp['bv']
-        q = q.reshape(b, width, nh, hd)
-        k = k.reshape(b, width, nkv, hd)
-        v = v.reshape(b, width, nkv, hd)
-        q = _rope_verify(q, angles)
-        k = _rope_verify(k, angles)
-        if quantized:
-            k_rows, ks_rows = decode._quantize_kv(k)
-            v_rows, vs_rows = decode._quantize_kv(v)
-        else:
-            k_rows, v_rows = k, v
-            ks_rows = vs_rows = None
-        # No in-layer write (it copied the layer's whole pool
-        # slice, as in the decode twin): the draft window's own rows
-        # go to attention as an operand, causally among themselves,
-        # beside the view of positions [0, pos); the merged scatter
-        # after the layer scan persists them. A padded lane's row is
-        # seen only by padded lanes, whose outputs are ignored.
-        ks_view, vs_view = (None, None) if scale_views is None \
-            else jax.lax.dynamic_index_in_dim(
-                scale_views, entry, 0, keepdims=False,
-                allow_negative_indices=False)
-        attn = da.paged_decode_attention(
-            q, kblocks, vblocks, block_tables + entry * nb, pos,
-            hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-            new=(k_rows, v_rows, ks_rows, vs_rows))   # [B, W, Hq, hd]
-        xc = decode.layer_tail(
-            config, xc, attn.reshape(b, width, nh * hd), lp)
-        return xc, (
-            k_rows.reshape(b * width, nkv, hd),
-            v_rows.reshape(b * width, nkv, hd),
-            None if ks_rows is None
-            else ks_rows.reshape(b * width, nkv),
-            None if vs_rows is None
-            else vs_rows.reshape(b * width, nkv))
-
-    x, rows = decode.looped_stack(config, cparams, x, layer, adapters)
-    with jax.named_scope('kv_write'):
-        kp = kp.at[:, wflat].set(rows[0])
-        vp = vp.at[:, wflat].set(rows[1])
-        if quantized:
-            ksp = ksp.at[:, wflat].set(rows[2])
-            vsp = vsp.at[:, wflat].set(rows[3])
-    if config.tie_embeddings:
-        logits = (x @ llama.output_head(cparams, config))
-    else:
-        logits = _mm(x, cparams['lm_head'])
-    with jax.named_scope('sampler'):
-        if sampling is None:
-            preds = logits.argmax(-1).astype(jnp.int32)   # [B, W]
-        else:
-            # Target realizations drawn with the keys plain decode
-            # would use at each position — the maximal-coupling half
-            # of the speculative-sampling rule
-            # (serve/sampling/accept.py).
-            allowed = sample_lib.gather_masks(sampling['mask_table'],
-                                              sampling['mask_idx'])
-            preds = sample_lib.verify_targets(
-                logits, sampling['temps'], sampling['top_ps'],
-                sampling['seeds'], pos, allowed)          # [B, W]
-        accepted = accept_tokens(tokens, preds, n_real)   # [B]
-    live = n_real > 0
-    new_pos = jnp.where(live, pos + accepted + 1, pos)
-    new_tok = jnp.where(
-        live,
-        jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0],
-        tokens[:, 0])
-    out_caches = (
-        kp.reshape(ne, nb, bs, nkv, hd),
-        vp.reshape(ne, nb, bs, nkv, hd),
-        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
-        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
-    return preds, accepted, new_pos, new_tok, out_caches
 
 
 # ---------------------------------------------------------------------
@@ -1041,7 +434,7 @@ def _engine_metrics():
             'Per-row accepted/proposed fraction of each verify '
             'round, labeled by decode mode — sampled rows accept '
             'by the speculative-sampling rule '
-            '(serve/sampling/accept.py), greedy rows by argmax '
+            '(ops/sampling/accept.py), greedy rows by argmax '
             'match. A sampled-mode distribution sitting far below '
             'greedy on the same traffic means drafts are being '
             'rejected by randomness, not by model disagreement.',
@@ -1208,7 +601,7 @@ class BatchingEngine:
     - ``speculative``: self-speculative n-gram decoding (default
       on): rows with a prompt-lookup draft verify draft_k+1 tokens
       in ONE forward (``verify_step_paged``); the acceptance rule
-      (serve/sampling/accept.py — argmax match for greedy rows,
+      (ops/sampling/accept.py — argmax match for greedy rows,
       maximal-coupling speculative sampling for sampled ones)
       keeps outputs token-for-token equal to plain decode, and an
       adaptive per-request controller collapses the draft length to
@@ -1269,34 +662,8 @@ class BatchingEngine:
         self.config = config
         self.slots = slots
         self.max_seq = max_seq or config.max_seq_len
-        from skypilot_tpu.ops import decode_attention as da
-        if da._use_pallas():  # pylint: disable=protected-access
-            # Round the per-request view up to the decode kernel's
-            # chunk size so the length-aware attention path engages
-            # on the gathered [B, MB * block_size] view (the padding
-            # is never read: reads scale with row lengths).
-            blk = da._BLOCK_S  # pylint: disable=protected-access
-            requested = self.max_seq
-            self.max_seq = max(2 * blk,
-                               -(-self.max_seq // blk) * blk)
-            if self.max_seq != requested:
-                logger.warning(
-                    'SKYTPU_PALLAS_DECODE: max_seq %d rounded up to '
-                    '%d (decode-kernel chunk %d); block tables grow '
-                    'accordingly — resize --slots/num_blocks if HBM '
-                    'is tight.', requested, self.max_seq, blk)
-        # max_seq must be block-aligned (the table maps whole
-        # blocks) — AND keep any Pallas rounding above intact: align
-        # to lcm(block_size, decode-kernel chunk) or the gathered
-        # [B, MB * block_size] view silently fails the kernel's
-        # divisibility guard and every dispatch falls back to the
-        # dense reference the operator opted out of.
-        align = block_size
-        if da._use_pallas():  # pylint: disable=protected-access
-            import math
-            blk = da._BLOCK_S  # pylint: disable=protected-access
-            align = block_size * blk // math.gcd(block_size, blk)
-        self.max_seq = -(-self.max_seq // align) * align
+        # Block-aligned: the table maps whole blocks.
+        self.max_seq = -(-self.max_seq // block_size) * block_size
         self.block_size = block_size
         self.max_blocks_per_req = self.max_seq // block_size
         if num_blocks is None:
@@ -1462,7 +829,7 @@ class BatchingEngine:
         self._verify_fn = jax.jit(verify_step_paged,
                                   static_argnums=(6, 7, 8),
                                   donate_argnums=(2,))
-        self._prefill_fn = jax.jit(decode.forward_paged,
+        self._prefill_fn = jax.jit(forward_paged,
                                    static_argnums=(6, 7),
                                    donate_argnums=(2,))
         # First-token selection from the final prefill chunk's
@@ -1574,7 +941,7 @@ class BatchingEngine:
         if not isinstance(seed, int) or isinstance(seed, bool):
             raise ValueError(
                 f'seed must be an integer, got {seed!r}')
-        # The PRNG keys on uint32(seed) (serve/sampling/prng.py), so
+        # The PRNG keys on uint32(seed) (ops/sampling/prng.py), so
         # any Python int is taken mod 2**32 — stored as the int32
         # two's-complement of that value because the per-row knob
         # arrays pack as int32 (an unmasked 2**31+ seed would

@@ -20,7 +20,9 @@ deadlocking when the pool runs dry.
 
 TPU-first design notes:
 - All shapes static: the pool, the per-request block tables
-  ``[B, max_blocks]`` and the gather/scatter index math below are
+  ``[B, max_blocks]`` and the gather/scatter index arithmetic
+  (``ops/decode_attention.py``, where the pool's format on the
+  device is stated; this module is the host allocator) are
   fixed-shape; occupancy is data.
 - Block 0 is a reserved SCRATCH block, never allocated: parked rows
   (inactive decode lanes) and padded prefill positions direct their
@@ -53,7 +55,7 @@ Admission matches an incoming prompt's hash chain
 only the suffix; ``free`` only ever decrements. Shared blocks are
 immutable by construction — only FULL blocks are registered, and a
 request's writes land strictly past its reused prefix — so the
-SCRATCH invariant and the write-index math above are unchanged.
+SCRATCH invariant and the write-index arithmetic are unchanged.
 """
 import collections
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -64,12 +66,10 @@ import jax.numpy as jnp
 from skypilot_tpu import exceptions
 from skypilot_tpu import tpu_logging
 from skypilot_tpu.models import llama
+from skypilot_tpu.ops.decode_attention import SCRATCH_BLOCK
 from skypilot_tpu.serve import prefix_hash
 
 logger = tpu_logging.init_logger(__name__)
-
-# The reserved scratch block (see module docstring).
-SCRATCH_BLOCK = 0
 
 # Partial-match (COW) index bound: at most this many registered
 # children per chain parent are kept discoverable for partial-block
@@ -80,75 +80,6 @@ SCRATCH_BLOCK = 0
 # for EXACT full-chain matching (the common win); they just aren't
 # COW candidates.
 MAX_PARTIAL_CHILDREN = 64
-
-
-# ---------------------------------------------------------------------
-# Index math (pure, shape-static; used inside jitted steps)
-# ---------------------------------------------------------------------
-
-
-def read_indices(block_tables: jax.Array,
-                 block_size: int) -> jax.Array:
-    """Flat pool-slot indices for every logical position of every
-    row: block_tables [..., MB] int32 -> [..., MB * block_size].
-    Positions in unallocated tail blocks land in the scratch block —
-    callers mask them via their per-row lengths before softmax."""
-    offs = jnp.arange(block_size, dtype=jnp.int32)
-    flat = (block_tables[..., :, None] * block_size +
-            offs[None, :])
-    return flat.reshape(*block_tables.shape[:-1], -1)
-
-
-def write_index(block_tables: jax.Array, pos: jax.Array,
-                block_size: int) -> jax.Array:
-    """Flat pool-slot index for each row's next write:
-    block_tables [B, MB], pos [B] -> [B]. Positions at or past the
-    table's capacity are redirected to the scratch block (overrun
-    tokens of rows that finished mid-dispatch, parked lanes)."""
-    mb = block_tables.shape[-1]
-    blk = jnp.minimum(pos // block_size, mb - 1)
-    idx = (jnp.take_along_axis(block_tables, blk[:, None],
-                               axis=1)[:, 0] * block_size +
-           pos % block_size)
-    safe = (pos >= 0) & (pos < mb * block_size)
-    return jnp.where(safe, idx, SCRATCH_BLOCK * block_size)
-
-
-def verify_write_indices(block_tables: jax.Array, pos: jax.Array,
-                         n_real: jax.Array, width: int,
-                         block_size: int) -> jax.Array:
-    """Flat pool-slot indices for a speculative VERIFY dispatch:
-    row b writes ``width`` consecutive positions starting at
-    ``pos[b]`` (its current token plus drafted continuation), of
-    which only the first ``n_real[b]`` are real. Padded draft lanes
-    (j >= n_real[b]), parked rows (n_real 0) and positions past the
-    table capacity all redirect to the scratch block — a rejected or
-    padded draft can never touch a block another request owns.
-    block_tables [B, MB], pos/n_real [B] -> [B, width]."""
-    t = jnp.arange(width, dtype=jnp.int32)
-    p = pos[:, None] + t[None, :]                        # [B, W]
-    mb = block_tables.shape[-1]
-    blk = jnp.minimum(jnp.maximum(p, 0) // block_size, mb - 1)
-    idx = (jnp.take_along_axis(block_tables, blk, axis=1) *
-           block_size + jnp.maximum(p, 0) % block_size)
-    valid = ((t[None, :] < n_real[:, None]) & (p >= 0) &
-             (p < mb * block_size))
-    return jnp.where(valid, idx, SCRATCH_BLOCK * block_size)
-
-
-def chunk_write_indices(block_row: jax.Array, start: jax.Array,
-                        real_len: jax.Array, chunk: int,
-                        block_size: int) -> jax.Array:
-    """Flat pool-slot indices for a prefill chunk's ``chunk`` rows
-    written at positions [start, start+real_len): block_row [MB].
-    Padded positions (t >= real_len) go to the scratch block."""
-    t = jnp.arange(chunk, dtype=jnp.int32)
-    pos = start + t
-    mb = block_row.shape[0]
-    blk = jnp.minimum(pos // block_size, mb - 1)
-    idx = block_row[blk] * block_size + pos % block_size
-    valid = (t < real_len) & (pos < mb * block_size)
-    return jnp.where(valid, idx, SCRATCH_BLOCK * block_size)
 
 
 # ---------------------------------------------------------------------
@@ -217,7 +148,8 @@ class KVBlockPool:
         # (scratch) is never handed out. Double-free detection moved
         # to the refcount table below — a block with no reference is
         # simply not freeable.
-        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        self._free: List[int] = list(
+            range(num_blocks - 1, SCRATCH_BLOCK, -1))
         # Prefix cache (module docstring): refcounts for allocated
         # blocks, LRU over refcount-0 registered blocks, and the
         # hash-chain registry. ``_hash_meta`` keeps (parent, tokens)

@@ -1,48 +1,19 @@
-"""Sampling subsystem for the paged batching engine
-(docs/sampling.md).
+"""The host half of the sampling subsystem (docs/sampling.md):
+structured decoding. ``grammar`` compiles JSON-schema / regex
+grammars (cached by grammar hash) to a character DFA and walks it
+against the token vocabulary to produce per-request allowed-token
+masks, which the jitted steps gather by traced index.
 
-Makes sampled decode a first-class citizen of the continuous-batching
-serve plane under a contract STRONGER than greedy exactness: **batch
-invariance** — a request's sampled output depends only on its own
-``(seed, position)`` pairs, never on its batch neighbors, its slot
-assignment, or whether it was preempted and resumed.
-
-Three pillars, one module each:
-
-- ``prng``   — counter-based per-row PRNG: every random draw is keyed
-  by ``(request_seed, absolute_position)`` alone, derived INSIDE the
-  jitted step functions from traced per-row arrays. No host RNG, no
-  split-chain whose value depends on how many draws other rows made.
-- ``sample`` — per-row temperature/top-p sampling usable inside the
-  jitted decode/prefill/verify steps (traced per-row knob arrays, one
-  executable for every request mix; ``temperature <= 0`` rows reduce
-  bitwise to the greedy argmax) plus the grammar-mask gather.
-- ``accept`` — THE single speculative-acceptance implementation
-  (``accept_tokens``): the Chen et al. 2023 rejection-sampling rule,
-  realized by maximal coupling so spec-on output is bitwise identical
-  to spec-off output (see accept.py for the math).
-- ``grammar`` — host-side structured decoding: JSON-schema / regex
-  grammars compiled (and cached by grammar hash) to a character DFA,
-  walked against the token vocabulary to produce per-request
-  allowed-token masks the jitted steps gather by traced index.
-
-The batch-invariance contract is machine-checked: the ``serve-jit-prng``
-skylint rule forbids PRNG-key construction / host RNG inside ``serve/``
-jitted step functions outside this package.
+The device half — the counter-keyed PRNG, per-row sampling and the
+single speculative-acceptance rule that the model steps trace — is
+``skypilot_tpu/ops/sampling/``, below ``models/decode.py``.
 """
-from skypilot_tpu.serve.sampling.accept import accept_tokens
 from skypilot_tpu.serve.sampling.grammar import (CompiledGrammar,
                                                  GrammarError,
                                                  compile_grammar,
                                                  grammar_hash)
-from skypilot_tpu.serve.sampling.prng import row_key, row_keys
-from skypilot_tpu.serve.sampling.sample import (gather_masks,
-                                                sample_first,
-                                                sample_rows,
-                                                verify_targets)
 
 __all__ = [
-    'accept_tokens', 'CompiledGrammar', 'GrammarError',
-    'compile_grammar', 'grammar_hash', 'row_key', 'row_keys',
-    'gather_masks', 'sample_first', 'sample_rows', 'verify_targets',
+    'CompiledGrammar', 'GrammarError', 'compile_grammar',
+    'grammar_hash',
 ]

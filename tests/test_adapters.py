@@ -391,14 +391,25 @@ class TestEngineExactness:
     def test_exact_across_preempt_resume(self, setup, tenants):
         """A pool sized to force preemption: the preempted adapter
         request resumes (prompt + generated recompute) and still
-        matches its solo run token-for-token."""
+        matches its solo run token-for-token.
+
+        The sizing has to hold whatever speculation does. Admission
+        gives each 16-token prompt 3 blocks of 8; a row asks for its
+        5th block past 32 tokens while the other still holds at
+        least 3: 8 > the 7 usable of ``num_blocks=8``, so the pool
+        runs dry while both are resident, and one request alone (6
+        blocks for 16 + 28 tokens) still fits. Nine usable blocks
+        did not force it: at this 61-token vocabulary tenant-b's
+        greedy output is one token repeated, its drafts are accepted
+        whole (8, 8, 5), and it retires holding 6 blocks while the
+        other row still holds 3."""
         config, params = setup
         want = [self._solo(params, config, tenants, p, a, 28)
                 for p, a in zip(self.PROMPTS[:2],
                                 ['tenant-a', 'tenant-b'])]
         engine = _engine(params, config, tenants,
                          preload=['tenant-a', 'tenant-b'],
-                         slots=2, num_blocks=10)
+                         slots=2, num_blocks=8)
         try:
             queues = [engine.submit(p, 28, adapter=a)
                       for p, a in zip(self.PROMPTS[:2],

@@ -174,9 +174,9 @@ FIXTURES = {
         ''',
     }, None),
     'serve-jit-prng': ({
-        # Scope-gated: serve/ outside serve/sampling/ — a jitted
-        # decode step that builds its own key chain, hidden behind
-        # a local helper (the call-graph pass catches it).
+        # Scope-gated: serve/ — a jitted decode step that builds
+        # its own key chain, hidden behind a local helper (the
+        # call-graph pass catches it).
         'serve/rogue_engine.py': '''
             import jax
             def _draw(logits, step):
@@ -266,6 +266,28 @@ class TestSeededViolations:
             assert ghost in messages, (rule, messages)
             assert len(findings) >= 2, (rule, messages)
 
+
+    def test_serve_prng_reaches_the_steps_below_the_scheduler(
+            self, tmp_path):
+        """The paged engine's model steps live in models/decode.py
+        and are jitted from serve/batching.py: the rule takes them
+        as roots by name, and leaves that file's other jitted
+        functions (the dense sampler) alone."""
+        files = {'models/decode.py': '''
+            import jax
+            def _draw(logits, seed):
+                return jax.random.categorical(
+                    jax.random.PRNGKey(seed), logits)
+            def decode_steps_paged(logits, seed):
+                return _draw(logits, seed)
+            def sample_tokens_scan(logits, key):
+                return jax.random.split(key)
+            scan_fn = jax.jit(sample_tokens_scan)
+        '''}
+        findings = run_fixture(tmp_path, 'serve-jit-prng', files, None)
+        assert findings and all(
+            'decode_steps_paged' in f.message for f in findings), [
+                f.render() for f in findings]
 
     def test_loop_phase_names_are_held_both_ways(self, tmp_path):
         """``trace.phase`` names (loop phases on the profiler's

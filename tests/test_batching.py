@@ -25,65 +25,6 @@ def _reference(params, config, prompt_ids, max_new):
     return [int(t) for t in out[0]]
 
 
-class TestDecodeStepsRows:
-
-    def test_rows_match_uniform_decode(self, setup):
-        """Per-row-position decode at EQUAL positions must equal the
-        shared-position decode path."""
-        config, params = setup
-        prompts = jnp.asarray([[1, 2, 3, 4], [9, 8, 7, 6]], jnp.int32)
-        want = decode.greedy_generate(params, prompts, config,
-                                      max_new_tokens=5, max_seq=32)
-
-        cache = decode.init_cache(config, 2, max_seq=32)
-        logits, cache = decode.forward_cached(params, prompts, cache,
-                                              config, True)
-        first = logits[:, -1].argmax(-1).astype(jnp.int32)
-        toks, _, _ = batching.decode_steps_rows(
-            params, first, (cache.k, cache.v, None, None),
-            jnp.asarray([4, 4], jnp.int32),
-            jnp.asarray([True, True]), config, 4)
-        got = jnp.concatenate([first[:, None], toks], axis=1)
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(want))
-
-    def test_int8_kv_rows_track_bf16(self, setup):
-        """int8-KV per-row decode: same inputs, quantized cache —
-        generated tokens should track the bf16 path closely on a
-        random-init model (int8 KV is lossy; assert agreement, not
-        equality)."""
-        config, params = setup
-        prompts = jnp.asarray([[1, 2, 3, 4], [9, 8, 7, 6]], jnp.int32)
-        want = decode.greedy_generate(params, prompts, config,
-                                      max_new_tokens=5, max_seq=32)
-        cache = decode.init_cache(config, 2, max_seq=32,
-                                  kv_int8=True)
-        logits, cache = decode.forward_cached(params, prompts, cache,
-                                              config, True)
-        assert cache.k.dtype == jnp.int8
-        first = logits[:, -1].argmax(-1).astype(jnp.int32)
-        toks, caches, _ = batching.decode_steps_rows(
-            params, first,
-            (cache.k, cache.v, cache.k_scale, cache.v_scale),
-            jnp.asarray([4, 4], jnp.int32),
-            jnp.asarray([True, True]), config, 4)
-        assert caches[0].dtype == jnp.int8
-        got = jnp.concatenate([first[:, None], toks], axis=1)
-        agree = (np.asarray(got) == np.asarray(want)).mean()
-        # Deflaked (tier-1 known-failure class): on a random-init
-        # model the int8-vs-bf16 logit gap at the argmax is often
-        # within one quantization step, so the winning token can flip
-        # on BLAS/thread-count differences even with every seed
-        # pinned (they are — PRNGKey(0) everywhere). One early flip
-        # then diverges the whole row. Assert a LOOSE agreement
-        # (tokens stay in-distribution, not token-exact): exact
-        # agreement is a property of trained models with real logit
-        # margins, not of this random init.
-        assert agree >= 1 / 3, (got, want)
-        assert np.all((np.asarray(got) >= 0)
-                      & (np.asarray(got) < config.vocab_size))
-
-
 class TestBatchingEngine:
 
     def test_concurrent_requests_match_single_stream(self, setup):

@@ -1,8 +1,6 @@
-"""Pallas decode-attention + cache-write kernels vs the dense
-references (interpret mode on the CPU backend — same pattern as the
-flash-attention kernel tests). The kernels are opt-in on TPU
-(``SKYTPU_PALLAS_DECODE=1``; see ops/decode_attention.py for the
-measured tradeoff) but stay correctness-certified here."""
+"""The paged pool's read path and attention over it
+(ops/decode_attention.py): block-wise gathers against the flat index
+arithmetic, int8 codes read as codes, this step's rows as an operand."""
 import numpy as np
 import pytest
 
@@ -10,60 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.ops import decode_attention as da
-
-
-@pytest.fixture(scope='module')
-def shapes():
-    B, Hq, Hkv, hd, S = 4, 16, 8, 64, 2048
-    q = jax.random.normal(jax.random.PRNGKey(0), (B, Hq, hd),
-                          jnp.float32)
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, S, Hkv, hd),
-                          jnp.float32)
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, S, Hkv, hd),
-                          jnp.float32)
-    return q, k, v
-
-
-class TestDecodeAttentionKernel:
-
-    def test_matches_reference_across_lengths(self, shapes):
-        q, k, v = shapes
-        scale = q.shape[-1] ** -0.5
-        # Lengths straddling block boundaries, incl. the 1-token and
-        # full-cache extremes.
-        lengths = jnp.asarray([1, 500, 513, 2048], jnp.int32)
-        ref = np.asarray(da._reference_decode_attention(
-            q, k, v, lengths, scale))
-        out = np.asarray(da._decode_attention_pallas(
-            q, k, v, lengths, scale, da._BLOCK_S, interpret=True))
-        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
-
-    def test_cache_write_matches_reference(self, shapes):
-        _, k, v = shapes
-        B, _, Hkv, hd = k.shape
-        kn = jax.random.normal(jax.random.PRNGKey(3), (B, Hkv, hd),
-                               jnp.float32)
-        vn = jax.random.normal(jax.random.PRNGKey(4), (B, Hkv, hd),
-                               jnp.float32)
-        # Positions at window starts, mid-window, and the last row.
-        pos = jnp.asarray([0, 7, 511, 2047], jnp.int32)
-        kr, vr = da._reference_cache_write(k, v, kn, vn, pos)
-        kp, vp = da._cache_write_pallas(k, v, kn, vn, pos,
-                                        interpret=True)
-        np.testing.assert_array_equal(np.asarray(kr), np.asarray(kp))
-        np.testing.assert_array_equal(np.asarray(vr), np.asarray(vp))
-
-    def test_dispatch_falls_back_off_tpu(self, shapes):
-        # On the CPU test backend the public entry must use the
-        # reference (no pallas), transparently.
-        q, k, v = shapes
-        lengths = jnp.asarray([100, 600, 1, 2048], jnp.int32)
-        out = da.decode_attention(q, k, v, lengths,
-                                  q.shape[-1] ** -0.5)
-        ref = da._reference_decode_attention(q, k, v, lengths,
-                                             q.shape[-1] ** -0.5)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6)
 
 
 # ---------------------------------------------------------------------
@@ -109,10 +53,10 @@ def _dequant(codes, scales):
 def _reference(q, kd, vd, lengths, scale):
     """Dequantise-then-attend, one query position at a time."""
     if q.ndim == 3:
-        return da._reference_decode_attention(q, kd, vd, lengths,
+        return da.decode_attention(q, kd, vd, lengths,
                                               scale)
     return jnp.stack([
-        da._reference_decode_attention(q[:, j], kd, vd, lengths + j,
+        da.decode_attention(q[:, j], kd, vd, lengths + j,
                                        scale)
         for j in range(q.shape[1])], axis=1)
 
@@ -121,12 +65,11 @@ class TestBlockGather:
 
     @pytest.mark.parametrize('what', ['codes', 'scales', 'floats'])
     def test_equals_read_indices_take(self, what):
-        from skypilot_tpu.serve import kv_pool
         kp, _, ksc, _, tables = _pools(int8=what != 'floats')
         pool = ksc if what == 'scales' else kp
         got = da.gather_blocks(pool, tables)
         flat = pool.reshape(_NB * _BS, *pool.shape[2:])
-        want = jnp.take(flat, kv_pool.read_indices(tables, _BS),
+        want = jnp.take(flat, da.read_indices(tables, _BS),
                         axis=0)
         assert got.dtype == want.dtype and got.shape == want.shape
         assert got.shape[:2] == (_B, _S)
@@ -134,7 +77,7 @@ class TestBlockGather:
                                       np.asarray(want))
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(da.paged_gather(
-                flat, kv_pool.read_indices(tables, _BS))))
+                flat, da.read_indices(tables, _BS))))
         if what == 'scales':
             # The scores' layout of the same values, also with every
             # layer's pool at once (leading dims pass through).
@@ -189,7 +132,6 @@ class TestNewRowAsOperand:
     @pytest.mark.parametrize('cur', [
         (0, 0, 0), (_BS - 1, _BS, 3), (_S - 3, 20, _BS + 1)])
     def test_equals_write_then_attend(self, int8, width, cur):
-        from skypilot_tpu.serve import kv_pool
         kp, vp, ksc, vsc, tables = _pools(2, int8)
         w = max(width, 1)
         cur = jnp.asarray(cur, jnp.int32)
@@ -219,10 +161,10 @@ class TestNewRowAsOperand:
         # Stale rows at and after the write positions: as large as
         # the type holds, so a leak of any weight shows.
         pos = cur[:, None] + jnp.arange(w)[None, :]        # [B, W]
-        widx = kv_pool.verify_write_indices(
+        widx = da.verify_write_indices(
             tables, cur, jnp.full((_B,), w, jnp.int32), w,
             _BS).reshape(-1)
-        at = kv_pool.read_indices(tables, _BS)              # [B, S]
+        at = da.read_indices(tables, _BS)              # [B, S]
         stale_idx = at[jnp.arange(_S)[None, :] >= cur[:, None]]
 
         def flat(x):
@@ -265,41 +207,6 @@ class TestNewRowAsOperand:
         assert float(jnp.max(jnp.abs(want))) < 50.0   # nothing leaked
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
-
-    def test_opt_in_kernel_gets_the_row_in_place(self, monkeypatch):
-        """With the Pallas kernel opted in, the paged path hands it
-        one float view with the new row placed at ``lengths``."""
-        import functools
-        bs, mb, nb = 256, 4, 9                     # S = 1024
-        ks = jax.random.split(jax.random.PRNGKey(3), 8)
-        shape = (nb, bs, _HKV, 64)
-        kp = jax.random.randint(ks[0], shape, -127, 128, jnp.int8)
-        vp = jax.random.randint(ks[1], shape, -127, 128, jnp.int8)
-        ksc = jax.random.uniform(ks[2], shape[:-1], jnp.float32,
-                                 0.004, 0.03).astype(jnp.bfloat16)
-        vsc = jax.random.uniform(ks[3], shape[:-1], jnp.float32,
-                                 0.004, 0.03).astype(jnp.bfloat16)
-        tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
-        cur = jnp.asarray([700, 255], jnp.int32)
-        q = jax.random.normal(ks[4], (2, _HQ, 64), jnp.float32)
-        new = (jax.random.randint(ks[5], (2, _HKV, 64), -127, 128,
-                                  jnp.int8),
-               jax.random.randint(ks[6], (2, _HKV, 64), -127, 128,
-                                  jnp.int8),
-               jnp.full((2, _HKV), 0.01, jnp.bfloat16),
-               jnp.full((2, _HKV), 0.02, jnp.bfloat16))
-        ksc, vsc = (da.gather_scales(ksc, tables),
-                    da.gather_scales(vsc, tables))
-        want = da.paged_decode_attention(q, kp, vp, tables, cur,
-                                         0.125, ksc, vsc, new=new)
-        monkeypatch.setattr(da, '_use_pallas', lambda which='': True)
-        monkeypatch.setattr(
-            da, '_decode_attention_pallas', functools.partial(
-                da._decode_attention_pallas, interpret=True))
-        got = da.paged_decode_attention(q, kp, vp, tables, cur,
-                                        0.125, ksc, vsc, new=new)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
 
 
 def _eqns(jaxpr):

@@ -1,7 +1,8 @@
 """Paged KV cache: block-pool allocator, index math, token-budget
 admission, chunked prefill, preempt-and-requeue, typed pool
 exhaustion, and the paged-engine numerics contract (serve/kv_pool.py,
-serve/batching.py, models/decode.forward_paged)."""
+ops/decode_attention.py, serve/batching.py, the paged steps of
+models/decode.py)."""
 import os
 import re
 import threading
@@ -14,7 +15,8 @@ import pytest
 
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import decode, llama
-from skypilot_tpu.serve import batching, kv_pool
+from skypilot_tpu.ops import decode_attention as da
+from skypilot_tpu.serve import kv_pool
 from skypilot_tpu.serve.batching import BatchingEngine
 
 
@@ -97,7 +99,7 @@ class TestIndexMath:
 
     def test_read_indices_flatten_blocks(self):
         bt = jnp.asarray([[3, 1, 0], [2, 0, 0]], jnp.int32)
-        got = kv_pool.read_indices(bt, 4)
+        got = da.read_indices(bt, 4)
         want = [[12, 13, 14, 15, 4, 5, 6, 7, 0, 1, 2, 3],
                 [8, 9, 10, 11, 0, 1, 2, 3, 0, 1, 2, 3]]
         np.testing.assert_array_equal(np.asarray(got), want)
@@ -105,16 +107,16 @@ class TestIndexMath:
     def test_write_index_and_overrun_scratch(self):
         bt = jnp.asarray([[3, 1], [2, 0]], jnp.int32)
         pos = jnp.asarray([5, 2], jnp.int32)   # row0 block1 off1
-        got = kv_pool.write_index(bt, pos, 4)
+        got = da.write_index(bt, pos, 4)
         np.testing.assert_array_equal(np.asarray(got), [4 + 1, 8 + 2])
         # Positions past the table capacity park in scratch.
-        over = kv_pool.write_index(bt, jnp.asarray([8, 9], jnp.int32),
+        over = da.write_index(bt, jnp.asarray([8, 9], jnp.int32),
                                    4)
         np.testing.assert_array_equal(np.asarray(over), [0, 0])
 
     def test_chunk_write_indices_pad_to_scratch(self):
         row = jnp.asarray([5, 2], jnp.int32)
-        got = kv_pool.chunk_write_indices(
+        got = da.chunk_write_indices(
             row, jnp.asarray(3, jnp.int32), jnp.asarray(2, jnp.int32),
             chunk=4, block_size=4)
         # start=3: positions 3,4 real -> block5 off3, block2 off0;
@@ -187,50 +189,58 @@ class TestPagedNumerics:
         finally:
             engine.close()
 
-    def test_decode_steps_paged_matches_rows_twin(self, setup):
-        """The block-table-indirected decode twin must reproduce
-        decode_steps_rows exactly when the tables lay the cache out
-        contiguously."""
+    @pytest.mark.parametrize('kv_int8', [False, True],
+                             ids=['bf16', 'int8'])
+    def test_paged_rows_match_uniform_decode(self, setup, kv_int8):
+        """``decode_steps_paged`` at per-row positions, through
+        tables that lay the dense cache's rows out contiguously,
+        against ``forward_cached``'s shared-position decode: at
+        EQUAL positions the two must pick the same tokens (bf16), or
+        track them (int8 KV is lossy against the bf16 reference)."""
         config, params = setup
         prompts = jnp.asarray([[1, 2, 3, 4], [9, 8, 7, 6]], jnp.int32)
-        cache = decode.init_cache(config, 2, max_seq=32)
+        want = decode.greedy_generate(params, prompts, config,
+                                      max_new_tokens=5, max_seq=32)
+        cache = decode.init_cache(config, 2, max_seq=32,
+                                  kv_int8=kv_int8)
         logits, cache = decode.forward_cached(params, prompts, cache,
                                               config, True)
         first = logits[:, -1].argmax(-1).astype(jnp.int32)
-        pos = jnp.asarray([4, 4], jnp.int32)
-        active = jnp.asarray([True, True])
-        want, _, want_pos = batching.decode_steps_rows(
-            params, first, (cache.k, cache.v, None, None), pos,
-            active, config, 4)
-        # Build a pool holding the same cache content: row b's slab
-        # becomes blocks [b*4+1 .. b*4+4] (block 0 stays scratch).
-        bs = 8
-        nb = 9
-        nl = config.n_layers
-        k_pool = jnp.zeros((nl, nb, bs, config.n_kv_heads,
-                            config.head_dim), cache.k.dtype)
-        v_pool = jnp.zeros_like(k_pool)
-        tables = []
-        for b in range(2):
-            blocks = [1 + b * 4 + i for i in range(4)]
-            tables.append(blocks)
-            rows_k = cache.k[:, b].reshape(nl, 4, bs,
-                                           config.n_kv_heads,
-                                           config.head_dim)
-            rows_v = cache.v[:, b].reshape(nl, 4, bs,
-                                           config.n_kv_heads,
-                                           config.head_dim)
-            for i, blk in enumerate(blocks):
-                k_pool = k_pool.at[:, blk].set(rows_k[:, i])
-                v_pool = v_pool.at[:, blk].set(rows_v[:, i])
-        block_tables = jnp.asarray(tables, jnp.int32)
-        got, _, got_pos = batching.decode_steps_paged(
-            params, first, (k_pool, v_pool, None, None),
-            block_tables, pos, active, config, 4, bs)
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(want))
-        np.testing.assert_array_equal(np.asarray(got_pos),
-                                      np.asarray(want_pos))
+        # Row b's [32] slab becomes blocks [b*4+1 .. b*4+4] of 8
+        # (block 0 stays scratch).
+        bs, per_row = 8, 4
+
+        def as_pool(slab):
+            if slab is None:
+                return None
+            blocks = slab.reshape(slab.shape[0], 2 * per_row, bs,
+                                  *slab.shape[3:])
+            return jnp.concatenate(
+                [jnp.zeros_like(blocks[:, :1]), blocks], axis=1)
+
+        pools = tuple(as_pool(c) for c in (
+            cache.k, cache.v, cache.k_scale, cache.v_scale))
+        tables = 1 + jnp.arange(2 * per_row, dtype=jnp.int32).reshape(
+            2, per_row)
+        toks, pools, pos = decode.decode_steps_paged(
+            params, first, pools, tables,
+            jnp.asarray([4, 4], jnp.int32), jnp.asarray([True, True]),
+            config, 4, bs)
+        np.testing.assert_array_equal(np.asarray(pos), [8, 8])
+        got = np.asarray(jnp.concatenate([first[:, None], toks],
+                                         axis=1))
+        if not kv_int8:
+            np.testing.assert_array_equal(got, np.asarray(want))
+            return
+        assert pools[0].dtype == jnp.int8
+        # On a random-init model the int8-vs-bf16 logit gap at the
+        # argmax is often within one quantization step, so the
+        # winning token can flip on BLAS/thread-count differences
+        # and one early flip then diverges the whole row: a LOOSE
+        # agreement, not token equality, which is a property of
+        # trained models with real logit margins.
+        assert (got == np.asarray(want)).mean() >= 1 / 3, (got, want)
+        assert np.all((got >= 0) & (got < config.vocab_size))
 
 
 # ---------------------------------------------------------------------

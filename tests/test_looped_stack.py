@@ -29,11 +29,10 @@ import pytest
 from perf.reference import ouro_block_f32 as reference
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import decode, llama
+from skypilot_tpu.models.decode import (decode_steps_paged,
+                                        verify_step_paged)
 from skypilot_tpu.serve import kv_pool
-from skypilot_tpu.serve.batching import (BatchingEngine,
-                                         decode_steps_paged,
-                                         decode_steps_rows,
-                                         verify_step_paged)
+from skypilot_tpu.serve.batching import BatchingEngine
 
 _BLOCK = 8
 _TOL = {False: 2e-4, True: 0.05}   # by int8 pool; module docstring
@@ -446,8 +445,7 @@ def test_one_pass_without_norms_or_gate_is_the_path_before():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize('body', ['forward', 'greedy_generate',
-                                  'decode_steps_rows'])
+@pytest.mark.parametrize('body', ['forward', 'greedy_generate'])
 def test_dense_bodies_refuse_a_looped_stack(body, looped):
     config, params = looped
     tokens = jnp.asarray([_prompt(8)], jnp.int32)
@@ -455,15 +453,9 @@ def test_dense_bodies_refuse_a_looped_stack(body, looped):
                        match='loop_passes=4'):
         if body == 'forward':
             llama.forward(params, tokens, config)
-        elif body == 'greedy_generate':
+        else:
             decode.greedy_generate(params, tokens, config, 4,
                                    max_seq=32)
-        else:
-            cache = decode.init_cache(config, 1, 32)
-            decode_steps_rows(
-                params, tokens[:, 0], (cache.k, cache.v, None, None),
-                jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
-                config, 2)
 
 
 @pytest.mark.parametrize('leave_out', ['a pass', 'a branch norm'])

@@ -686,6 +686,15 @@ class TestXskyTop:
 
         from skypilot_tpu import cli as cli_mod
         from skypilot_tpu.resilience import policy as policy_lib
+        # Series other tests on this worker left in the
+        # process-global registry: a ``CircuitBreaker(target=...)``
+        # built directly exports its state, and ``reset_breakers``
+        # (conftest) drops only the series of breakers it registered.
+        gauge = policy_lib._breaker_gauge()  # pylint: disable=protected-access
+        for labels, _ in list(gauge.collect()):
+            target = dict(labels)['target']
+            if target not in policy_lib._breakers:  # pylint: disable=protected-access
+                gauge.remove(target=target)
         # A driver-side breaker so the breaker line has content.
         policy_lib.breaker_for('10.0.0.9:8790')
         result = CliRunner().invoke(

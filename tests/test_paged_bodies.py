@@ -1,0 +1,212 @@
+"""The paged engine's three model steps (models/decode.py) share
+``layer_head``, ``layer_tail`` and ``looped_stack``; what each keeps
+of its own is its attention over its own view of the pool. These
+tests are the drift alarm for that remainder: one position computed
+by each of the three must come out the same. Also here: the one
+``rope`` against ``ops.attention.apply_rope`` on its three ``angles``
+layouts, and the rule that nothing below the scheduler imports it."""
+import ast
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import skypilot_tpu
+from skypilot_tpu.models import decode, llama
+from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.serve import kv_pool
+
+_BLOCK = 8
+_LENS = (11, 6)         # each row's context: position p differs a row
+_RANK, _SLOTS = 4, 3    # adapter slots: 0 is the all-zeros base slot
+
+
+def _case(name):
+    """(config, params, kv_int8, adapters, adapter_idx) of a case."""
+    overrides = {'qkv_bias': True} if name == 'qkv_bias' else {}
+    config = llama.get_config(
+        'tiny-loop' if name == 'tiny-loop' else 'tiny', **overrides)
+    params = llama.init_params(config, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    if name == 'qkv_bias':         # initialised to zeros: make them count
+        for leaf in ('bq', 'bk', 'bv'):
+            b = params['layers'][leaf]
+            params['layers'][leaf] = jnp.asarray(
+                0.3 * rng.standard_normal(b.shape), b.dtype)
+    adapters = adapter_idx = None
+    if name == 'adapters':
+        wq, wv = params['layers']['wq'], params['layers']['wv']
+        shapes = {'wq_a': (wq.shape[1], _RANK),
+                  'wq_b': (_RANK, wq.shape[2]),
+                  'wv_a': (wv.shape[1], _RANK),
+                  'wv_b': (_RANK, wv.shape[2])}
+        adapters = {
+            leaf: jnp.asarray(
+                0.2 * rng.standard_normal(
+                    (config.n_layers, _SLOTS, *shape)), jnp.float32
+            ).at[:, 0].set(0.0) for leaf, shape in shapes.items()}
+        adapter_idx = jnp.asarray([1, 2], jnp.int32)
+    return config, params, name == 'int8', adapters, adapter_idx
+
+
+def _recording(monkeypatch, name, sink):
+    """``sample_lib.<name>`` as it is, with the logits it was handed
+    copied out: the decode and verify steps return tokens only."""
+    real = getattr(decode.sample_lib, name)
+
+    def recorded(logits, *args):
+        jax.debug.callback(lambda x: sink.append(np.asarray(x)),
+                           logits)
+        return real(logits, *args)
+
+    monkeypatch.setattr(decode.sample_lib, name, recorded)
+
+
+@pytest.mark.parametrize(
+    'name', ['plain', 'qkv_bias', 'adapters', 'int8', 'tiny-loop'])
+def test_the_three_paged_bodies_agree_on_one_position(name,
+                                                      monkeypatch):
+    """Position p of each row from a one-token ``forward_paged``
+    chunk, from ``verify_step_paged`` at width 1 and from one step
+    of ``decode_steps_paged``, over the same context in the same
+    pool: the same token, logits equal to float32 tolerance, and the
+    same rows written at p for every KV entry.
+
+    An int8 pool is the one place the bodies differ by design: the
+    prefill chunk attends its OWN rows exact (``forward_paged``'s
+    docstring), the decode and verify steps attend theirs as the
+    codes the pool stores, so there the tolerance is the
+    quantisation step's and the tokens need only be within it."""
+    config, params, kv_int8, adapters, adapter_idx = _case(name)
+    tol = 0.05 if kv_int8 else 2e-4
+    rng = np.random.default_rng(9)
+    pools = kv_pool.KVBlockPool(config, 9, _BLOCK,
+                                kv_int8=kv_int8).caches
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    pos = jnp.asarray(_LENS, jnp.int32)
+    token = jnp.asarray(rng.integers(1, 500, 2), jnp.int32)
+
+    def prefill(tokens, pools, row, start):
+        padded = tokens + [0] * (-len(tokens) % 16)
+        return decode.forward_paged(
+            params, jnp.asarray([padded], jnp.int32), pools,
+            tables[row], jnp.asarray(start, jnp.int32),
+            jnp.asarray(len(tokens), jnp.int32), config, _BLOCK,
+            adapters,
+            None if adapter_idx is None else adapter_idx[row:row + 1])
+
+    # The context [0, p) of each row, then position p three ways.
+    for row, n in enumerate(_LENS):
+        _, pools = prefill(rng.integers(1, 500, n).tolist(), pools,
+                           row, 0)
+    chunk_logits, chunk_pools = [], pools
+    for row, n in enumerate(_LENS):
+        logits, chunk_pools = prefill([int(token[row])], chunk_pools,
+                                      row, n)
+        chunk_logits.append(np.asarray(logits[0]))
+    chunk_logits = np.stack(chunk_logits)
+
+    sampling = {'temps': jnp.zeros((2,), jnp.float32),
+                'top_ps': jnp.ones((2,), jnp.float32),
+                'seeds': jnp.zeros((2,), jnp.int32),
+                'mask_idx': jnp.zeros((2,), jnp.int32)}
+    seen = {'verify_targets': [], 'sample_rows': []}
+    for fn, sink in seen.items():
+        _recording(monkeypatch, fn, sink)
+    preds, accepted, new_pos, _, verify_pools = decode.verify_step_paged(
+        params, token[:, None], pools, tables, pos,
+        jnp.ones((2,), jnp.int32), config, 1, _BLOCK, adapters,
+        adapter_idx, dict(sampling, mask_table=jnp.ones(
+            (1, 1, config.vocab_size), bool)))
+    toks, decode_pools, _ = decode.decode_steps_paged(
+        params, token, pools, tables, pos, jnp.asarray([True, True]),
+        config, 1, _BLOCK, adapters, adapter_idx, dict(
+            sampling,
+            mask_table=jnp.ones((1, config.vocab_size), bool)))
+    jax.effects_barrier()
+    verify_logits = seen['verify_targets'][0][:, 0]
+    decode_logits = seen['sample_rows'][0]
+    assert np.asarray(accepted).tolist() == [0, 0]
+    assert np.asarray(new_pos).tolist() == [n + 1 for n in _LENS]
+
+    picked = {'chunk': chunk_logits.argmax(-1),
+              'verify': np.asarray(preds)[:, 0],
+              'decode': np.asarray(toks)[:, 0]}
+    for logits in (chunk_logits, verify_logits, decode_logits):
+        np.testing.assert_allclose(logits, chunk_logits, atol=tol,
+                                   rtol=0)
+        for body, tok in picked.items():
+            # The same token, or (int8) one within the tolerance of
+            # the top.
+            gap = logits.max(-1) - logits[np.arange(2), tok]
+            assert np.all(gap <= (2 * tol if kv_int8 else 0)), (
+                body, tok, gap)
+    assert np.array_equal(picked['verify'], picked['decode'])
+
+    # What each body wrote at position p, for every KV entry.
+    slot = np.asarray(tables)[np.arange(2), np.asarray(_LENS) //
+                              _BLOCK] * _BLOCK + np.asarray(_LENS) % _BLOCK
+    for want, v_got, d_got in zip(chunk_pools, verify_pools,
+                                  decode_pools):
+        if want is None:
+            continue
+
+        def at_p(pool):
+            flat = np.asarray(pool.astype(jnp.float32))
+            return flat.reshape(flat.shape[0], -1,
+                                *flat.shape[3:])[:, slot]
+
+        # A code may differ by one where a value sits on a rounding
+        # edge; float rows and scales by float32 rounding.
+        for got in (v_got, d_got):
+            np.testing.assert_allclose(
+                at_p(got), at_p(want), rtol=0,
+                atol=1 if want.dtype == jnp.int8 else 2e-4)
+
+
+@pytest.mark.parametrize('layout', ['chunk', 'decode rows',
+                                    'verify window'])
+def test_rope_equals_apply_rope(layout):
+    """``decode.rope`` on each ``angles`` layout a paged body hands
+    it, against ``apply_rope`` (x [B, T, H, D], angles [T, D/2]) a
+    row at a time."""
+    b, t = {'chunk': (1, 7), 'decode rows': (3, 1),
+            'verify window': (3, 5)}[layout]
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((b, t, 4, 16)), jnp.float32)
+    angles = jnp.asarray(rng.uniform(0, 6.0, (b, t, 8)), jnp.float32)
+    got = decode.rope(x, angles[0] if layout == 'chunk' else angles)
+    want = jnp.concatenate([
+        attention_ops.apply_rope(x[i:i + 1], angles[i])
+        for i in range(b)])
+    assert got.shape == x.shape and got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_nothing_below_the_scheduler_imports_it():
+    """models/, ops/ and parallel/ are below serve/: the scheduler
+    imports the model's steps, never the other way round."""
+    root = os.path.dirname(skypilot_tpu.__file__)
+    offenders = []
+    for layer in ('models', 'ops', 'parallel'):
+        for dirpath, _, files in os.walk(os.path.join(root, layer)):
+            for fn in files:
+                if not fn.endswith('.py'):
+                    continue
+                path = os.path.join(dirpath, fn)
+                tree = ast.parse(open(path, encoding='utf-8').read())
+                for node in ast.walk(tree):
+                    if isinstance(node, ast.Import):
+                        names = [a.name for a in node.names]
+                    elif isinstance(node, ast.ImportFrom):
+                        names = [f'{node.module}.{a.name}'
+                                 for a in node.names]
+                    else:
+                        continue
+                    offenders += [
+                        f'{os.path.relpath(path, root)}:{node.lineno}'
+                        for n in names
+                        if (n + '.').startswith('skypilot_tpu.serve.')]
+    assert not offenders, offenders

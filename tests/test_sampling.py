@@ -1,12 +1,13 @@
-"""The sampling subsystem (serve/sampling/): batch-invariant sampled
-decode, distribution-preserving speculative sampling, and
-grammar-constrained structured decoding on the paged engine.
+"""The sampling subsystem (ops/sampling/, serve/sampling/):
+batch-invariant sampled decode, distribution-preserving speculative
+sampling, and grammar-constrained structured decoding on the paged
+engine.
 
 The contract under test everywhere: a request's sampled tokens are a
 pure function of its own ``(seed, position)`` — never of batch width,
 slot index, speculation on/off, or a preempt/resume cycle. The
 speculative half rides the maximal-coupling acceptance
-(serve/sampling/accept.py): the verify step REALIZES the target
+(ops/sampling/accept.py): the verify step REALIZES the target
 draw for every position with the key plain decode would have used,
 so spec-on output is bitwise spec-off output and the emitted
 distribution is exactly the target distribution (the chi-square
@@ -23,12 +24,14 @@ import pytest
 
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import decode, llama
+from skypilot_tpu.ops.sampling import (accept_tokens, gather_masks,
+                                       row_key, row_keys,
+                                       sample_first, sample_rows,
+                                       verify_targets)
 from skypilot_tpu.serve.batching import BatchingEngine
-from skypilot_tpu.serve.sampling import (GrammarError, accept_tokens,
-                                         compile_grammar, gather_masks,
-                                         grammar_hash, row_key,
-                                         row_keys, sample_first,
-                                         sample_rows, verify_targets)
+from skypilot_tpu.serve.sampling import (GrammarError,
+                                         compile_grammar,
+                                         grammar_hash)
 from skypilot_tpu.serve.sampling.grammar import schema_to_regex
 
 

@@ -1,15 +1,15 @@
 """Speculative decoding on the paged engine: n-gram drafting,
 batched multi-token verify, the single acceptance rule, pos
 rollback, adaptive draft length, budget accounting, and the
-prefix-cache x speculation interaction (serve/batching.py
-verify_step_paged / propose_ngram_draft,
-serve/sampling/accept.accept_tokens,
-ops/decode_attention.paged_decode_attention ([B, W, ...] form),
-serve/kv_pool.verify_write_indices).
+prefix-cache x speculation interaction (models/decode.py
+verify_step_paged, serve/batching.py propose_ngram_draft,
+ops/sampling/accept.accept_tokens,
+ops/decode_attention.paged_decode_attention ([B, W, ...] form) and
+verify_write_indices).
 
 The non-negotiable contract everywhere: spec-on == spec-off ==
 single-stream decode, token for token — at any temperature (the
-maximal-coupling acceptance in serve/sampling/accept.py;
+maximal-coupling acceptance in ops/sampling/accept.py;
 tests/test_sampling.py covers the sampled half)."""
 import dataclasses
 import os
@@ -23,11 +23,12 @@ import pytest
 
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import decode, llama
+from skypilot_tpu.ops import decode_attention as da
+from skypilot_tpu.ops.sampling import accept_tokens
 from skypilot_tpu.serve import batching, kv_pool
 from skypilot_tpu.serve.batching import (BatchingEngine,
                                          propose_ngram_draft,
                                          update_spec_k)
-from skypilot_tpu.serve.sampling import accept_tokens
 
 
 @pytest.fixture(scope='module')
@@ -189,7 +190,7 @@ class TestVerifyStepPaged:
         config, params = setup
         first, pools, tables, pos = self._pool_from_prefill(setup)
         active = jnp.asarray([True, True])
-        want, _, _ = batching.decode_steps_paged(
+        want, _, _ = decode.decode_steps_paged(
             params, first, pools, tables, pos, active, config, 5, 8)
         want = np.asarray(want)                       # [2, 5]
         # Drafts = the TRUE continuation: everything accepts and the
@@ -198,7 +199,7 @@ class TestVerifyStepPaged:
         toks = jnp.concatenate([first[:, None],
                                 jnp.asarray(want[:, :3])], axis=1)
         preds, accepted, new_pos, new_tok, _ = \
-            batching.verify_step_paged(
+            decode.verify_step_paged(
                 params, toks.astype(jnp.int32), pools, tables, pos,
                 jnp.asarray([w, w], jnp.int32), config, w, 8)
         np.testing.assert_array_equal(np.asarray(accepted), [3, 3])
@@ -212,7 +213,7 @@ class TestVerifyStepPaged:
         config, params = setup
         first, pools, tables, pos = self._pool_from_prefill(setup)
         active = jnp.asarray([True, True])
-        want, _, _ = batching.decode_steps_paged(
+        want, _, _ = decode.decode_steps_paged(
             params, first, pools, tables, pos, active, config, 5, 8)
         want = np.asarray(want)
         # Corrupt row 0's second draft; row 1 keeps the truth.
@@ -221,7 +222,7 @@ class TestVerifyStepPaged:
         toks = jnp.concatenate([first[:, None],
                                 jnp.asarray(draft)], axis=1)
         preds, accepted, new_pos, new_tok, _ = \
-            batching.verify_step_paged(
+            decode.verify_step_paged(
                 params, toks.astype(jnp.int32), pools, tables, pos,
                 jnp.asarray([4, 4], jnp.int32), config, 4, 8)
         np.testing.assert_array_equal(np.asarray(accepted), [1, 3])
@@ -235,7 +236,7 @@ class TestVerifyStepPaged:
 
     def test_verify_write_indices_scratch_redirects(self):
         bt = jnp.asarray([[3, 1], [2, 5]], jnp.int32)
-        got = kv_pool.verify_write_indices(
+        got = da.verify_write_indices(
             bt, jnp.asarray([5, 2], jnp.int32),
             jnp.asarray([2, 1], jnp.int32), width=3, block_size=4)
         # Row 0: positions 5, 6 real (block 1 offsets 1, 2), lane 2
@@ -244,7 +245,7 @@ class TestVerifyStepPaged:
         np.testing.assert_array_equal(
             np.asarray(got), [[4 + 1, 4 + 2, 0], [8 + 2, 0, 0]])
         # Parked row (n_real 0, pos at capacity): all scratch.
-        parked = kv_pool.verify_write_indices(
+        parked = da.verify_write_indices(
             bt, jnp.asarray([8, 0], jnp.int32),
             jnp.asarray([0, 0], jnp.int32), width=3, block_size=4)
         np.testing.assert_array_equal(np.asarray(parked),
@@ -580,7 +581,7 @@ class TestSpecMetrics:
 
 class TestAcceptanceLint:
     """The speculative acceptance rule must have exactly ONE
-    implementation — ``serve/sampling/accept.accept_tokens``, the
+    implementation — ``ops/sampling/accept.accept_tokens``, the
     maximal-coupling rule the exactness suite certifies at every
     temperature. Any other draft-vs-realization comparison in the
     serving stack is a second acceptance path the tests do not
@@ -588,7 +589,7 @@ class TestAcceptanceLint:
     argmax semantics are accept_tokens' temperature-0
     specialization)."""
 
-    _ACCEPT_PATH = os.path.join('serve', 'sampling', 'accept.py')
+    _ACCEPT_PATH = os.path.join('ops', 'sampling', 'accept.py')
 
     def _py_files(self):
         import skypilot_tpu
@@ -617,14 +618,15 @@ class TestAcceptanceLint:
                          open(path, encoding='utf-8').read(), re.M)]
         assert not revivals, (
             'greedy_accept was reintroduced — the single acceptance '
-            'implementation is serve/sampling/accept.accept_tokens '
+            'implementation is ops/sampling/accept.accept_tokens '
             f'(temperature 0 IS the greedy rule): {revivals}')
 
     def test_no_draft_comparison_outside_the_function(self):
-        """No line outside serve/sampling/accept.py may compare
+        """No line outside ops/sampling/accept.py may compare
         drafted tokens against verify realizations (the
-        ``preds``/``draft`` comparison idiom), and batching.py must
-        route the engine's acceptance through accept_tokens."""
+        ``preds``/``draft`` comparison idiom), and the verify step
+        (models/decode.py) must route the engine's acceptance
+        through accept_tokens."""
         offenders = []
         for path in self._py_files():
             if path.endswith(self._ACCEPT_PATH):
@@ -642,7 +644,7 @@ class TestAcceptanceLint:
             'sampling.accept_tokens: ' + ', '.join(offenders))
         text = open(next(p for p in self._py_files()
                          if p.endswith(os.path.join(
-                             'serve', 'batching.py'))),
+                             'models', 'decode.py'))),
                     encoding='utf-8').read()
         assert 'accept_tokens(tokens, preds, n_real)' in text
 
