@@ -3,7 +3,9 @@
 passes, under open-loop load through ``submit_request`` - the call the
 HTTP handler makes. In-process, because only the process that holds
 the chip can seed the weights, compare logits and trace it."""
+import gc
 import math
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -174,12 +176,32 @@ def drive(served: Served, requests: List[Dict[str, Any]],
     trace_from = t_lead_end + float(traffic['trace_start_s'])
     trace_until = trace_from + float(traffic['trace_seconds'])
     state = {'opened': False, 'trace': 'idle'}
+    # The host's load average where the window opens and closes: a
+    # stall of seconds that the program's logs cannot explain shows
+    # here if the machine's other tenants caused it.
+    loadavg = {}
+    # Python's own collections, timed: the engine's thread and the
+    # consumers share this interpreter, and a collection stops both.
+    collections: List[tuple] = []  # (start, seconds, generation)
+    gc_started = 0.0
+
+    def on_gc(phase: str, info: Dict[str, Any]) -> None:
+        nonlocal gc_started
+        now = time.perf_counter()
+        if phase == 'start':
+            gc_started = now
+        else:
+            collections.append((gc_started, now - gc_started,
+                                info['generation']))
+
+    gc.callbacks.append(on_gc)
 
     def tick(now: float) -> None:
         """Open the window and run the profiler at their instants;
         called from the one scheduling thread."""
         if not state['opened'] and now >= t_lead_end:
             state['opened'] = True
+            loadavg['open'] = os.getloadavg()
             if on_open is not None:
                 on_open()
         if tracer is not None:
@@ -232,6 +254,8 @@ def drive(served: Served, requests: List[Dict[str, Any]],
                     'of its nominal end: the engine stopped emitting')
         with harness.annotate('collect'):
             time.sleep(0.01)
+    loadavg['close'] = os.getloadavg()
+    gc.callbacks.remove(on_gc)
     if state['trace'] == 'on':
         tracer.stop()
     if on_close is not None:
@@ -259,8 +283,15 @@ def drive(served: Served, requests: List[Dict[str, Any]],
     failed = [i for i in tracked
               if not whole(i) and not i.req.cancelled]
     complete = [i for i in tracked if whole(i)]
+    inside = sorted(t for t in stamps if t_open <= t < t_close)
+    gaps = [(b - a, a - t_open) for a, b in zip(inside, inside[1:])]
     return {'t_open': t_open, 't_close': t_close, 'tracked': tracked,
             'complete': complete, 'failed': failed,
+            'loadavg': loadavg,
+            'longest_token_gap': max(gaps, default=(0.0, 0.0)),
+            'collections': [(sec, gen, t0 - t_open)
+                            for t0, sec, gen in collections
+                            if t_open <= t0 < t_close],
             'finished_in_window': [
                 i for i in complete
                 if t_open <= i.times[-1] < t_close],
@@ -274,10 +305,11 @@ def drive(served: Served, requests: List[Dict[str, Any]],
 def summarize(drove: Dict[str, Any]) -> Dict[str, Any]:
     """The numbers of one window: tokens emitted inside it over its
     length; per-request time per output token over the requests that
-    finished inside it; time to first token, from the due instant,
-    over the requests whose first token fell inside it; how late the
-    generator sent them. Medians and 90th percentiles are linearly
-    interpolated; earlier lines of the output carry the rest."""
+    finished inside it, and its median over them; time to first
+    token, from the due instant, over the requests whose first token
+    fell inside it; how late the generator sent them. Medians and
+    90th percentiles are linearly interpolated; the per-layer metrics
+    and earlier lines of the output carry the rest."""
     window_s = drove['t_close'] - drove['t_open']
     finished, first = drove['finished_in_window'], \
         drove['first_in_window']
@@ -291,7 +323,6 @@ def summarize(drove: Dict[str, Any]) -> Dict[str, Any]:
            'e2e': {'out_tok_s': drove['window_tokens'] / window_s}}
     if tpot:
         out['e2e']['tpot_p50_ms'] = stats.percentile(tpot, 50)
-        out['e2e']['tpot_p90_ms'] = stats.percentile(tpot, 90)
     fifths = [0] * 5
     for item in drove['tracked']:
         for t in item.times:
@@ -307,6 +338,17 @@ def summarize(drove: Dict[str, Any]) -> Dict[str, Any]:
         f'{drove["window_tokens"]} ({out["e2e"]["out_tok_s"]:.2f}/s; '
         f'by fifths {fifths}); requests finished per second '
         f'{len(finished) / window_s:.4f}')
+    gap_s, gap_at = drove['longest_token_gap']
+    gcs = drove['collections']
+    gc_s, gc_gen, gc_at = max(gcs, default=(0.0, -1, 0.0))
+    harness.say(
+        f'stalls: longest gap between two bursts of tokens '
+        f'{gap_s:.3f} s at +{gap_at:.1f} s; longest Python garbage '
+        f'collection {gc_s:.3f} s (generation {gc_gen}) at '
+        f'+{gc_at:.1f} s, {len(gcs)} collections of '
+        f'{sum(c[0] for c in gcs):.3f} s in all; host load average '
+        f'(1, 5, 15 min) at the opening {drove["loadavg"].get("open")}'
+        f' at the close {drove["loadavg"].get("close")}')
     if ttft and tpot:
         harness.say(
             'ttft ms p50 %.1f p90 %.1f max %.1f (n=%d); tpot ms p50 '
@@ -331,6 +373,10 @@ def run(loaded: Dict[str, Any], seed: int, seconds: float, trace: bool,
     served = Served(loaded, seed, rehearse)
     requests = loadgen.generator_for(served.traffic['kind'])(
         served.traffic, seed, seconds, served.model['vocab_size'])
+    past = int(served.build['max_seq']) * 3 // 4
+    harness.say(f'offered (the same under every seed; decode '
+                f'row-steps past position {past}): '
+                f'{loadgen.offered(requests, past)}')
     registry = None
     if trace:
         from perf.lib.registry_delta import RegistryWindow

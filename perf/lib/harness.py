@@ -173,9 +173,16 @@ class TraceWindow:
         import jax
         jax.profiler.start_trace(self.dir)
         self._t0 = time.perf_counter()
+        # The traced stretch's edges on the trace's own clock
+        # (trace_reduce.traced_stretch): a program running at either
+        # is a call cut short, and is not counted as a whole one.
+        with annotate('trace_on'):
+            pass
 
     def stop(self) -> None:
         import jax
+        with annotate('trace_off'):
+            pass
         self.seconds = time.perf_counter() - self._t0
         jax.profiler.stop_trace()
 
@@ -285,20 +292,32 @@ def compared(name: str, value: float, limit: float,
     ok = bool(value <= limit)
     results.append({'name': name, 'value': float(value),
                     'limit': float(limit), 'ok': ok})
-    say(f'compare {name}: value {float(value):.6g} limit '
-        f'{float(limit):.6g} -> {"ok" if ok else "NOT CORRECT"}')
+    say(compared_line(results[-1]))
     return ok
+
+
+def compared_line(c: Dict[str, Any]) -> str:
+    return (f'compare {c["name"]}: value {c["value"]:.6g} limit '
+            f'{c["limit"]:.6g} -> {"ok" if c["ok"] else "NOT CORRECT"}')
 
 
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]],
                 device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                compared_numbers: Optional[List[Dict[str, Any]]] = None
+                ) -> str:
+    """The last line of standard output. ``compared`` comes last in
+    it: each number that decided ``correct`` beside its limit."""
     line = {'correct': bool(correct), 'attempted': int(attempted),
             'failed': int(failed), 'metrics': metrics,
             'device': device}
     if breakdown is not None:
         line['breakdown'] = breakdown
+    if compared_numbers is not None:
+        line['compared'] = {
+            c['name']: {'value': c['value'], 'limit': c['limit']}
+            for c in compared_numbers}
     return json.dumps(line)
 
 

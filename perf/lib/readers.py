@@ -81,6 +81,17 @@ def fact_percentile(params: Dict[str, Any], trace, records: Dict[str, Any]
     return stats.percentile(values, params['pct'])
 
 
+def fact_mean(params: Dict[str, Any], trace, records: Dict[str, Any]
+              ) -> Optional[float]:
+    """Mean of a list of values the driver states as a fact of the
+    run (``fact``): steadier than a median where the values fall
+    into two groups."""
+    values = records['facts'].get(params['fact'])
+    if not values:
+        return None
+    return sum(values) / len(values)
+
+
 def _module(params: Dict[str, Any], trace):
     if trace is None:
         return None

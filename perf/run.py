@@ -59,6 +59,10 @@ def main(argv=None) -> int:
         print(harness.result_line(out['correct'], out['attempted'],
                                   out['failed'], {}, device))
         return 0
+    # Each number compared beside its limit: the last lines of
+    # standard error, and the last key of the result line.
+    for c in out['compared']:
+        print(harness.compared_line(c), file=sys.stderr, flush=True)
     device['memory_peak_bytes'] = out['memory_peak_bytes']
     breakdown = None
     if args.trace:
@@ -84,7 +88,7 @@ def main(argv=None) -> int:
                    for m in loaded['end_to_end']}
     print(harness.result_line(out['correct'], out['attempted'],
                               out['failed'], metrics, device,
-                              breakdown), flush=True)
+                              breakdown, out['compared']), flush=True)
     return 0
 
 

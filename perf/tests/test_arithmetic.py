@@ -74,24 +74,131 @@ def test_schedule_is_a_function_of_the_seed_alone():
     assert a != c
 
 
-@pytest.mark.parametrize('mix', ['chat-steady', 'chat-backlog'])
-def test_every_seed_gets_the_same_sizes_in_another_order(mix):
+_SERVING_MIXES = ['chat-backlog', 'chat-steady', 'reason-backlog']
+_SIX_SEEDS = (0, 1, 2, 77, 2**31 + 3, 2**32 + 5)
+
+
+def _six_runs(mix):
     spec = loadgen.load_traffic(mix)
     gen = loadgen.generator_for(spec['kind'])
-    runs = [gen(spec, seed, 51, 32000) for seed in (1, 2, 2**31 + 3)]
+    return spec, [gen(spec, seed, 51, 32000) for seed in _SIX_SEEDS]
+
+
+def _triples(reqs):
+    return sorted((len(r['prompt']), r['max_new'], r['shared'])
+                  for r in reqs)
+
+
+@pytest.mark.parametrize('mix', _SERVING_MIXES)
+def test_every_seed_offers_the_same_schedule_with_other_tokens(mix):
+    """Not only the same marginals: the (prompt length, output
+    length, system prompt) triples, their order and their due
+    instants are the mix's, whatever the seed, and so is every count
+    made from them. The seed draws the token ids."""
+    _, runs = _six_runs(mix)
     a = runs[0]
+    schedule = lambda rs: [  # noqa: E731
+        (r['due_s'], len(r['prompt']), r['max_new'], r['shared'])
+        for r in rs]
     for b in runs[1:]:
-        assert len(a) == len(b)
-        assert sorted(r['max_new'] for r in a) == \
-            sorted(r['max_new'] for r in b)
-        assert sorted(len(r['prompt']) for r in a) == \
-            sorted(len(r['prompt']) for r in b)
-        assert sorted(r['shared'] for r in a) == \
-            sorted(r['shared'] for r in b)
-        gaps = lambda rs: sorted(np.round(np.diff(  # noqa: E731
-            [-spec['lead_s']] + [r['due_s'] for r in rs]), 9))
-        assert gaps(a) == gaps(b)
-        assert [r['max_new'] for r in a] != [r['max_new'] for r in b]
+        assert schedule(a) == schedule(b)
+        assert _triples(a) == _triples(b)
+        assert loadgen.offered(a, 3072) == loadgen.offered(b, 3072)
+        assert all(x['prompt'] != y['prompt'] for x, y in zip(a, b))
+
+
+@pytest.mark.parametrize('mix', _SERVING_MIXES)
+def test_marginals_count_and_rate_are_exact(mix):
+    spec, runs = _six_runs(mix)
+    reqs = runs[3]
+    n = len(reqs)
+    if spec['kind'] == 'backlog':
+        assert n == spec['n_requests']
+        assert all(r['due_s'] == -spec['lead_s'] for r in reqs)
+    else:
+        span = spec['lead_s'] + 51
+        assert n == math.floor(spec['rate_rps'] * span)
+        # The gaps' mean is 1 / rate exactly: the last arrival falls
+        # on n / rate after the lead-in's start.
+        assert reqs[-1]['due_s'] + spec['lead_s'] == pytest.approx(
+            n / spec['rate_rps'])
+    import statistics
+    nd = statistics.NormalDist()
+    for key, got in (('prompt_len', [len(r['prompt']) for r in reqs]),
+                     ('output_len', [r['max_new'] for r in reqs])):
+        d = spec[key]
+        want = sorted(int(np.clip(np.rint(d['median'] * math.exp(
+            d['sigma'] * nd.inv_cdf((i + 0.5) / n))), d['min'],
+            d['max'])) for i in range(n))
+        assert sorted(got) == want
+
+
+@pytest.mark.parametrize('mix', _SERVING_MIXES)
+def test_every_block_spans_both_ranges_and_tenths_meet_evenly(mix):
+    spec, runs = _six_runs(mix)
+    block = spec['deal_block']
+    for reqs in runs[:2]:
+        n = len(reqs)
+        n_blocks = math.ceil(n / block)
+        # The schedule is whole blocks one after another; each holds
+        # one prompt and one output from every round of n_blocks
+        # ranks (a last, short round reaches only some blocks).
+        lengths = sorted(len(r['prompt']) for r in reqs)
+        outs = sorted(r['max_new'] for r in reqs)
+        at = 0
+        round_of = lambda v, ordered: {  # noqa: E731
+            k // n_blocks for k, x in enumerate(ordered) if x == v}
+        for size in _schedule_block_sizes(n, block):
+            members = reqs[at:at + size]
+            at += size
+            for values, ordered in (
+                    ([len(r['prompt']) for r in members], lengths),
+                    ([r['max_new'] for r in members], outs)):
+                # Ties (clipped lengths) may sit in either of two
+                # rounds: every round is met by some member.
+                rounds = [round_of(v, ordered) for v in values]
+                for want in range(size):
+                    assert any(want in r for r in rounds), \
+                        (mix, want, values)
+        assert at == n
+
+
+def _schedule_block_sizes(n, block):
+    """Sizes of the blocks in the order the schedule holds them."""
+    blocks = loadgen._snake_blocks(n, block)  # pylint: disable=protected-access
+    stride = loadgen._stride(len(blocks))  # pylint: disable=protected-access
+    return [len(blocks[k * stride % len(blocks)])
+            for k in range(len(blocks))]
+
+
+@pytest.mark.parametrize('mix', _SERVING_MIXES)
+def test_prompt_and_output_lengths_are_independent_in_the_large(mix):
+    _, runs = _six_runs(mix)
+    reqs = runs[0]
+    n = len(reqs)
+    by_p = sorted(range(n), key=lambda i: (len(reqs[i]['prompt']), i))
+    by_o = sorted(range(n), key=lambda i: (reqs[i]['max_new'], i))
+    fifth_p = {i: k * 5 // n for k, i in enumerate(by_p)}
+    fifth_o = {i: k * 5 // n for k, i in enumerate(by_o)}
+    table = np.zeros((5, 5), int)
+    for i in range(n):
+        table[fifth_p[i], fifth_o[i]] += 1
+    # Every fifth of the prompts meets every fifth of the outputs
+    # about n / 25 times: to within a tenth where the blocks are many
+    # (640: 24-27 of 25.6), more loosely where they are a dozen.
+    slack = 0.1 if n >= 400 else 0.65
+    assert table.min() >= (1 - slack) * n / 25
+    assert table.max() <= (1 + slack) * n / 25
+    logs = np.log([[len(r['prompt']), r['max_new']] for r in reqs])
+    assert abs(np.corrcoef(logs.T)[0, 1]) < 0.1
+
+
+def test_a_stride_visits_every_residue():
+    for m in range(1, 70):
+        s = loadgen._stride(m)  # pylint: disable=protected-access
+        assert sorted(k * s % m for k in range(m)) == list(range(m))
+    assert loadgen._stride(64) == 39  # pylint: disable=protected-access
+    assert loadgen._stride(10) == 7  # pylint: disable=protected-access
 
 
 def test_lengths_are_the_quantile_mid_points():
@@ -106,10 +213,14 @@ def test_lengths_are_the_quantile_mid_points():
         0.8 * nd.inv_cdf((i + 0.5) / n))), 320, 3072))
         for i in range(n))
     assert sorted(len(r['prompt']) for r in reqs) == want
-    # Any stretch of two dozen requests carries about the same work.
-    sums = [sum(len(r['prompt']) for r in reqs[i:i + 24])
-            for i in range(0, n - 24, 24)]
-    assert max(sums) < 1.25 * min(sums)
+    # Any whole block carries about the same work, of either kind,
+    # and any stretch of two dozen requests nearly so.
+    for size, slack in ((spec['deal_block'], 1.1), (24, 1.45)):
+        for work in (lambda r: len(r['prompt']),
+                     lambda r: r['max_new']):
+            sums = [sum(work(r) for r in reqs[i:i + size])
+                    for i in range(0, n - size + 1, size)]
+            assert max(sums) < slack * min(sums)
 
 
 def test_schedule_keeps_rate_lead_and_limits():

@@ -76,7 +76,8 @@ def test_reason_backlog_offers_the_same_work_to_every_seed():
         assert sorted(r[key] for r in a) == sorted(r[key] for r in b)
     assert sorted(len(r['prompt']) for r in a) == \
         sorted(len(r['prompt']) for r in b)
-    assert [r['max_new'] for r in a] != [r['max_new'] for r in b]
+    assert [r['max_new'] for r in a] == [r['max_new'] for r in b]
+    assert [r['prompt'] for r in a] != [r['prompt'] for r in b]
     longest = max(len(r['prompt']) for r in a) + \
         max(r['max_new'] for r in a)
     assert longest <= _file()['build']['max_seq']

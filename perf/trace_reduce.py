@@ -21,6 +21,9 @@ Interval = Tuple[float, float]  # start, end, seconds
 
 _MODULE_LINE = 'XLA Modules'
 _OPS_LINE = 'XLA Ops'
+# Zero-length spans the harness writes just after the profiler has
+# started and just before it is stopped (perf/lib/harness.py).
+TRACE_ON, TRACE_OFF = 'perf.trace_on', 'perf.trace_off'
 COLLECTIVE = re.compile(
     r'all-reduce|all-gather|reduce-scatter|all-to-all|'
     r'collective-permute|collective-broadcast')
@@ -55,11 +58,31 @@ def _is_container(name: str) -> bool:
     return re.match(r'%?(while|conditional|call)\b', name) is not None
 
 
+def _programs(module_events) -> Tuple[List[float], List[Tuple]]:
+    """The runs of compiled programs on one device, sorted, as
+    ``(start, end, name)`` with ``jit_`` and the fingerprint cut
+    off, and their starts for ``bisect``."""
+    runs = sorted((s, s + d, name.split('(')[0].replace('jit_', '', 1))
+                  for name, s, d in module_events)
+    return [r[0] for r in runs], runs
+
+
+def _program_at(starts: List[float], runs: List[Tuple],
+                when: float) -> str:
+    """The compiled program that was running at ``when``, or ''."""
+    i = bisect.bisect_right(starts, when) - 1
+    return runs[i][2] if i >= 0 and when < runs[i][1] else ''
+
+
 def load(path: str) -> Dict[str, Any]:
     """The trace as plain data: ``{'devices': {plane name: {line
     name: [(name, start_s, dur_s)]}}, 'host': [(name, start_s,
-    dur_s)], 'shapes': {operation name: result type and shape}}``. Operation names are cut to their short form. A path
-    ending in ``.gz`` is a gzipped ``.xplane.pb``."""
+    dur_s)], 'shapes': {(compiled program, operation name): result
+    type and shape}}``. Operation names are cut to their short form;
+    the compiler numbers each program's operations from nought, so
+    ``fusion.241`` is one operation in ``decode_steps_paged`` and
+    another in ``forward_paged``, and a shape is kept by both names.
+    A path ending in ``.gz`` is a gzipped ``.xplane.pb``."""
     from jax.profiler import ProfileData
     if path.endswith('.gz'):
         import gzip
@@ -69,23 +92,29 @@ def load(path: str) -> Dict[str, Any]:
         data = ProfileData.from_file(path)
     devices: Dict[str, Dict[str, list]] = {}
     host: List[Tuple[str, float, float]] = []
-    shapes: Dict[str, str] = {}
+    shapes: Dict[Tuple[str, str], str] = {}
     for plane in data.planes:
         is_dev = plane.name.startswith('/device:TPU:')
         is_host = plane.name.startswith('/host:')
         if not (is_dev or is_host):
             continue
+        long_names = []
         for line in plane.lines:
             events = [(short_name(e.name), e.start_ns * 1e-9,
                        e.duration_ns * 1e-9) for e in line.events]
             if is_dev:
                 devices.setdefault(plane.name, {})[line.name] = events
                 if line.name == _OPS_LINE:
-                    for e in line.events:
-                        shapes.setdefault(short_name(e.name),
-                                          result_shape(e.name))
+                    long_names = [e.name for e in line.events]
             else:
                 host.extend(events)
+        if long_names:
+            lines = devices[plane.name]
+            starts, runs = _programs(lines.get(_MODULE_LINE, []))
+            for long, (short, s, _) in zip(long_names,
+                                           lines[_OPS_LINE]):
+                shapes.setdefault((_program_at(starts, runs, s), short),
+                                  result_shape(long))
     return {'devices': devices, 'host': host, 'shapes': shapes}
 
 
@@ -151,13 +180,41 @@ def busy_seconds(trace: Dict[str, Any]) -> float:
     return sum(per_dev) / len(per_dev)
 
 
+def traced_stretch(trace: Dict[str, Any]) -> Interval:
+    """The stretch in which the profiler was certainly recording, on
+    the trace's own clock: from the harness's ``perf.trace_on`` span
+    (written once ``start_trace`` has returned) to its
+    ``perf.trace_off`` (written before ``stop_trace`` is called), or
+    where a trace has neither, between the profiler's own
+    ``start_trace`` and ``stop_trace`` calls as its Python tracer
+    records them. A program that was running at either instant is
+    in the trace as far as the recording reaches: a call cut short."""
+    on = [s + d for name, s, d in trace['host']
+          if name == TRACE_ON or name.endswith(' start_trace')]
+    off = [s for name, s, _ in trace['host']
+           if name == TRACE_OFF or name.endswith(' stop_trace')]
+    return (max(on) if on else float('-inf'),
+            min(off) if off else float('inf'))
+
+
+def whole_calls(lines: Dict[str, list], stretch: Interval) -> list:
+    """The runs of compiled programs on one device that lie wholly
+    inside ``stretch``. The two that the traced stretch cuts at its
+    edges are left out: counted as whole calls they read a program's
+    time per call low, by up to a call in ten over a 5 s stretch."""
+    return [(name, s, d) for name, s, d in lines.get(_MODULE_LINE, [])
+            if stretch[0] <= s and s + d <= stretch[1]]
+
+
 def module_times(trace: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     """Per compiled program (``jit_<function>``, fingerprint cut
-    off): calls and seconds on the busiest device."""
+    off): calls and seconds on the busiest device, over the calls
+    that lie wholly inside the traced stretch."""
     out: Dict[str, Dict[str, float]] = {}
+    stretch = traced_stretch(trace)
     for lines in trace['devices'].values():
         mine: Dict[str, Dict[str, float]] = {}
-        for name, _, d in lines.get(_MODULE_LINE, []):
+        for name, _, d in whole_calls(lines, stretch):
             base = name.split('(')[0]
             rec = mine.setdefault(base, {'calls': 0, 'seconds': 0.0})
             rec['calls'] += 1
@@ -172,12 +229,19 @@ def module_times(trace: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
 def op_seconds(trace: Dict[str, Any], pattern: str
                ) -> Dict[str, float]:
     """Calls and summed seconds of the operations whose name matches
-    ``pattern`` (a regex), on the busiest device."""
+    ``pattern`` (a regex), on the busiest device. Where the device
+    names its programs, only operations inside the calls that
+    ``module_times`` counts: a kernel's time and the steps it is
+    set against are then of the same calls."""
     rx = re.compile(pattern)
     best = {'calls': 0, 'seconds': 0.0}
+    stretch = traced_stretch(trace)
     for lines in trace['devices'].values():
-        hit = [d for name, _, d in lines.get(_OPS_LINE, [])
-               if rx.search(name)]
+        named = bool(lines.get(_MODULE_LINE))
+        starts, runs = _programs(whole_calls(lines, stretch))
+        hit = [d for name, s, d in lines.get(_OPS_LINE, [])
+               if rx.search(name) and
+               (not named or _program_at(starts, runs, s))]
         if sum(hit) > best['seconds']:
             best = {'calls': len(hit), 'seconds': sum(hit)}
     return best
@@ -214,19 +278,16 @@ def top_ops(trace: Dict[str, Any], n: int = 10
     if not trace['devices']:
         return []
     lines = trace['devices'][sorted(trace['devices'])[0]]
-    modules = sorted((s, s + d, name.split('(')[0])
-                     for name, s, d in lines.get(_MODULE_LINE, []))
-    starts = [m[0] for m in modules]
+    starts, runs = _programs(lines.get(_MODULE_LINE, []))
     shapes = trace.get('shapes', {})
     by_name: Dict[str, float] = {}
     for name, s, d in busy_lines(lines):
         if _is_container(name):
             continue
-        i = bisect.bisect_right(starts, s) - 1
-        inside = i >= 0 and s < modules[i][1]
-        label = ((modules[i][2].replace('jit_', '', 1) + '/'
-                  if inside else '') + name +
-                 (' ' + shapes[name] if shapes.get(name) else ''))
+        program = _program_at(starts, runs, s)
+        shape = shapes.get((program, name))
+        label = ((program + '/' if program else '') + name +
+                 (' ' + shape if shape else ''))
         by_name[label] = by_name.get(label, 0.0) + d
     ranked = sorted(by_name.items(), key=lambda kv: -kv[1])[:n]
     return [[name, sec] for name, sec in ranked]
