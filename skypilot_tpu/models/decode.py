@@ -25,14 +25,18 @@ own view of the pool -> ``layer_tail``, under ``looped_stack``; the
 pool's format and index arithmetic are ``ops/decode_attention.py``'s.
 Nothing here imports the serve package, which is above it.
 """
+import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from skypilot_tpu import exceptions
 from skypilot_tpu.models import llama
+from skypilot_tpu.models import moe
 from skypilot_tpu.models.quant import matmul as _mm
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops import decode_attention as da
@@ -237,7 +241,9 @@ def _layer_cached(config: llama.LlamaConfig, x: jax.Array,
     h = llama._rms_norm(x, layer_params['mlp_norm'],
                         config.norm_eps, config.norm_offset)
     if config.n_experts:
-        moe_out, _ = llama._moe_mlp(config, h, layer_params)
+        # The dropless layer of the paged bodies (models/moe.py), so
+        # that the two engines keep serving the same tokens.
+        moe_out, _ = moe.moe_layer(config, h, layer_params)
         x = x + moe_out
     else:
         gate = llama.mlp_act(config)(
@@ -329,22 +335,31 @@ def forward_cached(params: Params, tokens: jax.Array,
                            k_scale=new_ks, v_scale=new_vs)
 
 
-def rope(x: jax.Array, angles: jax.Array) -> jax.Array:
+def rope(x: jax.Array, angles: jax.Array,
+         interleaved: bool = False) -> jax.Array:
     """Rotate-half RoPE of the three paged bodies: x [B, T, H, D];
     angles float32 [..., T, D/2], broadcast over x's leading axes —
     [T, D/2] for one request's chunk, [B, T, D/2] where each row
     stands at positions of its own (T = 1 for a decode step). The
     arithmetic is ``ops.attention.apply_rope``'s, which the training
-    path keeps for its one layout."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    path keeps for its one layout. ``interleaved``: pair i is
+    (x[2i], x[2i + 1]) ("rope_gptj") in place of (x[i], x[i + D/2]),
+    with the same angle i."""
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     # To x's rank in one step: leading axes angles lacks, and heads.
     to_x = (None,) * (x.ndim - 1 - angles.ndim) + (..., None,
                                                    slice(None))
     cos = jnp.cos(angles)[to_x]
     sin = jnp.sin(angles)[to_x]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-        axis=-1).astype(x.dtype)
+    r1, r2 = x1 * cos - x2 * sin, x1 * sin + x2 * cos
+    if interleaved:
+        return jnp.stack([r1, r2], axis=-1).reshape(
+            x.shape).astype(x.dtype)
+    return jnp.concatenate([r1, r2], axis=-1).astype(x.dtype)
 
 
 def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
@@ -369,7 +384,8 @@ def lora_gather_delta(h: jax.Array, a_slots: jax.Array,
 
 
 def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
-               ad, adapter_idx, angles: jax.Array, quantized: bool):
+               ad, adapter_idx, angles: jax.Array, quantized: bool,
+               kind: str = 'global'):
     """What precedes attention in a layer of the three PAGED bodies
     (``forward_paged``, ``decode_steps_paged``,
     ``verify_step_paged``; ``layer_tail`` is its complement): the
@@ -379,8 +395,9 @@ def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
     the identical delta, or prefill would write KV that decode's
     arithmetic does not imply and verify would accept drafts against
     a different model), ``qkv_bias``, the reshape to heads, RoPE
-    (``rope``: ``angles`` in any of its layouts), and the new rows in
-    the pool's type.
+    (``rope``: ``angles`` in any of its layouts; none on a ``kind``
+    'global' layer of a configuration without ``global_rope``), and
+    the new rows in the pool's type.
 
     ``xc`` [B, T, D]. Returns (q [B, T, H, hd], k, v [B, T, Hkv,
     hd], rows): ``rows`` = (k_rows, v_rows, ks_rows, vs_rows) is what
@@ -390,8 +407,7 @@ def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
     body."""
     b, t, _ = xc.shape
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    h = llama._rms_norm(xc, lp['attn_norm'], config.norm_eps,
-                        config.norm_offset)
+    h = llama.norm(config, xc, lp['attn_norm'])
     with jax.named_scope('qkv_proj'):
         q = _mm(h, lp['wq'])
         k = _mm(h, lp['wk'])
@@ -408,8 +424,9 @@ def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
     q = q.reshape(b, t, nh, hd)
     k = k.reshape(b, t, nkv, hd)
     v = v.reshape(b, t, nkv, hd)
-    q = rope(q, angles)
-    k = rope(k, angles)
+    if config.global_rope or kind != 'global':
+        q = rope(q, angles, config.rope_interleaved)
+        k = rope(k, angles, config.rope_interleaved)
     if quantized:
         k_rows, ks_rows = _quantize_kv(k)
         v_rows, vs_rows = _quantize_kv(v)
@@ -420,7 +437,8 @@ def layer_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
 
 
 def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
-               attn: jax.Array, lp: Params) -> jax.Array:
+               attn: jax.Array, lp: Params
+               ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """What follows attention in a layer of the three PAGED bodies
     (``forward_paged``, ``decode_steps_paged``,
     ``verify_step_paged``; ``layer_head`` is its complement): the
@@ -428,7 +446,14 @@ def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
     MLP, each added to the residual stream. ``xc`` [B, T, D]; ``attn``
     [B, T, H * hd]. With ``config.sandwich_norms`` each branch's
     output passes a norm of its own before the add
-    (``attn_out_norm``, ``mlp_out_norm``; scope ``branch_norm``)."""
+    (``attn_out_norm``, ``mlp_out_norm``; scope ``branch_norm``).
+    With ``config.parallel_block`` the MLP reads the layer's ONE norm
+    of the incoming stream, as attention did (taken here again from
+    ``xc``: the same expression, which the compiler computes once),
+    and both results are added at once. An expert layer is the
+    dropless ``moe.moe_layer``. Returns the stream and the pairs the
+    expert layer routed to each held expert (None for a dense
+    layer)."""
     def branch_norm(out, name):
         if not config.sandwich_norms:
             return out
@@ -436,20 +461,23 @@ def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
             return llama._rms_norm(out, lp[name], config.norm_eps,
                                    config.norm_offset)
 
+    if config.parallel_block:
+        h = llama.norm(config, xc, lp['attn_norm'])
     with jax.named_scope('o_proj'):
         xc = xc + branch_norm(_mm(attn, lp['wo']), 'attn_out_norm')
-    h = llama._rms_norm(xc, lp['mlp_norm'], config.norm_eps,
-                        config.norm_offset)
+    if not config.parallel_block:
+        h = llama.norm(config, xc, lp['mlp_norm'])
+    routed = None
     with jax.named_scope('mlp'):
         if config.n_experts:
-            out, _ = llama._moe_mlp(config, h, lp)
+            out, routed = moe.moe_layer(config, h, lp)
         else:
             gate = llama.mlp_act(config)(
                 _mm(h, lp['w_gate']).astype(jnp.float32)
             ).astype(h.dtype)
             up = _mm(h, lp['w_up'])
             out = _mm(gate * up, lp['w_down'])
-        return xc + branch_norm(out, 'mlp_out_norm')
+        return xc + branch_norm(out, 'mlp_out_norm'), routed
 
 
 def looped_stack(config: llama.LlamaConfig, cparams: Params,
@@ -464,9 +492,11 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
     ``layer(x, lp, entry, ad) -> (x, rows)`` is one layer on weights
     ``lp`` (and its slice ``ad`` of ``adapters``, None without),
     reading KV entry ``entry`` = pass x n_layers + layer and
-    returning its new K/V rows. ``last(h)`` cuts the normed state
-    down to the positions whose logits are wanted (a prefill chunk
-    wants one).
+    returning its new K/V rows (any pytree: the expert layer's tally
+    rides beside them). With layers of several kinds the scan runs
+    over periods (``period_scan`` below). ``last(h)`` cuts the normed
+    state down to the positions whose logits are wanted (a prefill
+    chunk wants one).
 
     Every pass always runs for every row: a static batch cannot let
     one row leave early. With ``config.exit_threshold`` = q the pass
@@ -480,18 +510,71 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
     One pass, no gate: one scan over the layers and the final norm
     on ``last(x)``, the program every other model traced before this
     function existed."""
+    kinds = config.layer_kinds
+    period = len(kinds)
+
+    layers, experts = cparams['layers'], None
+    if config.n_experts:
+        # The expert stacks stay whole and out of the scan
+        # (``moe.LayerOf`` says why); a layer gets them by index.
+        layers, experts = moe.stacked_experts(layers)
+
+    def with_experts(lp, li):
+        if experts is None:
+            return lp
+        return dict(lp, **{name: moe.LayerOf(w, li)
+                           for name, w in experts.items()})
+
     def layer_scan(xc, first_entry):
         def body(c, scanned):
             lp, li, ad = scanned
-            return layer(c, lp, first_entry + li, ad)
+            return layer(c, with_experts(lp, li), first_entry + li, ad)
         return jax.lax.scan(
             body, xc,
-            (cparams['layers'],
+            (layers,
              jnp.arange(config.n_layers, dtype=jnp.int32), adapters))
 
+    def period_scan(xc):
+        """Layers of several kinds (``config.layer_kinds``): the
+        scan's body is one period, each of its layers on its kind's
+        own block group, so ``layer`` is also told the ``kind`` and
+        ``entry`` counts within that kind's group (period i's j-th
+        layer of its kind among n: i * n + j). A layer's weights are
+        taken out of the stacks by index inside the body, where the
+        products read them in place (a period's worth scanned in as
+        one slice was a copy of it: 3 GB of expert codes a step).
+        The results come back in layer order, [n_layers, ...]."""
+        def body(c, pi):
+            ys = []
+            for j, kind in enumerate(kinds):
+                li = pi * period + j
+                lp, ad = jax.tree.map(
+                    lambda w: jax.lax.dynamic_index_in_dim(
+                        w, li, 0, keepdims=False), (layers, adapters))
+                c, y = layer(
+                    c, with_experts(lp, li),
+                    pi * kinds.count(kind) + kinds[:j].count(kind),
+                    ad, kind=kind)
+                ys.append(y)
+            return c, jax.tree.map(lambda *r: jnp.stack(r), *ys)
+
+        xc, ys = jax.lax.scan(
+            body, xc,
+            jnp.arange(config.n_layers // period, dtype=jnp.int32))
+        return xc, jax.tree.map(
+            lambda r: r.reshape(config.n_layers, *r.shape[2:]), ys)
+
     def final_norm(h):
-        return llama._rms_norm(h, cparams['final_norm'],
-                               config.norm_eps, config.norm_offset)
+        return llama.norm(config, h, cparams['final_norm'])
+
+    if period > 1:
+        if not (config.loop_passes == 1
+                and config.exit_threshold is None):
+            raise exceptions.NotSupportedError(
+                f'{config.name!r}: a looped stack with layers of '
+                f'several kinds is not implemented')
+        x, rows = period_scan(x)
+        return final_norm(last(x)), rows
 
     passes, q = config.loop_passes, config.exit_threshold
     if passes == 1 and q is None:
@@ -530,6 +613,66 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
     rows = jax.tree.map(
         lambda r: r.reshape(-1, *r.shape[2:]), rows)
     return served, rows
+
+
+def _by_kind(config: llama.LlamaConfig, x):
+    """``x`` by kind of layer. A configuration whose layers are all
+    of one kind hands the paged bodies one pool 4-tuple and one block
+    table, as ever; one with window AND global layers hands a dict
+    of each, keyed 'window' / 'global' (``kv_pool.KVBlockPool``: a
+    block group a kind). The bodies work on the dict."""
+    return x if isinstance(x, dict) else {config.layer_kinds[0]: x}
+
+
+def _as_given(given, by_kind):
+    return by_kind if isinstance(given, dict) else \
+        next(iter(by_kind.values()))
+
+
+def _flat_pools(config: llama.LlamaConfig, kind: str, pools,
+                block_size: int):
+    """One group's pool 4-tuple [E, NB, bs, ...] as flat [E, NB * bs,
+    ...] arrays (write index arithmetic is one-dimensional), with the
+    group's block count."""
+    k_pool, _, k_scale, _ = pools
+    ne, nb, bs = k_pool.shape[:3]
+    assert bs == block_size, (bs, block_size)
+    assert ne == config.kind_entries(kind), (
+        kind, ne, config.kind_entries(kind))
+    return tuple(
+        None if p is None else p.reshape(ne, nb * bs, *p.shape[3:])
+        for p in pools), nb
+
+
+def _unflat_pools(flat, nb: int):
+    return tuple(
+        None if p is None else
+        p.reshape(p.shape[0], nb, p.shape[1] // nb, *p.shape[2:])
+        for p in flat)
+
+
+def _kind_rows(config: llama.LlamaConfig, rows, kind: str):
+    """The new rows of one kind's layers, [E_kind, ...] in that
+    group's entry order, out of ``looped_stack``'s [n_layers, ...]
+    (layer order)."""
+    kinds = config.layer_kinds
+    if len(kinds) == 1:
+        return rows
+    mine = [j for j, k in enumerate(kinds) if k == kind]
+
+    def pick(r):
+        r = r.reshape(-1, len(kinds), *r.shape[1:])[:, mine]
+        return r.reshape(-1, *r.shape[2:])
+    return jax.tree.map(pick, rows)
+
+
+def _routed_sums(routed: jax.Array) -> jax.Array:
+    """Per-step tallies [steps, n_layers, held] as the paged bodies
+    return them, int32 [2, n_layers, held]: the pairs routed to each
+    held expert summed over the steps, and in how many of the steps
+    the expert got any."""
+    return jnp.stack([routed.sum(axis=0),
+                      (routed > 0).sum(axis=0, dtype=jnp.int32)])
 
 
 def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
@@ -598,9 +741,19 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     the model code at all.
 
     Returns (logits [1, vocab] f32 at the chunk's LAST REAL position,
-    new pools). Only the final chunk's logits are meaningful (they
-    seed greedy decoding); earlier chunks' are computed into the same
-    cheap [1, 1, vocab] projection and ignored.
+    new pools, routed). Only the final chunk's logits are meaningful
+    (they seed greedy decoding); earlier chunks' are computed into
+    the same cheap [1, 1, vocab] projection and ignored. ``routed``
+    is None for a dense model, else int32 [2, n_layers, experts held]
+    (``_routed_sums``, one step): the (token, expert) pairs this
+    chunk, padding included, routed to each expert held here.
+
+    A configuration with window layers (``config.sliding_window``)
+    hands ``pools`` and ``block_row`` as dicts by kind of layer
+    (``_by_kind``) and attends over key tiles (``da.chunk_attention``:
+    scopes ``window_attention``, ``global_attention``) with no
+    in-layer write; every other configuration runs the gathered form
+    below, as it did.
 
     The pools' leading axis ``l`` counts KV entries, one for every
     pass and layer (``kv_pool.KVBlockPool``); a looped configuration
@@ -620,11 +773,10 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     single-chunk prompts (multi-chunk tracks closely; see the engine
     docstring caveat).
     """
-    k_pool, v_pool, k_scale_pool, v_scale_pool = pools
-    quantized = k_scale_pool is not None
-    l, nb, bs = k_pool.shape[:3]
-    assert bs == block_size, (bs, block_size)
-    assert l == config.kv_entries, (l, config.kv_entries)
+    by_kind = _by_kind(config, pools)
+    tables = _by_kind(config, block_row)
+    quantized = next(iter(by_kind.values()))[2] is not None
+    windowed = config.sliding_window is not None
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     _, t = tokens.shape
 
@@ -638,51 +790,89 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
 
     # Flat [NB * bs, ...] pool views; write/read index vectors are
-    # chunk-invariant across layers, computed once.
-    kp = k_pool.reshape(l, nb * bs, nkv, hd)
-    vp = v_pool.reshape(l, nb * bs, nkv, hd)
-    ksp = k_scale_pool.reshape(l, nb * bs, nkv) if quantized else None
-    vsp = v_scale_pool.reshape(l, nb * bs, nkv) if quantized else None
-    gw = da.chunk_write_indices(block_row, start, real_len, t,
-                                block_size)                  # [T]
-    gr = da.read_indices(block_row[None], block_size)[0]     # [S_pad]
+    # chunk-invariant across layers, computed once (a group).
+    flat, nbs, gw, gr = {}, {}, {}, {}
+    for kind, group in by_kind.items():
+        flat[kind], nbs[kind] = _flat_pools(config, kind, group,
+                                            block_size)
+        gw[kind] = da.chunk_write_indices(
+            tables[kind], start, real_len, t, block_size)     # [T]
+        if not windowed:
+            gr[kind] = da.read_indices(tables[kind][None],
+                                       block_size)[0]     # [S_pad]
 
-    def layer(xc, lp, entry, ad):
-        # This pass's and layer's KV entry: taken out of the stacked
-        # pools by index, as a scan over them would (at one pass the
-        # entry is the layer).
-        kc, vc, ks, vs = (
-            None if p is None else
-            jax.lax.dynamic_index_in_dim(
-                p, entry, 0, keepdims=False,
-                allow_negative_indices=False)
-            for p in (kp, vp, ksp, vsp))
+    def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
+        if not windowed:
+            # This pass's and layer's KV entry: taken out of the
+            # stacked pools by index, as a scan over them would (at
+            # one pass the entry is the layer).
+            entry_pools = tuple(
+                None if p is None else
+                jax.lax.dynamic_index_in_dim(
+                    p, entry, 0, keepdims=False,
+                    allow_negative_indices=False)
+                for p in flat[kind])
         q, k, v, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
-            config, xc, lp, ad, adapter_idx, angles, quantized)
+            config, xc, lp, ad, adapter_idx, angles, quantized, kind)
+        if windowed:
+            # Over key tiles, bounded by the window in a window layer
+            # and by ``start`` in a global one; the chunk's own exact
+            # rows are an operand (``da.chunk_attention``), so there
+            # is no in-layer write and no view of ``max_seq``.
+            kp, vp, ksp, vsp = flat[kind]
+            with jax.named_scope(kind + '_attention'):
+                attn = da.chunk_attention(
+                    q[0], k[0], v[0], _all_blocks(kp, block_size),
+                    _all_blocks(vp, block_size),
+                    tables[kind] + entry * nbs[kind], start,
+                    hd ** -0.5,
+                    None if ksp is None
+                    else _all_blocks(ksp, block_size),
+                    None if vsp is None
+                    else _all_blocks(vsp, block_size),
+                    window=config.sliding_window
+                    if kind == 'window' else None)[None]
+        else:
+            attn = gathered_attention(entry_pools, kind, q, k, v,
+                                      k_rows, v_rows, ks_rows,
+                                      vs_rows)
+        xc, routed = layer_tail(config, xc,
+                                attn.reshape(1, t, nh * hd), lp)
+        return xc, (((k_rows[0], v_rows[0], ks_rows[0], vs_rows[0])
+                     if quantized else (k_rows[0], v_rows[0])),
+                    routed)
+
+    def gathered_attention(entry_pools, kind, q, k, v, k_rows, v_rows,
+                           ks_rows, vs_rows):
+        """What every configuration without window layers runs: the
+        chunk written into the entry's slice, the row's whole view
+        gathered and masked."""
+        kc, vc, ks, vs = entry_pools
         # In-layer write exists only so this chunk's attention sees
         # its own keys; the caller-visible pool update is the single
         # merged scatter after the layer scan (same split as
         # forward_cached — full-pool ys per layer would rewrite the
         # whole pool every chunk).
         with jax.named_scope('kv_write'):
-            kc = kc.at[gw].set(k_rows[0])
-            vc = vc.at[gw].set(v_rows[0])
+            kc = kc.at[gw[kind]].set(k_rows[0])
+            vc = vc.at[gw[kind]].set(v_rows[0])
             if quantized:
-                ks = ks.at[gw].set(ks_rows[0])
-                vs = vs.at[gw].set(vs_rows[0])
-        kd = _dequant_kv(da.paged_gather(kc, gr[None]),
+                ks = ks.at[gw[kind]].set(ks_rows[0])
+                vs = vs.at[gw[kind]].set(vs_rows[0])
+        view = gr[kind]
+        kd = _dequant_kv(da.paged_gather(kc, view[None]),
                          None if ks is None
-                         else da.paged_gather(ks, gr[None]), k.dtype)
-        vd = _dequant_kv(da.paged_gather(vc, gr[None]),
+                         else da.paged_gather(ks, view[None]), k.dtype)
+        vd = _dequant_kv(da.paged_gather(vc, view[None]),
                          None if vs is None
-                         else da.paged_gather(vs, gr[None]), v.dtype)
+                         else da.paged_gather(vs, view[None]), v.dtype)
         if quantized:
             # Attend the CURRENT chunk's exact bf16 rows, not their
             # int8 round trip — mirrors the dense prefill contract
             # ("quantization error only enters later decode steps",
             # here: later chunks and decode). Splice the chunk back
             # over its own logical positions in the gathered view.
-            col = jnp.arange(gr.shape[0])
+            col = jnp.arange(view.shape[0])
             rel = col - start
             in_chunk = (rel >= 0) & (rel < t)
             relc = jnp.clip(rel, 0, t - 1)
@@ -691,38 +881,37 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
             vd = jnp.where(in_chunk[None, :, None, None],
                            v[0][relc][None], vd)
         with jax.named_scope('prefill_attention'):
-            attn = _masked_attention(q, kd, vd, q_pos=start,
+            return _masked_attention(q, kd, vd, q_pos=start,
                                      kv_len=start + real_len,
                                      scale=hd ** -0.5)
-        xc = layer_tail(config, xc, attn.reshape(1, t, nh * hd), lp)
-        return xc, ((k_rows[0], v_rows[0], ks_rows[0], vs_rows[0])
-                    if quantized else (k_rows[0], v_rows[0]))
 
     # Project ONLY the chunk's last real position (start offsets make
     # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
     # materialization is the admission cost this path deletes.
-    x_last, rows = looped_stack(
+    x_last, (rows, routed) = looped_stack(
         config, cparams, x, layer, adapters,
         last=lambda h: jnp.take(
             h, jnp.maximum(real_len - 1, 0)[None], axis=1))  # [1,1,D]
     # Persist the chunk's rows with ONE scatter into the (donated)
-    # flat pools.
-    kp = kp.at[:, gw].set(rows[0])
-    vp = vp.at[:, gw].set(rows[1])
-    if quantized:
-        ksp = ksp.at[:, gw].set(rows[2])
-        vsp = vsp.at[:, gw].set(rows[3])
+    # flat pools (a group).
+    new_pools = {}
+    for kind, (kp, vp, ksp, vsp) in flat.items():
+        mine = _kind_rows(config, rows, kind)
+        kp = kp.at[:, gw[kind]].set(mine[0])
+        vp = vp.at[:, gw[kind]].set(mine[1])
+        if quantized:
+            ksp = ksp.at[:, gw[kind]].set(mine[2])
+            vsp = vsp.at[:, gw[kind]].set(mine[3])
+        new_pools[kind] = (kp, vp, ksp, vsp)
     if config.tie_embeddings:
         logits = (x_last @ llama.output_head(cparams, config)
                   ).astype(jnp.float32)
     else:
         logits = _mm(x_last, cparams['lm_head']).astype(jnp.float32)
-    new_pools = (
-        kp.reshape(l, nb, bs, nkv, hd),
-        vp.reshape(l, nb, bs, nkv, hd),
-        ksp.reshape(l, nb, bs, nkv) if quantized else None,
-        vsp.reshape(l, nb, bs, nkv) if quantized else None)
-    return logits[:, 0], new_pools
+    new_pools = {kind: _unflat_pools(group, nbs[kind])
+                 for kind, group in new_pools.items()}
+    return (logits[:, 0], _as_given(pools, new_pools),
+            None if routed is None else _routed_sums(routed[None]))
 
 
 def decode_steps_paged(params: Params, tokens: jax.Array,
@@ -805,43 +994,65 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     is the only place the chip's trace lets them be looked up; they
     change HLO metadata and nothing else.
 
-    Returns (out_tokens [B, num_steps], caches, new_pos).
+    A configuration with window AND global layers hands ``caches``
+    and ``block_tables`` as dicts by kind of layer (``_by_kind``): a
+    window layer reads ``da.window_view``'s columns of its table, a
+    fixed width whatever the context, and masks by the window; a
+    global layer reads the first ``view_blocks`` columns of its own.
+    Scopes ``window_attention`` / ``global_attention`` there.
+
+    Returns (out_tokens [B, num_steps], caches, new_pos). A model
+    with experts returns a fourth value, ``routed``, int32 [2,
+    n_layers, experts held] (``_routed_sums``): the (row, expert)
+    pairs routed to each expert held here, summed over the
+    dispatch's steps and over ALL rows, parked ones too (static
+    shapes: every lane computes), and the steps in which each expert
+    got any. (A dense model's returns are as they were: the
+    benchmark's own tests wrap this function by that arity.)
     """
-    k_pool, v_pool, k_scale, v_scale = caches
-    ne, nb, bs = k_pool.shape[:3]
-    assert bs == block_size, (bs, block_size)
-    assert ne == config.kv_entries, (ne, config.kv_entries)
+    by_kind = _by_kind(config, caches)
+    tables = dict(_by_kind(config, block_tables))
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
         params)
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     b = tokens.shape[0]
-    quantized = k_scale is not None  # static at trace
-    if view_blocks is not None:
-        block_tables = block_tables[:, :view_blocks]
+    bs = block_size
+    quantized = next(iter(by_kind.values()))[2] is not None  # static
+    if view_blocks is not None and 'global' in tables:
+        tables['global'] = tables['global'][:, :view_blocks]
 
     # Flat [NB * bs, ...] pool views — write index math is 1-D
     # flat-slot; attention reads whole blocks (``_all_blocks``).
-    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
-    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    flat, nbs = {}, {}
+    for kind, group in by_kind.items():
+        flat[kind], nbs[kind] = _flat_pools(config, kind, group, bs)
 
     def one_token(carry, _):
-        tok, kp_all, vp_all, ks_all, vs_all, cur = carry
+        tok, pools, cur = carry
         angles = llama._rope_frequencies(
             config, cur)[:, None]                       # [B, 1, hd/2]
         x = cparams['embed'][tok][:, None]              # [B, 1, D]
         if config.scale_embeddings:
             x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-        widx = da.write_index(block_tables, cur, block_size)  # [B]
-        scale_views = _scale_views(ks_all, vs_all, block_tables, bs)
+        widx, views, scale_views = {}, {}, {}
+        for kind, (_, _, ks_all, vs_all) in pools.items():
+            widx[kind] = da.write_index(tables[kind], cur, bs)  # [B]
+            # What a layer of this kind reads: the table's columns
+            # (cut above), or the window's (``da.window_view``).
+            views[kind] = (tables[kind], None) if kind == 'global' \
+                else da.window_view(tables[kind], cur,
+                                    config.sliding_window, bs)
+            scale_views[kind] = _scale_views(ks_all, vs_all,
+                                             views[kind][0], bs)
 
-        def layer(xc, lp, entry, ad):
+        def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
             # ``entry``: this pass's and layer's KV entry (at one
-            # pass, the layer); ``ad`` is None without adapters.
+            # pass, the layer) in its kind's group; ``ad`` is None
+            # without adapters.
             q, _, _, rows = layer_head(
-                config, xc, lp, ad, adapter_idx, angles, quantized)
+                config, xc, lp, ad, adapter_idx, angles, quantized,
+                kind)
             # No in-layer write: the layer's pool slice is a scanned
             # input, so ``kc.at[widx].set`` copied the whole slice
             # (75 MB of K and of V at 4,561 blocks, every layer of
@@ -850,28 +1061,39 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             # operand beside the view of positions [0, cur); the one
             # merged scatter after the layer scan persists them.
             new = tuple(None if r is None else r[:, 0] for r in rows)
-            ks_view, vs_view = (None, None) if scale_views is None \
+            kp_all, vp_all = pools[kind][:2]
+            view, key_start = views[kind]
+            ks_view, vs_view = (None, None) \
+                if scale_views[kind] is None \
                 else jax.lax.dynamic_index_in_dim(
-                    scale_views, entry, 0, keepdims=False,
+                    scale_views[kind], entry, 0, keepdims=False,
                     allow_negative_indices=False)
-            attn = da.paged_decode_attention(
-                q[:, 0], _all_blocks(kp_all, bs),
-                _all_blocks(vp_all, bs), block_tables + entry * nb,
-                cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-                new=new)[:, None]
-            xc = layer_tail(
+            with _attention_scope(config, kind):
+                attn = da.paged_decode_attention(
+                    q[:, 0], _all_blocks(kp_all, bs),
+                    _all_blocks(vp_all, bs), view + entry * nbs[kind],
+                    cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                    new=new, **_window_args(config, kind, key_start)
+                )[:, None]
+            xc, routed = layer_tail(
                 config, xc, attn.reshape(b, 1, nh * hd), lp)
-            return xc, new
+            return xc, (new, routed)
 
-        x, rows = looped_stack(config, cparams, x, layer, adapters)
+        x, (rows, routed) = looped_stack(config, cparams, x, layer,
+                                         adapters)
         # Persist the new rows: one merged scatter per token into the
         # carried (donated) flat pools.
         with jax.named_scope('kv_write'):
-            kp_all = kp_all.at[:, widx].set(rows[0])
-            vp_all = vp_all.at[:, widx].set(rows[1])
-            if quantized:
-                ks_all = ks_all.at[:, widx].set(rows[2])
-                vs_all = vs_all.at[:, widx].set(rows[3])
+            new_pools = {}
+            for kind, (kp_all, vp_all, ks_all, vs_all) in \
+                    pools.items():
+                mine = _kind_rows(config, rows, kind)
+                kp_all = kp_all.at[:, widx[kind]].set(mine[0])
+                vp_all = vp_all.at[:, widx[kind]].set(mine[1])
+                if quantized:
+                    ks_all = ks_all.at[:, widx[kind]].set(mine[2])
+                    vs_all = vs_all.at[:, widx[kind]].set(mine[3])
+                new_pools[kind] = (kp_all, vp_all, ks_all, vs_all)
         if config.tie_embeddings:
             logits = (x @ llama.output_head(cparams, config))
         else:
@@ -893,17 +1115,29 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
         # their next (scratch-redirected) write stays parked.
         nxt = jnp.where(active, nxt, tok)
         new_cur = jnp.where(active, cur + 1, cur)
-        return (nxt, kp_all, vp_all, ks_all, vs_all, new_cur), nxt
+        return (nxt, new_pools, new_cur), (nxt, routed)
 
-    (tok, kp, vp, ksp, vsp, pos), toks = jax.lax.scan(
-        one_token, (tokens, kp, vp, ksp, vsp, pos), None,
-        length=num_steps)
-    out_caches = (
-        kp.reshape(ne, nb, bs, nkv, hd),
-        vp.reshape(ne, nb, bs, nkv, hd),
-        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
-        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
-    return toks.swapaxes(0, 1), out_caches, pos
+    (tok, flat, pos), (toks, routed) = jax.lax.scan(
+        one_token, (tokens, flat, pos), None, length=num_steps)
+    out_caches = {kind: _unflat_pools(group, nbs[kind])
+                  for kind, group in flat.items()}
+    out = toks.swapaxes(0, 1), _as_given(caches, out_caches), pos
+    return out if routed is None else out + (_routed_sums(routed),)
+
+
+def _attention_scope(config: llama.LlamaConfig, kind: str):
+    """``window_attention`` / ``global_attention`` round a layer's
+    attention where a stack has window layers; no scope of its own
+    otherwise (the programs of the other models keep their text)."""
+    if config.sliding_window is None:
+        return contextlib.nullcontext()
+    return jax.named_scope(kind + '_attention')
+
+
+def _window_args(config: llama.LlamaConfig, kind: str, key_start):
+    if kind != 'window':
+        return {}
+    return {'window': config.sliding_window, 'key_start': key_start}
 
 
 def verify_step_paged(params: Params, tokens: jax.Array,
@@ -952,27 +1186,29 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     positions simply stay past the new frontier) and parked rows
     (n_real 0) are untouched.
     """
-    k_pool, v_pool, k_scale, v_scale = caches
-    ne, nb, bs = k_pool.shape[:3]
-    assert bs == block_size, (bs, block_size)
-    assert ne == config.kv_entries, (ne, config.kv_entries)
+    by_kind = _by_kind(config, caches)
+    tables = _by_kind(config, block_tables)
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
         params)
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     b = tokens.shape[0]
-    quantized = k_scale is not None  # static at trace
-
-    kp = k_pool.reshape(ne, nb * bs, nkv, hd)
-    vp = v_pool.reshape(ne, nb * bs, nkv, hd)
-    ksp = k_scale.reshape(ne, nb * bs, nkv) if quantized else None
-    vsp = v_scale.reshape(ne, nb * bs, nkv) if quantized else None
+    bs = block_size
+    quantized = next(iter(by_kind.values()))[2] is not None  # static
 
     # As in the decode twin: every entry's blocks as one pool read
     # through tables offset by entry * NB, and the scale views of
-    # all entries gathered once, outside the layer scan.
-    kblocks, vblocks = _all_blocks(kp, bs), _all_blocks(vp, bs)
-    scale_views = _scale_views(ksp, vsp, block_tables, bs)
+    # all entries gathered once, outside the layer scan (a group; a
+    # window layer through ``da.window_view``'s columns).
+    flat, nbs, blocks, views, scale_views = {}, {}, {}, {}, {}
+    for kind, group in by_kind.items():
+        flat[kind], nbs[kind] = _flat_pools(config, kind, group, bs)
+        kp, vp, ksp, vsp = flat[kind]
+        blocks[kind] = _all_blocks(kp, bs), _all_blocks(vp, bs)
+        views[kind] = (tables[kind], None) if kind == 'global' \
+            else da.window_view(tables[kind], pos,
+                                config.sliding_window, bs)
+        scale_views[kind] = _scale_views(ksp, vsp, views[kind][0], bs)
 
     positions = pos[:, None] + jnp.arange(width,
                                           dtype=jnp.int32)[None, :]
@@ -981,28 +1217,32 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     x = cparams['embed'][tokens]                   # [B, W, D]
     if config.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-    widx = da.verify_write_indices(
-        block_tables, pos, n_real, width, block_size)  # [B, W]
-    wflat = widx.reshape(-1)
+    wflat = {kind: da.verify_write_indices(
+        tables[kind], pos, n_real, width, block_size).reshape(-1)
+        for kind in flat}                          # [B * W] a group
 
-    def layer(xc, lp, entry, ad):
+    def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
         q, _, _, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
-            config, xc, lp, ad, adapter_idx, angles, quantized)
+            config, xc, lp, ad, adapter_idx, angles, quantized, kind)
         # No in-layer write (it copied the layer's whole pool
         # slice, as in the decode twin): the draft window's own rows
         # go to attention as an operand, causally among themselves,
         # beside the view of positions [0, pos); the merged scatter
         # after the layer scan persists them. A padded lane's row is
         # seen only by padded lanes, whose outputs are ignored.
-        ks_view, vs_view = (None, None) if scale_views is None \
+        view, key_start = views[kind]
+        ks_view, vs_view = (None, None) if scale_views[kind] is None \
             else jax.lax.dynamic_index_in_dim(
-                scale_views, entry, 0, keepdims=False,
+                scale_views[kind], entry, 0, keepdims=False,
                 allow_negative_indices=False)
-        attn = da.paged_decode_attention(
-            q, kblocks, vblocks, block_tables + entry * nb, pos,
-            hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-            new=(k_rows, v_rows, ks_rows, vs_rows))   # [B, W, Hq, hd]
-        xc = layer_tail(
+        with _attention_scope(config, kind):
+            attn = da.paged_decode_attention(
+                q, *blocks[kind], view + entry * nbs[kind], pos,
+                hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                new=(k_rows, v_rows, ks_rows, vs_rows),
+                **_window_args(config, kind, key_start)
+            )                                      # [B, W, Hq, hd]
+        xc, _ = layer_tail(
             config, xc, attn.reshape(b, width, nh * hd), lp)
         return xc, (
             k_rows.reshape(b * width, nkv, hd),
@@ -1014,11 +1254,14 @@ def verify_step_paged(params: Params, tokens: jax.Array,
 
     x, rows = looped_stack(config, cparams, x, layer, adapters)
     with jax.named_scope('kv_write'):
-        kp = kp.at[:, wflat].set(rows[0])
-        vp = vp.at[:, wflat].set(rows[1])
-        if quantized:
-            ksp = ksp.at[:, wflat].set(rows[2])
-            vsp = vsp.at[:, wflat].set(rows[3])
+        for kind, (kp, vp, ksp, vsp) in flat.items():
+            mine = _kind_rows(config, rows, kind)
+            kp = kp.at[:, wflat[kind]].set(mine[0])
+            vp = vp.at[:, wflat[kind]].set(mine[1])
+            if quantized:
+                ksp = ksp.at[:, wflat[kind]].set(mine[2])
+                vsp = vsp.at[:, wflat[kind]].set(mine[3])
+            flat[kind] = (kp, vp, ksp, vsp)
     if config.tie_embeddings:
         logits = (x @ llama.output_head(cparams, config))
     else:
@@ -1043,12 +1286,10 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         live,
         jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0],
         tokens[:, 0])
-    out_caches = (
-        kp.reshape(ne, nb, bs, nkv, hd),
-        vp.reshape(ne, nb, bs, nkv, hd),
-        ksp.reshape(ne, nb, bs, nkv) if quantized else None,
-        vsp.reshape(ne, nb, bs, nkv) if quantized else None)
-    return preds, accepted, new_pos, new_tok, out_caches
+    out_caches = {kind: _unflat_pools(group, nbs[kind])
+                  for kind, group in flat.items()}
+    return (preds, accepted, new_pos, new_tok,
+            _as_given(caches, out_caches))
 
 
 def decode_shardings(config: llama.LlamaConfig, mesh,

@@ -104,6 +104,40 @@ class LlamaConfig:
     # no gate, the last pass is served. Every pass always runs for
     # every row (static shapes); the gate selects by value.
     exit_threshold: Optional[float] = None
+    # ---- Window and global layers in one stack (Cohere2 / Command A
+    # family). ``sliding_window`` W: a sliding layer's query at i sees
+    # keys j with 0 <= i - j < W (its own position counts). With
+    # ``global_every`` = p every p-th layer (l % p == p - 1) is a
+    # global layer that sees the whole context; 0 = every layer
+    # slides. None: every layer is global, what the repo ran before
+    # these keys. Served by the paged engine only (two block groups,
+    # ``serve/kv_pool.py``). ----
+    sliding_window: Optional[int] = None
+    global_every: int = 0
+    # Global layers apply no positional transform ("NoPE").
+    global_rope: bool = True
+    # RoPE over interleaved pairs (x0, x1), (x2, x3), ... ("rope_gptj")
+    # in place of the rotate-half pairing (x_i, x_{i + hd/2}).
+    rope_interleaved: bool = False
+    # LayerNorm without bias (mean removed) in place of RMSNorm.
+    layer_norm: bool = False
+    # One norm a layer feeds attention AND the MLP / expert layer, and
+    # both results are added to the stream at once (no ``mlp_norm``).
+    parallel_block: bool = False
+    # ---- The dropless expert layer of the paged bodies
+    # (``models/moe.py``). ``moe_score``: how router logits become
+    # weights ('softmax' over all experts, Mixtral; 'sigmoid' each on
+    # its own); the top-k weights are normalised to sum 1 either way.
+    # ``n_shared_experts`` gated MLPs of width ``ffn_hidden`` see every
+    # token, their mean added beside the routed sum (leaves ``ws_*``,
+    # the experts side by side along the hidden axis).
+    # ``experts_held`` = (first, count): the routed experts whose
+    # weights this chip holds (expert parallelism's share; the router
+    # keeps all ``n_experts`` outputs and the result is this share's
+    # part). None: all of them. ----
+    moe_score: str = 'softmax'
+    n_shared_experts: int = 0
+    experts_held: Optional[tuple] = None
 
     def __post_init__(self):
         unknown = set(self.remat_saves.split('+')) - {
@@ -118,6 +152,22 @@ class LlamaConfig:
         if self.loop_passes < 1:
             raise ValueError(
                 f'loop_passes must be >= 1: {self.loop_passes}')
+        if self.moe_score not in ('softmax', 'sigmoid'):
+            raise ValueError(f'unknown moe_score {self.moe_score!r}')
+        if self.global_every and (
+                self.sliding_window is None
+                or self.n_layers % self.global_every):
+            raise ValueError(
+                f'global_every={self.global_every} needs a '
+                f'sliding_window and a whole number of periods in '
+                f'n_layers={self.n_layers}')
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f'experts_held={self.experts_held} lies outside '
+                    f'the {self.n_experts} routed experts')
 
     @property
     def head_dim(self) -> int:
@@ -132,21 +182,52 @@ class LlamaConfig:
         return self.loop_passes * self.n_layers
 
     @property
+    def layer_kinds(self) -> tuple:
+        """'window' or 'global' for each layer of one period of the
+        stack (the layer scan's body runs one period)."""
+        if self.sliding_window is None:
+            return ('global',)
+        if not self.global_every:
+            return ('window',)
+        return ('window',) * (self.global_every - 1) + ('global',)
+
+    def kind_entries(self, kind: str) -> int:
+        """KV entries of one kind of layer: the leading axis of that
+        kind's block group (``serve/kv_pool.py``)."""
+        kinds = self.layer_kinds
+        return self.kv_entries * kinds.count(kind) // len(kinds)
+
+    @property
+    def n_experts_held(self) -> int:
+        return (self.experts_held[1] if self.experts_held is not None
+                else self.n_experts)
+
+    @property
     def plain_stack(self) -> bool:
-        """Each layer run once, no branch norms, no exit gate: what
-        every layer body of the repo computes."""
+        """Each layer run once over the whole context, two RMSNorms,
+        no exit gate, no expert share: what the dense layer bodies
+        of the repo compute."""
         return (self.loop_passes == 1 and not self.sandwich_norms
-                and self.exit_threshold is None)
+                and self.exit_threshold is None
+                and self.sliding_window is None
+                and not self.parallel_block and not self.layer_norm
+                and not self.n_shared_experts
+                and self.experts_held is None
+                and self.moe_score == 'softmax'
+                and not self.rope_interleaved)
 
     def num_params(self) -> int:
         d, v, h = self.dim, self.vocab_size, self.ffn_hidden
         nh, nkv, hd = self.n_heads, self.n_kv_heads, self.head_dim
         mlp = 3 * d * h
         if self.n_experts:
-            mlp = self.n_experts * mlp + d * self.n_experts
+            # What is held here: a share of the routed experts, the
+            # whole router, the shared experts.
+            mlp = ((self.n_experts_held + self.n_shared_experts) * mlp
+                   + d * self.n_experts)
         per_layer = (
             d * nh * hd + 2 * d * nkv * hd + nh * hd * d +
-            mlp + 2 * d)
+            mlp + (d if self.parallel_block else 2 * d))
         if self.qkv_bias:
             per_layer += (nh + 2 * nkv) * hd
         if self.sandwich_norms:
@@ -160,9 +241,9 @@ class LlamaConfig:
         only top_k of the n_experts MLPs) — the FLOPs/token basis."""
         if not self.n_experts:
             return self.num_params()
-        unused = ((self.n_experts - self.moe_top_k) *
+        unused = ((self.n_experts_held - self.moe_top_k) *
                   3 * self.dim * self.ffn_hidden * self.n_layers)
-        return self.num_params() - unused
+        return self.num_params() - max(unused, 0)
 
 
 CONFIGS: Dict[str, LlamaConfig] = {
@@ -225,6 +306,25 @@ CONFIGS: Dict[str, LlamaConfig] = {
         n_heads=32, n_kv_heads=8, ffn_hidden=14336,
         rope_theta=1000000.0, max_seq_len=32768,
         n_experts=8, moe_top_k=2),
+    # Window and global layers, a parallel block, a dropless expert
+    # layer (HF CohereLabs/command-a-plus-05-2026 config.json,
+    # model_type cohere2_moe: 3 sliding layers of window 4,096 with
+    # interleaved RoPE, then 1 global layer without positions; one
+    # LayerNorm a layer feeds attention and experts; 128 routed
+    # experts of width 4,096, 8 a token by sigmoid scores normalised,
+    # 4 shared experts averaged; tied embeddings, logit_scale 1).
+    # Served by the paged engine only, at a chip's share
+    # (``experts_held``: ``recipes/serve_model --experts-held``;
+    # fewer layers and vocabulary rows as ``get_config`` overrides).
+    'command-a-plus': LlamaConfig(
+        name='command-a-plus', vocab_size=262144, dim=4096,
+        n_layers=32, n_heads=128, n_kv_heads=8, ffn_hidden=4096,
+        head_dim_override=128, rope_theta=50000.0, norm_eps=1e-5,
+        max_seq_len=12288, tie_embeddings=True,
+        sliding_window=4096, global_every=4, global_rope=False,
+        rope_interleaved=True, layer_norm=True, parallel_block=True,
+        n_experts=128, moe_top_k=8, moe_score='sigmoid',
+        n_shared_experts=4),
     # Small configs for tests / CPU dryruns.
     'debug-250m': LlamaConfig(
         name='debug-250m', vocab_size=32000, dim=1024, n_layers=8,
@@ -237,6 +337,15 @@ CONFIGS: Dict[str, LlamaConfig] = {
         name='tiny-moe', vocab_size=512, dim=128, n_layers=2,
         n_heads=4, n_kv_heads=2, ffn_hidden=256, max_seq_len=512,
         dtype=jnp.float32, remat=False, n_experts=4, moe_top_k=2),
+    'tiny-window-moe': LlamaConfig(
+        name='tiny-window-moe', vocab_size=512, dim=128, n_layers=4,
+        n_heads=8, n_kv_heads=2, ffn_hidden=64, head_dim_override=32,
+        rope_theta=50000.0, norm_eps=1e-5, max_seq_len=512,
+        dtype=jnp.float32, remat=False, tie_embeddings=True,
+        sliding_window=32, global_every=4, global_rope=False,
+        rope_interleaved=True, layer_norm=True, parallel_block=True,
+        n_experts=16, moe_top_k=4, moe_score='sigmoid',
+        n_shared_experts=2),
     'tiny-loop': LlamaConfig(
         name='tiny-loop', vocab_size=512, dim=128, n_layers=2,
         n_heads=4, n_kv_heads=4, ffn_hidden=256, max_seq_len=512,
@@ -263,9 +372,16 @@ def require_plain_stack(config: LlamaConfig, where: str) -> None:
             f'{where} does not implement {config.name!r} '
             f'(loop_passes={config.loop_passes}, sandwich_norms='
             f'{config.sandwich_norms}, exit_threshold='
-            f'{config.exit_threshold}): only the paged engine '
+            f'{config.exit_threshold}, sliding_window='
+            f'{config.sliding_window}, parallel_block='
+            f'{config.parallel_block}, layer_norm='
+            f'{config.layer_norm}, n_shared_experts='
+            f'{config.n_shared_experts}, experts_held='
+            f'{config.experts_held}, moe_score={config.moe_score!r}): '
+            f'only the paged engine '
             f'(serve/batching.BatchingEngine; serve_model --slots N) '
-            f'runs a looped layer stack')
+            f'runs a looped layer stack, window layers, a parallel '
+            f'block or a share of the experts')
 
 
 # ---------------------------------------------------------------------
@@ -303,12 +419,20 @@ def init_params(config: LlamaConfig, key: jax.Array,
     E = config.n_experts
     ks = jax.random.split(k_layers, 8 if E else 7)
     if E:
+        held = config.n_experts_held
         mlp_params = {
             'router': dense(ks[7], (L, d, E), d),
-            'w_gate': dense(ks[4], (L, E, d, ffn), d),
-            'w_up': dense(ks[5], (L, E, d, ffn), d),
-            'w_down': dense(ks[6], (L, E, ffn, d), ffn),
+            'w_gate': dense(ks[4], (L, held, d, ffn), d),
+            'w_up': dense(ks[5], (L, held, d, ffn), d),
+            'w_down': dense(ks[6], (L, held, ffn, d), ffn),
         }
+        if config.n_shared_experts:
+            # Keys of their own: the other leaves keep their seeds.
+            sk = jax.random.split(jax.random.fold_in(key, 0x73), 3)
+            wide = config.n_shared_experts * ffn
+            mlp_params['ws_gate'] = dense(sk[0], (L, d, wide), d)
+            mlp_params['ws_up'] = dense(sk[1], (L, d, wide), d)
+            mlp_params['ws_down'] = dense(sk[2], (L, wide, d), ffn)
     else:
         mlp_params = {
             'w_gate': dense(ks[4], (L, d, ffn), d),
@@ -328,6 +452,8 @@ def init_params(config: LlamaConfig, key: jax.Array,
         },
         'final_norm': norm_init((d,)),
     }
+    if config.parallel_block:
+        del params['layers']['mlp_norm']
     if config.qkv_bias:
         params['layers']['bq'] = jnp.zeros((L, nh * hd), dtype)
         params['layers']['bk'] = jnp.zeros((L, nkv * hd), dtype)
@@ -366,6 +492,10 @@ def param_sharding_rules(config: LlamaConfig,
             'w_up': P(pl, 'ep', 'fsdp', 'tp'),
             'w_down': P(pl, 'ep', 'tp', 'fsdp'),
         }
+        if config.n_shared_experts:
+            mlp_rules.update(ws_gate=P(pl, fs, 'tp'),
+                             ws_up=P(pl, fs, 'tp'),
+                             ws_down=P(pl, 'tp', fs))
     else:
         mlp_rules = {
             'w_gate': P(pl, fs, 'tp'),
@@ -385,6 +515,8 @@ def param_sharding_rules(config: LlamaConfig,
         },
         'final_norm': P(None),
     }
+    if config.parallel_block:
+        del rules['layers']['mlp_norm']
     if config.qkv_bias:
         rules['layers']['bq'] = P(pl, 'tp')
         rules['layers']['bk'] = P(pl, 'tp')
@@ -427,6 +559,20 @@ def _rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     if offset:
         w = 1.0 + w  # Gemma's zero-centered norm weights
     return (norm * w).astype(x.dtype)
+
+
+def norm(config: LlamaConfig, x: jax.Array,
+         weight: jax.Array) -> jax.Array:
+    """The configuration's norm: RMSNorm, or with
+    ``config.layer_norm`` LayerNorm without bias,
+    (x - mean) / sqrt(var + eps) * w, in float32."""
+    if not config.layer_norm:
+        return _rms_norm(x, weight, config.norm_eps, config.norm_offset)
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    return (centred * jax.lax.rsqrt(var + config.norm_eps) *
+            weight.astype(jnp.float32)).astype(x.dtype)
 
 
 def _rope_frequencies(config: LlamaConfig, positions: jax.Array

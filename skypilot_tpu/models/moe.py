@@ -1,0 +1,150 @@
+"""The dropless expert layer of the paged layer bodies.
+
+``llama._moe_mlp`` (the training path: the 'ep' mesh and the pipeline
+read it) dispatches through one-hot tensors ``[B, T * k, E, C]`` and
+drops what overflows an expert's capacity, so a token's result depends
+on which tokens share its chunk. Serving cannot have that
+(``docs/sampling.md``: a row's output never depends on its batch), and
+at 128 experts and 8 a token the one-hots alone outweigh the work.
+This layer has neither:
+
+- the router scores every token against ALL ``n_experts`` (float32;
+  softmax over the experts, or a sigmoid each: ``config.moe_score``),
+  takes the top ``moe_top_k`` and normalises their weights to sum 1
+  over all k, held here or not;
+- the (token, expert) pairs whose expert this chip HOLDS
+  (``config.experts_held`` = (first, count); all experts without it)
+  are sorted by expert and go through three grouped products
+  (``jax.lax.ragged_dot``; int8 expert weights are taken as codes,
+  their per-channel scales applied to each row by its expert) whose
+  cost follows the pairs routed here, in a 512-token chunk as in a
+  decode step. A pair whose expert lives on another chip adds nothing:
+  the result is this share's part of the layer, and no code stands in
+  for the absent chips or for their exchange;
+- the shared experts (``n_shared_experts`` gated MLPs side by side in
+  the ``ws_*`` leaves) are one dense gated product whose mean is added.
+
+A token's result is a fixed-order sum over its own k slots, so it is
+the same to the bit however the prompt was chunked and whoever shares
+the batch. Scopes: ``moe_router``, ``moe_experts``, ``moe_shared``.
+"""
+from typing import Any, Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from skypilot_tpu.models import llama
+from skypilot_tpu.models.quant import matmul as _mm
+
+Params = Dict[str, Any]
+EXPERT_LEAVES = ('w_gate', 'w_up', 'w_down')
+
+
+class LayerOf(NamedTuple):
+    """One layer's expert weights as the WHOLE stack ``[L, G, in,
+    out]`` (plain or ``{'q', 's'}``) and the layer's index. A grouped
+    product is a kernel call, and a kernel's operand is a buffer of
+    its own: a layer's slice taken out of the stack first is a copy
+    of it (268 MB of codes a product at 16 experts of 4,096 x 4,096,
+    in every layer of every step, where the dense products read
+    their slice in place). The product is therefore over all L x G
+    groups, with every group outside the layer empty."""
+    stack: Any
+    layer: jax.Array
+
+
+def stacked_experts(layers: Params):
+    """(``layers`` without the expert leaves, the expert leaves), for
+    a layer scan that hands the experts on whole (``LayerOf``)."""
+    rest = {k: v for k, v in layers.items() if k not in EXPERT_LEAVES}
+    return rest, {k: layers[k] for k in EXPERT_LEAVES}
+
+
+def route(config: llama.LlamaConfig, x: jax.Array,
+          router: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """x [N, D] -> (weights [N, k] float32 summing to 1 a token,
+    experts [N, k] int32 among all ``n_experts``). Float32
+    throughout: a near-tie flips on bf16 logits."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if config.moe_score == 'sigmoid'
+              else jax.nn.softmax(logits, axis=-1))
+    weights, experts = jax.lax.top_k(scores, config.moe_top_k)
+    weights = weights / jnp.maximum(
+        weights.sum(-1, keepdims=True), 1e-20)
+    return weights, experts.astype(jnp.int32)
+
+
+def _grouped(xs: jax.Array, w, sizes: jax.Array,
+             group: jax.Array) -> jax.Array:
+    """Rows ``xs`` [M, in], sorted by expert into groups of
+    ``sizes`` [G], times each group's own matrix of ``w`` [G, in,
+    out] -> [M, out] in xs's type. Quantised ``w`` is read as int8
+    codes inside the product; ``group`` [M] (each row's expert) picks
+    the row's per-channel scales afterwards, which is exact for
+    per-output-channel scaling. ``w`` may be a ``LayerOf``."""
+    if isinstance(w, LayerOf):
+        # All layers' groups end to end, this layer's alone filled.
+        per_layer = sizes.shape[0]
+        w, at = jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), w.stack), \
+            w.layer * per_layer
+        n_groups = jax.tree.leaves(w)[0].shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_groups,), sizes.dtype), sizes, (at,))
+        group = group + at
+    if isinstance(w, dict) and 'q' in w:
+        out = jax.lax.ragged_dot(xs, w['q'], sizes,
+                                 preferred_element_type=jnp.float32)
+        out = out * w['s'][:, 0].astype(jnp.float32)[group]
+    else:
+        out = jax.lax.ragged_dot(xs, w, sizes,
+                                 preferred_element_type=jnp.float32)
+    return out.astype(xs.dtype)
+
+
+def moe_layer(config: llama.LlamaConfig, h: jax.Array,
+              lp: Params) -> Tuple[jax.Array, jax.Array]:
+    """h [B, T, D] (the normed stream) -> (this share's part of the
+    expert layer's result [B, T, D], pairs routed to each held expert
+    [count] int32)."""
+    b, t, d = h.shape
+    n, k = b * t, config.moe_top_k
+    first, count = (config.experts_held if config.experts_held
+                    is not None else (0, config.n_experts))
+    x = h.reshape(n, d)
+    with jax.named_scope('moe_router'):
+        weights, experts = route(config, x, lp['router'])
+        # Pairs by the expert that serves them; an absent expert's
+        # pairs sort behind every group and belong to none.
+        local = experts - first
+        here = (local >= 0) & (local < count)
+        key = jnp.where(here, local, count).reshape(n * k)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(
+            1)[:count]
+        group = jnp.minimum(key[order], count - 1)
+        # Where each (token, slot) pair's row went, to bring the
+        # results back by a gather and not a scatter-add.
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+    with jax.named_scope('moe_experts'):
+        xs = x[order // k]                                   # [M, D]
+        gate = llama.mlp_act(config)(
+            _grouped(xs, lp['w_gate'], sizes, group).astype(
+                jnp.float32)).astype(xs.dtype)
+        up = _grouped(xs, lp['w_up'], sizes, group)
+        ys = _grouped(gate * up, lp['w_down'], sizes, group)
+        # Rows past the groups hold whatever the product left there.
+        held_rows = jnp.arange(n * k) < sizes.sum()
+        ys = jnp.where(held_rows[:, None], ys.astype(jnp.float32), 0.0)
+        pairs = ys[back].reshape(n, k, d) * weights[..., None]
+        out = pairs.sum(axis=1)
+    if config.n_shared_experts:
+        with jax.named_scope('moe_shared'):
+            sg = llama.mlp_act(config)(
+                _mm(x, lp['ws_gate']).astype(jnp.float32)
+            ).astype(x.dtype)
+            shared = _mm(sg * _mm(x, lp['ws_up']), lp['ws_down'])
+            out = out + shared.astype(jnp.float32) / \
+                config.n_shared_experts
+    return out.astype(h.dtype).reshape(b, t, d), sizes

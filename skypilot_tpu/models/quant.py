@@ -8,9 +8,9 @@ weights cross HBM as int8), and the per-channel scale applies AFTER
 the matmul, which is exact for per-output-channel scaling.
 
 Scope: the stacked layer projections (wq/wk/wv/wo, gate/up/down —
-including MoE expert stacks, per (layer, expert, out-channel)) and
-the LM head. Embedding stays bf16 (decode gathers one row per token —
-negligible traffic); norms/biases/MoE router stay bf16 (tiny; the
+including MoE expert stacks, per (layer, expert, out-channel), and
+the shared experts' ``ws_*``) and the LM head. Embedding stays bf16
+(decode gathers one row per token — negligible traffic); norms/biases/MoE router stay bf16 (tiny; the
 router also drives top-k selection — selective precision); the KV
 cache is not quantized yet.
 
@@ -28,7 +28,8 @@ from skypilot_tpu.models import llama
 Params = Dict[str, Any]
 
 # Leaves under params['layers'] that are [L, in, out] matmul weights.
-_LAYER_MATMULS = ('wq', 'wk', 'wv', 'wo', 'w_gate', 'w_up', 'w_down')
+_LAYER_MATMULS = ('wq', 'wk', 'wv', 'wo', 'w_gate', 'w_up', 'w_down',
+                  'ws_gate', 'ws_up', 'ws_down')
 
 
 def quantize_weight(w: jax.Array) -> Dict[str, jax.Array]:

@@ -38,6 +38,17 @@ entry can never corrupt a block that was recycled to another request.
   (ROADMAP S2b).
   ``decode_attention`` is the plain masked form over a contiguous
   float cache that ``models/decode._layer_cached`` calls.
+- Window layers (a query at i sees keys j with 0 <= i - j < W): the
+  table stays logical, column c holds positions [c * bs, (c + 1) *
+  bs), and the host sets a column to scratch once it lies behind
+  every query to come. A decode or verify step reads the
+  ``window_blocks`` columns from the first one a row's window still
+  touches (``window_view``: a table of fixed width whatever the
+  context) and ``view_attention`` masks by the window; a prefill
+  chunk of a configuration with window layers goes through
+  ``chunk_attention``, which walks key tiles between the window's (or
+  the context's) first block and the chunk and never builds a view
+  of ``max_seq``.
 """
 from typing import Optional, Sequence, Tuple
 
@@ -114,6 +125,33 @@ def view_width(widths: Sequence[int], positions: int,
     whole table) where none does."""
     return next((w for w in widths if w * block_size >= positions),
                 widths[-1])
+
+
+def window_blocks(window: int, block_size: int,
+                  table_blocks: int) -> int:
+    """Columns a window layer's decode view needs: the blocks that
+    ``window - 1`` cached positions can straddle, starting anywhere
+    in a block (257 at 4,096 / 16), never more than the table has."""
+    return min(table_blocks,
+               (window + 2 * block_size - 3) // block_size)
+
+
+def window_view(block_tables: jax.Array, pos: jax.Array, window: int,
+                block_size: int):
+    """The part of a window layer's table that a query at ``pos``
+    [B] (and the queries after it) can still see: block_tables
+    [B, MB] -> (sub-table [B, ``window_blocks``], key_start [B]: the
+    absolute position of the sub-table's first slot). Columns past
+    the table read the scratch block; the caller's length mask
+    covers them."""
+    mb = block_tables.shape[-1]
+    width = window_blocks(window, block_size, mb)
+    first = jnp.maximum(pos - window + 1, 0) // block_size     # [B]
+    cols = first[:, None] + jnp.arange(width, dtype=jnp.int32)[None]
+    sub = jnp.take_along_axis(block_tables,
+                              jnp.minimum(cols, mb - 1), axis=1)
+    return (jnp.where(cols < mb, sub, SCRATCH_BLOCK),
+            first * block_size)
 
 
 def verify_write_indices(block_tables: jax.Array, pos: jax.Array,
@@ -235,9 +273,16 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    lengths: jax.Array, scale: float,
                    k_scale: Optional[jax.Array] = None,
                    v_scale: Optional[jax.Array] = None,
-                   new=None) -> jax.Array:
+                   new=None, window: Optional[int] = None,
+                   key_start: Optional[jax.Array] = None
+                   ) -> jax.Array:
     """Dense masked attention of the decode and verify steps over a
     per-row view that may hold int8 codes, read as int8.
+
+    ``window`` (a window layer): query j, at position lengths[b] + j,
+    sees only keys less than ``window`` positions behind it, its own
+    counted; the view's slot s then holds position key_start[b] + s
+    (``window_view``), and ``lengths`` stay absolute.
 
     q [B, Hq, hd] (one position a row) or [B, W, Hq, hd] (the verify
     window); k/v [B, S, Hkv, hd], floats, or int8 codes with
@@ -309,7 +354,14 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     j = jnp.arange(w)
     span = lengths[:, None] + (j[None, :] if new is None else 0)
-    seen = jnp.arange(s)[None, None, :] < span[:, :, None]  # [B,W,S]
+    if window is None:
+        seen = jnp.arange(s)[None, None, :] < span[:, :, None]
+    else:                                                   # [B,W,S]
+        key_pos = key_start[:, None, None] + \
+            jnp.arange(s)[None, None, :]
+        query_pos = (lengths[:, None] + j[None, :])[:, :, None]
+        seen = (key_pos < span[:, :, None]) & \
+            (query_pos - key_pos < window)
     logits = jnp.where(seen[:, :, None, None, :],
                        scores(k, k_scale), _NEG_INF)
     top = jnp.max(logits, axis=-1, keepdims=True)
@@ -318,8 +370,10 @@ def view_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if ks_new is not None:          # [B, W, Hkv] -> [B, Hkv, W]
             ks_new = jnp.swapaxes(ks_new, 1, 2)
             vs_new = jnp.swapaxes(vs_new, 1, 2)
-        own = jnp.where((j[None, :] <= j[:, None])[None, :, None,
-                                                   None, :],
+        among = j[None, :] <= j[:, None]
+        if window is not None:
+            among &= j[:, None] - j[None, :] < window
+        own = jnp.where(among[None, :, None, None, :],
                         scores(k_new, ks_new), _NEG_INF)
         top = jnp.maximum(top, jnp.max(own, axis=-1, keepdims=True))
         p_own = jnp.exp(own - top)
@@ -342,7 +396,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            lengths: jax.Array, scale: float,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
-                           new=None) -> jax.Array:
+                           new=None, window: Optional[int] = None,
+                           key_start: Optional[jax.Array] = None
+                           ) -> jax.Array:
     """Decode (q [B, Hq, hd]) and speculative-verify (q [B, W, Hq,
     hd]: the row's current token plus its drafted continuation)
     attention over PAGED caches.
@@ -356,7 +412,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     block_size] as ``gather_scales`` lays them out (which pool it
     is, is decided at trace time from their presence). ``lengths``
     [B] and ``new`` (this step's own rows, not yet in the pool) as
-    in ``view_attention``.
+    in ``view_attention``; so ``window`` and ``key_start``, with
+    ``block_tables`` then the sub-table of ``window_view``.
 
     Gather-based: each row's blocks are gathered whole
     (``gather_blocks``) into the contiguous [B, MB * block_size,
@@ -377,6 +434,105 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     vd = gather_blocks(v_pool, block_tables)
     with jax.named_scope('decode_attention'):
         return view_attention(q, kd, vd, lengths, scale, k_scale,
-                              v_scale, new)
+                              v_scale, new, window, key_start)
+
+
+def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                    k_pool: jax.Array, v_pool: jax.Array,
+                    block_row: jax.Array, start: jax.Array,
+                    scale: float,
+                    k_scale: Optional[jax.Array] = None,
+                    v_scale: Optional[jax.Array] = None,
+                    window: Optional[int] = None,
+                    tile_blocks: int = 32) -> jax.Array:
+    """Attention of one request's PREFILL CHUNK over key tiles, for a
+    configuration with window layers: q [T, Hq, hd] at positions
+    start + t; k_new/v_new [T, Hkv, hd] the chunk's own exact rows,
+    an operand as in the decode step (no in-layer pool write);
+    k_pool/v_pool a block pool [num_blocks, block_size, Hkv, hd]
+    with ``block_row`` [MB] this request's table into it (offset to
+    the layer's entry by the caller); an int8 pool comes with its
+    scale pools ``k_scale``/``v_scale`` [num_blocks, block_size,
+    Hkv].
+
+    Query t sees the cached keys [0, start) and the chunk's rows
+    [0, t]; with ``window`` only those less than ``window`` positions
+    behind it. The cached part is walked in tiles of ``tile_blocks``
+    blocks with a running maximum and sum (float32), from the tile
+    that holds the first position any query of the chunk can see (0
+    in a global layer) to the one that holds ``start - 1``: the work
+    follows the context a layer reads, and the scores of one tile
+    ([Hq, T, tile] float32) are the largest array there is. The view
+    of ``max_seq`` that ``models/decode._masked_attention`` takes is
+    128 x 512 x 12,288 x 4 B = 3.2 GB a layer at 128 query heads.
+    Codes are converted inside the dots and the scales applied to
+    scores and probabilities, as ``view_attention`` does. Returns
+    [T, Hq, hd] in q's type. Padded query rows (past the chunk's
+    real length) give rows the caller discards."""
+    t, hq, hd = q.shape
+    nb_all, bs, hkv = k_pool.shape[:3]
+    groups = hq // hkv
+    tile = tile_blocks * bs
+    mb = block_row.shape[0]
+    n_tiles = -(-mb // tile_blocks)
+    row = jnp.pad(block_row, (0, n_tiles * tile_blocks - mb),
+                  constant_values=SCRATCH_BLOCK)
+    qg = q.reshape(t, hkv, groups, hd)
+    q_pos = start + jnp.arange(t, dtype=jnp.int32)            # [T]
+
+    def scores(keys, key_scale):
+        out = jnp.einsum('thgd,shd->hgts', qg, keys.astype(q.dtype),
+                         preferred_element_type=jnp.float32)
+        if key_scale is not None:
+            out = out * key_scale.astype(jnp.float32).T[:, None, None]
+        return out * scale
+
+    def weighted(probs, values, value_scale):
+        if value_scale is not None:
+            probs = probs * value_scale.astype(
+                jnp.float32).T[:, None, None]
+        return jnp.einsum('hgts,shd->hgtd', probs.astype(q.dtype),
+                          values.astype(q.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def visible(key_pos):                                  # [T, S]
+        seen = key_pos[None, :] <= q_pos[:, None]
+        if window is not None:
+            seen &= q_pos[:, None] - key_pos[None, :] < window
+        return seen
+
+    def fold(carry, logits, seen, values, value_scale):
+        top, total, acc = carry
+        logits = jnp.where(seen[None, None], logits, _NEG_INF)
+        new_top = jnp.maximum(top, logits.max(-1, keepdims=True))
+        shrink = jnp.exp(top - new_top)
+        p = jnp.where(seen[None, None], jnp.exp(logits - new_top), 0.0)
+        return (new_top, total * shrink + p.sum(-1, keepdims=True),
+                acc * shrink + weighted(p, values, value_scale))
+
+    def one_tile(i, carry):
+        cols = jax.lax.dynamic_slice(row, (i * tile_blocks,),
+                                     (tile_blocks,))
+        with jax.named_scope('paged_gather'):
+            kb, vb, ks, vs = (
+                None if pool is None else
+                jnp.take(pool, cols, axis=0, mode='clip').reshape(
+                    tile, *pool.shape[2:])
+                for pool in (k_pool, v_pool, k_scale, v_scale))
+        key_pos = i * tile + jnp.arange(tile, dtype=jnp.int32)
+        seen = visible(key_pos) & (key_pos < start)[None, :]
+        return fold(carry, scores(kb, ks), seen, vb, vs)
+
+    first = 0 if window is None else \
+        jnp.maximum(start - window + 1, 0) // tile
+    carry = (jnp.full((hkv, groups, t, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((hkv, groups, t, 1), jnp.float32),
+             jnp.zeros((hkv, groups, t, hd), jnp.float32))
+    carry = jax.lax.fori_loop(first, -(-start // tile), one_tile,
+                              carry)
+    _, total, acc = fold(carry, scores(k_new, None), visible(q_pos),
+                         v_new, None)
+    out = (acc / total).astype(q.dtype)                 # [h, g, T, d]
+    return jnp.moveaxis(out, 2, 0).reshape(t, hq, hd)
 
 

@@ -64,6 +64,18 @@ def main():
                              'admission bounds by actual usage and '
                              'the engine preempts-and-requeues on '
                              'exhaustion (engine.num_blocks)')
+    parser.add_argument('--window-num-blocks', type=int, default=0,
+                        help='blocks of the WINDOW layers\' block '
+                        'group of a model with window and global '
+                        'layers (the window is the configuration\'s: '
+                        'a row holds there what its window still '
+                        'sees plus the chunk in flight); 0 = that '
+                        'much for every row')
+    parser.add_argument('--experts-held', default='',
+                        help='FIRST:COUNT - this replica\'s share of '
+                        'the routed experts under expert parallelism '
+                        '(it routes over all of them and computes '
+                        'its own part); empty = all of them')
     parser.add_argument('--max-seq', type=int, default=0,
                         help='positions a decode row can reach (the '
                              'block table\'s width: a decode dispatch '
@@ -210,15 +222,24 @@ def main():
 
     device = jax_runtime.device_facts()
     print(jax_runtime.device_line(device), flush=True)
-    config = llama.get_config(args.model)
+    if args.experts_held:
+        try:
+            first, count = map(int, args.experts_held.split(':'))
+            config = llama.get_config(args.model,
+                                      experts_held=(first, count))
+        except ValueError as e:
+            parser.error(f'--experts-held {args.experts_held}: {e}')
+    else:
+        config = llama.get_config(args.model)
     if not config.plain_stack and args.slots <= 0:
-        # The serial path is the dense layer body, which refuses a
-        # looped stack on the first request: say so at start-up.
+        # The serial path is the dense layer body, which refuses
+        # such a stack on the first request: say so at start-up.
         parser.error(
-            f'--model {args.model} runs its layers '
-            f'{config.loop_passes} times over {config.kv_entries} KV '
-            f'entries, which only the batching engine implements: '
-            f'pass --slots N')
+            f'--model {args.model} (loop passes '
+            f'{config.loop_passes}, KV entries {config.kv_entries}, '
+            f'sliding window {config.sliding_window}, experts held '
+            f'{config.experts_held}) is a stack only the batching '
+            f'engine implements: pass --slots N')
     ckpt_params = None
     if args.checkpoint_dir:
         from skypilot_tpu.data.checkpoint import CheckpointManager
@@ -322,6 +343,7 @@ def main():
             max_seq=args.max_seq or None, kv_int8=args.kv_int8,
             block_size=args.block_size,
             num_blocks=args.num_blocks or None,
+            window_num_blocks=args.window_num_blocks or None,
             max_num_batched_tokens=args.max_batched_tokens,
             prefix_caching=args.prefix_caching == 'on',
             speculative=args.speculative == 'on',

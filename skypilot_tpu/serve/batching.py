@@ -531,6 +531,45 @@ def _engine_metrics():
             'Decode and verify dispatches (decode_steps_paged + '
             'verify_step_paged calls); decode_tokens_total over it '
             'is the tokens a dispatch yields.'),
+        'kv_window_blocks_total': reg.gauge(
+            'skytpu_batch_kv_window_blocks_total',
+            'Allocatable blocks of the WINDOW layers\' block group '
+            '(a model with window and global layers keeps one group '
+            'a kind; skytpu_batch_kv_blocks_total counts both).'),
+        'kv_window_blocks_used': reg.gauge(
+            'skytpu_batch_kv_window_blocks_used',
+            'Window-group blocks currently referenced by admitted '
+            'requests: at most window / block_size + 1 a row plus '
+            'the chunk or dispatch in flight.'),
+        'kv_window_released': reg.counter(
+            'skytpu_batch_kv_window_blocks_released_total',
+            'Window-group blocks given back because they fell behind '
+            'their row\'s window (a decrement where shared).'),
+        'moe_routed_pairs': reg.counter(
+            'skytpu_batch_moe_routed_pairs_total',
+            '(token, expert) pairs the routers chose, held here or '
+            'not: tokens computed (every lane of a dispatch, every '
+            'slot of a chunk bucket) x experts per token x expert '
+            'layers.'),
+        'moe_held_pairs': reg.counter(
+            'skytpu_batch_moe_held_pairs_total',
+            'Of those, the pairs whose expert this chip holds: what '
+            'the grouped products computed. A layer that drops '
+            'tokens moves held / routed off experts_held / '
+            'n_experts.'),
+        'moe_busiest_pairs': reg.counter(
+            'skytpu_batch_moe_busiest_expert_pairs_total',
+            'Pairs of the busiest held expert, summed over layers '
+            'and dispatches (or chunks): over held pairs / experts '
+            'held it is the load imbalance.'),
+        'moe_experts_hit': reg.counter(
+            'skytpu_batch_moe_experts_hit_total',
+            'Held experts that got at least one pair, counted a '
+            'layer and step: the expert weights a step had to read.'),
+        'moe_experts_held': reg.counter(
+            'skytpu_batch_moe_experts_held_total',
+            'Held experts, counted a layer and step (the denominator '
+            'of the above).'),
         'decode_view_blocks': reg.counter(
             'skytpu_batch_decode_view_blocks_total',
             'Block-table columns those dispatches read: the '
@@ -600,6 +639,11 @@ class BatchingEngine:
     - ``block_size``: KV block granularity in tokens.
     - ``num_blocks``: pool size; default sizes the pool so every row
       can reach ``max_seq`` (no preemption unless oversubscribed).
+    - ``window_num_blocks``: a model with window AND global layers
+      keeps a second block group for the window layers' entries
+      (``kv_pool.KVBlockPool``), of which a row holds only what its
+      window still sees plus the chunk in flight; default: that much
+      for every row.
     - ``max_num_batched_tokens``: per-scheduler-iteration prefill
       token budget — bounds how much prompt work can run between two
       decode dispatches (the chunked-prefill interleaving lever).
@@ -654,6 +698,7 @@ class BatchingEngine:
                  kv_int8: bool = False,
                  block_size: int = 16,
                  num_blocks: Optional[int] = None,
+                 window_num_blocks: Optional[int] = None,
                  max_num_batched_tokens: Optional[int] = 2048,
                  prefill_chunk: int = 512,
                  prefix_caching: bool = True,
@@ -751,15 +796,52 @@ class BatchingEngine:
         self._prefix_hits_local = 0
         self._prefix_misses_local = 0
         self._prefix_window: 'collections.deque' = collections.deque()
-        self.pool = kv_pool_lib.KVBlockPool(config, num_blocks,
-                                            block_size,
-                                            kv_int8=kv_int8)
+        # Window AND global layers: a second block group, the window
+        # layers' (``self.wpool``; None for every other model). A row
+        # holds there at most the blocks its window can touch and
+        # those of the chunk or dispatch in flight (``_window_cap``).
+        two_kinds = len(set(config.layer_kinds)) > 1
+        self._window_cap = 0
+        if two_kinds:
+            self._window_cap = min(
+                self.max_blocks_per_req,
+                da.window_blocks(config.sliding_window, block_size,
+                                 self.max_blocks_per_req) + 1 +
+                -(-max(self.prefill_chunk, steps_per_dispatch)
+                  // block_size))
+            if window_num_blocks is None:
+                window_num_blocks = slots * self._window_cap + 1
+        self.pool = kv_pool_lib.KVBlockPool(
+            config, num_blocks, block_size, kv_int8=kv_int8,
+            window_num_blocks=window_num_blocks if two_kinds else None)
+        self.wpool = self.pool.groups['window'] if two_kinds else None
         # The engine owns the device arrays (they are donated through
-        # every jitted step); the pool keeps only the allocator.
-        self.caches = self.pool.caches
-        self.pool.caches = None
-        self.block_tables = jnp.zeros(
-            (slots, self.max_blocks_per_req), jnp.int32)
+        # every jitted step); the pool keeps only the allocator. One
+        # 4-tuple, or with two groups a dict of them by kind, as the
+        # paged bodies take them (``models/decode._by_kind``).
+        if two_kinds:
+            self.caches = {kind: g.caches
+                           for kind, g in self.pool.groups.items()}
+        else:
+            self.caches = self.pool.caches
+        for group in self.pool.groups.values():
+            group.caches = None
+        # The block tables live on the host and are written in place
+        # (``_set_table_row``); a device program is handed a copy
+        # (``_tables``). A row's update is then a numpy assignment,
+        # where an eager ``.at[row].set`` of a device array cost a
+        # list conversion and a dispatch of its own, many a pass.
+        self.block_tables = np.full(
+            (slots, self.max_blocks_per_req),
+            kv_pool_lib.SCRATCH_BLOCK, np.int32)
+        # The window group's table: the same logical columns, of
+        # which only those a row's window still touches hold a block
+        # (the rest read scratch); ``slot_wblocks`` is its host side,
+        # column -> block.
+        self.wblock_tables = (self.block_tables.copy() if two_kinds
+                              else None)
+        self.slot_wblocks: List[Dict[int, int]] = [
+            {} for _ in range(slots)]
         self.pos = jnp.zeros((slots,), jnp.int32)
         self.tokens = jnp.zeros((slots,), jnp.int32)
         # Host-side per-row bookkeeping.
@@ -855,10 +937,11 @@ class BatchingEngine:
         # writes (src/dst traced — one executable for every copy).
         self._copy_fn = jax.jit(kv_pool_lib.copy_pool_block,
                                 donate_argnums=(0,))
-        if self.prefix_caching:
+        if self.prefix_caching and self.wpool is None:
             # Prewarm the copy executable (scratch onto itself is a
             # no-op) so the FIRST partial-block hit in production
-            # does not pay the compile inside a request's TTFT.
+            # does not pay the compile inside a request's TTFT. (With
+            # a window group a hit is whole blocks only: no copy.)
             scratch = jnp.asarray(kv_pool_lib.SCRATCH_BLOCK,
                                   jnp.int32)
             self.caches = self._copy_fn(self.caches, scratch,
@@ -873,7 +956,7 @@ class BatchingEngine:
             *_, self.caches = self._verify_fn(
                 self.params,
                 jnp.zeros((slots, self.draft_k + 1), jnp.int32),
-                self.caches, self.block_tables, self.pos,
+                self.caches, self._tables(), self.pos,
                 jnp.zeros((slots,), jnp.int32), self.config,
                 self.draft_k + 1, self.block_size,
                 *self._adapter_args())
@@ -886,9 +969,9 @@ class BatchingEngine:
         self._view_widths = da.view_widths(self.max_blocks_per_req)
         t_warm = time.perf_counter()
         for width in self._view_widths:
-            _, self.caches, _ = self._step_fn(
+            _, self.caches, *_ = self._step_fn(
                 self.params, self.tokens, self.caches,
-                self.block_tables, self.pos,
+                self._tables(), self.pos,
                 jnp.zeros((slots,), bool), self.config, self.steps,
                 self.block_size, *self._adapter_args(),
                 sampling=None, view_blocks=width)
@@ -902,10 +985,19 @@ class BatchingEngine:
         # an engine with caching off must not export a fake 0 ratio.
         self._hit_ratio_gauge = None
         self._metrics['slots'].set(slots)
-        self._cache_bytes = self.pool.nbytes
+        groups = self.pool.groups.values()
+        self._cache_bytes = sum(g.nbytes for g in groups)
         self._metrics['kv_bytes'].set(self._cache_bytes)
-        self._metrics['kv_blocks_total'].set(self.pool.usable_blocks)
-        self._metrics['kv_token_bytes'].set(self.pool.token_bytes)
+        self._metrics['kv_blocks_total'].set(
+            sum(g.usable_blocks for g in groups))
+        self._metrics['kv_token_bytes'].set(
+            sum(g.token_bytes for g in groups))
+        if self.wpool is not None:
+            self._metrics['kv_window_blocks_total'].set(
+                self.wpool.usable_blocks)
+        # Pairs the expert layers routed, as device arrays until the
+        # next emit adds them to the counters (``_count_routed``).
+        self._routed_pending: list = []
         from skypilot_tpu.utils import profiling as profiling_lib
         self._profiler = profiling_lib.StepProfiler('decode')
         self.thread = threading.Thread(target=self._loop, daemon=True)
@@ -1372,10 +1464,94 @@ class BatchingEngine:
 
     def _set_table_row(self, row: int) -> None:
         blocks = self.slot_blocks[row]
-        padded = blocks + [kv_pool_lib.SCRATCH_BLOCK] * (
-            self.max_blocks_per_req - len(blocks))
-        self.block_tables = self.block_tables.at[row].set(
-            jnp.asarray(padded, jnp.int32))
+        table = self.block_tables[row]
+        table[:len(blocks)] = blocks
+        table[len(blocks):] = kv_pool_lib.SCRATCH_BLOCK
+
+    def _set_wtable_row(self, row: int) -> None:
+        held = self.slot_wblocks[row]
+        table = self.wblock_tables[row]
+        table[:] = kv_pool_lib.SCRATCH_BLOCK
+        if held:
+            table[list(held)] = list(held.values())
+
+    def _tables(self, row: Optional[int] = None):
+        """The block tables as the paged bodies take them: one array
+        (one row of it for a prefill chunk), or with a window group a
+        dict of the two by kind. Copies: a program's transfer may
+        read its argument after the call returns, and the engine
+        writes the tables in place."""
+        pick = (np.copy if row is None
+                else (lambda t: t[row].copy()))
+        if self.wpool is None:
+            return pick(self.block_tables)
+        return {'global': pick(self.block_tables),
+                'window': pick(self.wblock_tables)}
+
+    def _free_window(self, row: int, cols) -> None:
+        """Give the row's window-group blocks at ``cols`` back (a
+        decrement where a block is shared), deepest first."""
+        held = self.slot_wblocks[row]
+        cols = sorted(cols, reverse=True)
+        if cols:
+            self.wpool.free([held.pop(c) for c in cols])
+            self._set_wtable_row(row)
+
+    def _release_behind(self, row: int, next_pos: int) -> None:
+        """Release the row's window-group blocks that lie behind the
+        window of every query to come, the next standing at
+        ``next_pos``."""
+        if self.wpool is None:
+            return
+        first = kv_pool_lib.first_window_block(
+            next_pos, self.config.sliding_window, self.block_size)
+        behind = [c for c in self.slot_wblocks[row] if c < first]
+        if behind:
+            self._metrics['kv_window_released'].inc(len(behind))
+            self._free_window(row, behind)
+
+    def _ensure_window(self, row: int, lo: int, hi: int) -> bool:
+        """Window-group blocks for the row's positions [lo, hi), the
+        chunk or dispatch about to be written; exhaustion preempts
+        as ``_ensure_blocks`` does. False if the row itself went."""
+        if self.wpool is None or hi <= lo:
+            return True
+        held = self.slot_wblocks[row]
+        cols = [c for c in range(lo // self.block_size,
+                                 (hi - 1) // self.block_size + 1)
+                if c not in held]
+        if not cols:
+            return True
+        got = self._alloc_or_preempt(
+            row, self.wpool, len(cols),
+            f'request needs {len(cols)} more window-group KV blocks '
+            f'but that group has only {self.wpool.usable_blocks} '
+            f'usable')
+        if got is None:
+            return False
+        held.update(zip(cols, got))
+        self._set_wtable_row(row)
+        return True
+
+    def _alloc_or_preempt(self, row: int, group, n: int,
+                          hopeless: str) -> Optional[List[int]]:
+        """``n`` blocks of ``group`` for ``row``, preempting the
+        youngest request for as long as the group is dry. None if the
+        row itself was preempted, or failed with ``hopeless`` because
+        it is the only admitted request and still cannot grow."""
+        while True:
+            got = group.try_alloc(n)
+            if got is not None:
+                return got
+            victim = self._pick_victim()
+            if victim is None:
+                req = self.slot_req[row]
+                self._release_row(row)
+                self._fail_request(req, hopeless)
+                return None
+            self._preempt(victim)
+            if victim == row:
+                return None
 
     def _release_row(self, row: int) -> None:
         req = self.slot_req[row]
@@ -1395,6 +1571,8 @@ class BatchingEngine:
             # so eviction peels chains from the tail instead of
             # orphaning descendants by evicting their parent.
             self.pool.free(list(reversed(self.slot_blocks[row])))
+        if self.slot_wblocks[row]:
+            self._free_window(row, list(self.slot_wblocks[row]))
         self.slot_blocks[row] = []
         self.slot_req[row] = None
         self.slot_left[row] = 0
@@ -1446,41 +1624,33 @@ class BatchingEngine:
         extra = need - len(self.slot_blocks[row])
         if extra <= 0:
             return True
-        while True:
-            got = self.pool.try_alloc(extra)
-            if got is not None:
-                self.slot_blocks[row].extend(got)
-                self._set_table_row(row)
-                return True
-            victim = self._pick_victim()
-            if victim is None:
-                # This row is the only admitted request and still
-                # cannot grow: the pool can never satisfy it.
-                req = self.slot_req[row]
-                self._release_row(row)
-                self._fail_request(
-                    req, f'request needs {need} KV blocks but the '
-                    f'pool has only {self.pool.usable_blocks} '
-                    f'usable (block_size={self.block_size})')
-                return False
-            self._preempt(victim)
-            if victim == row:
-                return False
+        got = self._alloc_or_preempt(
+            row, self.pool, extra,
+            f'request needs {need} KV blocks but the pool has only '
+            f'{self.pool.usable_blocks} usable '
+            f'(block_size={self.block_size})')
+        if got is None:
+            return False
+        self.slot_blocks[row].extend(got)
+        self._set_table_row(row)
+        return True
 
     # -- engine loop ----------------------------------------------------
 
     def _match_prefix(self, req: _Request, tokens_all: List[int],
                       t0: int):
         """Prefix-cache lookup for an admission: returns
-        (pinned_blocks, cow, cached_tokens) where ``pinned_blocks``
-        are the full-block chain hits (already pinned) and ``cow``
-        is an optional (src_block, shared_tokens) partial hit past
-        them. Reuse is capped at t0 - 1 tokens: the LAST prompt
-        token is always recomputed so its logits seed decoding.
+        (pinned_blocks, cow, cached_tokens, window_hit) where
+        ``pinned_blocks`` are the full-block chain hits (already
+        pinned), ``cow`` is an optional (src_block, shared_tokens)
+        partial hit past them and ``window_hit`` the window group's
+        pinned blocks by column ({} without that group). Reuse is
+        capped at t0 - 1 tokens: the LAST prompt token is always
+        recomputed so its logits seed decoding.
         The computed chain is stashed on the request for
         ``_register_prefix`` to reuse."""
         if not self.prefix_caching or t0 < 2:
-            return [], None, 0
+            return [], None, 0, {}
         if req.chain_t0 == t0 and req.chain_hashes:
             # Re-admission of a request requeued by
             # _unwind_admission (pool momentarily full): the token
@@ -1503,6 +1673,20 @@ class BatchingEngine:
         matched = self.pool.match(hashes)
         max_reuse_blocks = (t0 - 1) // self.block_size
         matched = matched[:max_reuse_blocks]
+        if self.wpool is not None:
+            # A hit is usable only as far as the window group still
+            # holds what a query after it can see; whole blocks.
+            at = self.wpool.lookup(hashes[:len(matched)])
+            k = kv_pool_lib.usable_prefix(
+                [b is not None for b in at],
+                self.config.sliding_window, self.block_size)
+            first = kv_pool_lib.first_window_block(
+                k * self.block_size, self.config.sliding_window,
+                self.block_size)
+            window_hit = {c: at[c] for c in range(first, k)}
+            self.pool.pin(matched[:k])
+            self.wpool.pin(list(window_hit.values()))
+            return matched[:k], None, k * self.block_size, window_hit
         cached_tokens = len(matched) * self.block_size
         parent = hashes[len(matched) - 1] if matched \
             else prefix_hash.adapter_root(req.adapter)
@@ -1514,16 +1698,20 @@ class BatchingEngine:
             cow = self.pool.partial_match(parent, rest)
         if matched:
             self.pool.pin(matched)
-        return matched, cow, cached_tokens
+        return matched, cow, cached_tokens, {}
 
-    def _unwind_admission(self, req: _Request,
-                          blocks: List[int]) -> None:
+    def _unwind_admission(self, req: _Request, blocks: List[int],
+                          window_hit: Optional[Dict[int, int]] = None
+                          ) -> None:
         """Admission could not complete (pool momentarily full):
         release whatever was pinned/allocated — exactly once — and
         requeue the request at the front to retry after
         retirements free capacity."""
         if blocks:
             self.pool.free(list(reversed(blocks)))
+        if window_hit:
+            self.wpool.free([window_hit[c] for c in
+                             sorted(window_hit, reverse=True)])
         self._push_front(req)
 
     def _poll_adapter_loads(self) -> None:
@@ -1646,8 +1834,8 @@ class BatchingEngine:
                     f'blocks but the pool has only '
                     f'{self.pool.usable_blocks} usable')
                 continue
-            matched, cow, cached_tokens = self._match_prefix(
-                req, tokens_all, t0)
+            matched, cow, cached_tokens, window_hit = \
+                self._match_prefix(req, tokens_all, t0)
             blocks = list(matched)
             if cow is not None:
                 # Copy-on-write: duplicate the partially-matching
@@ -1669,11 +1857,19 @@ class BatchingEngine:
                 cached_tokens += shared
             extra = need - len(blocks)
             got = self.pool.try_alloc(extra) if extra > 0 else []
-            if got is None:
+            # The window group allocates as the row advances
+            # (``_ensure_window``); admission asks that it could hold
+            # the row at its widest: what its window touches plus the
+            # chunk in flight, less what the hit brought.
+            short_of_window = self.wpool is not None and (
+                self.wpool.free_blocks <
+                min(need, self._window_cap) - len(window_hit))
+            if got is None or short_of_window:
                 # Not enough free blocks yet: wait for retirements
                 # (in-flight rows make progress every iteration, so
                 # this cannot deadlock).
-                self._unwind_admission(req, blocks)
+                self._unwind_admission(req, blocks + (got or []),
+                                       window_hit)
                 return
             blocks.extend(got)
             if self.prefix_caching:
@@ -1730,6 +1926,9 @@ class BatchingEngine:
                 self.slot_adapter[row] = 0
             self.slot_req[row] = req
             self.slot_blocks[row] = blocks
+            if self.wpool is not None:
+                self.slot_wblocks[row] = window_hit
+                self._set_wtable_row(row)
             # Cache-hit tokens are ALREADY in the row's blocks —
             # prefill starts at the suffix (the whole TTFT win).
             self.slot_off[row] = cached_tokens
@@ -1794,6 +1993,8 @@ class BatchingEngine:
             return 0
         bucket = self._chunk_bucket(t0 - off)
         real = min(t0 - off, bucket)
+        if not self._ensure_window(row, off, off + real):
+            return 0
         with trace_lib.phase('engine.prefill_chunk', row=row,
                              bucket=bucket, real=real, offset=off):
             if self._prefill_t0[row] is None:
@@ -1814,10 +2015,10 @@ class BatchingEngine:
                          req.generated[:off + real - n_p])
             padded = chunk + [0] * (bucket - real)
             self._mark_enqueue()
-            chunk_tokens = jnp.asarray([padded], jnp.int32)
-            logits, self.caches = self._prefill_fn(
+            chunk_tokens = np.asarray([padded], np.int32)
+            logits, self.caches, routed = self._prefill_fn(
                 self.params, chunk_tokens, self.caches,
-                self.block_tables[row],
+                self._tables(row),
                 jnp.asarray(off, jnp.int32),
                 jnp.asarray(real, jnp.int32),
                 self.config, self.block_size,
@@ -1825,7 +2026,16 @@ class BatchingEngine:
         self._metrics['prefill_chunks'].inc()
         self._metrics['prefill_tokens'].inc(real)
         self._metrics['prefill_bucket_tokens'].inc(bucket)
+        if routed is not None:
+            self._routed_pending.append((routed, bucket, 1))
         self.slot_off[row] = off + real
+        if self.wpool is not None:
+            # The window group publishes a prompt's blocks as their
+            # chunks complete, since it gives them back long before
+            # the prompt ends: a released block then stays matchable
+            # in that group's cache until it is evicted.
+            self._register_window(row)
+            self._release_behind(row, off + real)
         self._prefill_chunks[row] += 1
         self.events.append(('prefill_chunk', row, off + real, t0))
         if self.slot_off[row] >= t0:
@@ -1932,6 +2142,25 @@ class BatchingEngine:
                 break
         return ran_any
 
+    def _register_window(self, row: int) -> None:
+        """Publish in the WINDOW group the row's prompt blocks that
+        are complete (wholly below ``slot_off``) and still held."""
+        req = self.slot_req[row]
+        if not (self.prefix_caching and req.chain_hashes
+                and req.chain_t0 == self.slot_total[row]):
+            return
+        tokens_all = req.prompt_ids + req.generated
+        full = min(self.slot_off[row] // self.block_size,
+                   len(req.chain_hashes))
+        root = prefix_hash.adapter_root(req.adapter)
+        for col, block in self.slot_wblocks[row].items():
+            if col < full:
+                self.wpool.register(
+                    block, req.chain_hashes[col],
+                    req.chain_hashes[col - 1] if col else root,
+                    tokens_all[col * self.block_size:
+                               (col + 1) * self.block_size])
+
     def _register_prefix(self, row: int) -> None:
         """Publish the row's FULL prompt blocks into the prefix
         cache: each complete block's content now equals its chain
@@ -1967,6 +2196,7 @@ class BatchingEngine:
         req = self.slot_req[row]
         t0 = self.slot_total[row]
         self._register_prefix(row)
+        self._release_behind(row, t0)
         if self.sampling and (req.temperature > 0.0
                               or req.grammar is not None):
             # Counter-keyed first token at position t0 - 1 (the
@@ -2138,6 +2368,9 @@ class BatchingEngine:
         never alias a recycled block."""
         keep = self.pool.blocks_for(min(self.slot_len[row] + 1,
                                         self.max_seq))
+        if self.wpool is not None:
+            self._free_window(row, [c for c in self.slot_wblocks[row]
+                                    if c >= keep])
         extra = self.slot_blocks[row][keep:]
         if not extra:
             return
@@ -2217,8 +2450,10 @@ class BatchingEngine:
             need = min(self.slot_left[i], n)
             if i in drafts:
                 need = max(need, len(drafts[i]) + 1)
-            self._ensure_blocks(
-                i, min(self.slot_len[i] + need, self.max_seq))
+            target = min(self.slot_len[i] + need, self.max_seq)
+            if self._ensure_blocks(i, target):
+                self._release_behind(i, self.slot_len[i])
+                self._ensure_window(i, self.slot_len[i], target)
         active_rows = self._decode_rows()
         if not active_rows:
             return None
@@ -2245,12 +2480,14 @@ class BatchingEngine:
         self._count_view(view)
         t_dispatch = time.perf_counter()
         self._mark_enqueue(t_dispatch)
-        toks, self.caches, self.pos = self._step_fn(
+        toks, self.caches, self.pos, *routed = self._step_fn(
             self.params, self.tokens, self.caches,
-            self.block_tables, self.pos, active, self.config, n,
+            self._tables(), self.pos, active, self.config, n,
             self.block_size, *self._adapter_args(),
             sampling=sampling, view_blocks=view)
         self.tokens = toks[:, -1]
+        if routed:
+            self._routed_pending.append((routed[0], self.slots, n))
         for i in active_rows:
             if self.slot_left[i] > 0:
                 self.slot_len[i] = min(self.slot_len[i] + n,
@@ -2283,6 +2520,7 @@ class BatchingEngine:
     def _finish_decode(self, active_rows: List[int], n: int,
                        host_toks, dispatch_s: float) -> None:
         """The emission tail of a plain decode dispatch."""
+        self._count_routed()
         if dispatch_s > 0:
             self._metrics['tok_s'].set(
                 len(active_rows) * n / dispatch_s)
@@ -2299,6 +2537,28 @@ class BatchingEngine:
                                          t_chunk_start, t_chunk_end)
         if emitted:
             self._count_tokens(emitted)
+
+    def _count_routed(self) -> None:
+        """Add what the expert layers routed since the last emit to
+        the ``skytpu_batch_moe_*`` counters. Each entry is one
+        dispatch's or chunk's tally (``decode._routed_sums``: pairs
+        and hit steps, [n_layers, experts held] each) with the
+        tokens a step carried (every lane of a dispatch and every
+        slot of a chunk bucket computes, real or not) and its steps;
+        nothing else reads them."""
+        pending, self._routed_pending = self._routed_pending, []
+        if not pending:
+            return
+        top_k = self.config.moe_top_k
+        m = self._metrics
+        for (pairs, hit_steps), tokens, steps in \
+                jax.device_get(pending):
+            layers, held = pairs.shape
+            m['moe_routed_pairs'].inc(tokens * steps * top_k * layers)
+            m['moe_held_pairs'].inc(int(pairs.sum()))
+            m['moe_busiest_pairs'].inc(int(pairs.max(axis=1).sum()))
+            m['moe_experts_hit'].inc(int(hit_steps.sum()))
+            m['moe_experts_held'].inc(layers * held * steps)
 
     def _count_tokens(self, n: int) -> None:
         """``n`` tokens handed to clients, with the passes over the
@@ -2383,7 +2643,7 @@ class BatchingEngine:
         preds, accepted, self.pos, self.tokens, self.caches = \
             self._verify_fn(
                 self.params, jnp.asarray(toks, jnp.int32),
-                self.caches, self.block_tables, self.pos,
+                self.caches, self._tables(), self.pos,
                 jnp.asarray(n_real, jnp.int32), self.config, w,
                 self.block_size, *self._adapter_args(),
                 sampling=self._verify_sampling_args(toks, n_real))
@@ -2550,13 +2810,18 @@ class BatchingEngine:
         # reclaimable) bytes are split out so a full-looking pool
         # that is mostly reusable cache reads as healthy
         # (docs/observability.md).
-        self._metrics['kv_blocks_used'].set(self.pool.used_blocks)
+        groups = self.pool.groups.values()
+        self._metrics['kv_blocks_used'].set(
+            sum(g.used_blocks for g in groups))
         self._metrics['kv_used'].set(
-            self.pool.used_blocks * self.pool.block_bytes)
+            sum(g.used_blocks * g.block_bytes for g in groups))
         self._metrics['kv_cached'].set(
-            self.pool.cached_blocks * self.pool.block_bytes)
+            sum(g.cached_blocks * g.block_bytes for g in groups))
         self._metrics['prefix_cached_blocks'].set(
-            self.pool.cached_blocks)
+            sum(g.cached_blocks for g in groups))
+        if self.wpool is not None:
+            self._metrics['kv_window_blocks_used'].set(
+                self.wpool.used_blocks)
         if self._adapters is not None:
             self._adapter_metrics['resident'].set(
                 self._adapters.resident_count())

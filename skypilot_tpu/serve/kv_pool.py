@@ -10,9 +10,10 @@ TPU-native: KV storage is ONE pool of fixed-size blocks
     k/v:    [E, num_blocks, block_size, Hkv, hd]
     scales: [E, num_blocks, block_size, Hkv]      (int8 pool only)
 
-(E = KV entries, one for every pass and layer: see ``KVBlockPool``)
-and each request holds a host-side list of block ids plus a device
-block-table row that maps its logical positions onto pool slots.
+(E = KV entries, one for every pass and layer: see ``BlockGroup``;
+a configuration with window AND global layers keeps one such pool a
+kind of layer, ``KVBlockPool.groups``) and each request holds a
+host-side list of block ids plus a device block-table row that maps its logical positions onto pool slots.
 Admission is then bounded by FREE BLOCKS (a token budget), not free
 slabs: short requests pack tightly, long ones grow block by block,
 and the engine preempts-and-requeues the youngest request instead of
@@ -87,8 +88,13 @@ MAX_PARTIAL_CHILDREN = 64
 # ---------------------------------------------------------------------
 
 
-class KVBlockPool:
-    """Device KV block pool + host free-list allocator.
+class BlockGroup:
+    """Device KV block pool + host free-list allocator of the layers
+    of ONE kind (``config.layer_kinds``: 'global' layers keep the
+    whole context, 'window' layers what a sliding window still
+    sees): its own pool arrays, free list, refcounts and prefix
+    index. ``KVBlockPool`` is the group a single-kind configuration
+    has, and holds the second one where there are two.
 
     ``caches`` is the engine-facing tuple
     ``(k, v, k_scale, v_scale)`` with k/v
@@ -99,7 +105,8 @@ class KVBlockPool:
     through jit like the old slabs were.
 
     THE LEADING AXIS, here and wherever the engine's docstrings
-    write the pool's shape: E = ``config.kv_entries`` =
+    write the pool's shape: E = ``config.kind_entries(kind)``, for a
+    single-kind configuration ``config.kv_entries`` =
     ``loop_passes x n_layers`` KV entries. A model whose layers run
     once has one entry a layer (E = L). A looped stack keeps the
     keys and values of pass t, layer l at entry ``t * n_layers + l``
@@ -112,7 +119,7 @@ class KVBlockPool:
 
     def __init__(self, config: llama.LlamaConfig, num_blocks: int,
                  block_size: int, kv_int8: bool = False,
-                 shardings=None):
+                 shardings=None, kind: Optional[str] = None):
         if block_size < 1:
             raise ValueError(f'block_size must be >= 1: {block_size}')
         if num_blocks < 2:
@@ -122,11 +129,12 @@ class KVBlockPool:
                 f'num_blocks must be >= 2 (block 0 is reserved '
                 f'scratch): {num_blocks}')
         self.config = config
+        self.kind = kind or config.layer_kinds[0]
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.kv_int8 = kv_int8
-        shape = (config.kv_entries, num_blocks, block_size,
-                 config.n_kv_heads, config.head_dim)
+        shape = (config.kind_entries(self.kind), num_blocks,
+                 block_size, config.n_kv_heads, config.head_dim)
         if kv_int8:
             caches = (jnp.zeros(shape, jnp.int8),
                       jnp.zeros(shape, jnp.int8),
@@ -165,7 +173,6 @@ class KVBlockPool:
         self._hash_meta: Dict[bytes, Tuple[bytes, Tuple[int, ...]]] = {}
         self._by_parent: Dict[bytes, List[bytes]] = {}
         self.evictions = 0      # cached blocks reclaimed by alloc
-
 
     # -- capacity ------------------------------------------------------
 
@@ -302,6 +309,11 @@ class KVBlockPool:
             out.append(b)
         return out
 
+    def lookup(self, hashes: Sequence[bytes]) -> List[Optional[int]]:
+        """The live block of each link of the chain, None where the
+        group has none (a window group keeps only a chain's tail)."""
+        return [self._hash_to_block.get(h) for h in hashes]
+
     def partial_match(self, parent: bytes,
                       tokens: Sequence[int]
                       ) -> Optional[Tuple[int, int]]:
@@ -391,6 +403,67 @@ class KVBlockPool:
                 pass
             if not siblings:
                 del self._by_parent[parent]
+
+
+class KVBlockPool(BlockGroup):
+    """The engine's pool: one ``BlockGroup`` a kind of layer.
+
+    Every configuration the repo ran before window layers has one
+    kind, and this object IS that group (its arrays, its allocator,
+    ``groups`` = {kind: self}). A stack with window AND global layers
+    (``config.layer_kinds``) has two: this object is the 'global'
+    group, whose blocks a request keeps for its whole context, and
+    ``groups['window']`` a second one of ``window_num_blocks`` blocks
+    for the window layers' entries, of which a row holds only the
+    columns its window still touches (plus the chunk in flight) and
+    gives back what falls behind (``serve/batching.py``). A block id
+    names a slot of ONE group; a request has a table a group."""
+
+    def __init__(self, config: llama.LlamaConfig, num_blocks: int,
+                 block_size: int, kv_int8: bool = False,
+                 shardings=None,
+                 window_num_blocks: Optional[int] = None):
+        kinds = list(dict.fromkeys(config.layer_kinds))
+        primary = 'global' if 'global' in kinds else kinds[0]
+        super().__init__(config, num_blocks, block_size, kv_int8,
+                         shardings, kind=primary)
+        self.groups: Dict[str, BlockGroup] = {primary: self}
+        if len(kinds) > 1:
+            if window_num_blocks is None:
+                raise ValueError(
+                    f'{config.name!r} has window and global layers: '
+                    f'the window group needs window_num_blocks')
+            self.groups['window'] = BlockGroup(
+                config, window_num_blocks, block_size, kv_int8,
+                shardings, kind='window')
+
+
+def usable_prefix(present: Sequence[bool], window: int,
+                  block_size: int) -> int:
+    """The longest prefix-cache hit, in blocks, that a WINDOW group
+    can still serve: ``present[i]`` says whether the chain's block i
+    is matchable in that group. A hit of k blocks is usable only
+    while the group holds every block a query at k * block_size or
+    later can still see, i.e. blocks [first_block(k * block_size), k)
+    (``first_window_block``): the blocks before them were released
+    when they fell behind their row's window, and nothing reads
+    them again."""
+    run = 0     # matchable blocks in a row, ending at block k - 1
+    best = 0
+    for k in range(1, len(present) + 1):
+        run = run + 1 if present[k - 1] else 0
+        if run >= k - first_window_block(k * block_size, window,
+                                         block_size):
+            best = k
+    return best
+
+
+def first_window_block(next_pos: int, window: int,
+                       block_size: int) -> int:
+    """The first block a window layer still reads once the next
+    query stands at ``next_pos``: it sees keys from ``next_pos -
+    window + 1`` on (``ops/decode_attention.window_view``)."""
+    return max(next_pos - window + 1, 0) // block_size
 
 
 def copy_pool_block(caches, src: jax.Array, dst: jax.Array):

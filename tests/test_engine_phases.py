@@ -177,7 +177,15 @@ def profiled(setup, tmp_path_factory):
                      for e in line.events if e.name.startswith(PREFIX)]
             if spans:
                 threads.append(sorted(spans, key=lambda s: s[1]))
-    return threads
+    # The session records every thread of the process. Under xdist a
+    # worker runs other files' tests first, and an engine one of them
+    # left open keeps waking every half second to a pass that finds
+    # nothing (``dispatch`` with rows 0, no ``device_wait``): a
+    # second thread of ``engine.*`` spans that is not this engine's.
+    # The threads that waited on the device are this run's; that
+    # there is exactly one is the first test's to assert.
+    return [t for t in threads
+            if any(s[0] == 'device_wait' for s in t)]
 
 
 class TestLoopPhases:

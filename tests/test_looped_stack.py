@@ -107,7 +107,7 @@ def _prefill(params, config, tokens, pools, chunk, table=None):
     for start in range(0, len(tokens), chunk):
         part = tokens[start:start + chunk]
         padded = part + [0] * (chunk - len(part))
-        logits, pools = _PREFILL(
+        logits, pools, _ = _PREFILL(
             params, jnp.asarray([padded], jnp.int32), pools, table,
             jnp.asarray(start, jnp.int32),
             jnp.asarray(len(part), jnp.int32), config, _BLOCK)
@@ -197,7 +197,7 @@ def test_a_pass_reads_its_own_entries_and_no_other():
     _, pools = _prefill(params, config, tokens[:8], _pools(config), 8)
 
     def second_chunk(pools_in):
-        _, out = _PREFILL(
+        _, out, _ = _PREFILL(
             params, jnp.asarray([tokens[8:]], jnp.int32), pools_in,
             jnp.arange(1, 9, dtype=jnp.int32),
             jnp.asarray(8, jnp.int32), jnp.asarray(8, jnp.int32),

@@ -26,8 +26,13 @@ _RANK, _SLOTS = 4, 3    # adapter slots: 0 is the all-zeros base slot
 def _case(name):
     """(config, params, kv_int8, adapters, adapter_idx) of a case."""
     overrides = {'qkv_bias': True} if name == 'qkv_bias' else {}
+    if name == 'tiny-window-moe':
+        # Window 8 binds inside row 0's context of 11; experts 2 .. 9
+        # of 16 held.
+        overrides = {'sliding_window': 8, 'experts_held': (2, 8)}
     config = llama.get_config(
-        'tiny-loop' if name == 'tiny-loop' else 'tiny', **overrides)
+        name if name in ('tiny-loop', 'tiny-window-moe')
+        else 'tiny', **overrides)
     params = llama.init_params(config, jax.random.PRNGKey(0))
     rng = np.random.default_rng(5)
     if name == 'qkv_bias':         # initialised to zeros: make them count
@@ -51,6 +56,14 @@ def _case(name):
     return config, params, name == 'int8', adapters, adapter_idx
 
 
+def _leaves(pools):
+    """The pool arrays of one 4-tuple, or of a dict of them by kind
+    of layer, in one fixed order."""
+    if isinstance(pools, dict):
+        return [a for kind in sorted(pools) for a in pools[kind]]
+    return list(pools)
+
+
 def _recording(monkeypatch, name, sink):
     """``sample_lib.<name>`` as it is, with the logits it was handed
     copied out: the decode and verify steps return tokens only."""
@@ -65,7 +78,8 @@ def _recording(monkeypatch, name, sink):
 
 
 @pytest.mark.parametrize(
-    'name', ['plain', 'qkv_bias', 'adapters', 'int8', 'tiny-loop'])
+    'name', ['plain', 'qkv_bias', 'adapters', 'int8', 'tiny-loop',
+             'tiny-window-moe'])
 def test_the_three_paged_bodies_agree_on_one_position(name,
                                                       monkeypatch):
     """Position p of each row from a one-token ``forward_paged``
@@ -82,9 +96,18 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
     config, params, kv_int8, adapters, adapter_idx = _case(name)
     tol = 0.05 if kv_int8 else 2e-4
     rng = np.random.default_rng(9)
-    pools = kv_pool.KVBlockPool(config, 9, _BLOCK,
-                                kv_int8=kv_int8).caches
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    two_kinds = len(set(config.layer_kinds)) > 1
+    if two_kinds:
+        # A block group and a table a kind of layer; every column
+        # held in both, so that the three read the same context.
+        groups = kv_pool.KVBlockPool(config, 9, _BLOCK,
+                                     window_num_blocks=9).groups
+        pools = {kind: g.caches for kind, g in groups.items()}
+        tables = {kind: tables for kind in groups}
+    else:
+        pools = kv_pool.KVBlockPool(config, 9, _BLOCK,
+                                    kv_int8=kv_int8).caches
     pos = jnp.asarray(_LENS, jnp.int32)
     token = jnp.asarray(rng.integers(1, 500, 2), jnp.int32)
 
@@ -92,19 +115,20 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
         padded = tokens + [0] * (-len(tokens) % 16)
         return decode.forward_paged(
             params, jnp.asarray([padded], jnp.int32), pools,
-            tables[row], jnp.asarray(start, jnp.int32),
+            {k: t[row] for k, t in tables.items()} if two_kinds
+            else tables[row], jnp.asarray(start, jnp.int32),
             jnp.asarray(len(tokens), jnp.int32), config, _BLOCK,
             adapters,
             None if adapter_idx is None else adapter_idx[row:row + 1])
 
     # The context [0, p) of each row, then position p three ways.
     for row, n in enumerate(_LENS):
-        _, pools = prefill(rng.integers(1, 500, n).tolist(), pools,
-                           row, 0)
+        _, pools, _ = prefill(rng.integers(1, 500, n).tolist(),
+                               pools, row, 0)
     chunk_logits, chunk_pools = [], pools
     for row, n in enumerate(_LENS):
-        logits, chunk_pools = prefill([int(token[row])], chunk_pools,
-                                      row, n)
+        logits, chunk_pools, _ = prefill([int(token[row])],
+                                          chunk_pools, row, n)
         chunk_logits.append(np.asarray(logits[0]))
     chunk_logits = np.stack(chunk_logits)
 
@@ -120,7 +144,7 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
         jnp.ones((2,), jnp.int32), config, 1, _BLOCK, adapters,
         adapter_idx, dict(sampling, mask_table=jnp.ones(
             (1, 1, config.vocab_size), bool)))
-    toks, decode_pools, _ = decode.decode_steps_paged(
+    toks, decode_pools, *_ = decode.decode_steps_paged(
         params, token, pools, tables, pos, jnp.asarray([True, True]),
         config, 1, _BLOCK, adapters, adapter_idx, dict(
             sampling,
@@ -146,10 +170,12 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
     assert np.array_equal(picked['verify'], picked['decode'])
 
     # What each body wrote at position p, for every KV entry.
-    slot = np.asarray(tables)[np.arange(2), np.asarray(_LENS) //
-                              _BLOCK] * _BLOCK + np.asarray(_LENS) % _BLOCK
-    for want, v_got, d_got in zip(chunk_pools, verify_pools,
-                                  decode_pools):
+    table = tables['global'] if two_kinds else tables
+    slot = np.asarray(table)[np.arange(2), np.asarray(_LENS) //
+                             _BLOCK] * _BLOCK + np.asarray(_LENS) % _BLOCK
+    for want, v_got, d_got in zip(_leaves(chunk_pools),
+                                  _leaves(verify_pools),
+                                  _leaves(decode_pools)):
         if want is None:
             continue
 
