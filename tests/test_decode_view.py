@@ -4,9 +4,11 @@ prewarmed width that holds its longest active row
 ``view_blocks``, serve/batching.BatchingEngine._view_blocks).
 
 Tiny models on the CPU, every case over a plain and a looped stack
-and over an int8 and a float pool: the narrower program has to emit
-the whole-table program's tokens, touch no block of a row it does not
-read, and exist before the first request.
+(the engine's also over one with window layers and experts, where
+the cut is the global layers' table) and over an int8 and a float
+pool: the narrower program has to emit the whole-table program's
+tokens, touch no block of a row it does not read, and exist before
+the first request.
 """
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,9 @@ from skypilot_tpu.serve.batching import BatchingEngine
 
 _BLOCK = 8
 _STACKS = ['tiny', 'tiny-loop']
+# The engine sizes a window group by itself: the stack with window
+# and global layers and a share of the experts rides the engine case.
+_ENGINE_STACKS = _STACKS + ['tiny-window-moe']
 
 
 def _setup(name):
@@ -39,9 +44,10 @@ def _prompt(n, seed):
 
 
 @pytest.mark.parametrize('table_blocks, want', [
-    (256, (32, 64, 160, 192, 256)),   # Mistral's cell
-    (65, (9, 18, 45, 54, 65)),        # Ouro's cell
-    (8, (1, 2, 5, 6, 8)),
+    (256, (32, 64, 160, 192, 224, 256)),   # Mistral's cells
+    (65, (9, 18, 45, 54, 63, 65)),         # Ouro's cell
+    (768, (96, 192, 480, 576, 672, 768)),  # the global layers' table
+    (8, (1, 2, 5, 6, 7, 8)),
     (5, (1, 2, 5)),
     (1, (1,)),
 ])
@@ -67,6 +73,25 @@ def test_chosen_width_covers_longest_plus_steps(table_blocks, n):
             assert all(w * _BLOCK < longest + n for w in narrower)
         else:
             assert width == table_blocks                 # the top
+
+
+@pytest.mark.parametrize('table_blocks, positions, want', [
+    (256, 3072, 192),     # a prompt clipped at 3,072 fills 6/8 ...
+    (256, 3073, 224),     # ... and its first dispatch needs 7/8,
+    (256, 3584, 224),     # as does the longest chat request's last.
+    (256, 3585, 256),
+    (65, 54 * 16 + 1, 63),
+    (65, 63 * 16 + 1, 65),
+    (768, 9217, 672),
+    (768, 10752, 672),
+    (768, 10753, 768),
+    (8, 6 * 16 + 1, 7),
+    (8, 7 * 16 + 1, 8),
+])
+def test_a_row_past_six_eighths_takes_seven_and_past_seven_the_table(
+        table_blocks, positions, want):
+    assert da.view_width(da.view_widths(table_blocks), positions,
+                         16) == want
 
 
 # ---------------------------------------------------------------------
@@ -182,12 +207,19 @@ def _spy(engine):
 # prefill for eleven passes beside them, 22 blocks long.
 _MIX = [(_prompt(10, 1), 90), (_prompt(21, 2), 50),
         (_prompt(170, 3), 12), (_prompt(30, 4), 70)]
+# A short row beside one that decodes from 150 positions to the
+# table's end (the engine serves 255 of its 256): through six and
+# seven eighths (192 and 224 positions) onto the whole table.
+_LONG_MIX = [(_prompt(12, 5), 40), (_prompt(150, 6), 105)]
 
 
+@pytest.mark.parametrize('mix, to_the_end', [(_MIX, False),
+                                             (_LONG_MIX, True)],
+                         ids=['edges', 'to-the-end'])
 @pytest.mark.parametrize('kv_int8', [False, True])
-@pytest.mark.parametrize('name', _STACKS)
+@pytest.mark.parametrize('name', _ENGINE_STACKS)
 def test_engine_emits_the_whole_table_tokens_and_never_lowers(
-        name, kv_int8):
+        name, kv_int8, mix, to_the_end):
     config, params = _setup(name)
     counter = harness.CompileCounter()
     engine = _engine(params, config, kv_int8)
@@ -201,31 +233,36 @@ def test_engine_emits_the_whole_table_tokens_and_never_lowers(
                         for b in (1, 2, 4, 8, 16, 20)])
         calls = _spy(engine)
         counter.open()
-        got = _serve(engine, _MIX)
+        got = _serve(engine, mix)
         counter.close()
         assert counter.inside == 0, 'a width compiled under load'
     finally:
         engine.close()
     views = [view for view, _, _ in calls]
-    assert len(set(views)) >= 4 and max(views) < table, views
+    widths = da.view_widths(table)
+    if to_the_end:
+        # Every width the constructor built from 5/8 up was met
+        # while decoding, 7/8 among them, and none compiled there.
+        assert set(views) >= set(widths[2:]), views
+    else:
+        assert len(set(views)) >= 4 and max(views) < table, views
     for view, sampled, need in calls:
         assert not sampled and view * _BLOCK >= need
-        assert view == da.view_width(da.view_widths(table), need,
-                                     _BLOCK)
+        assert view == da.view_width(widths, need, _BLOCK)
     # The long prompt sat in prefill beside dispatches narrower than
-    # its own 22 blocks.
-    assert min(views) * _BLOCK < 170
+    # its own blocks.
+    assert min(views) * _BLOCK < 150
 
     whole = _engine(params, config, kv_int8)
     try:
         # The same engine held to the whole table.
         whole._view_widths = (table,)
         whole_calls = _spy(whole)
-        want = _serve(whole, _MIX)
+        want = _serve(whole, mix)
     finally:
         whole.close()
     assert {view for view, _, _ in whole_calls} == {table}
-    assert [len(out) for out in got] == [n for _, n in _MIX]
+    assert [len(out) for out in got] == [n for _, n in mix]
     assert got == want
 
 
