@@ -274,6 +274,11 @@ def forward_cached(params: Params, tokens: jax.Array,
     chunks then run causal FLASH attention over the local q/k/v
     instead of the dense mask over the whole cache (O(T) memory).
     Callers feeding a prompt into a fresh cache should set it."""
+    if config.kv_lora_rank is not None:
+        # Other leaves altogether: refused ahead of the layer scan.
+        llama.require_plain_stack(
+            config, 'decode.forward_cached (the contiguous-cache '
+            'decode)')
     # int8 leaves (weight-only quantization, models/quant.py) must NOT
     # be upcast here — they cross HBM as int8 and convert in-register
     # inside the matmuls.
@@ -467,17 +472,227 @@ def layer_tail(config: llama.LlamaConfig, xc: jax.Array,
         xc = xc + branch_norm(_mm(attn, lp['wo']), 'attn_out_norm')
     if not config.parallel_block:
         h = llama.norm(config, xc, lp['mlp_norm'])
-    routed = None
     with jax.named_scope('mlp'):
-        if config.n_experts:
-            out, routed = moe.moe_layer(config, h, lp)
-        else:
-            gate = llama.mlp_act(config)(
-                _mm(h, lp['w_gate']).astype(jnp.float32)
-            ).astype(h.dtype)
-            up = _mm(h, lp['w_up'])
-            out = _mm(gate * up, lp['w_down'])
+        out, routed = _mlp(config, h, lp)
         return xc + branch_norm(out, 'mlp_out_norm'), routed
+
+
+def _mlp(config: llama.LlamaConfig, h: jax.Array, lp: Params,
+         route_on: Optional[jax.Array] = None
+         ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The layer's MLP on the normed stream ``h`` [B, T, D]: the
+    dropless expert layer where the layer has a router (with the
+    pairs it routed to each held expert; ``route_on`` as
+    ``moe.moe_layer`` takes it), else the dense gated MLP (and
+    None)."""
+    if 'router' in lp:
+        if route_on is None:
+            return moe.moe_layer(config, h, lp)
+        return moe.moe_layer(config, h, lp, route_on)
+    gate = llama.mlp_act(config)(
+        _mm(h, lp['w_gate']).astype(jnp.float32)).astype(h.dtype)
+    return _mm(gate * _mm(h, lp['w_up']), lp['w_down']), None
+
+
+# ---------------------------------------------------------------------
+# A latent (MLA) layer over several residual streams
+# ---------------------------------------------------------------------
+
+
+def streams(config: llama.LlamaConfig, x: jax.Array) -> jax.Array:
+    """The stack's carry from the embedding ``x`` [B, T, D]: with
+    ``config.hc_mult`` = n > 1 the token's n residual streams [B, T,
+    n, D], each starting as the embedding, in FLOAT32: the mixers
+    compute in float32 anyway, the streams are 4 x D values a token
+    and never cached, and a carry rounded to bf16 after each of
+    twenty sublayers drifts by a percent, enough to make one expert
+    choice in ten fall the other way (PERF.md section 6, PR 38); the
+    sublayers still read and multiply in the model's type."""
+    if config.hc_mult == 1:
+        return x
+    return jnp.broadcast_to(
+        x[..., None, :].astype(jnp.float32),
+        (*x.shape[:-1], config.hc_mult, x.shape[-1]))
+
+
+def hc_pre(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
+           sub: str, wide: bool = False):
+    """What a sublayer ``sub`` ('attn' or 'mlp') reads of the n
+    streams ``xc`` [B, T, n, D], and how its result goes back (mHC).
+    With x = vec(X) [n D], in float32:
+
+        m      = (x phi) rsqrt(mean(x^2) + norm_eps)        [2 n + n n]
+        H_pre  = sigmoid(a_pre m[:n] + b[:n])
+        H_post = 2 sigmoid(a_post m[n:2n] + b[n:2n])
+        H_res  = Sinkhorn(exp(clamp(a_res mat(m[2n:]) + mat(b[2n:]))))
+
+    Sinkhorn: ``hc_sinkhorn_iters`` times, every row over (its sum +
+    ``hc_eps``), then every column likewise (scope ``hc_sinkhorn``;
+    the n x n matrices lie [n, n, rows] so that both sums are adds
+    across whole registers). Returns (u [B, T, D] = sum_j H_pre[j]
+    X_j in the model's type (``wide``: left in float32), the
+    sublayer's input ahead of its norm; mix = (H_post [rows, n],
+    H_res [n, n, rows]) for ``hc_post``). One stream: (xc, None).
+    """
+    if config.hc_mult == 1:
+        return xc, None
+    b, t, n, d = xc.shape
+    rows = b * t
+    f32 = jnp.float32
+    with jax.named_scope('hc_pre'):
+        xs = xc.reshape(rows, n, d).astype(f32)
+        x = xs.reshape(rows, n * d)
+        m = (x @ lp[f'hc_{sub}_phi'].astype(f32)) * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + config.norm_eps)
+        a = lp[f'hc_{sub}_a'].astype(f32)
+        bias = lp[f'hc_{sub}_b'].astype(f32)
+        h_pre = jax.nn.sigmoid(a[0] * m[:, :n] + bias[:n])
+        h_post = 2.0 * jax.nn.sigmoid(
+            a[1] * m[:, n:2 * n] + bias[n:2 * n])
+        res = jnp.clip(a[2] * m[:, 2 * n:] + bias[2 * n:],
+                       *config.hc_clamp)                 # [rows, n n]
+    with jax.named_scope('hc_sinkhorn'):
+        mat = jnp.exp(res).T.reshape(n, n, rows)         # [i, j, rows]
+        for _ in range(config.hc_sinkhorn_iters):
+            mat = mat / (mat.sum(axis=1, keepdims=True) + config.hc_eps)
+            mat = mat / (mat.sum(axis=0, keepdims=True) + config.hc_eps)
+    with jax.named_scope('hc_pre'):
+        u = sum(h_pre[:, j, None] * xs[:, j] for j in range(n))
+    if not wide:
+        u = u.astype(config.dtype)
+    return u.reshape(b, t, d), (h_post, mat)
+
+
+def hc_post(config: llama.LlamaConfig, xc: jax.Array, y: jax.Array,
+            mix) -> jax.Array:
+    """The streams after a sublayer whose result is ``y`` [B, T, D]:
+    X'_i = sum_j H_res[i, j] X_j + H_post[i] y, in float32 as the
+    carry is (``mix`` from ``hc_pre``). One stream: xc + y."""
+    if mix is None:
+        return xc + y
+    b, t, n, d = xc.shape
+    h_post, h_res = mix
+    with jax.named_scope('hc_post'):
+        xs = xc.reshape(b * t, n, d).astype(jnp.float32)
+        yf = y.reshape(b * t, d).astype(jnp.float32)
+        out = jnp.stack(
+            [sum(h_res[i, j][:, None] * xs[:, j] for j in range(n)) +
+             h_post[:, i, None] * yf for i in range(n)], axis=1)
+        return out.reshape(b, t, n, d)
+
+
+def latent_head(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
+                angles: jax.Array):
+    """What precedes attention in a LATENT layer of the paged bodies
+    (``layer_head``'s counterpart): the streams' mix for the
+    attention sublayer, its norm, the query through its rank-
+    ``q_lora_rank`` bottleneck and norm (scope ``mla_q``), and the
+    token's latent row (scope ``mla_latent``): ``[RMSNorm(c_kv) ;
+    RoPE(k_pe) ; 0..]``, what the pool stores (``da.latent_row``). RoPE turns the
+    ``qk_rope_head_dim`` values of each query head and the ONE k_pe
+    all heads share.
+
+    ``xc`` [B, T, n, D]. Returns (q_nope [B, T, H, nope], q_pe [B, T,
+    H, rope], latent [B, T, W], mix for ``latent_tail``)."""
+    b, t = xc.shape[:2]
+    nh, nope = config.n_heads, config.qk_nope_head_dim
+    rank = config.kv_lora_rank
+    u, mix = hc_pre(config, xc, lp, 'attn')
+    h = llama.norm(config, u, lp['attn_norm'])
+    with jax.named_scope('mla_q'):
+        cq = llama._rms_norm(_mm(h, lp['wq_a']), lp['q_norm'],
+                             config.norm_eps)
+        q = _mm(cq, lp['wq_b']).reshape(b, t, nh, config.head_dim)
+        q_nope = q[..., :nope]
+        q_pe = rope(q[..., nope:], angles, config.rope_interleaved)
+    with jax.named_scope('mla_latent'):
+        ckv = _mm(h, lp['wkv_a'])
+        c = llama._rms_norm(ckv[..., :rank], lp['kv_norm'],
+                            config.norm_eps)
+        k_pe = rope(ckv[..., None, rank:], angles,
+                    config.rope_interleaved)[..., 0, :]
+        latent = da.latent_row(c, k_pe)
+    return q_nope, q_pe, latent, mix
+
+
+def latent_tail(config: llama.LlamaConfig, xc: jax.Array,
+                attn: jax.Array, lp: Params, mix
+                ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """What follows attention in a latent layer (``layer_tail``'s
+    counterpart): the output projection back into the streams, then
+    the second sublayer (its own mix, norm, the dense MLP of a leading
+    layer or the expert layer) likewise. ``attn`` [B, T, H * vd].
+    Returns the streams and the expert layer's tally (None for a
+    dense layer)."""
+    with jax.named_scope('o_proj'):
+        y = _mm(attn, lp['wo'])
+    xc = hc_post(config, xc, y, mix)
+    u, mix = hc_pre(config, xc, lp, 'mlp', wide=True)
+    # The norm in the carry's float32: the router reads it as it is,
+    # the products its rounding to the model's type.
+    wide = llama.norm(config, u, lp['mlp_norm'])
+    with jax.named_scope('mlp'):
+        out, routed = _mlp(config, wide.astype(config.dtype), lp,
+                           route_on=wide)
+    return hc_post(config, xc, out, mix), routed
+
+
+def _kv_up(config: llama.LlamaConfig, lp: Params):
+    """``wkv_b`` by head: (weights [rank, H, nope + vd], plain or
+    int8 codes; per-channel scales [H, nope + vd] or None)."""
+    w = lp['wkv_b']
+    shape = (config.kv_lora_rank, config.n_heads,
+             config.qk_nope_head_dim + config.v_head_dim)
+    if isinstance(w, dict) and 'q' in w:
+        return w['q'].reshape(shape), w['s'].reshape(shape[1:])
+    return w.reshape(shape), None
+
+
+def absorb_query(config: llama.LlamaConfig, q_nope: jax.Array,
+                 lp: Params) -> jax.Array:
+    """q_nope [..., H, nope] through the key half of ``wkv_b``
+    transposed -> [..., H, rank]: the query of the absorbed form,
+    scored against the cached c_kv itself. int8 codes are read as
+    codes; their per-channel scales (a channel is one of a head's
+    nope values here) multiply the query first, which is exact."""
+    w, s = _kv_up(config, lp)
+    nope = config.qk_nope_head_dim
+    if s is not None:
+        q_nope = q_nope * s[:, :nope].astype(q_nope.dtype)
+    return jnp.einsum('...hn,rhn->...hr', q_nope,
+                      w[:, :, :nope].astype(q_nope.dtype))
+
+
+def value_up(config: llama.LlamaConfig, o_lat: jax.Array,
+             lp: Params) -> jax.Array:
+    """The probabilities' sum of c_kv, [..., H, rank], through the
+    value half of ``wkv_b`` -> [..., H, vd]."""
+    w, s = _kv_up(config, lp)
+    nope = config.qk_nope_head_dim
+    out = jnp.einsum('...hr,rhv->...hv', o_lat,
+                     w[:, :, nope:].astype(o_lat.dtype))
+    return out if s is None else out * s[:, nope:].astype(out.dtype)
+
+
+def expand_latent(config: llama.LlamaConfig, c_kv: jax.Array,
+                  lp: Params):
+    """c_kv [S, rank] -> (k_nope [S, H, nope], v [S, H, vd]): the
+    expanded form's per-head keys and values of cached rows."""
+    kv = _mm(c_kv, lp['wkv_b']).reshape(
+        c_kv.shape[0], config.n_heads, -1)
+    return (kv[..., :config.qk_nope_head_dim],
+            kv[..., config.qk_nope_head_dim:])
+
+
+def refuse_latent_speculation(config: llama.LlamaConfig) -> None:
+    """The verify step has no latent body: speculation is refused
+    by name, here and at ``recipes/serve_model``'s start-up."""
+    if config.kv_lora_rank is not None:
+        raise exceptions.NotSupportedError(
+            f'{config.name!r}: speculative decoding is not '
+            f'implemented for latent attention (verify_step_paged '
+            f'has no latent body): build the engine with '
+            f'speculative=False (serve_model --speculative off)')
 
 
 def looped_stack(config: llama.LlamaConfig, cparams: Params,
@@ -509,7 +724,13 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
 
     One pass, no gate: one scan over the layers and the final norm
     on ``last(x)``, the program every other model traced before this
-    function existed."""
+    function existed.
+
+    ``config.dense_first`` leading layers run ahead of the scan on
+    ``cparams['dense_layers']`` (the scan is then over the expert
+    layers alone, entries from ``dense_first`` on), and with
+    ``config.hc_mult`` > 1 the carry is the token's streams [.., n,
+    D], summed ahead of the final norm."""
     kinds = config.layer_kinds
     period = len(kinds)
 
@@ -532,7 +753,8 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
         return jax.lax.scan(
             body, xc,
             (layers,
-             jnp.arange(config.n_layers, dtype=jnp.int32), adapters))
+             jnp.arange(config.n_layers - config.dense_first,
+                        dtype=jnp.int32), adapters))
 
     def period_scan(xc):
         """Layers of several kinds (``config.layer_kinds``): the
@@ -565,7 +787,27 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
             lambda r: r.reshape(config.n_layers, *r.shape[2:]), ys)
 
     def final_norm(h):
+        if config.hc_mult > 1:
+            # The streams' sum is what the head reads.
+            h = h.sum(axis=-2).astype(config.dtype)
         return llama.norm(config, h, cparams['final_norm'])
+
+    if config.dense_first:
+        # Leading dense layers (leaves of their own, ``dense_layers``)
+        # ahead of the scan over the expert layers; their KV entries
+        # come first. ``layer`` returns (rows, tally) here, the tally
+        # None for a dense layer.
+        def dense_body(c, scanned):
+            lp, li = scanned
+            return layer(c, lp, li, None)
+        x, (dense_rows, _) = jax.lax.scan(
+            dense_body, x,
+            (cparams['dense_layers'],
+             jnp.arange(config.dense_first, dtype=jnp.int32)))
+        x, (rows, routed) = layer_scan(x, config.dense_first)
+        rows = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                            dense_rows, rows)
+        return final_norm(last(x)), (rows, routed)
 
     if period > 1:
         if not (config.loop_passes == 1
@@ -666,6 +908,34 @@ def _kind_rows(config: llama.LlamaConfig, rows, kind: str):
     return jax.tree.map(pick, rows)
 
 
+def _write_rows(group, idx: jax.Array, rows):
+    """One group's flat pools [E, NB * bs, ...] with ``rows`` (a
+    tuple in the pool tuple's order, [E, len(idx), ...] each) written
+    at the flat slots ``idx``: one scatter a pool array, whatever the
+    format has (K, V and their scales, K and V, or latent rows)."""
+    rows = tuple(rows) + (None,) * (len(group) - len(rows))
+    if group[1] is None:
+        return (_write_latent_rows(group[0], idx, rows[0]),) + \
+            group[1:]
+    return tuple(None if p is None else p.at[:, idx].set(r)
+                 for p, r in zip(group, rows))
+
+
+def _write_latent_rows(flat: jax.Array, idx: jax.Array,
+                       rows: jax.Array) -> jax.Array:
+    """A latent group's flat pool [E, NB * bs, W] with ``rows`` [E,
+    len(idx), W] written at the flat slots ``idx`` of every entry,
+    as ONE scatter of E x len(idx) rows into the pool laid end to
+    end. The scatter batched over E (``flat.at[:, idx]``) makes the
+    v5e's compiler keep the whole pool in a layout with E next to
+    the row, a copy of it of 5.8 GB at 18,945 blocks."""
+    ne, slots, width = flat.shape
+    at = (jnp.arange(ne, dtype=idx.dtype)[:, None] * slots +
+          idx[None, :]).reshape(-1)
+    return flat.reshape(ne * slots, width).at[at].set(
+        rows.reshape(-1, width)).reshape(flat.shape)
+
+
 def _routed_sums(routed: jax.Array) -> jax.Array:
     """Per-step tallies [steps, n_layers, held] as the paged bodies
     return them, int32 [2, n_layers, held]: the pairs routed to each
@@ -752,8 +1022,12 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     hands ``pools`` and ``block_row`` as dicts by kind of layer
     (``_by_kind``) and attends over key tiles (``da.chunk_attention``:
     scopes ``window_attention``, ``global_attention``) with no
-    in-layer write; every other configuration runs the gathered form
-    below, as it did.
+    in-layer write; a latent configuration (``config.kv_lora_rank``)
+    hands one pool tuple ``(rows, None, None, None)`` and attends
+    EXPANDED over key tiles (``latent_layer`` below:
+    ``da.latent_chunk_attention``, scope ``mla_expanded_attention``),
+    its carry the token's residual streams (``streams``); every
+    other configuration runs the gathered form below, as it did.
 
     The pools' leading axis ``l`` counts KV entries, one for every
     pass and layer (``kv_pool.KVBlockPool``); a looped configuration
@@ -777,6 +1051,7 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     tables = _by_kind(config, block_row)
     quantized = next(iter(by_kind.values()))[2] is not None
     windowed = config.sliding_window is not None
+    latent = config.kv_lora_rank is not None
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     _, t = tokens.shape
 
@@ -785,7 +1060,7 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
         params)
     positions = start + jnp.arange(t)
     angles = llama._rope_frequencies(config, positions)
-    x = cparams['embed'][tokens]
+    x = streams(config, cparams['embed'][tokens])
     if config.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
 
@@ -797,9 +1072,27 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
                                             block_size)
         gw[kind] = da.chunk_write_indices(
             tables[kind], start, real_len, t, block_size)     # [T]
-        if not windowed:
+        if not (windowed or latent):
             gr[kind] = da.read_indices(tables[kind][None],
                                        block_size)[0]     # [S_pad]
+
+    def latent_layer(xc, lp, entry, ad, kind='latent'):
+        """A latent layer: the chunk's queries over the cached
+        latent rows and its own, EXPANDED a tile of keys at a time
+        (``da.latent_chunk_attention``); no in-layer write."""
+        del ad
+        q_nope, q_pe, rows, mix = latent_head(config, xc, lp, angles)
+        with jax.named_scope('mla_expanded_attention'):
+            attn = da.latent_chunk_attention(
+                q_nope[0], q_pe[0], rows[0],
+                _all_blocks(flat[kind][0], block_size),
+                tables[kind] + entry * nbs[kind], start,
+                llama.attention_scale(config),
+                functools.partial(expand_latent, config, lp=lp),
+                config.kv_lora_rank)
+        xc, routed = latent_tail(config, xc, attn.reshape(1, t, -1),
+                                 lp, mix)
+        return xc, ((rows[0],), routed)
 
     def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
         if not windowed:
@@ -889,20 +1182,16 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
     # materialization is the admission cost this path deletes.
     x_last, (rows, routed) = looped_stack(
-        config, cparams, x, layer, adapters,
+        config, cparams, x, latent_layer if latent else layer,
+        adapters,
         last=lambda h: jnp.take(
             h, jnp.maximum(real_len - 1, 0)[None], axis=1))  # [1,1,D]
     # Persist the chunk's rows with ONE scatter into the (donated)
     # flat pools (a group).
-    new_pools = {}
-    for kind, (kp, vp, ksp, vsp) in flat.items():
-        mine = _kind_rows(config, rows, kind)
-        kp = kp.at[:, gw[kind]].set(mine[0])
-        vp = vp.at[:, gw[kind]].set(mine[1])
-        if quantized:
-            ksp = ksp.at[:, gw[kind]].set(mine[2])
-            vsp = vsp.at[:, gw[kind]].set(mine[3])
-        new_pools[kind] = (kp, vp, ksp, vsp)
+    new_pools = {
+        kind: _write_rows(group, gw[kind],
+                          _kind_rows(config, rows, kind))
+        for kind, group in flat.items()}
     if config.tie_embeddings:
         logits = (x_last @ llama.output_head(cparams, config)
                   ).astype(jnp.float32)
@@ -999,7 +1288,12 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     window layer reads ``da.window_view``'s columns of its table, a
     fixed width whatever the context, and masks by the window; a
     global layer reads the first ``view_blocks`` columns of its own.
-    Scopes ``window_attention`` / ``global_attention`` there.
+    Scopes ``window_attention`` / ``global_attention`` there. A
+    latent configuration (``config.kv_lora_rank``) hands one pool
+    tuple ``(rows, None, None, None)``; its layer is ``latent_layer``
+    below: the rows' latent view at the dispatch's width, attended
+    ABSORBED (scope ``mla_absorbed_attention``), over a carry of
+    residual streams.
 
     Returns (out_tokens [B, num_steps], caches, new_pos). A model
     with experts returns a fourth value, ``routed``, int32 [2,
@@ -1019,8 +1313,10 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     b = tokens.shape[0]
     bs = block_size
     quantized = next(iter(by_kind.values()))[2] is not None  # static
-    if view_blocks is not None and 'global' in tables:
-        tables['global'] = tables['global'][:, :view_blocks]
+    if view_blocks is not None:
+        for kind in tables:
+            if kind != 'window':    # a window layer's width is fixed
+                tables[kind] = tables[kind][:, :view_blocks]
 
     # Flat [NB * bs, ...] pool views — write index math is 1-D
     # flat-slot; attention reads whole blocks (``_all_blocks``).
@@ -1032,19 +1328,40 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
         tok, pools, cur = carry
         angles = llama._rope_frequencies(
             config, cur)[:, None]                       # [B, 1, hd/2]
-        x = cparams['embed'][tok][:, None]              # [B, 1, D]
-        if config.scale_embeddings:
+        x = streams(config, cparams['embed'][tok][:, None])
+        if config.scale_embeddings:                     # [B, 1, D]
             x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
         widx, views, scale_views = {}, {}, {}
         for kind, (_, _, ks_all, vs_all) in pools.items():
             widx[kind] = da.write_index(tables[kind], cur, bs)  # [B]
             # What a layer of this kind reads: the table's columns
             # (cut above), or the window's (``da.window_view``).
-            views[kind] = (tables[kind], None) if kind == 'global' \
+            views[kind] = (tables[kind], None) if kind != 'window' \
                 else da.window_view(tables[kind], cur,
                                     config.sliding_window, bs)
             scale_views[kind] = _scale_views(ks_all, vs_all,
                                              views[kind][0], bs)
+
+        def latent_layer(xc, lp, entry, ad, kind='latent'):
+            """A latent layer: every head scores the rows' one
+            shared latent view, ABSORBED (``absorb_query`` before,
+            ``value_up`` after ``da.latent_decode_attention``); this
+            step's row an operand, written after the layer scan."""
+            del ad
+            q_nope, q_pe, rows, mix = latent_head(config, xc, lp,
+                                                  angles)
+            with jax.named_scope('mla_absorbed_attention'):
+                view = da.latent_view(
+                    _all_blocks(pools[kind][0], bs),
+                    views[kind][0] + entry * nbs[kind])
+                o_lat = da.latent_decode_attention(
+                    absorb_query(config, q_nope[:, 0], lp),
+                    q_pe[:, 0], view, cur,
+                    llama.attention_scale(config), rows[:, 0])
+                attn = value_up(config, o_lat, lp)
+            xc, routed = latent_tail(
+                config, xc, attn.reshape(b, 1, -1), lp, mix)
+            return xc, ((rows[:, 0],), routed)
 
         def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
             # ``entry``: this pass's and layer's KV entry (at one
@@ -1079,21 +1396,17 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
                 config, xc, attn.reshape(b, 1, nh * hd), lp)
             return xc, (new, routed)
 
-        x, (rows, routed) = looped_stack(config, cparams, x, layer,
-                                         adapters)
+        x, (rows, routed) = looped_stack(
+            config, cparams, x,
+            latent_layer if config.kv_lora_rank is not None
+            else layer, adapters)
         # Persist the new rows: one merged scatter per token into the
         # carried (donated) flat pools.
         with jax.named_scope('kv_write'):
-            new_pools = {}
-            for kind, (kp_all, vp_all, ks_all, vs_all) in \
-                    pools.items():
-                mine = _kind_rows(config, rows, kind)
-                kp_all = kp_all.at[:, widx[kind]].set(mine[0])
-                vp_all = vp_all.at[:, widx[kind]].set(mine[1])
-                if quantized:
-                    ks_all = ks_all.at[:, widx[kind]].set(mine[2])
-                    vs_all = vs_all.at[:, widx[kind]].set(mine[3])
-                new_pools[kind] = (kp_all, vp_all, ks_all, vs_all)
+            new_pools = {
+                kind: _write_rows(group, widx[kind],
+                                  _kind_rows(config, rows, kind))
+                for kind, group in pools.items()}
         if config.tie_embeddings:
             logits = (x @ llama.output_head(cparams, config))
         else:
@@ -1185,7 +1498,11 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     advances by accepted+1 for live rows (the ROLLBACK: rejected
     positions simply stay past the new frontier) and parked rows
     (n_real 0) are untouched.
+
+    A latent configuration (``config.kv_lora_rank``) is refused by
+    name (``refuse_latent_speculation``).
     """
+    refuse_latent_speculation(config)
     by_kind = _by_kind(config, caches)
     tables = _by_kind(config, block_tables)
     cparams = jax.tree.map(

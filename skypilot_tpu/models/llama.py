@@ -138,6 +138,45 @@ class LlamaConfig:
     moe_score: str = 'softmax'
     n_shared_experts: int = 0
     experts_held: Optional[tuple] = None
+    # Selection by score plus a per-expert bias (leaf ``router_bias``),
+    # weights from the scores without it ("noaux_tc"), and a factor on
+    # the routed sum after the top-k weights were normalised.
+    moe_select_bias: bool = False
+    moe_routed_scale: float = 1.0
+    # ---- Latent attention (MLA; DeepSeek-V2 family). With
+    # ``kv_lora_rank`` set a layer caches ONE row a token,
+    # ``[c_kv ; k_pe]`` of ``kv_lora_rank + qk_rope_head_dim`` values
+    # shared by all heads (``serve/kv_pool.py``: a block group of kind
+    # 'latent'); queries come through a rank-``q_lora_rank``
+    # bottleneck, each head has ``qk_nope_head_dim`` values without
+    # positions and ``qk_rope_head_dim`` rotated ones, and values are
+    # ``v_head_dim`` wide. Served by the paged engine only, in two
+    # forms (``ops/decode_attention.py``: absorbed for a decode step,
+    # expanded for a prefill chunk). ----
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: (factor, original context, beta_fast, beta_slow,
+    # mscale_all_dim). Scales the RoPE frequencies by wavelength and
+    # the softmax scale by (0.1 mscale_all_dim ln factor + 1)^2.
+    rope_yarn: Optional[tuple] = None
+    # The first ``dense_first`` layers keep a dense gated MLP of width
+    # ``dense_ffn_hidden`` (leaves under ``params['dense_layers']``);
+    # the expert layers after them are ``params['layers']``.
+    dense_first: int = 0
+    dense_ffn_hidden: int = 0
+    # ---- Several residual streams (mHC, arXiv 2512.24880). With
+    # ``hc_mult`` = n > 1 a token carries n streams; each sublayer
+    # reads a mix of them and writes back through a doubly stochastic
+    # n x n matrix (``hc_sinkhorn_iters`` Sinkhorn passes over
+    # exp(clamp(.)), ``hc_eps`` in each division) and a gate a stream
+    # (``models/decode.py``: ``hc_pre``, ``hc_post``). ----
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
         unknown = set(self.remat_saves.split('+')) - {
@@ -168,12 +207,32 @@ class LlamaConfig:
                 raise ValueError(
                     f'experts_held={self.experts_held} lies outside '
                     f'the {self.n_experts} routed experts')
+        if self.kv_lora_rank is not None and not (
+                self.q_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError(
+                f'kv_lora_rank={self.kv_lora_rank} needs q_lora_rank, '
+                f'qk_nope_head_dim, qk_rope_head_dim and v_head_dim')
+        if not 0 <= self.dense_first < self.n_layers or (
+                self.dense_first and not self.dense_ffn_hidden):
+            raise ValueError(
+                f'dense_first={self.dense_first} needs a '
+                f'dense_ffn_hidden and an expert layer after it '
+                f'(n_layers={self.n_layers})')
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank is not None:
+            # What a query head is scored over.
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_dim_override is not None:
             return self.head_dim_override
         return self.dim // self.n_heads
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token keeps in a latent layer's cache entry."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def kv_entries(self) -> int:
@@ -184,7 +243,10 @@ class LlamaConfig:
     @property
     def layer_kinds(self) -> tuple:
         """'window' or 'global' for each layer of one period of the
-        stack (the layer scan's body runs one period)."""
+        stack (the layer scan's body runs one period); ('latent',)
+        where every layer caches a latent row (``kv_lora_rank``)."""
+        if self.kv_lora_rank is not None:
+            return ('latent',)
         if self.sliding_window is None:
             return ('global',)
         if not self.global_every:
@@ -214,11 +276,18 @@ class LlamaConfig:
                 and not self.n_shared_experts
                 and self.experts_held is None
                 and self.moe_score == 'softmax'
-                and not self.rope_interleaved)
+                and not self.rope_interleaved
+                and self.kv_lora_rank is None and self.hc_mult == 1
+                and not self.dense_first
+                and not self.moe_select_bias
+                and self.moe_routed_scale == 1.0
+                and self.rope_yarn is None)
 
     def num_params(self) -> int:
         d, v, h = self.dim, self.vocab_size, self.ffn_hidden
         nh, nkv, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        if self.kv_lora_rank is not None:
+            return self._num_params_latent()
         mlp = 3 * d * h
         if self.n_experts:
             # What is held here: a share of the routed experts, the
@@ -236,13 +305,38 @@ class LlamaConfig:
         gate = 0 if self.exit_threshold is None else d + 1
         return v * d + head + self.n_layers * per_layer + d + gate
 
+    def _num_params_latent(self) -> int:
+        """A latent-attention stack: the MLA projections and their
+        two inner norms, the stream mixers of both sublayers, dense
+        layers first and expert layers after them, an untied head."""
+        d, v, nh = self.dim, self.vocab_size, self.n_heads
+        rq, rkv = self.q_lora_rank, self.kv_lora_rank
+        attn = (d * rq + rq + rq * nh * self.head_dim +
+                d * self.latent_width + rkv +
+                rkv * nh * (self.qk_nope_head_dim + self.v_head_dim) +
+                nh * self.v_head_dim * d)
+        mixers = 0
+        if self.hc_mult > 1:
+            n = self.hc_mult
+            mixers = 2 * (n * d * (2 * n + n * n) + 2 * n + n * n + 3)
+        expert = 3 * d * self.ffn_hidden
+        moe = ((self.n_experts_held + self.n_shared_experts) * expert
+               + d * self.n_experts
+               + (self.n_experts if self.moe_select_bias else 0))
+        shared = attn + mixers + 2 * d
+        return (2 * v * d + d +
+                self.dense_first * (shared +
+                                    3 * d * self.dense_ffn_hidden) +
+                (self.n_layers - self.dense_first) * (shared + moe))
+
     def num_active_params(self) -> int:
         """Params touched per token (== num_params for dense; for MoE
         only top_k of the n_experts MLPs) — the FLOPs/token basis."""
         if not self.n_experts:
             return self.num_params()
         unused = ((self.n_experts_held - self.moe_top_k) *
-                  3 * self.dim * self.ffn_hidden * self.n_layers)
+                  3 * self.dim * self.ffn_hidden *
+                  (self.n_layers - self.dense_first))
         return self.num_params() - max(unused, 0)
 
 
@@ -325,6 +419,29 @@ CONFIGS: Dict[str, LlamaConfig] = {
         rope_interleaved=True, layer_norm=True, parallel_block=True,
         n_experts=128, moe_top_k=8, moe_score='sigmoid',
         n_shared_experts=4),
+    # Latent attention, four residual streams, dense layers before
+    # the expert layers (HF XingChen-AGI/Xing4.0-29B-A4B config.json,
+    # model_type xing4_0: 40 layers of which the first 2 are dense at
+    # width 9,216; 32 heads of 128 + 64 rotated query values over a
+    # 512 + 64 latent row, q rank 768; 64 routed experts of width
+    # 1,024, 4 a token by sigmoid score plus a selection bias,
+    # weights normalised and doubled, 1 shared expert; YaRN factor 64
+    # over 4,096; hc_mult 4 with 20 Sinkhorn passes; an untied head.
+    # The next-token-prediction module is not built). Served by the
+    # paged engine only; fewer layers as a ``get_config`` override.
+    'xing4.0-29b-a4b': LlamaConfig(
+        name='xing4.0-29b-a4b', vocab_size=131072, dim=3584,
+        n_layers=40, n_heads=32, n_kv_heads=32, ffn_hidden=1024,
+        rope_theta=10000.0, norm_eps=1e-6, max_seq_len=20480,
+        rope_interleaved=True, kv_lora_rank=512, q_lora_rank=768,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_yarn=(64.0, 4096, 32.0, 1.0, 1.0),
+        dense_first=2, dense_ffn_hidden=9216,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_clamp=(-30.0, 30.0),
+        n_experts=64, moe_top_k=4, moe_score='sigmoid',
+        n_shared_experts=1, moe_select_bias=True,
+        moe_routed_scale=2.0),
     # Small configs for tests / CPU dryruns.
     'debug-250m': LlamaConfig(
         name='debug-250m', vocab_size=32000, dim=1024, n_layers=8,
@@ -346,6 +463,17 @@ CONFIGS: Dict[str, LlamaConfig] = {
         rope_interleaved=True, layer_norm=True, parallel_block=True,
         n_experts=16, moe_top_k=4, moe_score='sigmoid',
         n_shared_experts=2),
+    'tiny-latent-moe': LlamaConfig(
+        name='tiny-latent-moe', vocab_size=512, dim=128, n_layers=4,
+        n_heads=4, n_kv_heads=4, ffn_hidden=64, rope_theta=10000.0,
+        norm_eps=1e-6, max_seq_len=512, dtype=jnp.float32,
+        remat=False, rope_interleaved=True, kv_lora_rank=48,
+        q_lora_rank=32, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=32, rope_yarn=(64.0, 64, 32.0, 1.0, 1.0),
+        dense_first=2, dense_ffn_hidden=256, hc_mult=4,
+        n_experts=8, moe_top_k=2, moe_score='sigmoid',
+        n_shared_experts=1, moe_select_bias=True,
+        moe_routed_scale=2.0),
     'tiny-loop': LlamaConfig(
         name='tiny-loop', vocab_size=512, dim=128, n_layers=2,
         n_heads=4, n_kv_heads=4, ffn_hidden=256, max_seq_len=512,
@@ -377,11 +505,14 @@ def require_plain_stack(config: LlamaConfig, where: str) -> None:
             f'{config.parallel_block}, layer_norm='
             f'{config.layer_norm}, n_shared_experts='
             f'{config.n_shared_experts}, experts_held='
-            f'{config.experts_held}, moe_score={config.moe_score!r}): '
+            f'{config.experts_held}, moe_score={config.moe_score!r}, '
+            f'kv_lora_rank={config.kv_lora_rank}, hc_mult='
+            f'{config.hc_mult}, dense_first={config.dense_first}): '
             f'only the paged engine '
             f'(serve/batching.BatchingEngine; serve_model --slots N) '
             f'runs a looped layer stack, window layers, a parallel '
-            f'block or a share of the experts')
+            f'block, a share of the experts, latent attention or '
+            f'several residual streams')
 
 
 # ---------------------------------------------------------------------
@@ -413,6 +544,10 @@ def init_params(config: LlamaConfig, key: jax.Array,
         # (1 + w) — init to zeros; plain RMSNorm inits to ones.
         return (jnp.zeros(shape, dtype) if config.norm_offset
                 else jnp.ones(shape, dtype))
+
+    if config.kv_lora_rank is not None:
+        return _init_latent_params(config, key, dense, norm_init,
+                                   dtype)
 
     # Dense configs keep the historical 7-way split so a fixed seed
     # reproduces pre-MoE initializations exactly.
@@ -471,6 +606,101 @@ def init_params(config: LlamaConfig, key: jax.Array,
     return params
 
 
+def latent_leaf_shapes(config: LlamaConfig) -> Dict[str, tuple]:
+    """Shape and fan-in of the leaves every layer of a latent
+    stack has, dense or expert: the MLA projections (``wq_a`` ->
+    ``q_norm`` -> ``wq_b``; ``wkv_a`` -> ``kv_norm`` on the latent
+    part -> ``wkv_b``, whose columns are a head's ``qk_nope_head_dim``
+    key values then its ``v_head_dim`` value values; ``wo``), the two
+    sublayer norms, and with ``hc_mult`` > 1 each sublayer's stream
+    mixer: ``hc_*_phi`` [n d, 2 n + n n], ``hc_*_b`` [2 n + n n] and
+    the three scalars ``hc_*_a`` (pre, post, res)."""
+    d, nh, n = config.dim, config.n_heads, config.hc_mult
+    rq, rkv = config.q_lora_rank, config.kv_lora_rank
+    shapes = {
+        'wq_a': ((d, rq), d), 'q_norm': ((rq,), None),
+        'wq_b': ((rq, nh * config.head_dim), rq),
+        'wkv_a': ((d, config.latent_width), d),
+        'kv_norm': ((rkv,), None),
+        'wkv_b': ((rkv, nh * (config.qk_nope_head_dim +
+                              config.v_head_dim)), rkv),
+        'wo': ((nh * config.v_head_dim, d), nh * config.v_head_dim),
+        'attn_norm': ((d,), None), 'mlp_norm': ((d,), None),
+    }
+    if n > 1:
+        for sub in ('attn', 'mlp'):
+            shapes[f'hc_{sub}_phi'] = ((n * d, 2 * n + n * n), n * d)
+            shapes[f'hc_{sub}_b'] = ((2 * n + n * n,), 0)
+            shapes[f'hc_{sub}_a'] = ((3,), 0)
+    return shapes
+
+
+def hc_bias_init(n: int) -> jax.Array:
+    """A stream mixer's bias [2 n + n n] at init: both gates at
+    rest, the residual matrix favouring the identity (2 on the
+    diagonal, -2 off it, ahead of the Sinkhorn passes)."""
+    return jnp.concatenate([jnp.zeros((2 * n,)),
+                            4.0 * jnp.eye(n).reshape(-1) - 2.0])
+
+
+def _init_latent_params(config: LlamaConfig, key: jax.Array, dense,
+                        norm_init, dtype) -> Params:
+    """``init_params`` of a latent stack: ``dense_layers`` (the
+    first ``dense_first`` layers, a gated MLP of ``dense_ffn_hidden``)
+    and ``layers`` (the expert layers), each stacked along a leading
+    axis. The mixers start as the paper's: ``phi`` small, the gates'
+    biases 0 and the residual matrix's bias favouring the identity,
+    the three scalars 1."""
+    d, ffn, e = config.dim, config.ffn_hidden, config.n_experts
+    n = config.hc_mult
+
+    def shared_leaves(k, count):
+        out = {}
+        for i, (name, (shape, fan_in)) in enumerate(
+                latent_leaf_shapes(config).items()):
+            kk = jax.random.fold_in(k, i)
+            if fan_in is None:
+                out[name] = norm_init((count, *shape))
+            elif name.startswith('hc_') and name.endswith('_a'):
+                out[name] = jnp.ones((count, 3), dtype)
+            elif name.startswith('hc_') and name.endswith('_b'):
+                out[name] = jnp.tile(hc_bias_init(n), (count, 1)
+                                     ).astype(dtype)
+            else:
+                out[name] = dense(kk, (count, *shape), fan_in)
+        return out
+
+    kd, ke, k_embed, k_out = jax.random.split(key, 4)
+    ks = jax.random.split(ke, 8)
+    n_moe = config.n_layers - config.dense_first
+    held = config.n_experts_held
+    wide = config.n_shared_experts * ffn
+    layers = dict(
+        shared_leaves(ks[0], n_moe),
+        router=dense(ks[1], (n_moe, d, e), d),
+        w_gate=dense(ks[2], (n_moe, held, d, ffn), d),
+        w_up=dense(ks[3], (n_moe, held, d, ffn), d),
+        w_down=dense(ks[4], (n_moe, held, ffn, d), ffn))
+    if config.moe_select_bias:
+        layers['router_bias'] = jnp.zeros((n_moe, e), dtype)
+    if config.n_shared_experts:
+        layers.update(ws_gate=dense(ks[5], (n_moe, d, wide), d),
+                      ws_up=dense(ks[6], (n_moe, d, wide), d),
+                      ws_down=dense(ks[7], (n_moe, wide, d), ffn))
+    params = {'embed': dense(k_embed, (config.vocab_size, d), d),
+              'layers': layers, 'final_norm': norm_init((d,)),
+              'lm_head': dense(k_out, (d, config.vocab_size), d)}
+    if config.dense_first:
+        kf = jax.random.split(kd, 4)
+        nd, wd = config.dense_first, config.dense_ffn_hidden
+        params['dense_layers'] = dict(
+            shared_leaves(kf[0], nd),
+            w_gate=dense(kf[1], (nd, d, wd), d),
+            w_up=dense(kf[2], (nd, d, wd), d),
+            w_down=dense(kf[3], (nd, wd, d), wd))
+    return params
+
+
 def param_sharding_rules(config: LlamaConfig,
                          pipeline: bool = False) -> Params:
     """PartitionSpec per param over mesh axes (pp, fsdp, ep, tp).
@@ -485,6 +715,8 @@ def param_sharding_rules(config: LlamaConfig,
     """
     pl = 'pp' if pipeline else None
     fs = ('fsdp', 'ep')
+    if config.kv_lora_rank is not None:
+        return _latent_sharding_rules(config, pl, fs)
     if config.n_experts:
         mlp_rules = {
             'router': P(pl, fs, None),
@@ -529,6 +761,34 @@ def param_sharding_rules(config: LlamaConfig,
         rules['exit_gate_b'] = P(None)
     if not config.tie_embeddings:
         rules['lm_head'] = P(fs, 'tp')
+    return rules
+
+
+def _latent_sharding_rules(config: LlamaConfig, pl, fs) -> Params:
+    """A latent stack over (pp, fsdp, ep, tp): the per-head
+    projections (``wq_b``, ``wkv_b``, ``wo``) shard their head axis
+    over 'tp'; the two bottlenecks, their norms and the stream mixers
+    are whole on every chip (the latent row has no head axis)."""
+    whole = {name: P(pl, *([None] * len(shape)))
+             for name, (shape, _) in latent_leaf_shapes(config).items()}
+    whole.update(wq_a=P(pl, fs, None), wkv_a=P(pl, fs, None),
+                 wq_b=P(pl, None, 'tp'), wkv_b=P(pl, None, 'tp'),
+                 wo=P(pl, 'tp', fs))
+    layers = dict(whole, router=P(pl, fs, None),
+                  w_gate=P(pl, 'ep', 'fsdp', 'tp'),
+                  w_up=P(pl, 'ep', 'fsdp', 'tp'),
+                  w_down=P(pl, 'ep', 'tp', 'fsdp'))
+    if config.moe_select_bias:
+        layers['router_bias'] = P(pl, None)
+    if config.n_shared_experts:
+        layers.update(ws_gate=P(pl, fs, 'tp'), ws_up=P(pl, fs, 'tp'),
+                      ws_down=P(pl, 'tp', fs))
+    rules = {'embed': P('tp', fs), 'layers': layers,
+             'final_norm': P(None), 'lm_head': P(fs, 'tp')}
+    if config.dense_first:
+        rules['dense_layers'] = dict(
+            whole, w_gate=P(pl, fs, 'tp'), w_up=P(pl, fs, 'tp'),
+            w_down=P(pl, 'tp', fs))
     return rules
 
 
@@ -577,10 +837,29 @@ def norm(config: LlamaConfig, x: jax.Array,
 
 def _rope_frequencies(config: LlamaConfig, positions: jax.Array
                       ) -> jax.Array:
-    """[T, head_dim/2] complex rotation angles."""
-    hd = config.head_dim
+    """[T, head_dim/2] complex rotation angles (of a latent
+    stack: over the ``qk_rope_head_dim`` rotated values)."""
+    hd = (config.qk_rope_head_dim if config.kv_lora_rank is not None
+          else config.head_dim)
     freqs = 1.0 / (config.rope_theta ** (
         jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if config.rope_yarn is not None:
+        # YaRN: pairs that turn more than beta_fast times over the
+        # original context keep their frequency, those that turn
+        # fewer than beta_slow times are slowed by ``factor``, a
+        # linear ramp between (the published modelling code's
+        # ``find_correction_range``, the bounds rounded outwards).
+        factor, orig, fast, slow, _ = config.rope_yarn
+
+        def turns_at(turns):
+            return (hd * math.log(orig / (turns * 2 * math.pi)) /
+                    (2 * math.log(config.rope_theta)))
+        low = max(math.floor(turns_at(fast)), 0)
+        high = min(math.ceil(turns_at(slow)), hd - 1)
+        ramp = jnp.clip(
+            (jnp.arange(hd // 2, dtype=jnp.float32) - low) /
+            max(high - low, 0.001), 0.0, 1.0)
+        freqs = freqs / factor * ramp + freqs * (1.0 - ramp)
     if config.rope_scaling:
         # Llama-3.1 NTK-style frequency scaling (factor 8, low/high
         # freq cutoffs 1 and 4, original context 8192).
@@ -594,6 +873,17 @@ def _rope_frequencies(config: LlamaConfig, positions: jax.Array
                                      smooth * freqs))
         freqs = scaled
     return positions.astype(jnp.float32)[:, None] * freqs[None, :]
+
+
+def attention_scale(config: LlamaConfig) -> float:
+    """What scores are multiplied by ahead of the softmax:
+    head_dim^-0.5, under YaRN times (0.1 mscale_all_dim ln factor +
+    1)^2."""
+    scale = config.head_dim ** -0.5
+    if config.rope_yarn is not None:
+        factor, _, _, _, all_dim = config.rope_yarn
+        scale *= (0.1 * all_dim * math.log(factor) + 1.0) ** 2
+    return scale
 
 
 def mlp_act(config: LlamaConfig):
@@ -906,6 +1196,10 @@ def forward_hidden(params: Params, tokens: jax.Array,
     tp products are collective matmuls (``_layer``); the hidden state
     is gathered once for the head.
     """
+    if config.kv_lora_rank is not None:
+        # Other leaves altogether: refused ahead of the layer scan.
+        require_plain_stack(config, 'llama.forward_hidden (the dense '
+                            'forward)')
     if attn_impl is None:
         attn_impl = default_attn_impl()
     _, t = tokens.shape

@@ -10,8 +10,10 @@ This layer has neither:
 
 - the router scores every token against ALL ``n_experts`` (float32;
   softmax over the experts, or a sigmoid each: ``config.moe_score``),
-  takes the top ``moe_top_k`` and normalises their weights to sum 1
-  over all k, held here or not;
+  takes the top ``moe_top_k`` (of score plus the leaf ``router_bias``
+  with ``config.moe_select_bias``; the weights are the scores
+  without it) and normalises their weights to sum 1 over all k, held
+  here or not, times ``config.moe_routed_scale``;
 - the (token, expert) pairs whose expert this chip HOLDS
   (``config.experts_held`` = (first, count); all experts without it)
   are sorted by expert and go through three grouped products
@@ -28,7 +30,7 @@ A token's result is a fixed-order sum over its own k slots, so it is
 the same to the bit however the prompt was chunked and whoever shares
 the batch. Scopes: ``moe_router``, ``moe_experts``, ``moe_shared``.
 """
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,16 +63,32 @@ def stacked_experts(layers: Params):
 
 
 def route(config: llama.LlamaConfig, x: jax.Array,
-          router: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x [N, D] -> (weights [N, k] float32 summing to 1 a token,
-    experts [N, k] int32 among all ``n_experts``). Float32
-    throughout: a near-tie flips on bf16 logits."""
-    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+          router: jax.Array, bias: Optional[jax.Array] = None
+          ) -> Tuple[jax.Array, jax.Array]:
+    """x [N, D] -> (weights [N, k] float32 summing to
+    ``config.moe_routed_scale`` (1 unless set) a token, experts
+    [N, k] int32 among all ``n_experts``). Float32 throughout: a
+    near-tie flips on bf16 logits. With ``bias`` [n_experts]
+    (``config.moe_select_bias``) the experts are the top k of score
+    + bias, and their weights the scores without it."""
+    # A float32 ``x`` (a caller that kept the router's input wide)
+    # is multiplied at full precision: the default rounds both
+    # operands to bf16 on the TPU, which is what the caller avoided.
+    logits = jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision='highest' if x.dtype == jnp.float32 else None)
     scores = (jax.nn.sigmoid(logits) if config.moe_score == 'sigmoid'
               else jax.nn.softmax(logits, axis=-1))
-    weights, experts = jax.lax.top_k(scores, config.moe_top_k)
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, config.moe_top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                   config.moe_top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     weights = weights / jnp.maximum(
         weights.sum(-1, keepdims=True), 1e-20)
+    if config.moe_routed_scale != 1.0:
+        weights = weights * config.moe_routed_scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -102,18 +120,29 @@ def _grouped(xs: jax.Array, w, sizes: jax.Array,
     return out.astype(xs.dtype)
 
 
-def moe_layer(config: llama.LlamaConfig, h: jax.Array,
-              lp: Params) -> Tuple[jax.Array, jax.Array]:
+def moe_layer(config: llama.LlamaConfig, h: jax.Array, lp: Params,
+              route_on: Optional[jax.Array] = None
+              ) -> Tuple[jax.Array, jax.Array]:
     """h [B, T, D] (the normed stream) -> (this share's part of the
     expert layer's result [B, T, D], pairs routed to each held expert
-    [count] int32)."""
+    [count] int32). ``route_on`` [B, T, D]: what the router reads
+    where that is not ``h`` itself (the same normed stream kept in
+    float32, where ``h`` was rounded to the model's type for the
+    products: a near-tie among the scores falls one way or the other
+    on that rounding)."""
     b, t, d = h.shape
     n, k = b * t, config.moe_top_k
     first, count = (config.experts_held if config.experts_held
                     is not None else (0, config.n_experts))
     x = h.reshape(n, d)
     with jax.named_scope('moe_router'):
-        weights, experts = route(config, x, lp['router'])
+        # (The benchmark's tests wrap ``route`` by its three
+        # arguments: the bias is handed over only where there is one.)
+        bias = {'bias': lp['router_bias']} if 'router_bias' in lp \
+            else {}
+        weights, experts = route(
+            config, x if route_on is None else route_on.reshape(n, d),
+            lp['router'], **bias)
         # Pairs by the expert that serves them; an absent expert's
         # pairs sort behind every group and belong to none.
         local = experts - first

@@ -27,9 +27,13 @@ from skypilot_tpu.models import llama
 
 Params = Dict[str, Any]
 
-# Leaves under params['layers'] that are [L, in, out] matmul weights.
+# Leaves under params['layers'] (and, for a stack with leading dense
+# layers, params['dense_layers']) that are [L, in, out] matmul
+# weights; the last four are a latent-attention layer's.
 _LAYER_MATMULS = ('wq', 'wk', 'wv', 'wo', 'w_gate', 'w_up', 'w_down',
-                  'ws_gate', 'ws_up', 'ws_down')
+                  'ws_gate', 'ws_up', 'ws_down',
+                  'wq_a', 'wq_b', 'wkv_a', 'wkv_b')
+_LAYER_STACKS = ('layers', 'dense_layers')
 
 
 def quantize_weight(w: jax.Array) -> Dict[str, jax.Array]:
@@ -78,11 +82,11 @@ def quantize_params(params: Params, config: llama.LlamaConfig
     router stays full precision (selective precision, it is tiny and
     drives top-k selection)."""
     out = dict(params)
-    layers = dict(params['layers'])
-    for name in _LAYER_MATMULS:
-        if name in layers:
-            layers[name] = quantize_weight(layers[name])
-    out['layers'] = layers
+    for stack in _LAYER_STACKS:
+        if stack in params:
+            out[stack] = {
+                name: quantize_weight(w) if name in _LAYER_MATMULS
+                else w for name, w in params[stack].items()}
     if 'lm_head' in params:
         out['lm_head'] = quantize_weight(params['lm_head'])
     return out
@@ -104,10 +108,16 @@ def init_quantized(config: llama.LlamaConfig, key: jax.Array,
     quantize = jax.jit(quantize_weight)
 
     def init_leaf(name, sd, k):
+        if name.startswith('hc_') and name.endswith('_a'):
+            return jnp.ones(sd.shape, dtype)     # a mixer's scalars
+        if name.startswith('hc_') and name.endswith('_b'):
+            return jnp.broadcast_to(
+                llama.hc_bias_init(config.hc_mult), sd.shape
+            ).astype(dtype)
         if 'norm' in name:
             return (jnp.zeros(sd.shape, dtype) if config.norm_offset
                     else jnp.ones(sd.shape, dtype))
-        if name in ('bq', 'bk', 'bv', 'exit_gate_b'):
+        if name in ('bq', 'bk', 'bv', 'exit_gate_b', 'router_bias'):
             return jnp.zeros(sd.shape, dtype)
         # Same per-leaf fan-in rule as init_params' dense(): matmul
         # weights are [..., in, out] (fan_in = shape[-2]); the
@@ -119,7 +129,7 @@ def init_quantized(config: llama.LlamaConfig, key: jax.Array,
                         scale).astype(dtype))
         return normal(k)
 
-    out: Params = {'layers': {}}
+    out: Params = {}
     flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
     for i, (path, sd) in enumerate(flat):
         name = path[-1].key
@@ -127,7 +137,7 @@ def init_quantized(config: llama.LlamaConfig, key: jax.Array,
         if name in _LAYER_MATMULS or name == 'lm_head':
             leaf = quantize(leaf)  # frees the wide original
         if len(path) == 2:
-            out['layers'][name] = leaf
+            out.setdefault(path[0].key, {})[name] = leaf
         else:
             out[name] = leaf
     return out
@@ -143,14 +153,14 @@ def quantize_params_streamed(params: Params,
     cast = jax.jit(lambda x: x.astype(config.dtype))
 
     out = dict(params)
-    out['layers'] = dict(params['layers'])
-    for name, leaf in params['layers'].items():
-        if name in _LAYER_MATMULS:
-            out['layers'][name] = quantize(leaf)
-        else:
-            out['layers'][name] = cast(jnp.asarray(leaf))
+    for stack in _LAYER_STACKS:
+        if stack in params:
+            out[stack] = {
+                name: quantize(leaf) if name in _LAYER_MATMULS
+                else cast(jnp.asarray(leaf))
+                for name, leaf in params[stack].items()}
     for name in params:
-        if name not in ('layers', 'lm_head'):
+        if name not in _LAYER_STACKS + ('lm_head',):
             # embed, final_norm and the exit gate's two leaves.
             out[name] = cast(jnp.asarray(params[name]))
     if 'lm_head' in params:
@@ -159,5 +169,6 @@ def quantize_params_streamed(params: Params,
 
 
 def is_quantized(params: Params) -> bool:
-    wq = params.get('layers', {}).get('wq')
+    layers = params.get('layers', {})
+    wq = layers.get('wq', layers.get('wq_a'))
     return isinstance(wq, dict) and 'q' in wq

@@ -1,10 +1,25 @@
 """The paged KV pool's format on the device, and attention over it.
 
-The format, stated here and nowhere else: a pool is
-``[num_blocks, block_size, Hkv, hd]`` a KV entry (int8 codes with
-bf16 scales ``[num_blocks, block_size, Hkv]``, or floats), a request
-maps its logical positions onto pool blocks through a block-table row
-``[MB]``, and logical position p of a row lives at
+The three formats, stated here and nowhere else. A pool holds, a KV
+entry (a pass and a layer, ``serve/kv_pool.BlockGroup``),
+
+- float keys and values: k and v ``[num_blocks, block_size, Hkv,
+  hd]`` in the model's type, no scales;
+- int8 keys and values: k and v ``[num_blocks, block_size, Hkv, hd]``
+  int8 codes with bf16 scales ``[num_blocks, block_size, Hkv]``, one
+  a position and head;
+- latent rows (a group of kind 'latent', ``config.kv_lora_rank``): ONE
+  array ``[num_blocks, block_size, W]`` in the model's type and
+  nothing else: a position's ``[c_kv ; k_pe ; 0..]``, the normed
+  compressed row all heads' keys and values are products of, the one
+  rotated key part they share, and zeros up to W =
+  ``latent_pool_width(rank + rope)``, the next multiple of the
+  128-lane register (640 for 512 + 64). It has no head axis and no K
+  and V pair; the pool tuple's other three members are None.
+
+In all three a request maps its logical positions onto pool blocks
+through a block-table row ``[MB]``, and logical position p of a row
+lives at
 
     flat slot = block_table[p // block_size] * block_size
                 + p % block_size
@@ -49,8 +64,17 @@ entry can never corrupt a block that was recycled to another request.
   ``chunk_attention``, which walks key tiles between the window's (or
   the context's) first block and the chunk and never builds a view
   of ``max_seq``.
+- Latent layers, one layer in two forms that give the same numbers:
+  a decode step reads the rows' ``latent_view`` at the prewarmed
+  widths through ``latent_decode_attention``, ABSORBED (the key
+  up-projection folded into the query, the value up-projection
+  applied after the sum: every head scores the one shared row and no
+  per-head key or value is built); a prefill chunk goes through
+  ``latent_chunk_attention``, EXPANDED (a tile of cached rows at a
+  time is multiplied out to per-head keys and values, as the chunk's
+  own rows are).
 """
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -537,3 +561,154 @@ def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     return jnp.moveaxis(out, 2, 0).reshape(t, hq, hd)
 
 
+# ---------------------------------------------------------------------
+# Latent (MLA) attention over a pool of latent rows
+# ---------------------------------------------------------------------
+
+
+_LANES = 128
+
+
+def latent_pool_width(width: int) -> int:
+    """The row width of a latent pool for ``width`` = rank + rope
+    values a token: the next multiple of 128. The v5e tiles an
+    array's minor axis by 128 lanes, so a 576-wide row occupies 640
+    in memory whatever is declared; declared as 576 the compiler
+    avoids the padding by laying the pool out with its BLOCK axis
+    minor, and every program that reads blocks then opens and closes
+    with a relayout copy of the whole pool (3.9 GB at 18,945 blocks,
+    seen in the deviceless v5e compile). The padding lanes hold
+    zeros and meet zeros of the query."""
+    return -(-width // _LANES) * _LANES
+
+
+def latent_row(c_kv: jax.Array, k_pe: jax.Array) -> jax.Array:
+    """``[c_kv ; k_pe ; 0..]`` [..., W]: a token's row as a latent
+    pool stores it."""
+    width = c_kv.shape[-1] + k_pe.shape[-1]
+    pad = jnp.zeros((*c_kv.shape[:-1], latent_pool_width(width) - width),
+                    c_kv.dtype)
+    return jnp.concatenate([c_kv, k_pe, pad], axis=-1)
+
+
+def latent_view(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Rows' logical views of a latent pool, block by block: pool
+    [num_blocks, block_size, W], block_tables [B, MB] (cut by the
+    caller to a prewarmed width, ``view_widths``) -> [B, MB *
+    block_size, W]."""
+    return gather_blocks(pool, block_tables)
+
+
+def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
+                            view: jax.Array, lengths: jax.Array,
+                            scale: float, new: jax.Array) -> jax.Array:
+    """One decode position a row over a latent view, ABSORBED: q_lat
+    [B, H, rank] is the head's position-free query part already taken
+    through the key up-projection (q_nope W_kb^K^T), q_pe [B, H,
+    rope] its rotated part; view [B, S, W] (``latent_view``; W >=
+    rank + rope, ``latent_row``) of which row b's positions [0,
+    lengths[b]) count; ``new`` [B, W] this step's own latent row, an
+    operand and not yet a pool write (as in ``view_attention``). Scores are (q_lat . c_kv +
+    q_pe . k_pe) * scale over the ONE row all H heads share, the
+    softmax in float32. Returns the probabilities' sum of c_kv, [B,
+    H, rank] in q's type: the caller takes it through the value
+    up-projection."""
+    rank = q_lat.shape[-1]
+    q = latent_row(q_lat, q_pe)                          # [B, H, W]
+    s = view.shape[1]
+    logits = jnp.einsum('bhw,bsw->bhs', q, view,
+                        preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(s)[None, :] < lengths[:, None]       # [B, S]
+    logits = jnp.where(seen[:, None, :], logits, _NEG_INF)
+    own = jnp.einsum('bhw,bw->bh', q, new,
+                     preferred_element_type=jnp.float32) * scale
+    top = jnp.maximum(jnp.max(logits, axis=-1), own)       # [B, H]
+    p = jnp.exp(logits - top[..., None])
+    p_own = jnp.exp(own - top)
+    total = jnp.sum(p, axis=-1) + p_own
+    out = jnp.einsum('bhs,bsc->bhc',
+                     (p / total[..., None]).astype(q.dtype),
+                     view[..., :rank],
+                     preferred_element_type=jnp.float32)
+    out = out + (p_own / total)[..., None] * \
+        new[:, None, :rank].astype(jnp.float32)
+    return out.astype(q.dtype)
+
+
+def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
+                           new: jax.Array, pool: jax.Array,
+                           block_row: jax.Array, start: jax.Array,
+                           scale: float, expand: Callable, rank: int,
+                           tile_blocks: int = 32) -> jax.Array:
+    """One request's PREFILL CHUNK over a latent pool, EXPANDED:
+    q_nope [T, H, nope] and q_pe [T, H, rope] at positions start + t;
+    ``new`` [T, W] the chunk's own latent rows (``latent_row``; an
+    operand, no in-layer pool write); pool [num_blocks, block_size,
+    W] with ``block_row`` [MB] this request's table into it
+    (offset to the layer's entry by the caller). ``expand(c_kv [S,
+    rank]) -> (k_nope [S, H, nope], v [S, H, vd])`` is the layer's
+    key and value up-projection; a row's first ``rank`` values are
+    c_kv, the next rope k_pe.
+
+    Query t sees the cached rows [0, start) and the chunk's rows [0,
+    t]. The cached part is walked in tiles of ``tile_blocks`` blocks,
+    each gathered, multiplied out to per-head keys and values and
+    folded into a running maximum and sum (float32), as
+    ``chunk_attention`` does: the expanded keys of 18 k positions x
+    32 heads x 192 never exist at once, nor a view of ``max_seq``.
+    Returns [T, H, vd] in q's type; padded query rows give rows the
+    caller discards."""
+    t, heads, _ = q_nope.shape
+    bs, width = pool.shape[1], pool.shape[2]
+    rope = q_pe.shape[-1]
+    tile = tile_blocks * bs
+    mb = block_row.shape[0]
+    n_tiles = -(-mb // tile_blocks)
+    row = jnp.pad(block_row, (0, n_tiles * tile_blocks - mb),
+                  constant_values=SCRATCH_BLOCK)
+    q_pos = start + jnp.arange(t, dtype=jnp.int32)
+
+    def fold(carry, rows, seen):
+        """Fold latent ``rows`` [S, W], visible to query t where
+        ``seen`` [T, S], into the running (top, total, acc); None
+        starts it."""
+        k_nope, v = expand(rows[:, :rank])
+        if carry is None:
+            carry = (jnp.full((heads, t, 1), _NEG_INF, jnp.float32),
+                     jnp.zeros((heads, t, 1), jnp.float32),
+                     jnp.zeros((heads, t, v.shape[-1]), jnp.float32))
+        top, total, acc = carry
+        logits = (jnp.einsum('thn,shn->hts', q_nope, k_nope,
+                             preferred_element_type=jnp.float32) +
+                  jnp.einsum('thr,sr->hts', q_pe,
+                             rows[:, rank:rank + rope],
+                             preferred_element_type=jnp.float32)
+                  ) * scale
+        logits = jnp.where(seen[None], logits, _NEG_INF)
+        new_top = jnp.maximum(top, logits.max(-1, keepdims=True))
+        shrink = jnp.exp(top - new_top)
+        p = jnp.where(seen[None], jnp.exp(logits - new_top), 0.0)
+        return (new_top, total * shrink + p.sum(-1, keepdims=True),
+                acc * shrink + jnp.einsum(
+                    'hts,shd->htd', p.astype(q_nope.dtype), v,
+                    preferred_element_type=jnp.float32))
+
+    def one_tile(i, carry):
+        cols = jax.lax.dynamic_slice(row, (i * tile_blocks,),
+                                     (tile_blocks,))
+        with jax.named_scope('paged_gather'):
+            rows = jnp.take(pool, cols, axis=0, mode='clip').reshape(
+                tile, width)
+        key_pos = i * tile + jnp.arange(tile, dtype=jnp.int32)
+        seen = jnp.broadcast_to((key_pos < start)[None, :], (t, tile))
+        return fold(carry, rows, seen)
+
+    # The chunk's own rows first (every query sees its own, so the
+    # running maximum is finite from the start), then the cached
+    # tiles: the sum's order is not the positions', which a softmax
+    # does not mind.
+    carry = fold(None, new, q_pos[None, :] <= q_pos[:, None])
+    _, total, acc = jax.lax.fori_loop(0, -(-start // tile), one_tile,
+                                      carry)
+    out = (acc / total).astype(q_nope.dtype)             # [H, T, vd]
+    return jnp.swapaxes(out, 0, 1)

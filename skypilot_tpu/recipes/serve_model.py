@@ -21,7 +21,15 @@ from skypilot_tpu import trace as trace_lib
 
 def main():
     parser = argparse.ArgumentParser()
-    parser.add_argument('--model', default='tiny')
+    parser.add_argument(
+        '--model', default='tiny',
+        help='a preset of models/llama.py (CONFIGS). Stacks only the '
+             'batching engine runs need --slots N: ouro-2.6b (looped), '
+             'command-a-plus (window and global layers, a share of '
+             'the experts) and xing4.0-29b-a4b (latent attention, '
+             'four residual streams, dense layers before the expert '
+             'layers: bf16 latent cache, so no --kv-int8, and '
+             '--speculative off)')
     parser.add_argument('--port', type=int,
                         default=int(os.environ.get(
                             'SKYTPU_REPLICA_PORT', '8080')))
@@ -218,6 +226,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from skypilot_tpu import exceptions
     from skypilot_tpu.models import decode, llama
 
     device = jax_runtime.device_facts()
@@ -231,6 +240,13 @@ def main():
             parser.error(f'--experts-held {args.experts_held}: {e}')
     else:
         config = llama.get_config(args.model)
+    if args.slots > 0 and args.speculative == 'on' and args.draft_k > 0:
+        # The engine would refuse by the same error while it warms
+        # its verify step up, after the weights are made.
+        try:
+            decode.refuse_latent_speculation(config)
+        except exceptions.NotSupportedError as e:
+            parser.error(f'--speculative on: {e}')
     if not config.plain_stack and args.slots <= 0:
         # The serial path is the dense layer body, which refuses
         # such a stack on the first request: say so at start-up.
@@ -238,7 +254,9 @@ def main():
             f'--model {args.model} (loop passes '
             f'{config.loop_passes}, KV entries {config.kv_entries}, '
             f'sliding window {config.sliding_window}, experts held '
-            f'{config.experts_held}) is a stack only the batching '
+            f'{config.experts_held}, latent rank '
+            f'{config.kv_lora_rank}, residual streams '
+            f'{config.hc_mult}) is a stack only the batching '
             f'engine implements: pass --slots N')
     ckpt_params = None
     if args.checkpoint_dir:

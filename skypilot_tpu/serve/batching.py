@@ -570,6 +570,20 @@ def _engine_metrics():
             'skytpu_batch_moe_experts_held_total',
             'Held experts, counted a layer and step (the denominator '
             'of the above).'),
+        'mla_absorbed_row_steps': reg.counter(
+            'skytpu_batch_mla_absorbed_row_steps_total',
+            'Decode steps of active rows that went through the '
+            'absorbed form of latent attention (a model with '
+            'kv_lora_rank): rows of a dispatch x its steps.'),
+        'mla_absorbed_context': reg.counter(
+            'skytpu_batch_mla_absorbed_context_tokens_total',
+            'Positions those row-steps attended, the step\'s own '
+            'counted: over the row-steps it is the mean context a '
+            'latent decode step reads.'),
+        'mla_expanded_tokens': reg.counter(
+            'skytpu_batch_mla_expanded_tokens_total',
+            'Real prompt tokens prefilled through the expanded form '
+            'of latent attention (chunk padding not counted).'),
         'decode_view_blocks': reg.counter(
             'skytpu_batch_decode_view_blocks_total',
             'Block-table columns those dispatches read: the '
@@ -869,6 +883,11 @@ class BatchingEngine:
         self.slot_adapter = [0] * slots
         self._adapter_wait: List[_Request] = []
         if adapter_registry is not None and adapter_capacity > 0:
+            if config.kv_lora_rank is not None:
+                raise exceptions.NotSupportedError(
+                    f'{config.name!r}: LoRA adapters attach to wq and '
+                    f'wv, which a latent-attention layer does not '
+                    f'have')
             from skypilot_tpu.serve.adapters import ResidentAdapterSet
             wq = params['layers']['wq']
             wv = params['layers']['wv']
@@ -2026,6 +2045,8 @@ class BatchingEngine:
         self._metrics['prefill_chunks'].inc()
         self._metrics['prefill_tokens'].inc(real)
         self._metrics['prefill_bucket_tokens'].inc(bucket)
+        if self.config.kv_lora_rank is not None:
+            self._metrics['mla_expanded_tokens'].inc(real)
         if routed is not None:
             self._routed_pending.append((routed, bucket, 1))
         self.slot_off[row] = off + real
@@ -2488,6 +2509,15 @@ class BatchingEngine:
         self.tokens = toks[:, -1]
         if routed:
             self._routed_pending.append((routed[0], self.slots, n))
+        if self.config.kv_lora_rank is not None:
+            # Step k of a row at length L attends L + k cached
+            # positions and its own.
+            lengths = [self.slot_len[i] for i in range(self.slots)
+                       if is_active[i]]
+            self._metrics['mla_absorbed_row_steps'].inc(
+                n * len(lengths))
+            self._metrics['mla_absorbed_context'].inc(
+                n * sum(lengths) + len(lengths) * n * (n + 1) // 2)
         for i in active_rows:
             if self.slot_left[i] > 0:
                 self.slot_len[i] = min(self.slot_len[i] + n,

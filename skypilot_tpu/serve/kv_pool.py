@@ -12,7 +12,9 @@ TPU-native: KV storage is ONE pool of fixed-size blocks
 
 (E = KV entries, one for every pass and layer: see ``BlockGroup``;
 a configuration with window AND global layers keeps one such pool a
-kind of layer, ``KVBlockPool.groups``) and each request holds a
+kind of layer, ``KVBlockPool.groups``; a group of kind 'latent'
+keeps ONE array ``[E, num_blocks, block_size, W]`` of latent rows
+and no K and V pair) and each request holds a
 host-side list of block ids plus a device block-table row that maps its logical positions onto pool slots.
 Admission is then bounded by FREE BLOCKS (a token budget), not free
 slabs: short requests pack tightly, long ones grow block by block,
@@ -32,7 +34,8 @@ TPU-first design notes:
 - The pool shards exactly like the dense cache did
   (``decode_shardings``): KV-head axis over 'tp', everything else
   replicated — blocks are shared across requests, so there is no
-  batch axis to shard. ``pool_shardings`` builds the NamedShardings
+  batch axis to shard; a latent group has no head axis and is whole
+  on every chip. ``pool_shardings`` builds the NamedShardings
   from the same rules→specs idiom as the training partitioner.
 
 Automatic prefix caching (the vLLM/SGLang radix-reuse lineage, block
@@ -67,7 +70,8 @@ import jax.numpy as jnp
 from skypilot_tpu import exceptions
 from skypilot_tpu import tpu_logging
 from skypilot_tpu.models import llama
-from skypilot_tpu.ops.decode_attention import SCRATCH_BLOCK
+from skypilot_tpu.ops.decode_attention import (SCRATCH_BLOCK,
+                                               latent_pool_width)
 from skypilot_tpu.serve import prefix_hash
 
 logger = tpu_logging.init_logger(__name__)
@@ -102,7 +106,15 @@ class BlockGroup:
     scales ``[E, num_blocks, block_size, Hkv]`` when ``kv_int8``;
     scales are None for a bf16 pool) — the same 4-tuple shape the
     decode step functions carry, so the pool arrays are donated
-    through jit like the old slabs were.
+    through jit like the old slabs were. A group of kind 'latent'
+    (``config.kv_lora_rank``) holds ``(rows, None, None, None)``
+    with rows ``[E, num_blocks, block_size, W]`` in the model's
+    type, W = ``config.latent_width`` rounded up to whole 128-lane
+    registers (``ops.decode_attention.latent_pool_width``): one row
+    a token and entry where K and V of every head would be ``2 x
+    n_heads x head size`` (576 values, 640 in memory = 1,280 B,
+    against 20,480 at 32 heads of 192 + 128). An int8 latent
+    is not implemented: ``kv_int8`` is refused there.
 
     THE LEADING AXIS, here and wherever the engine's docstrings
     write the pool's shape: E = ``config.kind_entries(kind)``, for a
@@ -135,7 +147,16 @@ class BlockGroup:
         self.kv_int8 = kv_int8
         shape = (config.kind_entries(self.kind), num_blocks,
                  block_size, config.n_kv_heads, config.head_dim)
-        if kv_int8:
+        if self.kind == 'latent':
+            if kv_int8:
+                raise exceptions.NotSupportedError(
+                    f'{config.name!r}: an int8 latent cache is not '
+                    f'implemented (kv_int8 with kv_lora_rank='
+                    f'{config.kv_lora_rank})')
+            caches = (jnp.zeros(
+                shape[:3] + (latent_pool_width(config.latent_width),),
+                config.dtype), None, None, None)
+        elif kv_int8:
             caches = (jnp.zeros(shape, jnp.int8),
                       jnp.zeros(shape, jnp.int8),
                       jnp.zeros(shape[:-1], jnp.bfloat16),
@@ -473,13 +494,8 @@ def copy_pool_block(caches, src: jax.Array, dst: jax.Array):
     private one, then prefill overwrites from the first divergent
     token. ``src``/``dst`` are traced int32 scalars, so one jitted
     executable (caches donated) serves every copy."""
-    k, v, ks, vs = caches
-    k = k.at[:, dst].set(k[:, src])
-    v = v.at[:, dst].set(v[:, src])
-    if ks is not None:
-        ks = ks.at[:, dst].set(ks[:, src])
-        vs = vs.at[:, dst].set(vs[:, src])
-    return (k, v, ks, vs)
+    return tuple(None if c is None else c.at[:, dst].set(c[:, src])
+                 for c in caches)
 
 
 # Re-exported for engine convenience (serve/prefix_hash.py is the
@@ -495,10 +511,15 @@ def pool_shardings(config: llama.LlamaConfig, mesh,
     """NamedShardings for the pool 4-tuple: KV-head axis over 'tp',
     blocks replicated (pool blocks are shared across requests — only
     the head axis has a natural shard dimension, exactly as in
-    ``decode.decode_shardings``)."""
+    ``decode.decode_shardings``). A latent group's one array has no
+    head axis (all heads read the same row): it is replicated over
+    'tp', and the tuple's other three members are None."""
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
+    if config.kv_lora_rank is not None:
+        return (NamedSharding(mesh, P(None, None, None, None)),
+                None, None, None)
     kv = NamedSharding(mesh, P(None, None, None, 'tp', None))
     scale = NamedSharding(mesh, P(None, None, None, 'tp')) \
         if kv_int8 else None

@@ -2,7 +2,9 @@
 ``layer_head``, ``layer_tail`` and ``looped_stack``; what each keeps
 of its own is its attention over its own view of the pool. These
 tests are the drift alarm for that remainder: one position computed
-by each of the three must come out the same. Also here: the one
+by each of the three must come out the same (by each of the two a
+latent layer has: ``latent_head``, ``latent_tail``, one layer in an
+expanded and an absorbed form). Also here: the one
 ``rope`` against ``ops.attention.apply_rope`` on its three ``angles``
 layouts, and the rule that nothing below the scheduler imports it."""
 import ast
@@ -190,6 +192,75 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
             np.testing.assert_allclose(
                 at_p(got), at_p(want), rtol=0,
                 atol=1 if want.dtype == jnp.int8 else 2e-4)
+
+
+def test_the_latent_bodies_agree_on_one_position(monkeypatch):
+    """A latent layer has two bodies (``verify_step_paged`` refuses
+    the configuration by name): position p of each row from a
+    one-token ``forward_paged`` chunk, the EXPANDED form over the
+    cached latent rows, and from one step of ``decode_steps_paged``,
+    the ABSORBED form over the same rows. The same token, logits
+    equal to float32 tolerance, and the same latent row written at p
+    for every entry, dense layers' and expert layers' alike."""
+    config = llama.get_config('tiny-latent-moe')
+    params = llama.init_params(config, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(9)
+    # The selection bias is initialised to zeros: make it count.
+    params['layers']['router_bias'] = jnp.asarray(
+        0.05 * rng.standard_normal(
+            params['layers']['router_bias'].shape), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    pools = kv_pool.KVBlockPool(config, 9, _BLOCK).caches
+    pos = jnp.asarray(_LENS, jnp.int32)
+    token = jnp.asarray(rng.integers(1, 500, 2), jnp.int32)
+
+    def prefill(tokens, pools, row, start):
+        padded = tokens + [0] * (-len(tokens) % 16)
+        return decode.forward_paged(
+            params, jnp.asarray([padded], jnp.int32), pools,
+            tables[row], jnp.asarray(start, jnp.int32),
+            jnp.asarray(len(tokens), jnp.int32), config, _BLOCK)
+
+    for row, n in enumerate(_LENS):
+        _, pools, _ = prefill(rng.integers(1, 500, n).tolist(),
+                               pools, row, 0)
+    chunk_logits, chunk_pools = [], pools
+    for row, n in enumerate(_LENS):
+        logits, chunk_pools, _ = prefill([int(token[row])],
+                                          chunk_pools, row, n)
+        chunk_logits.append(np.asarray(logits[0]))
+    chunk_logits = np.stack(chunk_logits)
+    seen = []
+    _recording(monkeypatch, 'sample_rows', seen)
+    toks, decode_pools, new_pos, _ = decode.decode_steps_paged(
+        params, token, pools, tables, pos, jnp.asarray([True, True]),
+        config, 1, _BLOCK, None, None, {
+            'temps': jnp.zeros((2,), jnp.float32),
+            'top_ps': jnp.ones((2,), jnp.float32),
+            'seeds': jnp.zeros((2,), jnp.int32),
+            'mask_idx': jnp.zeros((2,), jnp.int32),
+            'mask_table': jnp.ones((1, config.vocab_size), bool)})
+    jax.effects_barrier()
+    assert np.asarray(new_pos).tolist() == [n + 1 for n in _LENS]
+    np.testing.assert_allclose(seen[0], chunk_logits, atol=2e-4,
+                               rtol=0)
+    assert np.array_equal(np.asarray(toks)[:, 0],
+                          chunk_logits.argmax(-1))
+    slot = np.asarray(tables)[np.arange(2), np.asarray(_LENS) //
+                              _BLOCK] * _BLOCK + np.asarray(_LENS) % _BLOCK
+    want, got = (np.asarray(p[0]).reshape(config.n_layers, -1,
+                                          p[0].shape[-1])[:, slot]
+                 for p in (chunk_pools, decode_pools))
+    assert chunk_pools[1:] == decode_pools[1:] == (None, None, None)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    assert np.abs(want[..., :config.latent_width]).min(axis=-1).max() \
+        > 0 and not want[..., config.latent_width:].any()
+    from skypilot_tpu import exceptions
+    with pytest.raises(exceptions.NotSupportedError,
+                       match='verify_step_paged has no latent body'):
+        decode.verify_step_paged(
+            params, token[:, None], pools, tables, pos,
+            jnp.ones((2,), jnp.int32), config, 1, _BLOCK)
 
 
 @pytest.mark.parametrize('layout', ['chunk', 'decode rows',
