@@ -956,12 +956,15 @@ def _all_blocks(flat: jax.Array, block_size: int) -> jax.Array:
 
 
 def _scale_views(k_scale, v_scale, block_tables: jax.Array,
-                 block_size: int):
+                 block_size: int, walk: bool = False):
     """Every KV entry's K and V scales for the rows' views
     (``decode_attention.gather_scales``), gathered OUTSIDE the layer
     scan as ONE array [E, 2, B, Hkv, S] float32 (201 MB at 32 x 24 x
     8 x 4,096; 2.7 ms of a 48 ms decode step) that the layer body
-    indexes by entry; None for a bf16 pool. k_scale/v_scale are the
+    indexes by entry; None for a bf16 pool. Where the layers' attention
+    walks the pool (``walk``: ``decode_attention.walk_engages``) the
+    same values in the walk's layout, [E, 2, B, tiles, tile]
+    (``decode_attention.walk_scales``). k_scale/v_scale are the
     flat [E, NB * bs, Hkv] pools. Timed on the v5e (PERF.md, PR 26):
     scale pools read inside the layer scan cost 7-120 ms a step more
     — an array of 37-100 MB that rides the layer loop is placed in
@@ -971,10 +974,10 @@ def _scale_views(k_scale, v_scale, block_tables: jax.Array,
     (172 ms)."""
     if k_scale is None:
         return None
+    gather = da.walk_scales if walk else da.gather_scales
     return jnp.stack([
-        da.gather_scales(
-            sp.reshape(sp.shape[0], -1, block_size, sp.shape[-1]),
-            block_tables) for sp in (k_scale, v_scale)], axis=1)
+        gather(sp.reshape(sp.shape[0], -1, block_size, sp.shape[-1]),
+               block_tables) for sp in (k_scale, v_scale)], axis=1)
 
 
 def forward_paged(params: Params, tokens: jax.Array, pools,
@@ -1323,6 +1326,11 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
     flat, nbs = {}, {}
     for kind, group in by_kind.items():
         flat[kind], nbs[kind] = _flat_pools(config, kind, group, bs)
+    # The kinds whose attention walks the pool and gathers no view.
+    walks = {kind: da.walk_engages(
+        bs, nkv, hd, codes=quantized, positions=1,
+        window=config.sliding_window if kind == 'window' else None)
+        for kind in flat}
 
     def one_token(carry, _):
         tok, pools, cur = carry
@@ -1339,8 +1347,8 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
             views[kind] = (tables[kind], None) if kind != 'window' \
                 else da.window_view(tables[kind], cur,
                                     config.sliding_window, bs)
-            scale_views[kind] = _scale_views(ks_all, vs_all,
-                                             views[kind][0], bs)
+            scale_views[kind] = _scale_views(
+                ks_all, vs_all, views[kind][0], bs, walks[kind])
 
         def latent_layer(xc, lp, entry, ad, kind='latent'):
             """A latent layer: every head scores the rows' one
@@ -1385,12 +1393,18 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
                 else jax.lax.dynamic_index_in_dim(
                     scale_views[kind], entry, 0, keepdims=False,
                     allow_negative_indices=False)
+            # A walk reads what a row's length says: a lane that
+            # decodes nothing (free, or parked past the table while
+            # its prompt is prefilled) reads nothing. Its output is
+            # discarded under either form.
+            seen = jnp.where(active, cur, 0) if walks[kind] else cur
             with _attention_scope(config, kind):
                 attn = da.paged_decode_attention(
                     q[:, 0], _all_blocks(kp_all, bs),
                     _all_blocks(vp_all, bs), view + entry * nbs[kind],
-                    cur, hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-                    new=new, **_window_args(config, kind, key_start)
+                    seen, hd ** -0.5, k_scale=ks_view,
+                    v_scale=vs_view, new=new,
+                    **_window_args(config, kind, key_start)
                 )[:, None]
             xc, routed = layer_tail(
                 config, xc, attn.reshape(b, 1, nh * hd), lp)

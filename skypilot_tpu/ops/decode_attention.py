@@ -37,20 +37,31 @@ entry can never corrupt a block that was recycled to another request.
   position: the prefill chunk's one-row view), ``gather_blocks`` and
   ``gather_scales`` (block by block: the decode and verify steps).
 - Attention: the engine's decode and verify steps go through
-  ``paged_decode_attention`` -> ``view_attention`` (plain XLA, one
-  compiled program): the int8 pool is gathered block by block and
-  read AS int8 — codes converted inside the two dots, the K scale
-  applied to the scores and the V scale to the probabilities, this
-  step's own row an operand, not a pool write. What the v5e's trace
-  showed of the form before (PR 25: per-position gathers at 385 GB/s,
-  a bf16 copy of the whole padded K and V view, a copy of the layer's
-  pool slice for B new rows; 113.8 ms a step at 24 rows x 4,096) and
-  of this one (PR 26: 48 ms) is in PERF.md. It is dense over the
-  table it is handed: the decode step hands it the table's first
-  columns up to a prewarmed width that holds the dispatch's longest
-  row (``view_widths``, ``view_width``), so what is left is the
-  read past each shorter row's own blocks and over inactive rows
-  (ROADMAP S2b).
+  ``paged_decode_attention``, which reads an int8 pool AS int8 in
+  either of two forms that give the same numbers: codes converted
+  inside the two dots, the K scale applied to the scores and the V
+  scale to the probabilities, this step's own row an operand, not a
+  pool write. Which form runs is ``walk_engages``' answer, from the
+  platform and the operands alone:
+  on a TPU, for ONE query position a row over an int8 pool without a
+  window (head width 128, block 16: the decode step of every int8
+  serving configuration, and of a stack with window layers its
+  global ones), ``walk_attention``, a Pallas kernel that copies a
+  tile of each row's OWN blocks from the pool in HBM into VMEM by
+  the block table and folds it into a running maximum and sum; what
+  it reads follows each row's length, the table's width only bounds
+  it, and the scales come as a dense operand (``walk_scales``).
+  Everywhere else (the CPU, the verify window, a window layer, a
+  float pool) ``gather_blocks`` + ``view_attention`` (plain XLA):
+  the pool gathered block by block into a view that is dense over
+  the table it is handed, the table's first columns up to a
+  prewarmed width that holds the dispatch's longest row
+  (``view_widths``, ``view_width``), and over every lane. What the
+  v5e's trace showed of the forms before (PR 25: per-position
+  gathers at 385 GB/s, a bf16 copy of the whole padded K and V view,
+  a copy of the layer's pool slice for B new rows; 113.8 ms a step
+  at 24 rows x 4,096; PR 26, the view read as int8: 48 ms; PR 40,
+  the walk) is in PERF.md.
   ``decode_attention`` is the plain masked form over a contiguous
   float cache that ``models/decode._layer_cached`` calls.
 - Window layers (a query at i sees keys j with 0 <= i - j < W): the
@@ -74,10 +85,13 @@ entry can never corrupt a block that was recycled to another request.
   time is multiplied out to per-head keys and values, as the chunk's
   own rows are).
 """
+import functools
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -440,26 +454,312 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     in ``view_attention``; so ``window`` and ``key_start``, with
     ``block_tables`` then the sub-table of ``window_view``.
 
-    Gather-based: each row's blocks are gathered whole
+    Two forms, chosen by ``walk_engages`` from the platform and
+    these operands. One decode position a row over an int8 pool
+    without a window, on a TPU: ``walk_attention``, which reads each
+    row's own blocks in the pool where they lie, as far as
+    ``lengths[b]`` says (``k_scale``/``v_scale`` then come as
+    ``walk_scales`` lays them out; the caller asks the same rule).
+    Otherwise gather-based: each row's blocks are gathered whole
     (``gather_blocks``) into the contiguous [B, MB * block_size,
-    Hkv, hd] view, codes as codes. Positions past a query's span
-    gather scratch/stale rows and are masked to -inf before the
-    softmax, so rejected-draft garbage and recycled blocks
-    contribute exactly 0. The gather cost scales with the width of
-    the TABLE HANDED IN, not the pool allocation and not each row's
-    length: the decode step hands in the first columns that hold its
-    longest active row (``view_widths``); a kernel that walks each
-    row's own blocks is ROADMAP S2b. The result equals the
-    contiguous-cache
-    path's to float32 rounding (the new row's term is summed after
-    the view's, not in its place), not bit for bit; the engines'
-    token-for-token tests hold both to the same tokens.
+    Hkv, hd] view, codes as codes, at a cost that scales with the
+    width of the TABLE HANDED IN and with every lane, not with each
+    row's length. Under both, positions past a query's span (scratch
+    or stale rows) are masked to -inf before the softmax, so
+    rejected-draft garbage and recycled blocks contribute exactly 0,
+    and the decode step hands in the first columns that hold its
+    longest active row (``view_widths``). The result equals the
+    contiguous-cache path's to float32 rounding (the new row's term
+    is summed after the view's, not in its place), not bit for bit;
+    the engines' token-for-token tests hold all of them to the same
+    tokens.
     """
+    if walk_engages(*k_pool.shape[1:], codes=k_scale is not None,
+                    positions=1 if q.ndim == 3 else q.shape[1],
+                    window=window):
+        with jax.named_scope('decode_attention'):
+            return walk_attention(q, k_pool, v_pool, block_tables,
+                                  lengths, scale, k_scale, v_scale,
+                                  new)
     kd = gather_blocks(k_pool, block_tables)     # [B, S_pad, Hkv, hd]
     vd = gather_blocks(v_pool, block_tables)
     with jax.named_scope('decode_attention'):
         return view_attention(q, kd, vd, lengths, scale, k_scale,
                               v_scale, new, window, key_start)
+
+
+# ---------------------------------------------------------------------
+# The block walk: one decode position a row over an int8 pool, each
+# row's own blocks read from the pool where they lie (Pallas, TPU)
+# ---------------------------------------------------------------------
+
+# The pallas_call's name: what a trace's ``device_ops`` and the
+# compiled text call the kernel.
+WALK_KERNEL_NAME = 'decode_block_walk'
+
+# What the kernel tiles: a 128-lane head, and a block whose rows
+# (positions x KV heads) fill whole (32, 128) int8 tiles.
+_WALK_HEAD_DIM = 128
+_WALK_BLOCK_SIZE = 16
+# The kernel's two products take bf16 operands (codes are exact in
+# bf16) and sum in float32, whatever the process's default matmul
+# precision is set to: Mosaic refuses a higher one for bf16.
+_WALK_PRECISION = jax.lax.Precision.DEFAULT
+
+
+def _on_tpu() -> bool:
+    """Whether the default backend is a TPU (the walk is a Mosaic
+    kernel; every other backend keeps the gathered view)."""
+    return jax.default_backend() == 'tpu'
+
+
+def _interpret() -> bool:
+    """Whether the walk runs in the Pallas interpreter: where a test
+    has made ``_on_tpu`` say yes on another backend."""
+    return jax.default_backend() != 'tpu'
+
+
+def walk_engages(block_size: int, n_kv_heads: int, head_dim: int, *,
+                 codes: bool, positions: int,
+                 window: Optional[int]) -> bool:
+    """Whether a step's attention walks the pool (``walk_attention``)
+    or gathers a view (``view_attention``), from what the code can
+    observe and nothing else: a TPU, an int8 pool (``codes``: scales
+    are present), ONE query position a row (``positions``: the verify
+    window has several), no window (``window_view`` already hands a
+    window layer 257 columns whatever the context), and a block the
+    kernel tiles. The model's step asks it to lay the scales out, the
+    attention to choose its path, the engine to count; it has no
+    other input."""
+    return (_on_tpu() and codes and positions == 1 and window is None
+            and head_dim == _WALK_HEAD_DIM
+            and block_size == _WALK_BLOCK_SIZE
+            and (block_size * n_kv_heads) % 32 == 0)
+
+
+def walk_tile_blocks(n_kv_heads: int) -> int:
+    """Blocks a tile of the walk holds: as many as make 4,096 rows
+    (positions x KV heads) of codes, 512 KB of K and of V. Timed on
+    the v5e on the three serving shapes (PERF.md, PR 40; ms for the
+    attention of one step, rows filled as the cells fill them): 8 KV
+    heads x 4 query heads at 16 / 32 / 64 blocks 5.77 / 5.71 / 6.11,
+    8 x 16 5.52 / 5.32 / 5.40, 16 x 1 (whose block is twice as
+    large) 13.3 / 14.9 / 16.1. A tile is scored and summed whole:
+    folded in chunks of an eighth it took 1.7 times as long (each
+    fold waits for the matrix unit to drain)."""
+    return 4096 // (_WALK_BLOCK_SIZE * n_kv_heads)
+
+
+def walk_scales(scale_pool: jax.Array,
+                block_tables: jax.Array) -> jax.Array:
+    """The int8 pool's scales for rows' walks, laid out as the
+    walk's scores take them: scale_pool [..., num_blocks,
+    block_size, Hkv], block_tables [B, MB] -> float32 [..., B, tiles,
+    tile_blocks * block_size * Hkv], a tile's scales one lane-dense
+    row in the order its codes have (block, position, head); the
+    table's tail up to whole tiles reads the scratch block. Dense
+    over the table handed in, as ``gather_scales`` is: 1/64 of the
+    codes' bytes, gathered by XLA once a step. (The kernel cannot
+    copy a block's scales itself: the v5e's compiler lays the scale
+    pools out with the ENTRY axis minor, so one entry's 128 scales of
+    a block are nowhere contiguous.)"""
+    *lead, nb, bs, hkv = scale_pool.shape
+    b, mb = block_tables.shape
+    tile = walk_tile_blocks(hkv)
+    tiles = -(-mb // tile)
+    tables = jnp.pad(block_tables, ((0, 0), (0, tiles * tile - mb)),
+                     constant_values=SCRATCH_BLOCK)
+    with jax.named_scope('paged_gather'):
+        rows = jnp.take(scale_pool.reshape(*lead, nb, bs * hkv),
+                        tables, axis=len(lead), mode='clip')
+    return rows.reshape(*lead, b, tiles, tile * bs * hkv).astype(
+        jnp.float32)
+
+
+def _walk_kernel(tables_ref, lengths_ref, q_ref, ks_ref, vs_ref,
+                 k_hbm, v_hbm, acc_ref, top_ref, total_ref, k_buf,
+                 v_buf, sems, base_ref, *, scale: float,
+                 table_blocks: int, block_size: int,
+                 n_kv_heads: int):
+    """One row of the walk (grid: the rows, in order). The row's
+    table and length are in SMEM; the code pools stay in HBM and the
+    kernel copies a tile of the row's own blocks at a time into one
+    of two VMEM buffers, the next tile (or the next row's first)
+    in flight while this one is folded. Only blocks that hold a
+    position under the row's length are copied."""
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    hq, hd = q_ref.shape[1:]
+    groups = hq // n_kv_heads
+    tile_blocks = k_buf.shape[1]
+    cols = tile_blocks * block_size * n_kv_heads
+
+    def blocks_of(r):
+        seen = jnp.minimum(lengths_ref[r], table_blocks * block_size)
+        return (seen + block_size - 1) // block_size
+
+    def copies(r, t, slot, act):
+        """Start, or wait for, the copies of tile t of row r."""
+        def one(j, _):
+            blk = tables_ref[r * table_blocks + t * tile_blocks + j]
+            for pool, buf, sem in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                act(pltpu.make_async_copy(
+                    pool.at[blk], buf.at[slot, j], sems.at[sem, slot]))
+        jax.lax.fori_loop(
+            0, jnp.minimum(tile_blocks, blocks_of(r) - t * tile_blocks),
+            one, None)
+
+    def start(r, t, slot):
+        copies(r, t, slot, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _():
+        base_ref[0] = 0
+        start(0, 0, 0)
+
+    length = lengths_ref[b]
+    n_tiles = (blocks_of(b) + tile_blocks - 1) // tile_blocks
+    base = base_ref[0]
+    q = q_ref[0]                                           # [Hq, hd]
+    col = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0)
+    # A column is a (position, KV head) pair of the tile: a query
+    # head reads those of its own KV head.
+    mine = col % n_kv_heads == head // groups
+
+    def fold(t, carry):
+        top, total, acc = carry
+        slot = (base + t) % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            start(b, t + 1, 1 - slot)
+
+        @pl.when((t + 1 == n_tiles) & (b + 1 < rows))
+        def _():
+            start(b + 1, 0, 1 - slot)
+
+        copies(b, t, slot, lambda c: c.wait())
+        logits = jax.lax.dot_general(
+            q, k_buf[slot].reshape(cols, hd).astype(q.dtype),
+            (((1,), (1,)), ((), ())), precision=_WALK_PRECISION,
+            preferred_element_type=jnp.float32)
+        logits = logits * ks_ref[0, pl.ds(t, 1), :] * scale
+        seen = mine & (col < (length - t * tile_blocks * block_size)
+                       * n_kv_heads)
+        logits = jnp.where(seen, logits, _NEG_INF)
+        new_top = jnp.maximum(top, logits.max(-1, keepdims=True))
+        shrink = jnp.exp(top - new_top)
+        p = jnp.exp(logits - new_top)
+        total = total * shrink + p.sum(-1, keepdims=True)
+        p = (p * vs_ref[0, pl.ds(t, 1), :]).astype(q.dtype)
+        acc = acc * shrink + jax.lax.dot_general(
+            p, v_buf[slot].reshape(cols, hd).astype(q.dtype),
+            (((1,), (0,)), ((), ())), precision=_WALK_PRECISION,
+            preferred_element_type=jnp.float32)
+        return new_top, total, acc
+
+    top, total, acc = jax.lax.fori_loop(
+        0, n_tiles, fold,
+        (jnp.full((hq, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((hq, 1), jnp.float32),
+         jnp.zeros((hq, hd), jnp.float32)))
+
+    # A row that reads nothing still hands the next row's first tile
+    # on; nothing of its own is in flight, so the slot stays.
+    @pl.when((n_tiles == 0) & (b + 1 < rows))
+    def _():
+        start(b + 1, 0, base)
+
+    base_ref[0] = (base + n_tiles) % 2
+    acc_ref[0] = acc
+    top_ref[0] = top
+    total_ref[0] = total
+
+
+def walk_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                   block_tables: jax.Array, lengths: jax.Array,
+                   scale: float, k_scale: jax.Array,
+                   v_scale: jax.Array, new) -> jax.Array:
+    """``view_attention``'s numbers for one query position a row over
+    an int8 pool, with no view: q [B, Hq, hd]; k_pool/v_pool
+    [num_blocks, 16, Hkv, 128] int8 codes; block_tables [B, MB];
+    k_scale/v_scale the rows' scales as ``walk_scales`` lays them out
+    (the tile's length is read off them); ``new`` = (k_new, v_new,
+    ks_new, vs_new), this step's own rows, as in ``view_attention``.
+
+    A Pallas TPU kernel (``_walk_kernel``) walks each row's own
+    blocks in tiles with a running maximum and sum in float32, as
+    ``chunk_attention`` folds its tiles; what a row reads is decided
+    by ``lengths[b]``, not by the table's width: a tile wholly past
+    the length is not copied, a row of length 0 reads nothing. The
+    arithmetic is ``view_attention``'s term for term: codes converted
+    inside the two products, the K scale on the float32 scores, the V
+    scale on the probabilities before their one cast to q's type,
+    float32 sums, positions at or past the length masked before the
+    maximum. The product runs over a tile's rows as they lie in the
+    pool, (position, KV head) pairs, and a query head keeps the
+    columns of its own KV head: the codes are never re-laid by head.
+    The own row's term is summed with the walk's here, in XLA."""
+    b, hq, hd = q.shape
+    nb, bs, hkv, _ = k_pool.shape
+    mb = block_tables.shape[1]
+    groups = hq // hkv
+    tiles, cols = k_scale.shape[1:]
+    tile_blocks = cols // (bs * hkv)
+    kernel = functools.partial(
+        _walk_kernel, scale=scale, table_blocks=mb, block_size=bs,
+        n_kv_heads=hkv)
+    row = lambda i, *_: (i, 0, 0)
+    stat = jax.ShapeDtypeStruct((b, hq, 1), jnp.float32)
+    acc, top, total = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, hq, hd), row),
+                pl.BlockSpec((1, tiles, cols), row),
+                pl.BlockSpec((1, tiles, cols), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, hq, hd), row),
+                       pl.BlockSpec((1, hq, 1), row),
+                       pl.BlockSpec((1, hq, 1), row)],
+            scratch_shapes=[
+                pltpu.VMEM((2, tile_blocks, bs * hkv, hd), jnp.int8),
+                pltpu.VMEM((2, tile_blocks, bs * hkv, hd), jnp.int8),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, hq, hd), jnp.float32),
+                   stat, stat],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name=WALK_KERNEL_NAME,
+    )(block_tables.reshape(-1), lengths.astype(jnp.int32), q, k_scale,
+      v_scale, k_pool.reshape(nb, bs * hkv, hd),
+      v_pool.reshape(nb, bs * hkv, hd))
+    # This step's own row, summed with the walk's result as
+    # ``view_attention`` sums it with the view's.
+    k_new, v_new, ks_new, vs_new = new
+    qg = q.reshape(b, hkv, groups, hd)
+    own = jnp.einsum('bhgd,bhd->bhg', qg, k_new.astype(q.dtype),
+                     preferred_element_type=jnp.float32)
+    with jax.named_scope('kv_dequant'):
+        own = own * ks_new.astype(jnp.float32)[:, :, None]
+    own = (own * scale).reshape(b, hq, 1)
+    new_top = jnp.maximum(top, own)
+    shrink = jnp.exp(top - new_top)
+    p_own = jnp.exp(own - new_top)
+    total = total * shrink + p_own
+    with jax.named_scope('kv_dequant'):
+        p_own = (p_own / total).reshape(b, hkv, groups) * \
+            vs_new.astype(jnp.float32)[:, :, None]
+    out = acc * (shrink / total) + jnp.einsum(
+        'bhg,bhd->bhgd', p_own.astype(q.dtype), v_new.astype(q.dtype),
+        preferred_element_type=jnp.float32).reshape(b, hq, hd)
+    return out.astype(q.dtype)
 
 
 def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,

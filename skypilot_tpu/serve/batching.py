@@ -595,6 +595,20 @@ def _engine_metrics():
             'Block-table columns those dispatches had (max_seq / '
             'block_size each); decode_view_blocks_total over it is '
             'the share of the table a dispatch read.'),
+        'decode_walk_blocks': reg.counter(
+            'skytpu_batch_decode_walk_blocks_total',
+            'Blocks a KV entry\'s attention read on decode '
+            'dispatches whose program walks each row\'s own blocks '
+            'in the pool (ops/decode_attention.walk_engages): the '
+            'sum over the ACTIVE rows of ceil((slot_len + steps) / '
+            'block_size). A dispatch that gathers a view moves '
+            'neither this nor the next.'),
+        'decode_walk_lane_blocks': reg.counter(
+            'skytpu_batch_decode_walk_lane_blocks_total',
+            'Blocks the gathered view of those dispatches would '
+            'have held an entry: slots x the dispatch\'s width. '
+            'decode_walk_blocks_total over it is the share of the '
+            'dense view the walk still reads.'),
     }
 
 
@@ -986,6 +1000,12 @@ class BatchingEngine:
         # No row is active, so every write lands in scratch and the
         # outputs are discarded.
         self._view_widths = da.view_widths(self.max_blocks_per_req)
+        # Whether those programs walk each row's own blocks in the
+        # pool (the rule the step itself asks): what the two
+        # decode_walk counters are counted under.
+        self._walks = da.walk_engages(
+            self.block_size, config.n_kv_heads, config.head_dim,
+            codes=kv_int8, positions=1, window=None)
         t_warm = time.perf_counter()
         for width in self._view_widths:
             _, self.caches, *_ = self._step_fn(
@@ -2509,6 +2529,15 @@ class BatchingEngine:
         self.tokens = toks[:, -1]
         if routed:
             self._routed_pending.append((routed[0], self.slots, n))
+        if self._walks:
+            # What the walk reads: each active row's own blocks up
+            # to the dispatch's last step, where the view held
+            # every lane at the dispatch's width.
+            self._metrics['decode_walk_blocks'].inc(sum(
+                -(-(self.slot_len[i] + n) // self.block_size)
+                for i in range(self.slots) if is_active[i]))
+            self._metrics['decode_walk_lane_blocks'].inc(
+                self.slots * view)
         if self.config.kv_lora_rank is not None:
             # Step k of a row at length L attends L + k cached
             # positions and its own.
