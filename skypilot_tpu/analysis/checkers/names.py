@@ -32,6 +32,10 @@ RES_DOC = 'resilience.md'
 _SPAN_FUNCS_SUFFIX = ('.span', '.record_span', '.emit_span', '._span',
                       '.phase')
 _SPAN_FUNCS_BARE = ('record_span', 'emit_span')
+# ``jax_runtime.stage('engine.build')`` (context manager or
+# decorator) emits the span ``startup.engine.build``.
+_STAGE_FUNC_SUFFIX = 'jax_runtime.stage'
+_STAGE_SPAN_PREFIX = 'startup.'
 _SPAN_NAME_RE = re.compile(r'[a-z0-9_.]+\Z')
 _METRIC_NAME_RE = re.compile(r'skytpu_[a-z0-9_]+\Z')
 _METRIC_KINDS = ('counter', 'gauge', 'histogram')
@@ -46,6 +50,11 @@ _FAULT_SITE_RE = re.compile(r'[a-z]+\.[a-z_]+\Z')
 def _span_literal(ctx: 'core.FileContext',
                   call: ast.Call) -> Optional[Tuple[str, ast.AST]]:
     qual = ctx.call_name(call) or ''
+    if qual.endswith(_STAGE_FUNC_SUFFIX):
+        val = ctx.string_value(call.args[0]) if call.args else None
+        if val and _SPAN_NAME_RE.match(val):
+            return _STAGE_SPAN_PREFIX + val, call.args[0]
+        return None
     is_span_call = (any(qual.endswith(s) for s in _SPAN_FUNCS_SUFFIX)
                     or qual in _SPAN_FUNCS_BARE)
     if not is_span_call:

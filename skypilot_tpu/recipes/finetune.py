@@ -18,6 +18,7 @@ the v6e README):
         --lora-rank 16 --checkpoint-dir /checkpoints
 """
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -144,20 +145,34 @@ def _flash_kernel_census(step_fn, state, batch) -> dict:
 
 
 def main():
+    # The start-up log's stages (docs/observability.md, "Start-up
+    # and compilation") are entered on this stack; ``_train`` closes
+    # it after the first step, and a start-up that raises closes it
+    # here.
+    with contextlib.ExitStack() as starting:
+        _train(starting)
+
+
+def _train(starting: contextlib.ExitStack):
     args = parse_args()
 
     from skypilot_tpu.utils import jax_runtime
-    jax_runtime.configure_compile_cache()
-    from skypilot_tpu import callbacks
-    from skypilot_tpu.parallel import distributed
-    distributed.initialize()  # no-op single-host
 
-    import jax
-    import jax.numpy as jnp
+    # ``train.start`` stays open until the first step, the one that
+    # compiles, has returned.
+    starting.enter_context(jax_runtime.stage('train.start'))
+    with jax_runtime.stage('train.start.backend'):
+        jax_runtime.configure_compile_cache()
+        from skypilot_tpu import callbacks
+        from skypilot_tpu.parallel import distributed
+        distributed.initialize()  # no-op single-host
 
+        import jax
+        import jax.numpy as jnp
+
+        device = jax_runtime.device_facts()
     if jax.process_index() == 0:
-        print(jax_runtime.device_line(jax_runtime.device_facts()),
-              flush=True)
+        print(jax_runtime.device_line(device), flush=True)
 
     from skypilot_tpu.models import llama
     from skypilot_tpu.parallel import (MeshConfig, auto_mesh_config,
@@ -214,41 +229,42 @@ def main():
             if args.elastic_scale_lr:
                 args.lr = args.lr * n_now / n_design
 
-    param_dtype = jnp.bfloat16 if args.param_dtype == 'bf16' \
-        else jnp.float32
-    optimizer = default_optimizer(learning_rate=args.lr)
-    state, shardings = init_train_state(
-        config, mesh, jax.random.PRNGKey(0), optimizer=optimizer,
-        param_dtype=param_dtype,
-        lora_rank=None if args.full_ft else args.lora_rank)
-    step_fn = build_train_step(config, mesh, shardings,
-                               optimizer=optimizer,
-                               pipeline_microbatches=args.microbatches)
-    # Step-time / tokens-per-sec / goodput buckets / MFU land in the
-    # process metrics registry and are published to the host agent's
-    # /metrics (textfile bridge) so the driver scrapes them
-    # cluster-wide. The accelerator for the MFU peak arrives via the
-    # SKYTPU_ACCELERATOR env stamp (runtime/env_contract.py).
-    step_fn = instrument_train_step(
-        step_fn, tokens_per_step=args.batch * args.seq,
-        model_config=config, full_finetune=args.full_ft)
-    from skypilot_tpu.metrics import publish as publish_lib
-    publisher = publish_lib.start_publisher('train')
+    with jax_runtime.stage('train.start.state'):
+        param_dtype = jnp.bfloat16 if args.param_dtype == 'bf16' \
+            else jnp.float32
+        optimizer = default_optimizer(learning_rate=args.lr)
+        state, shardings = init_train_state(
+            config, mesh, jax.random.PRNGKey(0), optimizer=optimizer,
+            param_dtype=param_dtype,
+            lora_rank=None if args.full_ft else args.lora_rank)
+        step_fn = build_train_step(config, mesh, shardings,
+                                   optimizer=optimizer,
+                                   pipeline_microbatches=args.microbatches)
+        # Step-time / tokens-per-sec / goodput buckets / MFU land in the
+        # process metrics registry and are published to the host agent's
+        # /metrics (textfile bridge) so the driver scrapes them
+        # cluster-wide. The accelerator for the MFU peak arrives via the
+        # SKYTPU_ACCELERATOR env stamp (runtime/env_contract.py).
+        step_fn = instrument_train_step(
+            step_fn, tokens_per_step=args.batch * args.seq,
+            model_config=config, full_finetune=args.full_ft)
+        from skypilot_tpu.metrics import publish as publish_lib
+        publisher = publish_lib.start_publisher('train')
 
-    ckpt = None
-    start_step = 0
-    if args.checkpoint_dir:
-        from skypilot_tpu.data.checkpoint import CheckpointManager
-        ckpt = CheckpointManager(
-            args.checkpoint_dir,
-            save_interval_steps=args.checkpoint_interval)
-        state, start_step = ckpt.restore_or(state)
-        if jax.process_index() == 0 and start_step:
-            info = ckpt.last_restore or {}
-            reshard = ' (resharded onto the current mesh)' \
-                if info.get('resharded') else ''
-            print(f'resumed from checkpoint at step {start_step}'
-                  f'{reshard}')
+        ckpt = None
+        start_step = 0
+        if args.checkpoint_dir:
+            from skypilot_tpu.data.checkpoint import CheckpointManager
+            ckpt = CheckpointManager(
+                args.checkpoint_dir,
+                save_interval_steps=args.checkpoint_interval)
+            state, start_step = ckpt.restore_or(state)
+            if jax.process_index() == 0 and start_step:
+                info = ckpt.last_restore or {}
+                reshard = ' (resharded onto the current mesh)' \
+                    if info.get('resharded') else ''
+                print(f'resumed from checkpoint at step {start_step}'
+                      f'{reshard}')
     # Recovery relaunch: price the dead time since the controller
     # observed the failure into the goodput `recovery_stall` bucket
     # (no-op outside a managed-job recovery).
@@ -260,6 +276,7 @@ def main():
     batches = data_iterator(args, config.vocab_size, rng)
     tokens_per_step = args.batch * args.seq
     t_start = time.time()
+    starting.enter_context(jax_runtime.stage('train.start.first_step'))
     for step in range(start_step, args.steps):
         batch_np = next(batches)
         batch = {'tokens': jnp.asarray(batch_np)}
@@ -276,6 +293,11 @@ def main():
         state, metrics = step_fn(state, batch)
         jax.block_until_ready(metrics['loss'])
         callbacks.step_end()
+        if step == start_step:
+            # From here a lowering lands inside a step: counted, and
+            # logged with the program's name.
+            starting.close()
+            jax_runtime.mark_ready()
         if ckpt is not None:
             ckpt.maybe_save(step, state)
         if jax.process_index() == 0 and \
@@ -292,8 +314,11 @@ def main():
         ckpt.wait()
         ckpt.close()
     publisher.close()
+    starting.close()  # still open after a run of no steps
     if jax.process_index() == 0:
-        print(f'runtime {json.dumps(jax_runtime.runtime_facts())}')
+        runtime = dict(jax_runtime.runtime_facts(),
+                       startup=jax_runtime.startup_seconds())
+        print(f'runtime {json.dumps(runtime)}')
         print('finetune done.')
 
 

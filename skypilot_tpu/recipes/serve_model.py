@@ -11,6 +11,7 @@ Runs under ``x serve up`` — the service spec's port arrives via
     python -m skypilot_tpu.recipes.serve_model --model tiny
 """
 import argparse
+import contextlib
 import json
 import os
 import threading
@@ -20,6 +21,14 @@ from skypilot_tpu import trace as trace_lib
 
 
 def main():
+    # The start-up log's stages (docs/observability.md, "Start-up
+    # and compilation") are entered on this stack; ``_serve`` closes
+    # it at ``ready``, and a start-up that raises closes it here.
+    with contextlib.ExitStack() as starting:
+        _serve(starting)
+
+
+def _serve(starting: contextlib.ExitStack):
     parser = argparse.ArgumentParser()
     parser.add_argument(
         '--model', default='tiny',
@@ -221,15 +230,19 @@ def main():
                      'unsharded and would replicate per device')
 
     from skypilot_tpu.utils import jax_runtime
-    jax_runtime.configure_compile_cache()
 
-    import jax
-    import jax.numpy as jnp
+    # ``replica.start`` stays open to ``ready``.
+    starting.enter_context(jax_runtime.stage('replica.start'))
+    with jax_runtime.stage('replica.start.backend'):
+        jax_runtime.configure_compile_cache()
 
-    from skypilot_tpu import exceptions
-    from skypilot_tpu.models import decode, llama
+        import jax
+        import jax.numpy as jnp
 
-    device = jax_runtime.device_facts()
+        from skypilot_tpu import exceptions
+        from skypilot_tpu.models import decode, llama
+
+        device = jax_runtime.device_facts()
     print(jax_runtime.device_line(device), flush=True)
     if args.experts_held:
         try:
@@ -258,77 +271,78 @@ def main():
             f'{config.kv_lora_rank}, residual streams '
             f'{config.hc_mult}) is a stack only the batching '
             f'engine implements: pass --slots N')
-    ckpt_params = None
-    if args.checkpoint_dir:
-        from skypilot_tpu.data.checkpoint import CheckpointManager
-        ckpt = CheckpointManager(args.checkpoint_dir,
-                                 use_task_namespace=False)
-        raw = ckpt.restore_latest_raw(keys=('params', 'lora'))
-        if raw is None:
-            # Name the RESOLVED directory and list what is actually
-            # there: finetune checkpoints are task-id namespaced
-            # (data/checkpoint.task_checkpoint_dir), so the committed
-            # steps usually live one subdirectory below the
-            # --checkpoint-dir the user passed.
-            resolved = ckpt.path
-            try:
-                entries = sorted(os.listdir(resolved))
-            except OSError:
-                entries = []
-            listing = ', '.join(entries[:20]) if entries else '(empty)'
-            raise SystemExit(
-                f'no committed checkpoint found in {resolved} '
-                f'(from --checkpoint-dir {args.checkpoint_dir}); the '
-                f'directory contains: {listing}. Finetune runs '
-                'namespace checkpoints by task id — point '
-                '--checkpoint-dir at the task-id subdirectory that '
-                'holds the step_* dirs.')
-        ckpt_params = raw['params']
-        if raw.get('lora') is not None:
-            # Serve merged weights — no adapter math in the hot
-            # loop. Merged ON HOST: the tp/int8 paths below exist
-            # precisely because the full tree must not land on one
-            # device.
-            from skypilot_tpu.parallel import lora as lora_lib
-            ckpt_params = lora_lib.merge_lora_host(ckpt_params,
-                                                   raw['lora'])
-        # Serve at the compute dtype: a training checkpoint is
-        # usually fp32 masters — serving those doubles weight HBM.
-        import numpy as np
-        ckpt_params = jax.tree.map(
-            lambda x: np.asarray(x).astype(config.dtype), ckpt_params)
-    cache_sh = None
-    if args.tp > 1:
-        from skypilot_tpu.parallel import auto_mesh_config, make_mesh
-        mesh = make_mesh(auto_mesh_config(tp=args.tp))
-        # Single-request replica: cache batch stays replicated.
-        param_sh, cache_sh = decode.decode_shardings(
-            config, mesh, shard_batch=False)
-        if ckpt_params is not None:
-            # Host->device transfer lands directly sharded.
-            params = jax.device_put(ckpt_params, param_sh)
+    with jax_runtime.stage('replica.start.weights'):
+        ckpt_params = None
+        if args.checkpoint_dir:
+            from skypilot_tpu.data.checkpoint import CheckpointManager
+            ckpt = CheckpointManager(args.checkpoint_dir,
+                                     use_task_namespace=False)
+            raw = ckpt.restore_latest_raw(keys=('params', 'lora'))
+            if raw is None:
+                # Name the RESOLVED directory and list what is actually
+                # there: finetune checkpoints are task-id namespaced
+                # (data/checkpoint.task_checkpoint_dir), so the committed
+                # steps usually live one subdirectory below the
+                # --checkpoint-dir the user passed.
+                resolved = ckpt.path
+                try:
+                    entries = sorted(os.listdir(resolved))
+                except OSError:
+                    entries = []
+                listing = ', '.join(entries[:20]) if entries else '(empty)'
+                raise SystemExit(
+                    f'no committed checkpoint found in {resolved} '
+                    f'(from --checkpoint-dir {args.checkpoint_dir}); the '
+                    f'directory contains: {listing}. Finetune runs '
+                    'namespace checkpoints by task id — point '
+                    '--checkpoint-dir at the task-id subdirectory that '
+                    'holds the step_* dirs.')
+            ckpt_params = raw['params']
+            if raw.get('lora') is not None:
+                # Serve merged weights — no adapter math in the hot
+                # loop. Merged ON HOST: the tp/int8 paths below exist
+                # precisely because the full tree must not land on one
+                # device.
+                from skypilot_tpu.parallel import lora as lora_lib
+                ckpt_params = lora_lib.merge_lora_host(ckpt_params,
+                                                       raw['lora'])
+            # Serve at the compute dtype: a training checkpoint is
+            # usually fp32 masters — serving those doubles weight HBM.
+            import numpy as np
+            ckpt_params = jax.tree.map(
+                lambda x: np.asarray(x).astype(config.dtype), ckpt_params)
+        cache_sh = None
+        if args.tp > 1:
+            from skypilot_tpu.parallel import auto_mesh_config, make_mesh
+            mesh = make_mesh(auto_mesh_config(tp=args.tp))
+            # Single-request replica: cache batch stays replicated.
+            param_sh, cache_sh = decode.decode_shardings(
+                config, mesh, shard_batch=False)
+            if ckpt_params is not None:
+                # Host->device transfer lands directly sharded.
+                params = jax.device_put(ckpt_params, param_sh)
+            else:
+                # Init DIRECTLY sharded (out_shardings on the jitted
+                # init) — materializing the full pytree on one device
+                # first would OOM for exactly the models --tp exists for.
+                params = jax.jit(
+                    lambda: llama.init_params(config,
+                                              jax.random.PRNGKey(0)),
+                    out_shardings=param_sh)()
+        elif args.quant == 'int8':
+            from skypilot_tpu.models import quant
+            if ckpt_params is not None:
+                # Leaf-streamed: each (host) leaf transfers + quantizes
+                # alone, so the bf16 tree never fully sits in HBM.
+                params = quant.quantize_params_streamed(ckpt_params,
+                                                        config)
+            else:
+                params = quant.init_quantized(config,
+                                              jax.random.PRNGKey(0))
+        elif ckpt_params is not None:
+            params = jax.tree.map(jnp.asarray, ckpt_params)
         else:
-            # Init DIRECTLY sharded (out_shardings on the jitted
-            # init) — materializing the full pytree on one device
-            # first would OOM for exactly the models --tp exists for.
-            params = jax.jit(
-                lambda: llama.init_params(config,
-                                          jax.random.PRNGKey(0)),
-                out_shardings=param_sh)()
-    elif args.quant == 'int8':
-        from skypilot_tpu.models import quant
-        if ckpt_params is not None:
-            # Leaf-streamed: each (host) leaf transfers + quantizes
-            # alone, so the bf16 tree never fully sits in HBM.
-            params = quant.quantize_params_streamed(ckpt_params,
-                                                    config)
-        else:
-            params = quant.init_quantized(config,
-                                          jax.random.PRNGKey(0))
-    elif ckpt_params is not None:
-        params = jax.tree.map(jnp.asarray, ckpt_params)
-    else:
-        params = llama.init_params(config, jax.random.PRNGKey(0))
+            params = llama.init_params(config, jax.random.PRNGKey(0))
 
     lock = threading.Lock()
     engine = None
@@ -356,24 +370,25 @@ def main():
                     f'--grammar-vocab {args.grammar_vocab} must hold '
                     f'a JSON list (token id -> string or null), got '
                     f'{type(grammar_vocab).__name__}')
-        engine = BatchingEngine(
-            params, config, slots=args.slots,
-            max_seq=args.max_seq or None, kv_int8=args.kv_int8,
-            block_size=args.block_size,
-            num_blocks=args.num_blocks or None,
-            window_num_blocks=args.window_num_blocks or None,
-            max_num_batched_tokens=args.max_batched_tokens,
-            prefix_caching=args.prefix_caching == 'on',
-            speculative=args.speculative == 'on',
-            draft_k=args.draft_k,
-            max_queued_requests=args.max_queued_requests or None,
-            max_queued_tokens=args.max_queued_tokens or None,
-            default_timeout_s=args.default_timeout_s or None,
-            adapter_registry=adapter_registry,
-            adapter_capacity=args.adapter_capacity,
-            adapter_preload=preload,
-            sampling=args.sampling == 'on',
-            grammar_vocab=grammar_vocab)
+        with jax_runtime.stage('replica.start.engine'):
+            engine = BatchingEngine(
+                params, config, slots=args.slots,
+                max_seq=args.max_seq or None, kv_int8=args.kv_int8,
+                block_size=args.block_size,
+                num_blocks=args.num_blocks or None,
+                window_num_blocks=args.window_num_blocks or None,
+                max_num_batched_tokens=args.max_batched_tokens,
+                prefix_caching=args.prefix_caching == 'on',
+                speculative=args.speculative == 'on',
+                draft_k=args.draft_k,
+                max_queued_requests=args.max_queued_requests or None,
+                max_queued_tokens=args.max_queued_tokens or None,
+                default_timeout_s=args.default_timeout_s or None,
+                adapter_registry=adapter_registry,
+                adapter_capacity=args.adapter_capacity,
+                adapter_preload=preload,
+                sampling=args.sampling == 'on',
+                grammar_vocab=grammar_vocab)
 
     # Publish this replica's registry (batching queue/TTFT/KV-cache
     # gauges + device HBM) to the host agent's /metrics via the
@@ -517,12 +532,14 @@ def main():
 
         def do_GET(self):  # noqa: N802
             if self.path == '/':
-                # Readiness, plus what this replica runs on and what
-                # it has compiled and allocated so far — a probe
-                # reply alone shows whether it is the chip.
+                # Readiness, plus what this replica runs on, what
+                # it has compiled and allocated so far, and where
+                # its start-up went — a probe reply alone shows
+                # whether it is the chip.
                 self._json({'status': 'ok', 'model': args.model,
                             'device': device,
-                            'runtime': jax_runtime.runtime_facts()})
+                            'runtime': jax_runtime.runtime_facts(),
+                            'startup': jax_runtime.startup_seconds()})
             else:
                 self._json({'error': 'not found'}, 404)
 
@@ -796,13 +813,18 @@ def main():
     # engine-gated: sampled decode only exists on the engine, and its
     # sampled executable is a SECOND compile (the greedy one stays
     # byte-identical to the pre-sampling engine).
-    generate([1, 2, 3], 2)
-    if engine is not None and engine.sampling:
-        req = engine.submit_request([1, 2, 3], 2, temperature=1.0,
-                                    top_p=0.9, seed=0)
-        while req.out.get() is not None:
-            pass
+    with jax_runtime.stage('replica.start.warm'):
+        generate([1, 2, 3], 2)
+        if engine is not None and engine.sampling:
+            req = engine.submit_request([1, 2, 3], 2, temperature=1.0,
+                                        top_p=0.9, seed=0)
+            while req.out.get() is not None:
+                pass
     server = ThreadingHTTPServer(('0.0.0.0', args.port), Handler)
+    starting.close()
+    # From here a lowering lands inside a request: counted, and
+    # logged with the program's name.
+    jax_runtime.mark_ready()
     print(f'serve_model ready on :{args.port} (model {args.model}, '
           f'platform={device["platform"]} '
           f'device_kind={device["device_kind"]!r} '

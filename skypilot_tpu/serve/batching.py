@@ -83,6 +83,7 @@ from skypilot_tpu.resilience import faults as faults_lib
 from skypilot_tpu.serve import kv_pool as kv_pool_lib
 from skypilot_tpu.serve import prefix_hash
 from skypilot_tpu.serve.sampling import grammar as grammar_lib
+from skypilot_tpu.utils import jax_runtime
 
 logger = tpu_logging.init_logger(__name__)
 
@@ -720,6 +721,7 @@ class BatchingEngine:
       grammars; must match the model vocab size.
     """
 
+    @jax_runtime.stage('engine.build')
     def __init__(self, params: Params, config: llama.LlamaConfig,
                  slots: int = 8, max_seq: Optional[int] = None,
                  steps_per_dispatch: int = 8,
@@ -839,39 +841,40 @@ class BatchingEngine:
                   // block_size))
             if window_num_blocks is None:
                 window_num_blocks = slots * self._window_cap + 1
-        self.pool = kv_pool_lib.KVBlockPool(
-            config, num_blocks, block_size, kv_int8=kv_int8,
-            window_num_blocks=window_num_blocks if two_kinds else None)
-        self.wpool = self.pool.groups['window'] if two_kinds else None
-        # The engine owns the device arrays (they are donated through
-        # every jitted step); the pool keeps only the allocator. One
-        # 4-tuple, or with two groups a dict of them by kind, as the
-        # paged bodies take them (``models/decode._by_kind``).
-        if two_kinds:
-            self.caches = {kind: g.caches
-                           for kind, g in self.pool.groups.items()}
-        else:
-            self.caches = self.pool.caches
-        for group in self.pool.groups.values():
-            group.caches = None
-        # The block tables live on the host and are written in place
-        # (``_set_table_row``); a device program is handed a copy
-        # (``_tables``). A row's update is then a numpy assignment,
-        # where an eager ``.at[row].set`` of a device array cost a
-        # list conversion and a dispatch of its own, many a pass.
-        self.block_tables = np.full(
-            (slots, self.max_blocks_per_req),
-            kv_pool_lib.SCRATCH_BLOCK, np.int32)
-        # The window group's table: the same logical columns, of
-        # which only those a row's window still touches hold a block
-        # (the rest read scratch); ``slot_wblocks`` is its host side,
-        # column -> block.
-        self.wblock_tables = (self.block_tables.copy() if two_kinds
-                              else None)
-        self.slot_wblocks: List[Dict[int, int]] = [
-            {} for _ in range(slots)]
-        self.pos = jnp.zeros((slots,), jnp.int32)
-        self.tokens = jnp.zeros((slots,), jnp.int32)
+        with jax_runtime.stage('engine.build.pool'):
+            self.pool = kv_pool_lib.KVBlockPool(
+                config, num_blocks, block_size, kv_int8=kv_int8,
+                window_num_blocks=window_num_blocks if two_kinds else None)
+            self.wpool = self.pool.groups['window'] if two_kinds else None
+            # The engine owns the device arrays (they are donated through
+            # every jitted step); the pool keeps only the allocator. One
+            # 4-tuple, or with two groups a dict of them by kind, as the
+            # paged bodies take them (``models/decode._by_kind``).
+            if two_kinds:
+                self.caches = {kind: g.caches
+                               for kind, g in self.pool.groups.items()}
+            else:
+                self.caches = self.pool.caches
+            for group in self.pool.groups.values():
+                group.caches = None
+            # The block tables live on the host and are written in place
+            # (``_set_table_row``); a device program is handed a copy
+            # (``_tables``). A row's update is then a numpy assignment,
+            # where an eager ``.at[row].set`` of a device array cost a
+            # list conversion and a dispatch of its own, many a pass.
+            self.block_tables = np.full(
+                (slots, self.max_blocks_per_req),
+                kv_pool_lib.SCRATCH_BLOCK, np.int32)
+            # The window group's table: the same logical columns, of
+            # which only those a row's window still touches hold a block
+            # (the rest read scratch); ``slot_wblocks`` is its host side,
+            # column -> block.
+            self.wblock_tables = (self.block_tables.copy() if two_kinds
+                                  else None)
+            self.slot_wblocks: List[Dict[int, int]] = [
+                {} for _ in range(slots)]
+            self.pos = jnp.zeros((slots,), jnp.int32)
+            self.tokens = jnp.zeros((slots,), jnp.int32)
         # Host-side per-row bookkeeping.
         self.slot_req: List[Optional[_Request]] = [None] * slots
         self.slot_left = [0] * slots
@@ -975,10 +978,11 @@ class BatchingEngine:
             # no-op) so the FIRST partial-block hit in production
             # does not pay the compile inside a request's TTFT. (With
             # a window group a hit is whole blocks only: no copy.)
-            scratch = jnp.asarray(kv_pool_lib.SCRATCH_BLOCK,
-                                  jnp.int32)
-            self.caches = self._copy_fn(self.caches, scratch,
-                                        scratch)
+            with jax_runtime.stage('engine.build.prewarm_copy'):
+                scratch = jnp.asarray(kv_pool_lib.SCRATCH_BLOCK,
+                                      jnp.int32)
+                self.caches = self._copy_fn(self.caches, scratch,
+                                            scratch)
         if self.speculative:
             # Prewarm the verify executable (n_real 0 everywhere:
             # every write lands in scratch, outputs discarded) — the
@@ -986,13 +990,14 @@ class BatchingEngine:
             # request's decode window (same rationale as the COW
             # prewarm above; the verify width is static, so this is
             # THE executable).
-            *_, self.caches = self._verify_fn(
-                self.params,
-                jnp.zeros((slots, self.draft_k + 1), jnp.int32),
-                self.caches, self._tables(), self.pos,
-                jnp.zeros((slots,), jnp.int32), self.config,
-                self.draft_k + 1, self.block_size,
-                *self._adapter_args())
+            with jax_runtime.stage('engine.build.prewarm_verify'):
+                *_, self.caches = self._verify_fn(
+                    self.params,
+                    jnp.zeros((slots, self.draft_k + 1), jnp.int32),
+                    self.caches, self._tables(), self.pos,
+                    jnp.zeros((slots,), jnp.int32), self.config,
+                    self.draft_k + 1, self.block_size,
+                    *self._adapter_args())
         # Prewarm the decode executable at every width of the table
         # a dispatch may read (``_view_blocks``), through the call
         # the live dispatch makes: a width first met inside a
@@ -1006,19 +1011,22 @@ class BatchingEngine:
         self._walks = da.walk_engages(
             self.block_size, config.n_kv_heads, config.head_dim,
             codes=kv_int8, positions=1, window=None)
-        t_warm = time.perf_counter()
-        for width in self._view_widths:
-            _, self.caches, *_ = self._step_fn(
-                self.params, self.tokens, self.caches,
-                self._tables(), self.pos,
-                jnp.zeros((slots,), bool), self.config, self.steps,
-                self.block_size, *self._adapter_args(),
-                sampling=None, view_blocks=width)
-        jax.block_until_ready(self.caches)
+        with jax_runtime.stage('engine.build.prewarm_decode') as warm:
+            for width in self._view_widths:
+                with jax_runtime.stage(
+                        'engine.build.prewarm_decode.width',
+                        width=width):
+                    _, self.caches, *_ = self._step_fn(
+                        self.params, self.tokens, self.caches,
+                        self._tables(), self.pos,
+                        jnp.zeros((slots,), bool), self.config,
+                        self.steps, self.block_size,
+                        *self._adapter_args(),
+                        sampling=None, view_blocks=width)
+            jax.block_until_ready(self.caches)
         logger.info('Decode step prewarmed at %d table widths %s '
                     '(blocks) in %.1f s.', len(self._view_widths),
-                    list(self._view_widths),
-                    time.perf_counter() - t_warm)
+                    list(self._view_widths), warm.seconds)
         self._metrics = _engine_metrics()
         # Lazily created on first real traffic (MFU-gauge precedent):
         # an engine with caching off must not export a fake 0 ratio.
