@@ -111,15 +111,12 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _dequant_kv(q: jax.Array, scale: Optional[jax.Array],
                 dtype) -> jax.Array:
     """Dequantise a [B, S, Hkv] -scaled int8 view to ``dtype`` for
-    the chunk paths (prefill chunk, multi-token append), whose
-    attention takes float K/V. NOT fused into the consumer on the
-    v5e: PR 25's chip trace shows the decode step's use of this
-    (``convert_multiply_fusion bf16[24,4096,8,128]``) materialised,
-    a quarter of that program's time, so since PR 26 the decode and
-    verify steps read int8 views through
-    ``ops.decode_attention.view_attention`` instead; the prefill
-    chunk's one-row view (4 MB a layer) still comes through here
-    (ROADMAP S5)."""
+    the contiguous cache's multi-token append (``_layer_cached``),
+    whose attention takes float K/V. NOT fused into the consumer on
+    the v5e (PR 25's chip trace: a quarter of the decode program's
+    time), so the paged steps read codes as codes: the decode and
+    verify steps through ``da.view_attention`` since PR 26, the
+    prefill chunk through ``da.chunk_attention`` since PR 42."""
     if scale is None:
         return q
     with jax.named_scope('kv_dequant'):
@@ -996,22 +993,24 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     cache 4-tuple (k, v, k_scale, v_scale) with k/v
     [entries, num_blocks, block_size, Hkv, hd]; ``block_row`` [MB]
     int32 is THIS request's block table. ``start``/``real_len`` are
-    traced
-    scalars — one executable serves every chunk of every prompt at a
-    given bucket T.
+    traced scalars — one executable serves every chunk of every
+    prompt at a given bucket T.
 
-    Attention per layer: the chunk's rows are written first, then the
-    row's logical view is gathered from the pool and attended with
-    the causal window mask (``_masked_attention`` with
-    q_pos=start, kv_len=start+real_len) — chunk c sees every earlier
-    chunk's keys plus itself causally, so chunked prefill is
-    numerically the plain prefill. The same contract carries the
-    engine's PREFIX-CACHE suffix prefill: when admission reuses
-    cached blocks for the leading ``start`` tokens (the block table
-    points at pinned shared blocks), the first chunk simply begins
-    at that offset and the gather reads the cached K/V exactly as if
-    this request had prefilled it — no cache-aware branch exists in
-    the model code at all.
+    Attention per layer: the chunk's queries walk the request's OWN
+    blocks, a tile of cached keys [0, start) at a time, then the
+    chunk's own exact rows, with a running maximum and sum
+    (``da.chunk_attention``): chunk c sees every earlier chunk's keys
+    plus itself causally, so chunked prefill is numerically the plain
+    prefill, and what a chunk reads follows ``start``, not
+    ``max_seq`` (until PR 42 every chunk scored the row's whole
+    padded view). No in-layer pool write: the chunk's rows reach the
+    pool by ONE merged scatter after the layer scan (``kv_write``).
+    The same contract carries the engine's PREFIX-CACHE suffix
+    prefill: when admission reuses cached blocks for the leading
+    ``start`` tokens (the table points at pinned shared blocks), the
+    first chunk simply begins at that offset and the walk reads the
+    cached K/V as if this request had prefilled it — no cache-aware
+    branch exists in the model code at all.
 
     Returns (logits [1, vocab] f32 at the chunk's LAST REAL position,
     new pools, routed). Only the final chunk's logits are meaningful
@@ -1021,41 +1020,42 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     (``_routed_sums``, one step): the (token, expert) pairs this
     chunk, padding included, routed to each expert held here.
 
-    A configuration with window layers (``config.sliding_window``)
-    hands ``pools`` and ``block_row`` as dicts by kind of layer
-    (``_by_kind``) and attends over key tiles (``da.chunk_attention``:
-    scopes ``window_attention``, ``global_attention``) with no
-    in-layer write; a latent configuration (``config.kv_lora_rank``)
-    hands one pool tuple ``(rows, None, None, None)`` and attends
-    EXPANDED over key tiles (``latent_layer`` below:
-    ``da.latent_chunk_attention``, scope ``mla_expanded_attention``),
-    its carry the token's residual streams (``streams``); every
-    other configuration runs the gathered form below, as it did.
+    Two attention forms, by the configuration's kind of cache. Keys
+    and values in a pool (float or int8): tiles of pool keys
+    (``layer`` below; scope ``prefill_attention``); with window
+    layers (``config.sliding_window``) ``pools`` and ``block_row``
+    come as dicts by kind of layer (``_by_kind``) and a window
+    layer's walk is bounded by the window (scopes
+    ``window_attention``, ``global_attention``). A latent
+    configuration (``config.kv_lora_rank``) hands one pool tuple
+    ``(rows, None, None, None)`` and attends EXPANDED over tiles of
+    latent rows (``latent_layer``: ``da.latent_chunk_attention``,
+    scope ``mla_expanded_attention``), its carry the token's
+    residual streams (``streams``).
 
     The pools' leading axis ``l`` counts KV entries, one for every
     pass and layer (``kv_pool.KVBlockPool``); a looped configuration
     runs the layers ``config.loop_passes`` times (``looped_stack``).
 
-    The layer is ``layer_head`` -> this body's own attention over
-    its own view -> ``layer_tail``, as in ``decode_steps_paged`` and
-    ``verify_step_paged``; what is written three times is what
-    differs (here: a chunk written, then gathered, with the chunk's
-    exact rows spliced in). The dense ``_layer_cached`` is still a
-    body of its own; ``tests/test_paged_bodies.py`` (the three agree
-    on one position) and the engine's token-for-token-equality tests
+    The layer is ``layer_head`` -> this body's own attention ->
+    ``layer_tail``, as in ``decode_steps_paged`` and
+    ``verify_step_paged``; the attention is what each of the three
+    keeps of its own. The dense ``_layer_cached`` is still a body of
+    its own; ``tests/test_paged_bodies.py`` (the three agree on one
+    position; a chunk over tiles equals the chunk over the row's
+    whole view) and the engine's token-for-token-equality tests
     against ``greedy_generate`` are the drift alarm. int8 pools:
-    within-chunk attention reads the exact bf16 rows (spliced below),
-    but a LATER chunk reads earlier chunks' int8 round trip — exact
-    equality with the dense int8 path therefore holds for
-    single-chunk prompts (multi-chunk tracks closely; see the engine
-    docstring caveat).
+    within-chunk attention reads the chunk's exact bf16 rows, but a
+    LATER chunk reads earlier chunks' int8 codes, so exact equality
+    with the dense int8 path holds for single-chunk prompts
+    (multi-chunk tracks closely; see the engine docstring caveat).
     """
     by_kind = _by_kind(config, pools)
     tables = _by_kind(config, block_row)
     quantized = next(iter(by_kind.values()))[2] is not None
     windowed = config.sliding_window is not None
     latent = config.kv_lora_rank is not None
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    nh, hd = config.n_heads, config.head_dim
     _, t = tokens.shape
 
     cparams = jax.tree.map(
@@ -1067,17 +1067,18 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     if config.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
 
-    # Flat [NB * bs, ...] pool views; write/read index vectors are
+    # Flat [NB * bs, ...] pool views; the write index vector is
     # chunk-invariant across layers, computed once (a group).
-    flat, nbs, gw, gr = {}, {}, {}, {}
+    flat, nbs, gw, scale_views = {}, {}, {}, {}
     for kind, group in by_kind.items():
         flat[kind], nbs[kind] = _flat_pools(config, kind, group,
                                             block_size)
         gw[kind] = da.chunk_write_indices(
             tables[kind], start, real_len, t, block_size)     # [T]
-        if not (windowed or latent):
-            gr[kind] = da.read_indices(tables[kind][None],
-                                       block_size)[0]     # [S_pad]
+        # This request's scales of every entry, [E, 2, 1, Hkv, S],
+        # gathered outside the layer loop (``_scale_views`` says why).
+        scale_views[kind] = _scale_views(
+            *flat[kind][2:], tables[kind][None], block_size)
 
     def latent_layer(xc, lp, entry, ad, kind='latent'):
         """A latent layer: the chunk's queries over the cached
@@ -1098,88 +1099,34 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
         return xc, ((rows[0],), routed)
 
     def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
-        if not windowed:
-            # This pass's and layer's KV entry: taken out of the
-            # stacked pools by index, as a scan over them would (at
-            # one pass the entry is the layer).
-            entry_pools = tuple(
-                None if p is None else
-                jax.lax.dynamic_index_in_dim(
-                    p, entry, 0, keepdims=False,
-                    allow_negative_indices=False)
-                for p in flat[kind])
+        """A layer that caches keys and values: the chunk's queries
+        over the request's own blocks, a tile of cached keys at a
+        time, and over its own exact rows (``da.chunk_attention``);
+        no in-layer write and no view of ``max_seq``."""
         q, k, v, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
             config, xc, lp, ad, adapter_idx, angles, quantized, kind)
-        if windowed:
-            # Over key tiles, bounded by the window in a window layer
-            # and by ``start`` in a global one; the chunk's own exact
-            # rows are an operand (``da.chunk_attention``), so there
-            # is no in-layer write and no view of ``max_seq``.
-            kp, vp, ksp, vsp = flat[kind]
-            with jax.named_scope(kind + '_attention'):
-                attn = da.chunk_attention(
-                    q[0], k[0], v[0], _all_blocks(kp, block_size),
-                    _all_blocks(vp, block_size),
-                    tables[kind] + entry * nbs[kind], start,
-                    hd ** -0.5,
-                    None if ksp is None
-                    else _all_blocks(ksp, block_size),
-                    None if vsp is None
-                    else _all_blocks(vsp, block_size),
-                    window=config.sliding_window
-                    if kind == 'window' else None)[None]
-        else:
-            attn = gathered_attention(entry_pools, kind, q, k, v,
-                                      k_rows, v_rows, ks_rows,
-                                      vs_rows)
+        with jax.named_scope(kind + '_attention' if windowed
+                             else 'prefill_attention'):
+            # This pass's and layer's KV entry is read through the
+            # table, offset into ONE pool of every entry's blocks
+            # (at one pass the entry is the layer).
+            ks_view, vs_view = (None, None) \
+                if scale_views[kind] is None \
+                else jax.lax.dynamic_index_in_dim(
+                    scale_views[kind], entry, 0, keepdims=False,
+                    allow_negative_indices=False)[:, 0]
+            attn = da.chunk_attention(
+                q[0], k[0], v[0],
+                *(_all_blocks(p, block_size) for p in flat[kind][:2]),
+                tables[kind] + entry * nbs[kind], start, hd ** -0.5,
+                ks_view, vs_view,
+                window=config.sliding_window
+                if kind == 'window' else None)[None]
         xc, routed = layer_tail(config, xc,
                                 attn.reshape(1, t, nh * hd), lp)
         return xc, (((k_rows[0], v_rows[0], ks_rows[0], vs_rows[0])
                      if quantized else (k_rows[0], v_rows[0])),
                     routed)
-
-    def gathered_attention(entry_pools, kind, q, k, v, k_rows, v_rows,
-                           ks_rows, vs_rows):
-        """What every configuration without window layers runs: the
-        chunk written into the entry's slice, the row's whole view
-        gathered and masked."""
-        kc, vc, ks, vs = entry_pools
-        # In-layer write exists only so this chunk's attention sees
-        # its own keys; the caller-visible pool update is the single
-        # merged scatter after the layer scan (same split as
-        # forward_cached — full-pool ys per layer would rewrite the
-        # whole pool every chunk).
-        with jax.named_scope('kv_write'):
-            kc = kc.at[gw[kind]].set(k_rows[0])
-            vc = vc.at[gw[kind]].set(v_rows[0])
-            if quantized:
-                ks = ks.at[gw[kind]].set(ks_rows[0])
-                vs = vs.at[gw[kind]].set(vs_rows[0])
-        view = gr[kind]
-        kd = _dequant_kv(da.paged_gather(kc, view[None]),
-                         None if ks is None
-                         else da.paged_gather(ks, view[None]), k.dtype)
-        vd = _dequant_kv(da.paged_gather(vc, view[None]),
-                         None if vs is None
-                         else da.paged_gather(vs, view[None]), v.dtype)
-        if quantized:
-            # Attend the CURRENT chunk's exact bf16 rows, not their
-            # int8 round trip — mirrors the dense prefill contract
-            # ("quantization error only enters later decode steps",
-            # here: later chunks and decode). Splice the chunk back
-            # over its own logical positions in the gathered view.
-            col = jnp.arange(view.shape[0])
-            rel = col - start
-            in_chunk = (rel >= 0) & (rel < t)
-            relc = jnp.clip(rel, 0, t - 1)
-            kd = jnp.where(in_chunk[None, :, None, None],
-                           k[0][relc][None], kd)
-            vd = jnp.where(in_chunk[None, :, None, None],
-                           v[0][relc][None], vd)
-        with jax.named_scope('prefill_attention'):
-            return _masked_attention(q, kd, vd, q_pos=start,
-                                     kv_len=start + real_len,
-                                     scale=hd ** -0.5)
 
     # Project ONLY the chunk's last real position (start offsets make
     # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
@@ -1191,10 +1138,11 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
             h, jnp.maximum(real_len - 1, 0)[None], axis=1))  # [1,1,D]
     # Persist the chunk's rows with ONE scatter into the (donated)
     # flat pools (a group).
-    new_pools = {
-        kind: _write_rows(group, gw[kind],
-                          _kind_rows(config, rows, kind))
-        for kind, group in flat.items()}
+    with jax.named_scope('kv_write'):
+        new_pools = {
+            kind: _write_rows(group, gw[kind],
+                              _kind_rows(config, rows, kind))
+            for kind, group in flat.items()}
     if config.tie_embeddings:
         logits = (x_last @ llama.output_head(cparams, config)
                   ).astype(jnp.float32)

@@ -33,9 +33,9 @@ entry can never corrupt a block that was recycled to another request.
 - Write side: ``write_index`` (one position a row: the decode step),
   ``verify_write_indices`` (a draft window a row),
   ``chunk_write_indices`` (a prefill chunk of one request).
-- Read side: ``read_indices`` + ``paged_gather`` (position by
-  position: the prefill chunk's one-row view), ``gather_blocks`` and
-  ``gather_scales`` (block by block: the decode and verify steps).
+- Read side: ``gather_blocks`` and ``gather_scales`` (block by block:
+  the decode and verify steps' views; ``read_indices`` is the same
+  layout position by position, which the tests hold them to).
 - Attention: the engine's decode and verify steps go through
   ``paged_decode_attention``, which reads an int8 pool AS int8 in
   either of two forms that give the same numbers: codes converted
@@ -70,11 +70,12 @@ entry can never corrupt a block that was recycled to another request.
   every query to come. A decode or verify step reads the
   ``window_blocks`` columns from the first one a row's window still
   touches (``window_view``: a table of fixed width whatever the
-  context) and ``view_attention`` masks by the window; a prefill
-  chunk of a configuration with window layers goes through
-  ``chunk_attention``, which walks key tiles between the window's (or
-  the context's) first block and the chunk and never builds a view
-  of ``max_seq``.
+  context) and ``view_attention`` masks by the window.
+- A prefill chunk of any configuration that caches keys and values
+  goes through ``chunk_attention``, which walks key tiles
+  (``chunk_tile_blocks``) between the context's first block (the
+  window's, in a window layer) and the chunk, and never builds a
+  view of ``max_seq``; ``chunk_keys_read`` counts them on the host.
 - Latent layers, one layer in two forms that give the same numbers:
   a decode step reads the rows' ``latent_view`` at the prewarmed
   widths through ``latent_decode_attention``, ABSORBED (the key
@@ -109,7 +110,9 @@ def read_indices(block_tables: jax.Array,
     """Flat pool-slot indices for every logical position of every
     row: block_tables [..., MB] int32 -> [..., MB * block_size].
     Positions in unallocated tail blocks land in the scratch block —
-    callers mask them via their per-row lengths before softmax."""
+    callers mask them via their per-row lengths before softmax. No
+    step gathers position by position since PR 42: the layout as
+    code, which the tests hold the block gathers and tile walks to."""
     offs = jnp.arange(block_size, dtype=jnp.int32)
     flat = (block_tables[..., :, None] * block_size +
             offs[None, :])
@@ -259,25 +262,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------
 
 
-def paged_gather(pool_flat: jax.Array,
-                 gather_idx: jax.Array) -> jax.Array:
-    """Gather rows' logical KV views out of a flattened pool,
-    position by position: pool_flat [num_blocks * block_size, ...]
-    indexed by the precomputed flat indices from
-    ``read_indices`` ([B, S_pad] -> [B, S_pad, ...]). The
-    prefill chunk's form (one row's view); the decode and verify
-    steps use ``gather_blocks``. Scoped ``paged_gather`` in the
-    compiled program's ``op_name`` metadata."""
-    with jax.named_scope('paged_gather'):
-        return jnp.take(pool_flat, gather_idx, axis=0)
-
-
 def gather_blocks(pool: jax.Array,
                   block_tables: jax.Array) -> jax.Array:
     """Gather rows' logical KV views out of a pool, block by block:
     pool [num_blocks, block_size, ...], block_tables [B, MB] ->
     [B, MB * block_size, ...], the same values in the same order as
-    ``paged_gather(pool_flat, read_indices(block_tables))``, moved in
+    the flat pool taken at ``read_indices(block_tables)``, moved in
     slices of a whole block (16 KB of int8 codes at block 16 x 8
     heads x 128) where the per-position form moves 1 KB: on the v5e
     the 24 x 4,096 view's per-position gather ran at 385 GB/s
@@ -762,6 +752,40 @@ def walk_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return out.astype(q.dtype)
 
 
+def chunk_tile_blocks(block_size: int, table_blocks: int) -> int:
+    """Blocks a key tile of a prefill chunk's walk holds: 512 key
+    positions, never more than the table has, for every shape. Timed
+    on the v5e (PERF.md, PR 42; ms a 512-token chunk, tiles of 16 /
+    32 / 64 blocks of 16): Mistral-7B (8 KV heads x 4) at ``start``
+    256 49.3 / 50.0 / 55.9, at 2,560 57.7 / 56.2 / 62.6; Ouro (16 x
+    1) at 128 85.0 / 87.8 / 92.8, at 512 88.8 / 87.8 / 92.8. A tile
+    is scored whole: a wide one pays for the keys past ``start`` it
+    masks, a narrow one for a rescale of the accumulator a tile."""
+    return max(1, min(512 // block_size, table_blocks))
+
+
+def chunk_keys_read(start: int, chunk: int, block_size: int,
+                    table_blocks: int,
+                    window: Optional[int] = None) -> int:
+    """Key positions a chunk of ``chunk`` rows at ``start`` scores in
+    one layer, on the host for the engine's counters: the whole tiles
+    ``chunk_attention`` folds, and the chunk's own rows."""
+    tile = chunk_tile_blocks(block_size, table_blocks) * block_size
+    first = 0 if window is None else max(start - window + 1, 0) // tile
+    return (-(-start // tile) - first) * tile + chunk
+
+
+def _tiled_row(block_row: jax.Array, block_size: int,
+               tile_blocks: Optional[int]):
+    """(tile_blocks, key positions a tile, the table padded with the
+    scratch block to whole tiles) for a chunk's walk."""
+    mb = block_row.shape[0]
+    tile_blocks = tile_blocks or chunk_tile_blocks(block_size, mb)
+    return (tile_blocks, tile_blocks * block_size,
+            jnp.pad(block_row, (0, -mb % tile_blocks),
+                    constant_values=SCRATCH_BLOCK))
+
+
 def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                     k_pool: jax.Array, v_pool: jax.Array,
                     block_row: jax.Array, start: jax.Array,
@@ -769,21 +793,27 @@ def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
                     window: Optional[int] = None,
-                    tile_blocks: int = 32) -> jax.Array:
-    """Attention of one request's PREFILL CHUNK over key tiles, for a
-    configuration with window layers: q [T, Hq, hd] at positions
+                    tile_blocks: Optional[int] = None) -> jax.Array:
+    """Attention of one request's PREFILL CHUNK over key tiles, for
+    any stack that caches keys and values (with window layers or
+    without; a float or an int8 pool): q [T, Hq, hd] at positions
     start + t; k_new/v_new [T, Hkv, hd] the chunk's own exact rows,
     an operand as in the decode step (no in-layer pool write);
     k_pool/v_pool a block pool [num_blocks, block_size, Hkv, hd]
     with ``block_row`` [MB] this request's table into it (offset to
-    the layer's entry by the caller); an int8 pool comes with its
-    scale pools ``k_scale``/``v_scale`` [num_blocks, block_size,
-    Hkv].
+    the layer's entry by the caller); an int8 pool comes with the
+    request's scales ``k_scale``/``v_scale`` as ``gather_scales``
+    lays this one row's out, float32 [Hkv, MB * block_size],
+    gathered by the caller OUTSIDE its layer loop. (Taken from the
+    scale pools inside the loop they cost a 512-token Mistral chunk
+    80 ms: the v5e keeps those pools with the ENTRY axis minor and
+    re-laid both, 37 MB each, in every layer; PERF.md, PR 42.)
 
     Query t sees the cached keys [0, start) and the chunk's rows
     [0, t]; with ``window`` only those less than ``window`` positions
     behind it. The cached part is walked in tiles of ``tile_blocks``
-    blocks with a running maximum and sum (float32), from the tile
+    blocks (``chunk_tile_blocks``) with a running maximum and sum
+    (float32), from the tile
     that holds the first position any query of the chunk can see (0
     in a global layer) to the one that holds ``start - 1``: the work
     follows the context a layer reads, and the scores of one tile
@@ -795,13 +825,14 @@ def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     [T, Hq, hd] in q's type. Padded query rows (past the chunk's
     real length) give rows the caller discards."""
     t, hq, hd = q.shape
-    nb_all, bs, hkv = k_pool.shape[:3]
+    bs, hkv = k_pool.shape[1:3]
     groups = hq // hkv
-    tile = tile_blocks * bs
-    mb = block_row.shape[0]
-    n_tiles = -(-mb // tile_blocks)
-    row = jnp.pad(block_row, (0, n_tiles * tile_blocks - mb),
-                  constant_values=SCRATCH_BLOCK)
+    tile_blocks, tile, row = _tiled_row(block_row, bs, tile_blocks)
+    # Scales up to whole tiles too: what the padding holds is masked.
+    k_scale, v_scale = (
+        None if sc is None else
+        jnp.pad(sc, ((0, 0), (0, row.shape[0] * bs - sc.shape[1])))
+        for sc in (k_scale, v_scale))
     qg = q.reshape(t, hkv, groups, hd)
     q_pos = start + jnp.arange(t, dtype=jnp.int32)            # [T]
 
@@ -809,13 +840,14 @@ def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         out = jnp.einsum('thgd,shd->hgts', qg, keys.astype(q.dtype),
                          preferred_element_type=jnp.float32)
         if key_scale is not None:
-            out = out * key_scale.astype(jnp.float32).T[:, None, None]
+            with jax.named_scope('kv_dequant'):
+                out = out * key_scale[:, None, None]
         return out * scale
 
     def weighted(probs, values, value_scale):
         if value_scale is not None:
-            probs = probs * value_scale.astype(
-                jnp.float32).T[:, None, None]
+            with jax.named_scope('kv_dequant'):
+                probs = probs * value_scale[:, None, None]
         return jnp.einsum('hgts,shd->hgtd', probs.astype(q.dtype),
                           values.astype(q.dtype),
                           preferred_element_type=jnp.float32)
@@ -839,11 +871,14 @@ def chunk_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         cols = jax.lax.dynamic_slice(row, (i * tile_blocks,),
                                      (tile_blocks,))
         with jax.named_scope('paged_gather'):
-            kb, vb, ks, vs = (
-                None if pool is None else
+            kb, vb = (
                 jnp.take(pool, cols, axis=0, mode='clip').reshape(
                     tile, *pool.shape[2:])
-                for pool in (k_pool, v_pool, k_scale, v_scale))
+                for pool in (k_pool, v_pool))
+        ks, vs = (
+            None if sc is None else
+            jax.lax.dynamic_slice_in_dim(sc, i * tile, tile, axis=1)
+            for sc in (k_scale, v_scale))                 # [Hkv, tile]
         key_pos = i * tile + jnp.arange(tile, dtype=jnp.int32)
         seen = visible(key_pos) & (key_pos < start)[None, :]
         return fold(carry, scores(kb, ks), seen, vb, vs)
@@ -939,7 +974,7 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
                            new: jax.Array, pool: jax.Array,
                            block_row: jax.Array, start: jax.Array,
                            scale: float, expand: Callable, rank: int,
-                           tile_blocks: int = 32) -> jax.Array:
+                           tile_blocks: Optional[int] = None):
     """One request's PREFILL CHUNK over a latent pool, EXPANDED:
     q_nope [T, H, nope] and q_pe [T, H, rope] at positions start + t;
     ``new`` [T, W] the chunk's own latent rows (``latent_row``; an
@@ -961,11 +996,7 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
     t, heads, _ = q_nope.shape
     bs, width = pool.shape[1], pool.shape[2]
     rope = q_pe.shape[-1]
-    tile = tile_blocks * bs
-    mb = block_row.shape[0]
-    n_tiles = -(-mb // tile_blocks)
-    row = jnp.pad(block_row, (0, n_tiles * tile_blocks - mb),
-                  constant_values=SCRATCH_BLOCK)
+    tile_blocks, tile, row = _tiled_row(block_row, bs, tile_blocks)
     q_pos = start + jnp.arange(t, dtype=jnp.int32)
 
     def fold(carry, rows, seen):

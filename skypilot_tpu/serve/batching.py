@@ -24,7 +24,9 @@ TPU-first design:
   blocks (``models/decode.forward_paged``): long prompts prefill in
   fixed-size chunks interleaved with decode dispatches, so one 8k
   prompt cannot stall every in-flight decode (the p99-TTFT lever),
-  and there is no staging cache or row-insert copy on admission.
+  and there is no staging cache or row-insert copy on admission. A
+  chunk attends tiles of the request's own blocks up to its offset,
+  not a view of ``max_seq`` (the ``prefill_keys_*`` counters).
 - Pool exhaustion PREEMPTS the youngest request (blocks freed, the
   request requeued at the front; resume re-prefills prompt+generated,
   which under greedy decoding reproduces the continuation exactly) —
@@ -47,9 +49,9 @@ TPU-first design:
   gather view is masked so recycled-block garbage contributes exactly
   0). int8 caveat: equality vs the plain int8 path holds for prompts
   within ONE prefill chunk — a later chunk attends earlier chunks'
-  int8-round-tripped keys where whole-prompt prefill attends exact
-  bf16 (``forward_paged`` restores only the CURRENT chunk's exact
-  rows), so multi-chunk int8 prompts track rather than equal the
+  int8 codes where whole-prompt prefill attends exact bf16
+  (``forward_paged`` attends only the CURRENT chunk's rows exact, as
+  an operand), so multi-chunk int8 prompts track rather than equal the
   dense path; quantization error still never enters within-chunk
   attention. MoE caveat: equality holds while expert capacity does
   not bind — the engine's power-of-two chunk padding enters the
@@ -516,6 +518,17 @@ def _engine_metrics():
             'Tokens those chunks were charged, bucket padding '
             'included: attempts, against the useful count in '
             'prefill_tokens_total.'),
+        'prefill_keys_read': reg.counter(
+            'skytpu_batch_prefill_keys_read_total',
+            'Key positions those chunks\' attention scored, a kind '
+            'of layer (ops/decode_attention.chunk_keys_read): the '
+            'key tiles up to a chunk\'s start, and its bucket.'),
+        'prefill_keys_view': reg.counter(
+            'skytpu_batch_prefill_keys_view_total',
+            'Key positions the row\'s whole view held for those '
+            'chunks and kinds (max_seq each), which a chunk scored '
+            'until PR 42; prefill_keys_read_total over it is the '
+            'share that is left.'),
         'loop_passes': reg.counter(
             'skytpu_batch_loop_passes_total',
             'Passes over the layer stack run for the tokens in '
@@ -2073,6 +2086,14 @@ class BatchingEngine:
         self._metrics['prefill_chunks'].inc()
         self._metrics['prefill_tokens'].inc(real)
         self._metrics['prefill_bucket_tokens'].inc(bucket)
+        kinds = self.config.layer_kinds
+        self._metrics['prefill_keys_read'].inc(sum(
+            da.chunk_keys_read(
+                off, bucket, self.block_size, self.max_blocks_per_req,
+                self.config.sliding_window if kind == 'window'
+                else None) for kind in kinds))
+        self._metrics['prefill_keys_view'].inc(
+            len(kinds) * self.max_seq)
         if self.config.kv_lora_rank is not None:
             self._metrics['mla_expanded_tokens'].inc(real)
         if routed is not None:

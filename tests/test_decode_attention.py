@@ -75,9 +75,6 @@ class TestBlockGather:
         assert got.shape[:2] == (_B, _S)
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(want))
-        np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(da.paged_gather(
-                flat, da.read_indices(tables, _BS))))
         if what == 'scales':
             # The scores' layout of the same values, also with every
             # layer's pool at once (leading dims pass through).

@@ -23,6 +23,7 @@ from skypilot_tpu.serve.batching import BatchingEngine
 
 FAMILIES = ('iterations', 'iteration_seconds', 'host_gap_seconds',
             'prefill_chunks', 'prefill_tokens', 'prefill_bucket_tokens',
+            'prefill_keys_read', 'prefill_keys_view',
             'decode_dispatches')
 TOP_PHASES = ('sweep', 'admit', 'prefill', 'dispatch', 'device_wait',
               'emit', 'gauges')
@@ -345,6 +346,39 @@ class TestIterationCounters:
         assert cold['prefill_tokens'] == len(prompt)
         assert warm['prefill_tokens'] == len(prompt) - admits[1][2]
         assert warm['prefill_chunks'] < cold['prefill_chunks']
+
+    @pytest.mark.parametrize('cached', [0, 16])
+    def test_the_key_counters_follow_the_tiles(self, setup,
+                                               monkeypatch, cached):
+        """``prefill_keys_read`` rises by the whole key tiles up to a
+        chunk's offset plus its bucket, ``prefill_keys_view`` by
+        ``max_seq`` a chunk: over a 30-token prompt in chunks of 8
+        at tiles of 16 positions, cold (offsets 0, 8, 16, 24) and
+        behind a prefix hit of two blocks (offsets 16, 24)."""
+        from skypilot_tpu.ops import decode_attention as da
+        monkeypatch.setattr(da, 'chunk_tile_blocks', lambda bs, mb: 2)
+        engine = _engine(setup, prefix_caching=True)
+        shared, tail = _prompt(3, 16), _prompt(7, 14)
+        try:
+            if cached:
+                engine.generate(shared + _prompt(5, 4), 2)
+                _passes(engine, 2)
+            before = _counters()
+            engine.generate(shared + tail, 2)
+            _passes(engine, 2)
+            d = _delta(before)
+        finally:
+            engine.close()
+        assert [e[2] for e in engine.events
+                if e[0] == 'admit'][-1] == cached
+        offsets = list(range(cached, 30, 8))
+        assert d['prefill_chunks'] == len(offsets)
+        assert d['prefill_bucket_tokens'] == 8 * len(offsets)
+        assert d['prefill_keys_view'] == 64 * len(offsets)
+        assert d['prefill_keys_read'] == sum(
+            -(-off // 16) * 16 + 8 for off in offsets) == \
+            (64 if cached else 96)
+        assert d['prefill_keys_read'] < d['prefill_keys_view']
 
 
 # ---------------------------------------------------------------------

@@ -8,7 +8,9 @@ expanded and an absorbed form). Also here: the one
 ``rope`` against ``ops.attention.apply_rope`` on its three ``angles``
 layouts, and the rule that nothing below the scheduler imports it."""
 import ast
+import functools
 import os
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ import pytest
 import skypilot_tpu
 from skypilot_tpu.models import decode, llama
 from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.ops import decode_attention as da
 from skypilot_tpu.serve import kv_pool
 
 _BLOCK = 8
@@ -261,6 +264,201 @@ def test_the_latent_bodies_agree_on_one_position(monkeypatch):
         decode.verify_step_paged(
             params, token[:, None], pools, tables, pos,
             jnp.ones((2,), jnp.int32), config, 1, _BLOCK)
+
+
+# ---------------------------------------------------------------------
+# A prefill chunk over key tiles against the row's whole view
+# ---------------------------------------------------------------------
+
+_T = 16                 # the chunk's bucket
+_TILE_BLOCKS = 2        # a tile of 16 positions: tables of 64 hold four
+_TABLE = (11, 3, 7, 5, 12, 2, 9, 6)     # the request's own blocks
+_OTHER = (11, 3, 14, 15, 1, 4, 8, 10)   # a row that shares the first two
+# ``start`` of the chunk under test, and whose prefill wrote [0, start).
+_STARTS = {'nothing cached': (0, _TABLE),
+           'inside a block': (5, _TABLE),
+           'whole tiles': (32, _TABLE),
+           'past one tile': (21, _TABLE),
+           'a prefix another row wrote': (16, _OTHER)}
+
+
+def _chunk_config(stack):
+    """4 query heads a KV head, 1, or the looped stack (1 a KV head,
+    8 KV entries: the table's offset by entry counts)."""
+    if stack == 'loop':
+        return llama.get_config('tiny-loop')
+    return llama.get_config(
+        'tiny', n_heads=8, head_dim_override=16,
+        n_kv_heads=2 if stack == 'groups of 4' else 8)
+
+
+def _view_attention(q, k_new, v_new, k_pool, v_pool, block_row, start,
+                    scale, k_scale=None, v_scale=None, window=None,
+                    tile_blocks=None):
+    """``da.chunk_attention``'s contract computed the plain way, as
+    ``forward_paged`` did until PR 42: the row's WHOLE view gathered
+    position by position and dequantised, the chunk's exact rows
+    spliced in over their own positions, one dense masked softmax
+    (``decode._masked_attention``)."""
+    assert window is None and tile_blocks is None
+    t = q.shape[0]
+    bs = k_pool.shape[1]
+    at = da.read_indices(block_row[None], bs)               # [1, S]
+
+    def view(pool, scales, own):                # scales [Hkv, S]
+        flat = pool.reshape(-1, *pool.shape[2:])
+        rows = decode._dequant_kv(
+            jnp.take(flat, at, axis=0),
+            None if scales is None else scales.T[None], q.dtype)
+        rel = jnp.arange(at.shape[1]) - start
+        mine = (rel >= 0) & (rel < t)
+        return jnp.where(mine[None, :, None, None],
+                         own[jnp.clip(rel, 0, t - 1)][None], rows)
+
+    return decode._masked_attention(
+        q[None], view(k_pool, k_scale, k_new),
+        view(v_pool, v_scale, v_new), q_pos=start, kv_len=start + t,
+        scale=scale)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_fn(form):
+    """``forward_paged`` jitted in one of two forms: ``tiles`` as it
+    is, at a tile of ``_TILE_BLOCKS``; ``view`` with the attention
+    swapped for ``_view_attention``. The swap acts while a call
+    traces, which is the only time the name is looked up."""
+    def run(params, tokens, pools, row, start, real_len, config):
+        swap = ((da, 'chunk_tile_blocks', lambda bs, mb: _TILE_BLOCKS)
+                if form == 'tiles' else
+                (da, 'chunk_attention', _view_attention))
+        with mock.patch.object(*swap):
+            return decode.forward_paged(params, tokens, pools, row,
+                                        start, real_len, config,
+                                        _BLOCK)
+    return jax.jit(run, static_argnums=(6,))
+
+
+def _chunk(form, config, params, pools, table, tokens, start,
+           bucket=_T):
+    padded = list(tokens) + [0] * (bucket - len(tokens))
+    logits, pools, _ = _chunk_fn(form)(
+        params, jnp.asarray([padded], jnp.int32), pools,
+        jnp.asarray(table, jnp.int32), jnp.asarray(start, jnp.int32),
+        jnp.asarray(len(tokens), jnp.int32), config)
+    return np.asarray(logits[0]), pools
+
+
+def _flat(pool):
+    """[E, NB * bs, ...] float32 of one pool array."""
+    a = np.asarray(pool.astype(jnp.float32))
+    return a.reshape(a.shape[0], -1, *a.shape[3:])
+
+
+def _slots(table, lo, hi):
+    p = np.arange(lo, hi)
+    return np.asarray(table)[p // _BLOCK] * _BLOCK + p % _BLOCK
+
+
+@pytest.mark.parametrize('real_len', [_T, 11])
+@pytest.mark.parametrize('where', list(_STARTS))
+@pytest.mark.parametrize('stack', ['groups of 4', 'groups of 1', 'loop'])
+@pytest.mark.parametrize('pool', ['float', 'int8'])
+def test_a_chunk_over_key_tiles_equals_the_whole_view(pool, stack,
+                                                      where, real_len):
+    """One prefill chunk of ``forward_paged`` (key tiles up to
+    ``start`` plus the chunk's own exact rows) against the same chunk
+    over the row's whole gathered view: the same logits to float32
+    rounding, and the same pool afterwards, of which only the chunk's
+    ``real_len`` slots (and the scratch block, where its padding
+    lands) differ from the pool before: the one merged scatter is
+    the only write."""
+    config = _chunk_config(stack)
+    params = llama.init_params(config, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(13)
+    start, writer = _STARTS[where]
+    before = kv_pool.KVBlockPool(config, 16, _BLOCK,
+                                 kv_int8=pool == 'int8').caches
+    if start:
+        # Whoever prefilled [0, start): this row, or the row whose
+        # first two blocks this row's table shares.
+        _, before = _chunk('tiles', config, params, before, writer,
+                           rng.integers(1, 500, start).tolist(), 0,
+                           bucket=32)
+    tokens = rng.integers(1, 500, real_len).tolist()
+    want_logits, want = _chunk('view', config, params, before, _TABLE,
+                               tokens, start)
+    got_logits, got = _chunk('tiles', config, params, before, _TABLE,
+                             tokens, start)
+    np.testing.assert_allclose(got_logits, want_logits, atol=2e-4,
+                               rtol=0)
+    assert got_logits.argmax() == want_logits.argmax()
+    written = _slots(_TABLE, start, start + real_len)
+    rest = np.setdiff1d(
+        np.arange(_BLOCK, 16 * _BLOCK), written)  # scratch left out
+    for was, w, g in zip(before, want, got):
+        if was is None:
+            assert w is None and g is None
+            continue
+        assert g.dtype == was.dtype and g.shape == was.shape
+        np.testing.assert_array_equal(_flat(g)[:, rest],
+                                      _flat(was)[:, rest])
+        # The first entry's rows hang on no attention: bit for bit.
+        np.testing.assert_array_equal(_flat(g)[0, written],
+                                      _flat(w)[0, written])
+        # A code may differ by one where a value sits on a rounding
+        # edge; float rows and scales by float32 rounding.
+        np.testing.assert_allclose(
+            _flat(g)[:, written], _flat(w)[:, written], rtol=0,
+            atol=1 if was.dtype == jnp.int8 else 2e-4)
+        assert np.abs(_flat(g)[:, written]).max() > 0
+
+
+@pytest.mark.parametrize('stack', ['groups of 4', 'groups of 1', 'loop'])
+@pytest.mark.parametrize('pool', ['float', 'int8'])
+def test_three_chunks_equal_one(pool, stack):
+    """A prompt of 40 tokens prefilled as chunks of 16, 16 and 8
+    (the last padded to its bucket; the second and third read the
+    tiles the earlier ones wrote) against the same prompt in one
+    chunk of 48: the same last-position logits and the same 40 rows
+    in the pool. A float pool to float32 rounding; an int8 pool to
+    the quantisation step's (a later chunk reads the earlier ones'
+    codes where the single chunk reads its own rows exact)."""
+    config = _chunk_config(stack)
+    params = llama.init_params(config, jax.random.PRNGKey(1))
+    tokens = np.random.default_rng(17).integers(1, 500, 40).tolist()
+    empty = kv_pool.KVBlockPool(config, 16, _BLOCK,
+                                kv_int8=pool == 'int8').caches
+    want_logits, want = _chunk('tiles', config, params, empty, _TABLE,
+                               tokens, 0, bucket=48)
+    got = empty
+    for lo in (0, 16, 32):
+        got_logits, got = _chunk('tiles', config, params, got, _TABLE,
+                                 tokens[lo:lo + 16], lo)
+    tol = 0.05 if pool == 'int8' else 2e-4
+    np.testing.assert_allclose(got_logits, want_logits, atol=tol,
+                               rtol=0)
+    gap = want_logits.max() - want_logits[got_logits.argmax()]
+    assert gap <= (2 * tol if pool == 'int8' else 0)
+    written = _slots(_TABLE, 0, 40)
+    for w, g in zip(want, got):
+        if w is None:
+            continue
+        if w.dtype == jnp.int8:
+            # Codes against their own scales: compare what they
+            # stand for, below.
+            continue
+        np.testing.assert_allclose(_flat(g)[:, written],
+                                   _flat(w)[:, written], rtol=0,
+                                   atol=tol)
+    if pool == 'int8':
+        for codes, scales in ((0, 2), (1, 3)):
+            step = [_flat(p[scales])[:, written][..., None]
+                    for p in (want, got)]
+            value = [_flat(p[codes])[:, written] * s
+                     for p, s in zip((want, got), step)]
+            # Within the rows' own drift and a code step and a half.
+            assert np.all(np.abs(value[1] - value[0]) <=
+                          tol + 1.5 * np.maximum(*step))
 
 
 @pytest.mark.parametrize('layout', ['chunk', 'decode rows',
