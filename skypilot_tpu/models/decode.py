@@ -19,8 +19,10 @@ TPU-first design:
 
 The paged engine's three model steps live here too, below the
 scheduler that jits them (``serve/batching.py``): ``forward_paged``
-(a prefill chunk), ``decode_steps_paged`` and ``verify_step_paged``.
-Their layer is ``layer_head`` -> the body's own attention over its
+(a prefill chunk), ``decode_steps_paged`` and ``verify_step_paged``,
+and ``mtp_rounds_paged``, the drafting rounds of a model with a
+next-token-prediction module (verify, acceptance, the module and the
+next draft as one scanned program). Their layer is ``layer_head`` -> the body's own attention over its
 own view of the pool -> ``layer_tail``, under ``looped_stack``; the
 pool's format and index arithmetic are ``ops/decode_attention.py``'s.
 Nothing here imports the serve package, which is above it.
@@ -504,9 +506,14 @@ def streams(config: llama.LlamaConfig, x: jax.Array) -> jax.Array:
     and never cached, and a carry rounded to bf16 after each of
     twenty sublayers drifts by a percent, enough to make one expert
     choice in ten fall the other way (PERF.md section 6, PR 38); the
-    sublayers still read and multiply in the model's type."""
+    sublayers still read and multiply in the model's type. A latent
+    stack with ONE stream carries it in float32 for the same reason
+    (its router then reads the normed stream unrounded, as the four
+    streams' does: PERF.md section 6, PR 43); every other stack's
+    carry is the embedding as it is."""
     if config.hc_mult == 1:
-        return x
+        return x.astype(jnp.float32) \
+            if config.kv_lora_rank is not None else x
     return jnp.broadcast_to(
         x[..., None, :].astype(jnp.float32),
         (*x.shape[:-1], config.hc_mult, x.shape[-1]))
@@ -529,10 +536,11 @@ def hc_pre(config: llama.LlamaConfig, xc: jax.Array, lp: Params,
     across whole registers). Returns (u [B, T, D] = sum_j H_pre[j]
     X_j in the model's type (``wide``: left in float32), the
     sublayer's input ahead of its norm; mix = (H_post [rows, n],
-    H_res [n, n, rows]) for ``hc_post``). One stream: (xc, None).
+    H_res [n, n, rows]) for ``hc_post``). One stream: (xc, None),
+    in the model's type unless ``wide``.
     """
     if config.hc_mult == 1:
-        return xc, None
+        return (xc if wide else xc.astype(config.dtype)), None
     b, t, n, d = xc.shape
     rows = b * t
     f32 = jnp.float32
@@ -681,17 +689,6 @@ def expand_latent(config: llama.LlamaConfig, c_kv: jax.Array,
             kv[..., config.qk_nope_head_dim:])
 
 
-def refuse_latent_speculation(config: llama.LlamaConfig) -> None:
-    """The verify step has no latent body: speculation is refused
-    by name, here and at ``recipes/serve_model``'s start-up."""
-    if config.kv_lora_rank is not None:
-        raise exceptions.NotSupportedError(
-            f'{config.name!r}: speculative decoding is not '
-            f'implemented for latent attention (verify_step_paged '
-            f'has no latent body): build the engine with '
-            f'speculative=False (serve_model --speculative off)')
-
-
 def looped_stack(config: llama.LlamaConfig, cparams: Params,
                  x: jax.Array, layer, adapters=None,
                  last=lambda h: h):
@@ -786,8 +783,9 @@ def looped_stack(config: llama.LlamaConfig, cparams: Params,
     def final_norm(h):
         if config.hc_mult > 1:
             # The streams' sum is what the head reads.
-            h = h.sum(axis=-2).astype(config.dtype)
-        return llama.norm(config, h, cparams['final_norm'])
+            h = h.sum(axis=-2)
+        return llama.norm(config, h.astype(config.dtype),
+                          cparams['final_norm'])
 
     if config.dense_first:
         # Leading dense layers (leaves of their own, ``dense_layers``)
@@ -905,32 +903,50 @@ def _kind_rows(config: llama.LlamaConfig, rows, kind: str):
     return jax.tree.map(pick, rows)
 
 
-def _write_rows(group, idx: jax.Array, rows):
+def _write_rows(group, idx: jax.Array, rows, *module):
     """One group's flat pools [E, NB * bs, ...] with ``rows`` (a
     tuple in the pool tuple's order, [E, len(idx), ...] each) written
     at the flat slots ``idx``: one scatter a pool array, whatever the
-    format has (K, V and their scales, K and V, or latent rows)."""
+    format has (K, V and their scales, K and V, or latent rows).
+    ``module``: a latent group's further write (entry, idx, rows) in
+    the same scatter (``_write_latent_rows``)."""
     rows = tuple(rows) + (None,) * (len(group) - len(rows))
     if group[1] is None:
-        return (_write_latent_rows(group[0], idx, rows[0]),) + \
-            group[1:]
+        return (_write_latent_rows(group[0], (0, idx, rows[0]),
+                                   *([module] if module else [])),
+                ) + group[1:]
     return tuple(None if p is None else p.at[:, idx].set(r)
                  for p, r in zip(group, rows))
 
 
-def _write_latent_rows(flat: jax.Array, idx: jax.Array,
-                       rows: jax.Array) -> jax.Array:
-    """A latent group's flat pool [E, NB * bs, W] with ``rows`` [E,
-    len(idx), W] written at the flat slots ``idx`` of every entry,
-    as ONE scatter of E x len(idx) rows into the pool laid end to
-    end. The scatter batched over E (``flat.at[:, idx]``) makes the
-    v5e's compiler keep the whole pool in a layout with E next to
-    the row, a copy of it of 5.8 GB at 18,945 blocks."""
+def _write_latent_rows(flat: jax.Array, *writes) -> jax.Array:
+    """A latent group's flat pool [E, NB * bs, W] with each of
+    ``writes`` = (entry, idx, rows [n, len(idx), W]) written at the
+    flat slots ``idx`` of the entries ``entry`` .. ``entry + n`` (the
+    main stack's layers from 0; a next-token-prediction module's one
+    entry after them, at slots of its own), as ONE scatter of all
+    the rows into the pool laid end to end. The scatter batched over
+    E (``flat.at[:, idx]``) makes the v5e's compiler keep the whole
+    pool in a layout with E next to the row, a copy of it of 5.8 GB
+    at 18,945 blocks; and two scatters one after the other made a
+    one-token chunk's program hold a second pool (3.4 GB at 18,561
+    blocks, deviceless v5e compile, PR 43)."""
     ne, slots, width = flat.shape
-    at = (jnp.arange(ne, dtype=idx.dtype)[:, None] * slots +
-          idx[None, :]).reshape(-1)
-    return flat.reshape(ne * slots, width).at[at].set(
-        rows.reshape(-1, width)).reshape(flat.shape)
+    at = []
+    for entry, idx, rows in writes:
+        assert entry + rows.shape[0] <= ne, (entry, rows.shape, ne)
+        entries = jnp.arange(rows.shape[0], dtype=idx.dtype)
+        if entry:
+            entries = entries + entry
+        at.append((entries[:, None] * slots +
+                   idx[None, :]).reshape(-1))
+    # (In this order a single write lowers to the text it had before
+    # there could be several.)
+    pool = flat.reshape(ne * slots, width)
+    values = [rows.reshape(-1, width) for _, _, rows in writes]
+    if len(writes) > 1:
+        at, values = [jnp.concatenate(at)], [jnp.concatenate(values)]
+    return pool.at[at[0]].set(values[0]).reshape(flat.shape)
 
 
 def _routed_sums(routed: jax.Array) -> jax.Array:
@@ -980,7 +996,8 @@ def _scale_views(k_scale, v_scale, block_tables: jax.Array,
 def forward_paged(params: Params, tokens: jax.Array, pools,
                   block_row: jax.Array, start: jax.Array,
                   real_len: jax.Array, config: llama.LlamaConfig,
-                  block_size: int, adapters=None, adapter_idx=None):
+                  block_size: int, adapters=None, adapter_idx=None,
+                  mtp=None):
     """One PREFILL CHUNK of one request, written directly into paged
     KV-pool blocks (serve/kv_pool.py) — the paged engine's
     copy-on-admit removal: no per-request staging cache, no
@@ -1019,6 +1036,25 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     is None for a dense model, else int32 [2, n_layers, experts held]
     (``_routed_sums``, one step): the (token, expert) pairs this
     chunk, padding included, routed to each expert held here.
+
+    A configuration with a next-token-prediction module
+    (``config.nextn_layers``) is handed ``mtp`` = (h_prev [1, 1, D],
+    hidden) and also runs the module over the chunk's pairs
+    (``mtp_module``, EXPANDED as the main layers are). The pair of
+    the token at position i is (h_{i-1}, t_i) and its row lies at
+    slot i of the module's entry, so the chunk's pairs need the
+    state of the position BEFORE the chunk: ``h_prev``, which the
+    previous chunk returned (a fourth value, the final-normed state
+    of this chunk's last real position; the engine carries it from
+    chunk to chunk and hands the last one to ``mtp_first_paged``).
+    Position 0 has no pair: its slot stays empty and masked. After a
+    PREFIX HIT nothing kept that state, so the engine starts the
+    first chunk one token early with ``hidden`` = 1: the chunk's
+    first lane recomputes position start, whose rows the shared
+    block already holds, as a read-only lane (its writes go to the
+    scratch block, as padded lanes' do; the module reads that slot
+    from the pool and not from the lane, whose pair lacks its
+    state). ``routed`` then counts the module's layer last.
 
     Two attention forms, by the configuration's kind of cache. Keys
     and values in a pool (float or int8): tiles of pool keys
@@ -1075,6 +1111,11 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
                                             block_size)
         gw[kind] = da.chunk_write_indices(
             tables[kind], start, real_len, t, block_size)     # [T]
+        if mtp is not None:
+            # Read-only leading lanes write scratch.
+            gw[kind] = jnp.where(
+                jnp.arange(t) < mtp[1],
+                da.SCRATCH_BLOCK * block_size, gw[kind])
         # This request's scales of every entry, [E, 2, 1, Hkv, S],
         # gathered outside the layer loop (``_scale_views`` says why).
         scale_views[kind] = _scale_views(
@@ -1131,27 +1172,56 @@ def forward_paged(params: Params, tokens: jax.Array, pools,
     # Project ONLY the chunk's last real position (start offsets make
     # it real_len - 1 within the chunk) — a full [1, T, vocab] f32
     # materialization is the admission cost this path deletes.
+    def last_real(h):
+        return jnp.take(h, jnp.maximum(real_len - 1, 0)[None],
+                        axis=1)                              # [1,1,D]
+
     x_last, (rows, routed) = looped_stack(
         config, cparams, x, latent_layer if latent else layer,
-        adapters,
-        last=lambda h: jnp.take(
-            h, jnp.maximum(real_len - 1, 0)[None], axis=1))  # [1,1,D]
+        adapters, last=last_real if mtp is None else lambda h: h)
+    module_rows = {}
+    if mtp is not None:
+        # The module over the chunk's pairs (h_{i-1}, t_i), each at
+        # RoPE position i - 1, its row at slot i.
+        kind = config.layer_kinds[0]
+        entry = config.kv_entries - 1
+        h_all, x_last = x_last, last_real(x_last)
+        h_before = jnp.concatenate(
+            [mtp[0].astype(h_all.dtype), h_all[:, :-1]], axis=1)
+
+        def attend(q_nope, q_pe, m_rows, lp):
+            with jax.named_scope('mla_expanded_attention'):
+                return da.latent_chunk_attention(
+                    q_nope[0], q_pe[0], m_rows[0],
+                    _all_blocks(flat[kind][0], block_size),
+                    tables[kind] + entry * nbs[kind], start,
+                    llama.attention_scale(config),
+                    functools.partial(expand_latent, config, lp=lp),
+                    config.kv_lora_rank, first=1, hidden=mtp[1]
+                ).reshape(1, t, -1)
+
+        _, m_rows, m_routed = mtp_module(
+            config, cparams, h_before, tokens,
+            llama._rope_frequencies(
+                config, jnp.maximum(positions - 1, 0)), attend)
+        module_rows[kind] = (entry, jnp.where(
+            positions == 0, da.SCRATCH_BLOCK * block_size, gw[kind]),
+            m_rows)
+        routed = jnp.concatenate([routed, m_routed[None]])
     # Persist the chunk's rows with ONE scatter into the (donated)
-    # flat pools (a group).
+    # flat pools (a group), the module's among them.
     with jax.named_scope('kv_write'):
         new_pools = {
             kind: _write_rows(group, gw[kind],
-                              _kind_rows(config, rows, kind))
+                              _kind_rows(config, rows, kind),
+                              *module_rows.get(kind, ()))
             for kind, group in flat.items()}
-    if config.tie_embeddings:
-        logits = (x_last @ llama.output_head(cparams, config)
-                  ).astype(jnp.float32)
-    else:
-        logits = _mm(x_last, cparams['lm_head']).astype(jnp.float32)
+    logits = _head_logits(config, cparams, x_last).astype(jnp.float32)
     new_pools = {kind: _unflat_pools(group, nbs[kind])
                  for kind, group in new_pools.items()}
-    return (logits[:, 0], _as_given(pools, new_pools),
-            None if routed is None else _routed_sums(routed[None]))
+    out = (logits[:, 0], _as_given(pools, new_pools),
+           None if routed is None else _routed_sums(routed[None]))
+    return out if mtp is None else out + (x_last,)
 
 
 def decode_steps_paged(params: Params, tokens: jax.Array,
@@ -1369,10 +1439,7 @@ def decode_steps_paged(params: Params, tokens: jax.Array,
                 kind: _write_rows(group, widx[kind],
                                   _kind_rows(config, rows, kind))
                 for kind, group in pools.items()}
-        if config.tie_embeddings:
-            logits = (x @ llama.output_head(cparams, config))
-        else:
-            logits = _mm(x, cparams['lm_head'])
+        logits = _head_logits(config, cparams, x)
         with jax.named_scope('sampler'):
             if sampling is None:
                 nxt = logits[:, -1].argmax(-1).astype(jnp.int32)
@@ -1415,6 +1482,117 @@ def _window_args(config: llama.LlamaConfig, kind: str, key_start):
     return {'window': config.sliding_window, 'key_start': key_start}
 
 
+def _head_logits(config: llama.LlamaConfig, cparams: Params,
+                 x: jax.Array) -> jax.Array:
+    """The model's head on the normed state ``x`` [..., D]."""
+    if config.tie_embeddings:
+        return x @ llama.output_head(cparams, config)
+    return _mm(x, cparams['lm_head'])
+
+
+def _verify_forward(config: llama.LlamaConfig, cparams: Params,
+                    tokens: jax.Array, flat, nbs, tables,
+                    pos: jax.Array, n_real: jax.Array, width: int,
+                    block_size: int, adapters=None, adapter_idx=None):
+    """The layer stack of a VERIFY step over flat pools: ``tokens``
+    [B, W] at positions pos[b] .. pos[b] + W - 1, the first
+    ``n_real[b]`` real. What ``verify_step_paged`` and a round of
+    ``mtp_rounds_paged`` share: ``flat`` / ``nbs`` / ``tables`` by
+    kind of layer (``_flat_pools``, ``_by_kind``). Returns (the
+    final-normed state [B, W, D], the flat pools with the window's
+    rows written (padded lanes to scratch), the expert layers' tally
+    [n_layers, held] or None).
+
+    Two bodies, by the configuration's kind of cache. Keys and values
+    in a pool: ``da.paged_decode_attention`` in its [B, W, ...] form
+    with the intra-draft causal mask (query j attends [0, pos + j]).
+    A latent configuration (``config.kv_lora_rank``): the rows'
+    latent view attended ABSORBED at W query positions a row
+    (``da.latent_verify_attention``, scope
+    ``mla_absorbed_attention``), over a carry of residual streams.
+    Either way there is no in-layer write: the window's own rows go
+    to attention as an operand, causally among themselves, beside the
+    view of positions [0, pos), and one merged scatter after the
+    layer scan persists them. A padded lane's row is seen only by
+    padded lanes, whose outputs are ignored."""
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    b = tokens.shape[0]
+    bs = block_size
+    latent = config.kv_lora_rank is not None
+    quantized = next(iter(flat.values()))[2] is not None     # static
+
+    # As in the decode twin: every entry's blocks as one pool read
+    # through tables offset by entry * NB, and the scale views of
+    # all entries gathered once, outside the layer scan (a group; a
+    # window layer through ``da.window_view``'s columns).
+    blocks, views, scale_views = {}, {}, {}
+    for kind, (kp, vp, ksp, vsp) in flat.items():
+        blocks[kind] = (_all_blocks(kp, bs),) if latent else \
+            (_all_blocks(kp, bs), _all_blocks(vp, bs))
+        views[kind] = (tables[kind], None) if kind != 'window' \
+            else da.window_view(tables[kind], pos,
+                                config.sliding_window, bs)
+        scale_views[kind] = _scale_views(ksp, vsp, views[kind][0], bs)
+
+    positions = pos[:, None] + jnp.arange(width,
+                                          dtype=jnp.int32)[None, :]
+    angles = llama._rope_frequencies(
+        config, positions.reshape(-1)).reshape(b, width, -1)
+    x = streams(config, cparams['embed'][tokens])      # [B, W, D]
+    if config.scale_embeddings:
+        x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
+    wflat = {kind: da.verify_write_indices(
+        tables[kind], pos, n_real, width, block_size).reshape(-1)
+        for kind in flat}                          # [B * W] a group
+
+    def latent_layer(xc, lp, entry, ad, kind='latent'):
+        del ad
+        q_nope, q_pe, rows, mix = latent_head(config, xc, lp, angles)
+        with jax.named_scope('mla_absorbed_attention'):
+            view = da.latent_view(
+                blocks[kind][0], views[kind][0] + entry * nbs[kind])
+            o_lat = da.latent_verify_attention(
+                absorb_query(config, q_nope, lp), q_pe, view, pos,
+                llama.attention_scale(config), rows)
+            attn = value_up(config, o_lat, lp)
+        xc, routed = latent_tail(
+            config, xc, attn.reshape(b, width, -1), lp, mix)
+        return xc, ((rows.reshape(b * width, -1),), routed)
+
+    def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
+        q, _, _, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
+            config, xc, lp, ad, adapter_idx, angles, quantized, kind)
+        view, key_start = views[kind]
+        ks_view, vs_view = (None, None) if scale_views[kind] is None \
+            else jax.lax.dynamic_index_in_dim(
+                scale_views[kind], entry, 0, keepdims=False,
+                allow_negative_indices=False)
+        with _attention_scope(config, kind):
+            attn = da.paged_decode_attention(
+                q, *blocks[kind], view + entry * nbs[kind], pos,
+                hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
+                new=(k_rows, v_rows, ks_rows, vs_rows),
+                **_window_args(config, kind, key_start)
+            )                                      # [B, W, Hq, hd]
+        xc, routed = layer_tail(
+            config, xc, attn.reshape(b, width, nh * hd), lp)
+        rows = (k_rows.reshape(b * width, nkv, hd),
+                v_rows.reshape(b * width, nkv, hd))
+        if quantized:
+            rows += (ks_rows.reshape(b * width, nkv),
+                     vs_rows.reshape(b * width, nkv))
+        return xc, (rows, routed)
+
+    x, (rows, routed) = looped_stack(
+        config, cparams, x, latent_layer if latent else layer,
+        adapters)
+    with jax.named_scope('kv_write'):
+        flat = {kind: _write_rows(group, wflat[kind],
+                                  _kind_rows(config, rows, kind))
+                for kind, group in flat.items()}
+    return x, flat, routed
+
+
 def verify_step_paged(params: Params, tokens: jax.Array,
                       caches, block_tables: jax.Array,
                       pos: jax.Array, n_real: jax.Array,
@@ -1440,10 +1618,9 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     host-side ``pos`` back so the stale rows are never attended
     again — no block copying,
     no scatter-undo (the length-masked paged attention makes
-    abandoning them free). Attention is
-    ``ops.decode_attention.paged_decode_attention`` in its
-    [B, W, ...] form with the intra-draft causal mask (query j
-    attends [0, pos+j]).
+    abandoning them free). The layer stack is ``_verify_forward``,
+    with a body for pools of keys and values and one for a latent
+    pool.
 
     Returns (preds [B, W] int32, accepted [B] int32, new_pos [B],
     new_tokens [B], caches): ``preds[b, j]`` is the target model's
@@ -1460,91 +1637,33 @@ def verify_step_paged(params: Params, tokens: jax.Array,
     advances by accepted+1 for live rows (the ROLLBACK: rejected
     positions simply stay past the new frontier) and parked rows
     (n_real 0) are untouched.
-
-    A latent configuration (``config.kv_lora_rank``) is refused by
-    name (``refuse_latent_speculation``).
     """
-    refuse_latent_speculation(config)
     by_kind = _by_kind(config, caches)
     tables = _by_kind(config, block_tables)
     cparams = jax.tree.map(
         lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
         params)
-    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    b = tokens.shape[0]
-    bs = block_size
-    quantized = next(iter(by_kind.values()))[2] is not None  # static
-
-    # As in the decode twin: every entry's blocks as one pool read
-    # through tables offset by entry * NB, and the scale views of
-    # all entries gathered once, outside the layer scan (a group; a
-    # window layer through ``da.window_view``'s columns).
-    flat, nbs, blocks, views, scale_views = {}, {}, {}, {}, {}
+    flat, nbs = {}, {}
     for kind, group in by_kind.items():
-        flat[kind], nbs[kind] = _flat_pools(config, kind, group, bs)
-        kp, vp, ksp, vsp = flat[kind]
-        blocks[kind] = _all_blocks(kp, bs), _all_blocks(vp, bs)
-        views[kind] = (tables[kind], None) if kind == 'global' \
-            else da.window_view(tables[kind], pos,
-                                config.sliding_window, bs)
-        scale_views[kind] = _scale_views(ksp, vsp, views[kind][0], bs)
+        flat[kind], nbs[kind] = _flat_pools(config, kind, group,
+                                            block_size)
+    x, flat, _ = _verify_forward(
+        config, cparams, tokens, flat, nbs, tables, pos, n_real,
+        width, block_size, adapters, adapter_idx)
+    logits = _head_logits(config, cparams, x)
+    preds, accepted, new_pos, new_tok = _verify_commit(
+        tokens, logits, pos, n_real, sampling)
+    out_caches = {kind: _unflat_pools(group, nbs[kind])
+                  for kind, group in flat.items()}
+    return (preds, accepted, new_pos, new_tok,
+            _as_given(caches, out_caches))
 
-    positions = pos[:, None] + jnp.arange(width,
-                                          dtype=jnp.int32)[None, :]
-    angles = llama._rope_frequencies(
-        config, positions.reshape(-1)).reshape(b, width, -1)
-    x = cparams['embed'][tokens]                   # [B, W, D]
-    if config.scale_embeddings:
-        x = x * jnp.asarray(math.sqrt(config.dim), x.dtype)
-    wflat = {kind: da.verify_write_indices(
-        tables[kind], pos, n_real, width, block_size).reshape(-1)
-        for kind in flat}                          # [B * W] a group
 
-    def layer(xc, lp, entry, ad, kind=config.layer_kinds[0]):
-        q, _, _, (k_rows, v_rows, ks_rows, vs_rows) = layer_head(
-            config, xc, lp, ad, adapter_idx, angles, quantized, kind)
-        # No in-layer write (it copied the layer's whole pool
-        # slice, as in the decode twin): the draft window's own rows
-        # go to attention as an operand, causally among themselves,
-        # beside the view of positions [0, pos); the merged scatter
-        # after the layer scan persists them. A padded lane's row is
-        # seen only by padded lanes, whose outputs are ignored.
-        view, key_start = views[kind]
-        ks_view, vs_view = (None, None) if scale_views[kind] is None \
-            else jax.lax.dynamic_index_in_dim(
-                scale_views[kind], entry, 0, keepdims=False,
-                allow_negative_indices=False)
-        with _attention_scope(config, kind):
-            attn = da.paged_decode_attention(
-                q, *blocks[kind], view + entry * nbs[kind], pos,
-                hd ** -0.5, k_scale=ks_view, v_scale=vs_view,
-                new=(k_rows, v_rows, ks_rows, vs_rows),
-                **_window_args(config, kind, key_start)
-            )                                      # [B, W, Hq, hd]
-        xc, _ = layer_tail(
-            config, xc, attn.reshape(b, width, nh * hd), lp)
-        return xc, (
-            k_rows.reshape(b * width, nkv, hd),
-            v_rows.reshape(b * width, nkv, hd),
-            None if ks_rows is None
-            else ks_rows.reshape(b * width, nkv),
-            None if vs_rows is None
-            else vs_rows.reshape(b * width, nkv))
-
-    x, rows = looped_stack(config, cparams, x, layer, adapters)
-    with jax.named_scope('kv_write'):
-        for kind, (kp, vp, ksp, vsp) in flat.items():
-            mine = _kind_rows(config, rows, kind)
-            kp = kp.at[:, wflat[kind]].set(mine[0])
-            vp = vp.at[:, wflat[kind]].set(mine[1])
-            if quantized:
-                ksp = ksp.at[:, wflat[kind]].set(mine[2])
-                vsp = vsp.at[:, wflat[kind]].set(mine[3])
-            flat[kind] = (kp, vp, ksp, vsp)
-    if config.tie_embeddings:
-        logits = (x @ llama.output_head(cparams, config))
-    else:
-        logits = _mm(x, cparams['lm_head'])
+def _verify_commit(tokens: jax.Array, logits: jax.Array,
+                   pos: jax.Array, n_real: jax.Array, sampling):
+    """The target's realizations at a verify window's positions, how
+    many drafts each row keeps, and the committed frontier:
+    (preds [B, W], accepted [B], new_pos [B], new_tok [B])."""
     with jax.named_scope('sampler'):
         if sampling is None:
             preds = logits.argmax(-1).astype(jnp.int32)   # [B, W]
@@ -1553,8 +1672,9 @@ def verify_step_paged(params: Params, tokens: jax.Array,
             # would use at each position — the maximal-coupling half
             # of the speculative-sampling rule
             # (ops/sampling/accept.py).
-            allowed = sample_lib.gather_masks(sampling['mask_table'],
-                                              sampling['mask_idx'])
+            allowed = None if 'mask_table' not in sampling else \
+                sample_lib.gather_masks(sampling['mask_table'],
+                                        sampling['mask_idx'])
             preds = sample_lib.verify_targets(
                 logits, sampling['temps'], sampling['top_ps'],
                 sampling['seeds'], pos, allowed)          # [B, W]
@@ -1565,10 +1685,242 @@ def verify_step_paged(params: Params, tokens: jax.Array,
         live,
         jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0],
         tokens[:, 0])
-    out_caches = {kind: _unflat_pools(group, nbs[kind])
-                  for kind, group in flat.items()}
-    return (preds, accepted, new_pos, new_tok,
-            _as_given(caches, out_caches))
+    return preds, accepted, new_pos, new_tok
+
+
+# ---------------------------------------------------------------------
+# The next-token-prediction module as the engine's drafter
+# ---------------------------------------------------------------------
+
+
+def mtp_module(config: llama.LlamaConfig, cparams: Params,
+               h: jax.Array, tokens: jax.Array, angles: jax.Array,
+               attend):
+    """The model's next-token-prediction module (DeepSeek-V3 report,
+    section 2.2, depth 1) up to its last norm. For a position i with
+    the main stack's final-normed state h_i and the NEXT token
+    t_{i+1}:
+
+        x_i = W_eh [ RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i) ]
+        y_i = Layer(x_i; RoPE position i, its own latent rows 0..i)
+        l'_i = Head(RMSNorm_s(y_i))        (``mtp_logits``)
+
+    l'_i predicts t_{i+2}. Emb and Head are the main model's; Layer
+    is one expert layer in the main layers' form (``latent_head``,
+    ``latent_tail``) on the leaves ``cparams['mtp']['layers']``, with
+    the latent group's LAST cache entry as its own. ``h`` [B, T, D],
+    ``tokens`` [B, T] (pair by pair), ``angles`` of the pairs' RoPE
+    positions; ``attend(q_nope, q_pe, rows, lp) -> [B, T, H * vd]``
+    is the calling body's attention over that entry (absorbed in a
+    round, expanded in a prefill chunk). Returns (y [B, T, D] ahead
+    of the last norm, the pairs' latent rows [B, T, W], the expert
+    layer's tally [held]). Scopes ``mtp_eh_proj``, ``mtp_layer``."""
+    mp = cparams['mtp']
+    lp = jax.tree.map(lambda a: a[0], mp['layers'])
+    with jax.named_scope('mtp_eh_proj'):
+        pair = jnp.concatenate(
+            [llama.norm(config, cparams['embed'][tokens], mp['enorm']),
+             llama.norm(config, h, mp['hnorm'])], axis=-1)
+        x = _mm(pair, mp['eh_proj'])
+    with jax.named_scope('mtp_layer'):
+        q_nope, q_pe, rows, mix = latent_head(config, x, lp, angles)
+        attn = attend(q_nope, q_pe, rows, lp)
+        y, routed = latent_tail(config, x, attn, lp, mix)
+    return y, rows, routed
+
+
+def mtp_logits(config: llama.LlamaConfig, cparams: Params,
+               y: jax.Array) -> jax.Array:
+    """l' = Head(RMSNorm_s(y)): the module's logits through the main
+    model's head (scope ``mtp_head``)."""
+    with jax.named_scope('mtp_head'):
+        return _head_logits(
+            config, cparams,
+            llama.norm(config, y, cparams['mtp']['final_norm']))
+
+
+def _mtp_step(config: llama.LlamaConfig, cparams: Params,
+              h: jax.Array, tokens: jax.Array, pos: jax.Array,
+              n_real: jax.Array, flat, nb: int, table: jax.Array,
+              block_size: int):
+    """The module over the pairs a commit completed, through the
+    cache: pair j of row b is (h[b, j], tokens[b, j]) at RoPE position
+    pos[b] + j, the first ``n_real[b]`` real. A pair's latent row
+    lies in the module's entry at the slot of the token it embeds,
+    pos + j + 1 (slot 0 stays empty and masked: a block's rows then
+    depend on tokens up to its own end only, which is what lets a
+    shared prefix block carry them, ``forward_paged``). Attention is
+    ABSORBED over slots [1, pos + 1) of the rows' view and the pairs'
+    own rows, causally. ``flat`` the latent group's flat pool tuple,
+    ``table`` [B, MB] (cut to the dispatch's width by the caller).
+    Returns (y [B, T, D], flat with the real pairs' rows written,
+    the tally [held])."""
+    b, t = tokens.shape
+    entry = config.kv_entries - 1
+    positions = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    angles = llama._rope_frequencies(
+        config, positions.reshape(-1)).reshape(b, t, -1)
+
+    def attend(q_nope, q_pe, rows, lp):
+        with jax.named_scope('mla_absorbed_attention'):
+            view = da.latent_view(_all_blocks(flat[0], block_size),
+                                  table + entry * nb)
+            o_lat = da.latent_verify_attention(
+                absorb_query(config, q_nope, lp), q_pe, view, pos + 1,
+                llama.attention_scale(config), rows, first=1)
+            return value_up(config, o_lat, lp).reshape(b, t, -1)
+
+    y, rows, routed = mtp_module(config, cparams, h, tokens, angles,
+                                 attend)
+    widx = da.verify_write_indices(table, pos + 1, n_real, t,
+                                   block_size).reshape(-1)
+    with jax.named_scope('kv_write'):
+        flat = (_write_latent_rows(
+            flat[0], (entry, widx, rows.reshape(1, b * t, -1))),
+        ) + flat[1:]
+    return y, flat, routed
+
+
+def _draft_tokens(logits: jax.Array, sampling, positions: jax.Array
+                  ) -> jax.Array:
+    """The module's draft for the token after each row's frontier:
+    drawn from ITS logits with the counter key the target will use
+    there, ``(seed, positions[b])`` (``positions``: where the
+    committed last token stands, whose main logits decide that
+    token). ``jax.random.categorical`` is the argmax of logits / T +
+    Gumbel noise of the key, so draft and target share the noise and
+    agree wherever the noise decides: on independent unit logits
+    over 129,280 ids at T = 1, four times in ten (PERF.md section 6,
+    PR 43). Greedy rows, and ``sampling`` None, take the argmax."""
+    with jax.named_scope('mtp_draft_sample'):
+        if sampling is None:
+            return logits.argmax(-1).astype(jnp.int32)
+        return sample_lib.sample_rows(
+            logits, sampling['temps'], sampling['top_ps'],
+            sampling['seeds'], positions)
+
+
+def mtp_rounds_paged(params: Params, tokens: jax.Array,
+                     drafts: jax.Array, caches,
+                     block_tables: jax.Array, pos: jax.Array,
+                     active: jax.Array, grant: jax.Array,
+                     config: llama.LlamaConfig, num_rounds: int,
+                     block_size: int, sampling=None, *,
+                     view_blocks: Optional[int] = None):
+    """``num_rounds`` drafting ROUNDS for every row, as one dispatch
+    (a ``lax.scan``, as ``decode_steps_paged`` scans steps): what the
+    engine runs in place of the decode scan where the model's own
+    next-token-prediction module is the drafter
+    (``config.nextn_layers``; ``speculative='mtp'``).
+
+    A row's state: ``pos``, its committed last token ``tokens[b]``
+    and one pending draft ``drafts[b]`` of the token after it. A
+    round, for every ``active`` row:
+
+    1. the main stack on ``[token, draft]`` at positions pos, pos + 1
+       through the latent verify body (``_verify_forward``: both
+       rows written up front; a row without ``grant`` verifies its
+       token alone, the draft lane padded);
+    2. ``preds`` = the target's realizations there
+       (``_verify_commit``: ``sample.verify_targets`` and the ONE
+       acceptance rule), ``accepted`` 0 or 1, and ``accepted + 1``
+       tokens are committed: ``pos += accepted + 1``;
+    3. the module on the one or two pairs the commit completed,
+       ``(h_pos, preds[0])`` and, if the draft was kept, ``(h_pos+1,
+       preds[1])`` (``_mtp_step``: its rows into the last entry);
+    4. the next draft from the module's logits at the last pair
+       (``_draft_tokens``), keyed as the target will be.
+
+    A rejected draft leaves no trace: its main row lies past the
+    frontier and is overwritten by the next round's, and its pair was
+    never run. ``sampling``: None (every row greedy), or the per-row
+    knob arrays of ``decode_steps_paged`` without the grammar table
+    (a draft under a grammar mask is not implemented; the engine
+    refuses constrained requests on this path). ``view_blocks`` as
+    there: the width has to hold ``pos + 2 * num_rounds + 1`` of
+    every active row.
+
+    Returns (out_tokens [B, num_rounds, 2], counts [B, num_rounds]:
+    the tokens each round committed, 0 for an inactive row; caches;
+    new_pos; new tokens [B]; new drafts [B]; routed int32 [2,
+    n_layers + 1, held] as ``decode_steps_paged`` gives it, the
+    module's layer last, every round carrying 2 B lanes)."""
+    if not config.nextn_layers:
+        raise exceptions.NotSupportedError(
+            f'{config.name!r} has no next-token-prediction module '
+            f'(nextn_layers) to draft with')
+    kind = config.layer_kinds[0]
+    cparams = jax.tree.map(
+        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
+        params)
+    table = block_tables if view_blocks is None \
+        else block_tables[:, :view_blocks]
+    flat, nb = _flat_pools(config, kind, caches, block_size)
+    n_real = jnp.where(active, jnp.where(grant, 2, 1), 0
+                       ).astype(jnp.int32)
+
+    def one_round(carry, _):
+        tok, draft, pools, cur = carry
+        pair = jnp.stack([tok, draft], axis=1)               # [B, 2]
+        h, pools, routed = _verify_forward(
+            config, cparams, pair, {kind: pools}, {kind: nb},
+            {kind: table}, cur, n_real, 2, block_size)
+        preds, accepted, new_cur, new_tok = _verify_commit(
+            pair, _head_logits(config, cparams, h), cur, n_real,
+            sampling)
+        count = jnp.where(active, accepted + 1, 0)
+        y, pools, m_routed = _mtp_step(
+            config, cparams, h, preds, cur, count, pools[kind], nb,
+            table, block_size)
+        y_last = jnp.take_along_axis(
+            y, accepted[:, None, None], axis=1)[:, 0]       # [B, D]
+        new_draft = _draft_tokens(
+            mtp_logits(config, cparams, y_last), sampling, new_cur)
+        new_draft = jnp.where(active, new_draft, draft)
+        tally = jnp.concatenate([routed, m_routed[None]])
+        return (new_tok, new_draft, pools, new_cur), \
+            (preds, count, tally)
+
+    (tok, draft, flat, pos), (toks, counts, routed) = jax.lax.scan(
+        one_round, (tokens, drafts, flat, pos), None,
+        length=num_rounds)
+    return (toks.swapaxes(0, 1), counts.swapaxes(0, 1),
+            _unflat_pools(flat, nb), pos, tok, draft,
+            _routed_sums(routed))
+
+
+def mtp_first_paged(params: Params, h_last: jax.Array,
+                    token: jax.Array, caches, block_row: jax.Array,
+                    pos: jax.Array, config: llama.LlamaConfig,
+                    block_size: int, temperature: jax.Array,
+                    top_p: jax.Array, seed: jax.Array):
+    """A request's FIRST draft, once its first token is known: the
+    module on the pair (``h_last`` [1, 1, D], the final-normed state
+    of the last prompt position ``pos``; ``token``, the first
+    generated token), its row into slot pos + 1 of the module's
+    entry, and the draft of the token after, drawn with key ``(seed,
+    pos + 1)`` (``_draft_tokens``; temperature 0 is the argmax). The
+    prefill chunks wrote the module's rows of the prompt
+    (``forward_paged``); this is the one pair that needs a token the
+    prefill had not drawn yet. Returns (draft int32 scalar, caches,
+    routed)."""
+    kind = config.layer_kinds[0]
+    cparams = jax.tree.map(
+        lambda p: p if p.dtype == jnp.int8 else p.astype(config.dtype),
+        params)
+    flat, nb = _flat_pools(config, kind, caches, block_size)
+    at = jnp.reshape(pos, (1,)).astype(jnp.int32)
+    y, flat, routed = _mtp_step(
+        config, cparams, h_last, jnp.reshape(token, (1, 1)), at,
+        jnp.ones((1,), jnp.int32), flat, nb, block_row[None],
+        block_size)
+    sampling = {'temps': jnp.reshape(temperature, (1,)),
+                'top_ps': jnp.reshape(top_p, (1,)),
+                'seeds': jnp.reshape(seed, (1,))}
+    draft = _draft_tokens(mtp_logits(config, cparams, y[:, 0]),
+                          sampling, at + 1)
+    return draft[0], _unflat_pools(flat, nb), \
+        _routed_sums(routed[None, None])
 
 
 def decode_shardings(config: llama.LlamaConfig, mesh,

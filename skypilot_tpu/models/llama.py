@@ -177,6 +177,17 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: tuple = (-30.0, 30.0)
+    # ---- Next-token-prediction modules (DeepSeek-V3 report, section
+    # 2.2; ``num_nextn_predict_layers``). With ``nextn_layers`` = 1
+    # the model carries one module (leaves ``params['mtp']``): two
+    # norms, a ``2 d -> d`` projection of [the next token's embedding
+    # ; the main stack's normed state], one expert layer in the main
+    # layers' form with a cache entry of its own (the latent group's
+    # last, ``kv_entries``), a norm, and the main model's head. The
+    # paged engine runs it as its drafter (``models/decode.py``:
+    # ``mtp_module``, ``mtp_rounds_paged``); it changes no logit of
+    # the model. ----
+    nextn_layers: int = 0
 
     def __post_init__(self):
         unknown = set(self.remat_saves.split('+')) - {
@@ -213,6 +224,13 @@ class LlamaConfig:
             raise ValueError(
                 f'kv_lora_rank={self.kv_lora_rank} needs q_lora_rank, '
                 f'qk_nope_head_dim, qk_rope_head_dim and v_head_dim')
+        if self.nextn_layers not in (0, 1) or (self.nextn_layers and (
+                self.kv_lora_rank is None or self.hc_mult != 1
+                or not self.n_experts)):
+            raise ValueError(
+                f'nextn_layers={self.nextn_layers}: one module, on a '
+                f'latent stack with expert layers and one residual '
+                f'stream, is what is implemented')
         if not 0 <= self.dense_first < self.n_layers or (
                 self.dense_first and not self.dense_ffn_hidden):
             raise ValueError(
@@ -236,9 +254,11 @@ class LlamaConfig:
 
     @property
     def kv_entries(self) -> int:
-        """KV caches a token holds: one for every pass and layer.
-        The leading axis of the paged pool (serve/kv_pool.py)."""
-        return self.loop_passes * self.n_layers
+        """KV caches a token holds: one for every pass and layer,
+        and one for a next-token-prediction module's layer (the
+        last). The leading axis of the paged pool
+        (serve/kv_pool.py)."""
+        return self.loop_passes * self.n_layers + self.nextn_layers
 
     @property
     def layer_kinds(self) -> tuple:
@@ -324,10 +344,13 @@ class LlamaConfig:
                + d * self.n_experts
                + (self.n_experts if self.moe_select_bias else 0))
         shared = attn + mixers + 2 * d
+        # A module: an expert layer, its three norms, the projection.
+        module = shared + moe + 3 * d + 2 * d * d
         return (2 * v * d + d +
                 self.dense_first * (shared +
                                     3 * d * self.dense_ffn_hidden) +
-                (self.n_layers - self.dense_first) * (shared + moe))
+                (self.n_layers - self.dense_first) * (shared + moe) +
+                self.nextn_layers * module)
 
     def num_active_params(self) -> int:
         """Params touched per token (== num_params for dense; for MoE
@@ -336,7 +359,8 @@ class LlamaConfig:
             return self.num_params()
         unused = ((self.n_experts_held - self.moe_top_k) *
                   3 * self.dim * self.ffn_hidden *
-                  (self.n_layers - self.dense_first))
+                  (self.n_layers - self.dense_first +
+                   self.nextn_layers))
         return self.num_params() - max(unused, 0)
 
 
@@ -442,6 +466,28 @@ CONFIGS: Dict[str, LlamaConfig] = {
         n_experts=64, moe_top_k=4, moe_score='sigmoid',
         n_shared_experts=1, moe_select_bias=True,
         moe_routed_scale=2.0),
+    # Latent attention and a next-token-prediction module (HF
+    # jdopensource/JoyAI-LLM-Flash config.json, model_type
+    # joyai_llm_flash, 48B-A2.7B: 40 layers of which the first is
+    # dense at width 7,168; 32 heads of 128 + 64 rotated query values
+    # over a 512 + 64 latent row, q rank 1,536; 256 routed experts of
+    # width 768, 8 a token by sigmoid score plus a selection bias,
+    # weights normalised and multiplied by 2.5, 1 shared expert;
+    # interleaved RoPE at theta 32,000,000 with NO rope_scaling, so
+    # the softmax scale is 192^-0.5; an untied head over 129,280 ids;
+    # num_nextn_predict_layers 1, built: ``nextn_layers``). Served by
+    # the paged engine only; fewer layers as a ``get_config``
+    # override.
+    'joyai-llm-flash': LlamaConfig(
+        name='joyai-llm-flash', vocab_size=129280, dim=2048,
+        n_layers=40, n_heads=32, n_kv_heads=32, ffn_hidden=768,
+        rope_theta=32000000.0, norm_eps=1e-6, max_seq_len=4096,
+        rope_interleaved=True, kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        dense_first=1, dense_ffn_hidden=7168,
+        n_experts=256, moe_top_k=8, moe_score='sigmoid',
+        n_shared_experts=1, moe_select_bias=True,
+        moe_routed_scale=2.5, nextn_layers=1),
     # Small configs for tests / CPU dryruns.
     'debug-250m': LlamaConfig(
         name='debug-250m', vocab_size=32000, dim=1024, n_layers=8,
@@ -474,6 +520,16 @@ CONFIGS: Dict[str, LlamaConfig] = {
         n_experts=8, moe_top_k=2, moe_score='sigmoid',
         n_shared_experts=1, moe_select_bias=True,
         moe_routed_scale=2.0),
+    'tiny-latent-mtp': LlamaConfig(
+        name='tiny-latent-mtp', vocab_size=512, dim=128, n_layers=4,
+        n_heads=4, n_kv_heads=4, ffn_hidden=64, rope_theta=10000.0,
+        norm_eps=1e-6, max_seq_len=512, dtype=jnp.float32,
+        remat=False, rope_interleaved=True, kv_lora_rank=48,
+        q_lora_rank=32, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=32, dense_first=1, dense_ffn_hidden=256,
+        n_experts=8, moe_top_k=2, moe_score='sigmoid',
+        n_shared_experts=1, moe_select_bias=True,
+        moe_routed_scale=2.0, nextn_layers=1),
     'tiny-loop': LlamaConfig(
         name='tiny-loop', vocab_size=512, dim=128, n_layers=2,
         n_heads=4, n_kv_heads=4, ffn_hidden=256, max_seq_len=512,
@@ -671,25 +727,38 @@ def _init_latent_params(config: LlamaConfig, key: jax.Array, dense,
         return out
 
     kd, ke, k_embed, k_out = jax.random.split(key, 4)
-    ks = jax.random.split(ke, 8)
-    n_moe = config.n_layers - config.dense_first
     held = config.n_experts_held
     wide = config.n_shared_experts * ffn
-    layers = dict(
-        shared_leaves(ks[0], n_moe),
-        router=dense(ks[1], (n_moe, d, e), d),
-        w_gate=dense(ks[2], (n_moe, held, d, ffn), d),
-        w_up=dense(ks[3], (n_moe, held, d, ffn), d),
-        w_down=dense(ks[4], (n_moe, held, ffn, d), ffn))
-    if config.moe_select_bias:
-        layers['router_bias'] = jnp.zeros((n_moe, e), dtype)
-    if config.n_shared_experts:
-        layers.update(ws_gate=dense(ks[5], (n_moe, d, wide), d),
-                      ws_up=dense(ks[6], (n_moe, d, wide), d),
-                      ws_down=dense(ks[7], (n_moe, wide, d), ffn))
+
+    def expert_layers(k, count):
+        ks = jax.random.split(k, 8)
+        layers = dict(
+            shared_leaves(ks[0], count),
+            router=dense(ks[1], (count, d, e), d),
+            w_gate=dense(ks[2], (count, held, d, ffn), d),
+            w_up=dense(ks[3], (count, held, d, ffn), d),
+            w_down=dense(ks[4], (count, held, ffn, d), ffn))
+        if config.moe_select_bias:
+            layers['router_bias'] = jnp.zeros((count, e), dtype)
+        if config.n_shared_experts:
+            layers.update(ws_gate=dense(ks[5], (count, d, wide), d),
+                          ws_up=dense(ks[6], (count, d, wide), d),
+                          ws_down=dense(ks[7], (count, wide, d), ffn))
+        return layers
+
     params = {'embed': dense(k_embed, (config.vocab_size, d), d),
-              'layers': layers, 'final_norm': norm_init((d,)),
+              'layers': expert_layers(
+                  ke, config.n_layers - config.dense_first),
+              'final_norm': norm_init((d,)),
               'lm_head': dense(k_out, (d, config.vocab_size), d)}
+    if config.nextn_layers:
+        # A key of its own: the other leaves keep their seeds.
+        km = jax.random.split(jax.random.fold_in(key, 0x6d7470))
+        params['mtp'] = {
+            'enorm': norm_init((d,)), 'hnorm': norm_init((d,)),
+            'eh_proj': dense(km[0], (2 * d, d), 2 * d),
+            'layers': expert_layers(km[1], config.nextn_layers),
+            'final_norm': norm_init((d,))}
     if config.dense_first:
         kf = jax.random.split(kd, 4)
         nd, wd = config.dense_first, config.dense_ffn_hidden
@@ -785,6 +854,12 @@ def _latent_sharding_rules(config: LlamaConfig, pl, fs) -> Params:
                       ws_down=P(pl, 'tp', fs))
     rules = {'embed': P('tp', fs), 'layers': layers,
              'final_norm': P(None), 'lm_head': P(fs, 'tp')}
+    if config.nextn_layers:
+        # The module's layer as the expert layers; its projection
+        # and norms whole (a tp mesh has not run it: ROADMAP R4).
+        rules['mtp'] = {'enorm': P(None), 'hnorm': P(None),
+                        'eh_proj': P(fs, None), 'layers': layers,
+                        'final_norm': P(None)}
     if config.dense_first:
         rules['dense_layers'] = dict(
             whole, w_gate=P(pl, fs, 'tp'), w_up=P(pl, fs, 'tp'),

@@ -34,6 +34,25 @@ _LAYER_MATMULS = ('wq', 'wk', 'wv', 'wo', 'w_gate', 'w_up', 'w_down',
                   'ws_gate', 'ws_up', 'ws_down',
                   'wq_a', 'wq_b', 'wkv_a', 'wkv_b')
 _LAYER_STACKS = ('layers', 'dense_layers')
+# Matmul weights that stand alone: the head, and a
+# next-token-prediction module's projection (params['mtp'], whose
+# 'layers' is one more stack).
+_LONE_MATMULS = ('lm_head', 'eh_proj')
+
+
+def _quantize_stack(stack: Params, quantize, other=lambda w: w
+                    ) -> Params:
+    return {name: quantize(w) if name in _LAYER_MATMULS else other(w)
+            for name, w in stack.items()}
+
+
+def _quantize_module(mtp: Params, quantize, other=lambda w: w
+                     ) -> Params:
+    """``params['mtp']``: its layer as the stacks, its projection,
+    its three norms left as they are."""
+    return {name: _quantize_stack(w, quantize, other)
+            if name == 'layers' else quantize(w) if name == 'eh_proj'
+            else other(w) for name, w in mtp.items()}
 
 
 def quantize_weight(w: jax.Array) -> Dict[str, jax.Array]:
@@ -84,9 +103,10 @@ def quantize_params(params: Params, config: llama.LlamaConfig
     out = dict(params)
     for stack in _LAYER_STACKS:
         if stack in params:
-            out[stack] = {
-                name: quantize_weight(w) if name in _LAYER_MATMULS
-                else w for name, w in params[stack].items()}
+            out[stack] = _quantize_stack(params[stack],
+                                         quantize_weight)
+    if 'mtp' in params:
+        out['mtp'] = _quantize_module(params['mtp'], quantize_weight)
     if 'lm_head' in params:
         out['lm_head'] = quantize_weight(params['lm_head'])
     return out
@@ -134,12 +154,12 @@ def init_quantized(config: llama.LlamaConfig, key: jax.Array,
     for i, (path, sd) in enumerate(flat):
         name = path[-1].key
         leaf = init_leaf(name, sd, jax.random.fold_in(key, i))
-        if name in _LAYER_MATMULS or name == 'lm_head':
+        if name in _LAYER_MATMULS + _LONE_MATMULS:
             leaf = quantize(leaf)  # frees the wide original
-        if len(path) == 2:
-            out.setdefault(path[0].key, {})[name] = leaf
-        else:
-            out[name] = leaf
+        node = out
+        for part in path[:-1]:
+            node = node.setdefault(part.key, {})
+        node[name] = leaf
     return out
 
 
@@ -152,15 +172,19 @@ def quantize_params_streamed(params: Params,
     quantize = jax.jit(quantize_weight)
     cast = jax.jit(lambda x: x.astype(config.dtype))
 
+    def to_device(leaf):
+        return cast(jnp.asarray(leaf))
+
     out = dict(params)
     for stack in _LAYER_STACKS:
         if stack in params:
-            out[stack] = {
-                name: quantize(leaf) if name in _LAYER_MATMULS
-                else cast(jnp.asarray(leaf))
-                for name, leaf in params[stack].items()}
+            out[stack] = _quantize_stack(params[stack], quantize,
+                                         to_device)
+    if 'mtp' in params:
+        out['mtp'] = _quantize_module(params['mtp'], quantize,
+                                      to_device)
     for name in params:
-        if name not in _LAYER_STACKS + ('lm_head',):
+        if name not in _LAYER_STACKS + ('lm_head', 'mtp'):
             # embed, final_norm and the exit gate's two leaves.
             out[name] = cast(jnp.asarray(params[name]))
     if 'lm_head' in params:

@@ -970,11 +970,51 @@ def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
     return out.astype(q.dtype)
 
 
+def latent_verify_attention(q_lat: jax.Array, q_pe: jax.Array,
+                            view: jax.Array, lengths: jax.Array,
+                            scale: float, new: jax.Array,
+                            first: int = 0) -> jax.Array:
+    """``latent_decode_attention`` at SEVERAL query positions a row
+    (a verify step's draft window, a drafting round's two): q_lat [B,
+    T, H, rank], q_pe [B, T, H, rope] at positions lengths[b] + t;
+    ``new`` [B, T, W] the window's own latent rows, operands as
+    there. Query t sees the view's slots [``first``, lengths[b]) and
+    the window's rows [0, t] (the intra-draft causal mask). ``first``
+    (static): slots below it hold nothing and are never seen (a
+    next-token-prediction module's entry keeps slot 0 empty).
+    Returns [B, T, H, rank] in q's type."""
+    rank = q_lat.shape[-1]
+    t = q_lat.shape[1]
+    q = latent_row(q_lat, q_pe)                       # [B, T, H, W]
+    slot = jnp.arange(view.shape[1])[None, :]
+    seen = (slot >= first) & (slot < lengths[:, None])    # [B, S]
+    logits = jnp.einsum('bthw,bsw->bths', q, view,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(seen[:, None, None, :], logits, _NEG_INF)
+    own = jnp.einsum('bthw,bjw->bthj', q, new,
+                     preferred_element_type=jnp.float32) * scale
+    causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]  # [T, J]
+    own = jnp.where(causal[None, :, None, :], own, _NEG_INF)
+    top = jnp.maximum(jnp.max(logits, axis=-1), jnp.max(own, axis=-1))
+    p = jnp.exp(logits - top[..., None])
+    p_own = jnp.exp(own - top[..., None])
+    total = jnp.sum(p, axis=-1) + jnp.sum(p_own, axis=-1)
+    out = jnp.einsum('bths,bsc->bthc',
+                     (p / total[..., None]).astype(q.dtype),
+                     view[..., :rank],
+                     preferred_element_type=jnp.float32)
+    out = out + jnp.einsum('bthj,bjc->bthc', p_own / total[..., None],
+                           new[..., :rank].astype(jnp.float32))
+    return out.astype(q.dtype)
+
+
 def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
                            new: jax.Array, pool: jax.Array,
                            block_row: jax.Array, start: jax.Array,
                            scale: float, expand: Callable, rank: int,
-                           tile_blocks: Optional[int] = None):
+                           tile_blocks: Optional[int] = None,
+                           first: int = 0,
+                           hidden: Optional[jax.Array] = None):
     """One request's PREFILL CHUNK over a latent pool, EXPANDED:
     q_nope [T, H, nope] and q_pe [T, H, rope] at positions start + t;
     ``new`` [T, W] the chunk's own latent rows (``latent_row``; an
@@ -992,7 +1032,16 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
     ``chunk_attention`` does: the expanded keys of 18 k positions x
     32 heads x 192 never exist at once, nor a view of ``max_seq``.
     Returns [T, H, vd] in q's type; padded query rows give rows the
-    caller discards."""
+    caller discards.
+
+    A next-token-prediction module's entry (``models/decode.py``:
+    ``mtp_module``) adds two things. ``first`` (static): slots below
+    it hold nothing and no query sees them (but the one standing
+    there, so that its sum is not empty). ``hidden`` (traced count):
+    the chunk's first ``hidden`` rows are recomputed lanes whose own
+    rows are not to be trusted; every other query reads their slots
+    from the pool instead, i.e. the cached part ends at ``start +
+    hidden``."""
     t, heads, _ = q_nope.shape
     bs, width = pool.shape[1], pool.shape[2]
     rope = q_pe.shape[-1]
@@ -1031,15 +1080,28 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
             rows = jnp.take(pool, cols, axis=0, mode='clip').reshape(
                 tile, width)
         key_pos = i * tile + jnp.arange(tile, dtype=jnp.int32)
-        seen = jnp.broadcast_to((key_pos < start)[None, :], (t, tile))
+        cached = key_pos < cached_end
+        if first:
+            cached &= key_pos >= first
+        seen = jnp.broadcast_to(cached[None, :], (t, tile))
         return fold(carry, rows, seen)
 
     # The chunk's own rows first (every query sees its own, so the
     # running maximum is finite from the start), then the cached
     # tiles: the sum's order is not the positions', which a softmax
     # does not mind.
-    carry = fold(None, new, q_pos[None, :] <= q_pos[:, None])
-    _, total, acc = jax.lax.fori_loop(0, -(-start // tile), one_tile,
-                                      carry)
+    own = q_pos[None, :] <= q_pos[:, None]
+    cached_end = start
+    if first or hidden is not None:
+        # A row is always seen by its own query.
+        itself = q_pos[None, :] == q_pos[:, None]
+        if hidden is not None:
+            cached_end = start + hidden
+            own &= (q_pos >= cached_end)[None, :] | itself
+        if first:
+            own &= (q_pos >= first)[None, :] | itself
+    carry = fold(None, new, own)
+    _, total, acc = jax.lax.fori_loop(0, -(-cached_end // tile),
+                                      one_tile, carry)
     out = (acc / total).astype(q_nope.dtype)             # [H, T, vd]
     return jnp.swapaxes(out, 0, 1)

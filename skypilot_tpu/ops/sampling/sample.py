@@ -42,27 +42,37 @@ def _filter_top_p_row(logits: jax.Array,
     sorted_desc = jnp.flip(jnp.sort(logits))
     probs = jax.nn.softmax(sorted_desc)
     cum = jnp.cumsum(probs)
-    outside = (cum - probs) >= top_p
+    # top_p = 1 keeps every token: the float32 running sum reaches 1
+    # a few tokens before the end, and would cut a tail of about 1e-6
+    # of the mass that no nucleus was asked to cut.
+    outside = ((cum - probs) >= top_p) & (top_p < 1.0)
     kth = jnp.where(outside, jnp.inf, sorted_desc).min()
     return jnp.where(logits < kth, NEG_INF, logits)
 
 
-def _sample_row(logits: jax.Array, temperature: jax.Array,
-                top_p: jax.Array, seed: jax.Array,
-                position: jax.Array,
-                allowed: Optional[jax.Array]) -> jax.Array:
-    """One row: greedy argmax when ``temperature <= 0`` (bitwise the
-    pre-sampling engine behavior), else top-p + temperature
-    categorical keyed (seed, position)."""
-    logits = logits.astype(jnp.float32)
-    if allowed is not None:
-        logits = jnp.where(allowed, logits, NEG_INF)
-    greedy = logits.argmax(-1)
-    filtered = _filter_top_p_row(logits, top_p)
+def _filter_rows(logits: jax.Array, top_ps: jax.Array) -> jax.Array:
+    """``_filter_top_p_row`` over rows [N, V], skipped as a whole
+    where no row asks for a nucleus (every ``top_p`` is 1, at which
+    the filter keeps every token anyway): a full sort of the
+    vocabulary a row and draw is most of a sampled step's sampler on
+    the chip, and a mix at top_p 1 never needs it."""
+    return jax.lax.cond(
+        jnp.any(top_ps < 1.0),
+        lambda: jax.vmap(_filter_top_p_row)(logits, top_ps),
+        lambda: logits)
+
+
+def _draw_row(logits: jax.Array, filtered: jax.Array,
+              temperature: jax.Array, seed: jax.Array,
+              position: jax.Array) -> jax.Array:
+    """One row: greedy argmax of ``logits`` when ``temperature <=
+    0`` (bitwise the pre-sampling engine behavior), else the
+    temperature categorical over ``filtered`` (the row's logits
+    through its nucleus) keyed (seed, position)."""
     t_safe = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
     key = prng.row_key(seed, position)
     sampled = jax.random.categorical(key, filtered / t_safe)
-    return jnp.where(temperature <= 0.0, greedy,
+    return jnp.where(temperature <= 0.0, logits.argmax(-1),
                      sampled).astype(jnp.int32)
 
 
@@ -74,15 +84,18 @@ def sample_rows(logits: jax.Array, temperatures: jax.Array,
 
     ``logits`` [B, V]; ``temperatures``/``top_ps``/``seeds``/
     ``positions`` [B] traced; ``allowed`` optional [B, V] bool.
-    Returns int32 [B]. Each row is independent — the vmap carries no
-    cross-row state, which is the batch-invariance property.
+    Returns int32 [B]: the greedy argmax where ``temperature <= 0``,
+    else top-p + temperature categorical keyed (seed, position).
+    Each row is independent — nothing carries cross-row state but
+    the one question whether ANY row wants a nucleus
+    (``_filter_rows``), which changes no row's result: that is the
+    batch-invariance property.
     """
-    if allowed is None:
-        return jax.vmap(
-            lambda l, t, p, s, c: _sample_row(l, t, p, s, c, None)
-        )(logits, temperatures, top_ps, seeds, positions)
-    return jax.vmap(_sample_row)(logits, temperatures, top_ps, seeds,
-                                 positions, allowed)
+    logits = logits.astype(jnp.float32)
+    if allowed is not None:
+        logits = jnp.where(allowed, logits, NEG_INF)
+    return jax.vmap(_draw_row)(logits, _filter_rows(logits, top_ps),
+                               temperatures, seeds, positions)
 
 
 def sample_first(logits: jax.Array, temperature: jax.Array,
@@ -94,8 +107,11 @@ def sample_first(logits: jax.Array, temperature: jax.Array,
     Same keying as decode at the same absolute position, so the
     prompt/decode boundary is invisible to the (seed, position)
     contract. Returns an int32 scalar."""
-    return _sample_row(logits[0], temperature, top_p, seed, position,
-                       allowed)[()]
+    one = jnp.reshape
+    return sample_rows(
+        logits[:1], one(temperature, (1,)), one(top_p, (1,)),
+        one(seed, (1,)), one(position, (1,)),
+        None if allowed is None else allowed[None])[0]
 
 
 def verify_targets(logits: jax.Array, temperatures: jax.Array,
@@ -114,21 +130,14 @@ def verify_targets(logits: jax.Array, temperatures: jax.Array,
     ``allowed`` optional [B, W, V]: per-position grammar masks walked
     host-side along the draft path. Returns int32 [B, W].
     """
-    w = logits.shape[1]
+    b, w, v = logits.shape
     positions = pos[:, None] + jnp.arange(w, dtype=pos.dtype)[None, :]
 
-    def one_row(l, t, p, s, c, a):
-        if a is None:
-            return jax.vmap(
-                lambda lj, cj: _sample_row(lj, t, p, s, cj, None)
-            )(l, c)
-        return jax.vmap(
-            lambda lj, cj, aj: _sample_row(lj, t, p, s, cj, aj)
-        )(l, c, a)
+    def lanes(knob):
+        return jnp.repeat(knob, w)
 
-    if allowed is None:
-        return jax.vmap(
-            lambda l, t, p, s, c: one_row(l, t, p, s, c, None)
-        )(logits, temperatures, top_ps, seeds, positions)
-    return jax.vmap(one_row)(logits, temperatures, top_ps, seeds,
-                             positions, allowed)
+    return sample_rows(
+        logits.reshape(b * w, v), lanes(temperatures), lanes(top_ps),
+        lanes(seeds), positions.reshape(-1),
+        None if allowed is None else allowed.reshape(b * w, v)
+    ).reshape(b, w)

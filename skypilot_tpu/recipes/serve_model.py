@@ -37,8 +37,10 @@ def _serve(starting: contextlib.ExitStack):
              'command-a-plus (window and global layers, a share of '
              'the experts) and xing4.0-29b-a4b (latent attention, '
              'four residual streams, dense layers before the expert '
-             'layers: bf16 latent cache, so no --kv-int8, and '
-             '--speculative off)')
+             'layers: bf16 latent cache, so no --kv-int8) and '
+             'joyai-llm-flash (latent attention and a '
+             'next-token-prediction module: --speculative mtp makes '
+             'it the drafter)')
     parser.add_argument('--port', type=int,
                         default=int(os.environ.get(
                             'SKYTPU_REPLICA_PORT', '8080')))
@@ -116,16 +118,22 @@ def _serve(starting: contextlib.ExitStack):
                              'their prefill (token-exact under '
                              'greedy decoding; engine.prefix_caching '
                              'in the service YAML)')
-    parser.add_argument('--speculative', choices=['on', 'off'],
-                        default=('on' if os.environ.get(
-                            'SKYTPU_ENGINE_SPECULATIVE', '1')
-                            not in ('0', 'off', 'false') else 'off'),
+    parser.add_argument('--speculative', choices=['on', 'off', 'mtp'],
+                        default={'0': 'off', 'off': 'off',
+                                 'false': 'off', 'mtp': 'mtp'}.get(
+                            os.environ.get(
+                                'SKYTPU_ENGINE_SPECULATIVE', '1'),
+                            'on'),
                         help='speculative decoding on the paged '
                              'engine: self-speculative n-gram '
                              'drafting + batched multi-token verify '
                              '(token-exact under greedy decoding; '
                              'engine.speculative in the service '
-                             'YAML)')
+                             'YAML). mtp: the model\'s own '
+                             'next-token-prediction module drafts on '
+                             'the device, one token a round (a model '
+                             'with nextn_layers; token-exact for '
+                             'greedy and sampled rows)')
     parser.add_argument('--draft-k', type=int,
                         default=int(os.environ.get(
                             'SKYTPU_ENGINE_DRAFT_K', '8')),
@@ -253,13 +261,14 @@ def _serve(starting: contextlib.ExitStack):
             parser.error(f'--experts-held {args.experts_held}: {e}')
     else:
         config = llama.get_config(args.model)
-    if args.slots > 0 and args.speculative == 'on' and args.draft_k > 0:
-        # The engine would refuse by the same error while it warms
-        # its verify step up, after the weights are made.
-        try:
-            decode.refuse_latent_speculation(config)
-        except exceptions.NotSupportedError as e:
-            parser.error(f'--speculative on: {e}')
+    if args.speculative == 'mtp' and not (args.slots > 0
+                                          and config.nextn_layers):
+        # The engine would refuse while it is built, after the
+        # weights are made.
+        parser.error(
+            f'--speculative mtp: --model {args.model} has no '
+            f'next-token-prediction module (nextn_layers) or the '
+            f'batching engine is off (--slots 0)')
     if not config.plain_stack and args.slots <= 0:
         # The serial path is the dense layer body, which refuses
         # such a stack on the first request: say so at start-up.
@@ -379,7 +388,8 @@ def _serve(starting: contextlib.ExitStack):
                 window_num_blocks=args.window_num_blocks or None,
                 max_num_batched_tokens=args.max_batched_tokens,
                 prefix_caching=args.prefix_caching == 'on',
-                speculative=args.speculative == 'on',
+                speculative='mtp' if args.speculative == 'mtp'
+                else args.speculative == 'on',
                 draft_k=args.draft_k,
                 max_queued_requests=args.max_queued_requests or None,
                 max_queued_tokens=args.max_queued_tokens or None,

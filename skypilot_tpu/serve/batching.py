@@ -78,6 +78,8 @@ from skypilot_tpu import trace as trace_lib
 from skypilot_tpu.models import llama
 from skypilot_tpu.models.decode import (decode_steps_paged,
                                         forward_paged,
+                                        mtp_first_paged,
+                                        mtp_rounds_paged,
                                         verify_step_paged)
 from skypilot_tpu.ops import decode_attention as da
 from skypilot_tpu.ops.sampling import sample as sample_lib
@@ -594,6 +596,23 @@ def _engine_metrics():
             'Positions those row-steps attended, the step\'s own '
             'counted: over the row-steps it is the mean context a '
             'latent decode step reads.'),
+        'mtp_rounds': reg.counter(
+            'skytpu_batch_mtp_row_rounds_total',
+            'Drafting rounds of active rows (a model whose own '
+            'next-token-prediction module drafts on the device, '
+            'decode.mtp_rounds_paged): rows of a dispatch x its '
+            'rounds. A round verifies the row\'s token and its one '
+            'pending draft and commits one or two tokens.'),
+        'mtp_tokens': reg.counter(
+            'skytpu_batch_mtp_round_tokens_total',
+            'Tokens those rounds committed (what the device '
+            'emitted, before a row\'s budget or EOS cut it): over '
+            'the row-rounds it is the tokens a round yields.'),
+        'mtp_positions': reg.counter(
+            'skytpu_batch_mtp_module_positions_total',
+            'Pairs the module computed for real in those rounds and '
+            'in first drafts (one a committed token); prefill chunks '
+            'count theirs under mla_expanded_tokens_total.'),
         'mla_expanded_tokens': reg.counter(
             'skytpu_batch_mla_expanded_tokens_total',
             'Real prompt tokens prefilled through the expanded form '
@@ -706,6 +725,16 @@ class BatchingEngine:
       0 on low-repeat traffic (the batch then takes the plain scan
       path). A verify row costs draft+1 of the per-iteration token
       budget, so speculation degrades before it can starve prefill.
+      ``'mtp'``: the model's own next-token-prediction module
+      (``config.nextn_layers``) drafts ON THE DEVICE: every decode
+      dispatch is ``steps_per_dispatch`` drafting rounds
+      (``decode.mtp_rounds_paged``), each verifying a row's token and
+      its one pending draft and committing one or two tokens, so a
+      dispatch emits between ``steps`` and ``2 x steps`` tokens a
+      row. The n-gram drafter, ``draft_k`` and the adaptive
+      controller are bypassed (their thresholds price a drafter that
+      costs a dispatch); a round costs a row 2 of the token budget.
+      Outputs stay token-for-token those of plain decode.
     - ``draft_k``: max drafted tokens per row per verify (the
       static verify width is draft_k + 1).
     - ``tenant_weights``: optional per-tenant weights for the
@@ -745,7 +774,7 @@ class BatchingEngine:
                  max_num_batched_tokens: Optional[int] = 2048,
                  prefill_chunk: int = 512,
                  prefix_caching: bool = True,
-                 speculative: bool = True,
+                 speculative=True,
                  draft_k: int = 8,
                  tenant_weights: Optional[Dict[str, float]] = None,
                  max_queued_requests: Optional[int] = None,
@@ -788,7 +817,14 @@ class BatchingEngine:
         # above): drafting/acceptance are host-side; the device-side
         # verify width is STATIC at draft_k + 1 (shorter drafts pad
         # to scratch), so speculation adds exactly one executable.
-        self.speculative = speculative and draft_k > 0
+        self._mtp = speculative == 'mtp'
+        if self._mtp and not config.nextn_layers:
+            raise exceptions.NotSupportedError(
+                f'{config.name!r} has no next-token-prediction '
+                f'module to draft with: speculative=\'mtp\' needs a '
+                f'model with nextn_layers')
+        self.speculative = (bool(speculative) and not self._mtp
+                            and draft_k > 0)
         self.draft_k = max(0, draft_k)
         # Sampling subsystem (serve/sampling/): sampled decode +
         # structured decoding are compiled into the SAME executables
@@ -888,6 +924,18 @@ class BatchingEngine:
                 {} for _ in range(slots)]
             self.pos = jnp.zeros((slots,), jnp.int32)
             self.tokens = jnp.zeros((slots,), jnp.int32)
+            # The module's pending draft of each row's next token,
+            # beside its last token (``speculative='mtp'``).
+            self.drafts = jnp.zeros((slots,), jnp.int32)
+        # A prefilling row's carry for the module: the final-normed
+        # state of its last prefilled position (a device array; None
+        # ahead of the first chunk), and whether its next chunk
+        # opens with a read-only lane (``forward_paged``).
+        self._mtp_h: List[Any] = [None] * slots
+        self._mtp_hidden = [0] * slots
+        # Rows the dispatch in flight granted a draft (what its
+        # rounds proposed, a round each).
+        self._rounds_granted = 0
         # Host-side per-row bookkeeping.
         self.slot_req: List[Optional[_Request]] = [None] * slots
         self.slot_left = [0] * slots
@@ -976,6 +1024,13 @@ class BatchingEngine:
         self._prefill_fn = jax.jit(forward_paged,
                                    static_argnums=(6, 7),
                                    donate_argnums=(2,))
+        self._rounds_fn = jax.jit(mtp_rounds_paged,
+                                  static_argnums=(8, 9, 10),
+                                  static_argnames=('view_blocks',),
+                                  donate_argnums=(3,))
+        self._mtp_first_fn = jax.jit(mtp_first_paged,
+                                     static_argnums=(6, 7),
+                                     donate_argnums=(3,))
         # First-token selection from the final prefill chunk's
         # logits for sampled/constrained rows — keyed at position
         # t0 - 1 (the last prompt token's index), so the
@@ -1003,14 +1058,18 @@ class BatchingEngine:
             # request's decode window (same rationale as the COW
             # prewarm above; the verify width is static, so this is
             # THE executable).
+            # Under the live call's own keywords (``_launch_verify``
+            # passes ``sampling=``, None while every row is greedy): a
+            # call that leaves the keyword out is another signature,
+            # and the first live verify lowered the step anew.
             with jax_runtime.stage('engine.build.prewarm_verify'):
                 *_, self.caches = self._verify_fn(
                     self.params,
-                    jnp.zeros((slots, self.draft_k + 1), jnp.int32),
+                    np.zeros((slots, self.draft_k + 1), np.int32),
                     self.caches, self._tables(), self.pos,
-                    jnp.zeros((slots,), jnp.int32), self.config,
+                    np.zeros((slots,), np.int32), self.config,
                     self.draft_k + 1, self.block_size,
-                    *self._adapter_args())
+                    *self._adapter_args(), sampling=None)
         # Prewarm the decode executable at every width of the table
         # a dispatch may read (``_view_blocks``), through the call
         # the live dispatch makes: a width first met inside a
@@ -1029,6 +1088,13 @@ class BatchingEngine:
                 with jax_runtime.stage(
                         'engine.build.prewarm_decode.width',
                         width=width):
+                    if self._mtp:
+                        # The rounds are this engine's only decode
+                        # program, under one signature (sampled rows
+                        # and greedy ones ride the same knob arrays).
+                        self._enqueue_rounds(
+                            [False] * slots, [False] * slots, width)
+                        continue
                     _, self.caches, *_ = self._step_fn(
                         self.params, self.tokens, self.caches,
                         self._tables(), self.pos,
@@ -1036,6 +1102,22 @@ class BatchingEngine:
                         self.steps, self.block_size,
                         *self._adapter_args(),
                         sampling=None, view_blocks=width)
+            if self._mtp:
+                # A request's first draft and, for sampled rows, its
+                # first token: one executable each, every write to
+                # scratch.
+                self._first_draft(
+                    jnp.zeros((1, 1, config.dim), config.dtype), 0,
+                    np.full((self.max_blocks_per_req,),
+                            kv_pool_lib.SCRATCH_BLOCK, np.int32), 0,
+                    0.0, 1.0, 0)
+                if self.sampling:
+                    self._first_fn(
+                        jnp.zeros((1, config.vocab_size), jnp.float32),
+                        jnp.asarray(1.0, jnp.float32),
+                        jnp.asarray(1.0, jnp.float32),
+                        jnp.asarray(0, jnp.int32),
+                        jnp.asarray(0, jnp.int32), None)
             jax.block_until_ready(self.caches)
         logger.info('Decode step prewarmed at %d table widths %s '
                     '(blocks) in %.1f s.', len(self._view_widths),
@@ -1165,6 +1247,11 @@ class BatchingEngine:
             # touched — the adapter-refusal precedent. serve_model
             # maps GrammarError to 400.
             try:
+                if self._mtp:
+                    raise grammar_lib.GrammarError(
+                        'this engine drafts with the model\'s '
+                        'next-token-prediction module, and a draft '
+                        'under a grammar mask is not implemented')
                 if self._grammar_vocab is None:
                     raise grammar_lib.GrammarError(
                         'this engine serves no structured decoding '
@@ -1371,16 +1458,22 @@ class BatchingEngine:
                                or r.grammar is not None)
             for r in self.slot_req)
 
-    def _knob_rows(self):
-        """Per-slot (temps, top_ps, seeds) lists — empty rows get
-        greedy-neutral values; their lanes are inactive/parked so
-        the draws are never emitted."""
-        temps, tps, seeds = [], [], []
-        for req in self.slot_req:
-            temps.append(req.temperature if req is not None else 0.0)
-            tps.append(req.top_p if req is not None else 1.0)
-            seeds.append(req.seed if req is not None else 0)
-        return temps, tps, seeds
+    def _knob_arrays(self):
+        """Per-slot ``temps`` / ``top_ps`` / ``seeds`` as the jitted
+        steps take them — empty rows get greedy-neutral values;
+        their lanes are inactive/parked so the draws are never
+        emitted."""
+        reqs = self.slot_req
+        return {
+            'temps': jnp.asarray(
+                [r.temperature if r is not None else 0.0
+                 for r in reqs], jnp.float32),
+            'top_ps': jnp.asarray(
+                [r.top_p if r is not None else 1.0 for r in reqs],
+                jnp.float32),
+            'seeds': jnp.asarray(
+                [r.seed if r is not None else 0 for r in reqs],
+                jnp.int32)}
 
     def _sampling_args(self):
         """Traced ``sampling`` kwarg for the jitted decode steps —
@@ -1392,15 +1485,12 @@ class BatchingEngine:
         slot's row of the persistent device mask table."""
         if not self._sampling_needed():
             return None
-        temps, tps, seeds = self._knob_rows()
         idx = [i + 1 if self.slot_req[i] is not None
                and self.slot_req[i].grammar is not None else 0
                for i in range(self.slots)]
-        return {'temps': jnp.asarray(temps, jnp.float32),
-                'top_ps': jnp.asarray(tps, jnp.float32),
-                'seeds': jnp.asarray(seeds, jnp.int32),
-                'mask_table': self._mask_table,
-                'mask_idx': jnp.asarray(idx, jnp.int32)}
+        return dict(self._knob_arrays(),
+                    mask_table=self._mask_table,
+                    mask_idx=jnp.asarray(idx, jnp.int32))
 
     def _verify_sampling_args(self, toks: List[List[int]],
                               n_real: List[int]):
@@ -1413,7 +1503,6 @@ class BatchingEngine:
         if not self._sampling_needed():
             return None
         w = self.draft_k + 1
-        temps, tps, seeds = self._knob_rows()
         con = [i for i in range(self.slots)
                if self.slot_req[i] is not None
                and self.slot_req[i].grammar is not None]
@@ -1434,11 +1523,9 @@ class BatchingEngine:
                 for j in range(1, n_real[i]):
                     st = req.grammar.advance(st, toks[i][j])
                     table[i + 1, j] = req.grammar.allowed(st)
-        return {'temps': jnp.asarray(temps, jnp.float32),
-                'top_ps': jnp.asarray(tps, jnp.float32),
-                'seeds': jnp.asarray(seeds, jnp.int32),
-                'mask_table': jnp.asarray(table),
-                'mask_idx': jnp.asarray(idx, jnp.int32)}
+        return dict(self._knob_arrays(),
+                    mask_table=jnp.asarray(table),
+                    mask_idx=jnp.asarray(idx, jnp.int32))
 
     def _refresh_mask_row(self, row: int) -> None:
         """Push the row's current grammar mask into the device mask
@@ -1992,6 +2079,15 @@ class BatchingEngine:
             # Cache-hit tokens are ALREADY in the row's blocks —
             # prefill starts at the suffix (the whole TTFT win).
             self.slot_off[row] = cached_tokens
+            self._mtp_h[row] = None
+            self._mtp_hidden[row] = 0
+            if self._mtp and cached_tokens:
+                # The module's pair at the first new token needs the
+                # state of the last cached position, which nothing
+                # kept: the first chunk recomputes that position as
+                # a read-only lane (``forward_paged``).
+                self.slot_off[row] = cached_tokens - 1
+                self._mtp_hidden[row] = 1
             self.slot_total[row] = t0
             self.slot_left[row] = 0
             self.slot_len[row] = 0
@@ -2076,13 +2172,24 @@ class BatchingEngine:
             padded = chunk + [0] * (bucket - real)
             self._mark_enqueue()
             chunk_tokens = np.asarray([padded], np.int32)
-            logits, self.caches, routed = self._prefill_fn(
+            mtp = {}
+            if self._mtp:
+                h_prev = self._mtp_h[row]
+                if h_prev is None:
+                    h_prev = jnp.zeros((1, 1, self.config.dim),
+                                       self.config.dtype)
+                mtp = {'mtp': (h_prev, jnp.asarray(
+                    self._mtp_hidden[row], jnp.int32))}
+                self._mtp_hidden[row] = 0
+            logits, self.caches, routed, *h_last = self._prefill_fn(
                 self.params, chunk_tokens, self.caches,
                 self._tables(row),
                 jnp.asarray(off, jnp.int32),
                 jnp.asarray(real, jnp.int32),
                 self.config, self.block_size,
-                *self._adapter_args([self.slot_adapter[row]]))
+                *self._adapter_args([self.slot_adapter[row]]), **mtp)
+            if h_last:
+                self._mtp_h[row] = h_last[0]
         self._metrics['prefill_chunks'].inc()
         self._metrics['prefill_tokens'].inc(real)
         self._metrics['prefill_bucket_tokens'].inc(bucket)
@@ -2318,6 +2425,17 @@ class BatchingEngine:
             self._retire(row)
         elif req.grammar is not None:
             self._refresh_mask_row(row)
+        elif self._mtp:
+            # The pair (state of the last prompt position, the first
+            # token) and the row's first draft.
+            self._mark_enqueue()
+            draft, routed = self._first_draft(
+                self._mtp_h[row], first, self._tables(row), t0 - 1,
+                req.temperature, req.top_p, req.seed)
+            self.drafts = self.drafts.at[row].set(draft)
+            self._routed_pending.append((routed, 1, 1))
+            self._metrics['mtp_positions'].inc()
+        self._mtp_h[row] = None
 
     def _spec_k_for(self, req: _Request) -> int:
         """Current draft length for a request (adaptive controller
@@ -2459,7 +2577,10 @@ class BatchingEngine:
         draft_k+1) when any row carries a live n-gram draft, the
         plain ``steps_per_dispatch`` decode scan otherwise — mixed
         batches verify and 1-token-decode in the same forward
-        (draft-less rows just pad their lanes to scratch).
+        (draft-less rows just pad their lanes to scratch). With the
+        model's own module as the drafter (``speculative='mtp'``)
+        every dispatch is ``steps_per_dispatch`` drafting rounds
+        (``_launch_rounds``).
 
         Three phases on the profiler's clock: ``engine.dispatch``
         (host work up to the return of the enqueue),
@@ -2482,7 +2603,8 @@ class BatchingEngine:
         active_rows, drafts, outputs, t_dispatch = launched
         with trace_lib.phase('engine.device_wait',
                              rows=len(active_rows),
-                             kind='verify' if drafts else 'decode'):
+                             kind='verify' if drafts else
+                             'rounds' if self._mtp else 'decode'):
             host = jax.device_get(outputs)
         # device_get synchronizes: this is real decode wall time, and
         # the device has nothing queued from here on.
@@ -2493,6 +2615,8 @@ class BatchingEngine:
             if drafts:
                 self._finish_verify(active_rows, drafts, host,
                                     dispatch_s)
+            elif self._mtp:
+                self._finish_rounds(active_rows, host, dispatch_s)
             else:
                 self._finish_decode(active_rows, n, host, dispatch_s)
         return True
@@ -2520,6 +2644,11 @@ class BatchingEngine:
             need = min(self.slot_left[i], n)
             if i in drafts:
                 need = max(need, len(drafts[i]) + 1)
+            if self._mtp:
+                # n rounds commit up to 2 n tokens, the last round's
+                # draft is written one position on, and the module's
+                # rows lie one slot past their pairs.
+                need = 2 * n + 1
             target = min(self.slot_len[i] + need, self.max_seq)
             if self._ensure_blocks(i, target):
                 self._release_behind(i, self.slot_len[i])
@@ -2544,6 +2673,8 @@ class BatchingEngine:
                      and self.slot_off[i] >= self.slot_total[i]
                      and self.slot_left[i] > 0
                      for i in range(self.slots)]
+        if self._mtp:
+            return self._launch_rounds(active_rows, is_active)
         active = jnp.asarray(is_active, bool)
         sampling = self._sampling_args()
         view = self._view_blocks(is_active, n, sampling)
@@ -2698,6 +2829,134 @@ class BatchingEngine:
             self._refresh_mask_row(row)
         return row_emitted
 
+    def _round_sampling(self):
+        """``sampling`` of the drafting rounds: the per-row knob
+        arrays whatever the mix (a greedy row rides at temperature 0
+        and reduces to the argmax), so that the rounds have ONE
+        signature and the constructor prewarmed it; None for an
+        engine built with sampling off. No grammar table: a
+        constrained request is refused at submit on this path."""
+        return self._knob_arrays() if self.sampling else None
+
+    def _enqueue_rounds(self, active: List[bool], grant: List[bool],
+                        view: int):
+        """``mtp_rounds_paged`` on the engine's state, through the
+        one call the constructor's prewarm and the live dispatch
+        share. Returns the device's (tokens, counts, routed)."""
+        toks, counts, self.caches, self.pos, self.tokens, \
+            self.drafts, routed = self._rounds_fn(
+                self.params, self.tokens, self.drafts, self.caches,
+                self._tables(), self.pos, jnp.asarray(active, bool),
+                jnp.asarray(grant, bool), self.config, self.steps,
+                self.block_size, sampling=self._round_sampling(),
+                view_blocks=view)
+        return toks, counts, routed
+
+    def _first_draft(self, h_last, token: int, table_row, pos: int,
+                     temperature: float, top_p: float, seed: int):
+        """``mtp_first_paged`` for one row (prewarm and live alike).
+        Returns (draft, routed), both on the device."""
+        draft, self.caches, routed = self._mtp_first_fn(
+            self.params, h_last, jnp.asarray(token, jnp.int32),
+            self.caches, table_row, jnp.asarray(pos, jnp.int32),
+            self.config, self.block_size,
+            jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_p, jnp.float32),
+            jnp.asarray(seed, jnp.int32))
+        return draft, routed
+
+    def _launch_rounds(self, active_rows: List[int],
+                       is_active: List[bool]):
+        """One dispatch of ``steps_per_dispatch`` drafting rounds
+        over every decode-ready row (``mtp_rounds_paged``). A round
+        costs a row 2 of the per-iteration token budget: every row's
+        own token is free, as in plain decode, and drafts are granted
+        oldest first from what prefill left; a row without a grant
+        verifies its token alone. The host-side ``spec_k`` controller
+        is not consulted: its thresholds price a drafter that costs
+        a dispatch, and this one rides the round."""
+        n = self.steps
+        left = float('inf') if self.max_batched_tokens is None else (
+            self.max_batched_tokens - self._prefill_spent_iter -
+            len(active_rows))
+        grant = [False] * self.slots
+        for i in sorted(active_rows, key=lambda i: self.slot_seq[i]):
+            if left <= 0:
+                break
+            grant[i] = is_active[i]
+            left -= 1
+        longest = max((self.slot_len[i] for i in range(self.slots)
+                       if is_active[i]), default=0)
+        view = da.view_width(self._view_widths, longest + 2 * n + 1,
+                             self.block_size)
+        self._count_view(view)
+        t_dispatch = time.perf_counter()
+        self._mark_enqueue(t_dispatch)
+        toks, counts, routed = self._enqueue_rounds(is_active, grant,
+                                                    view)
+        # Every lane of both query positions computes, in the main
+        # layers and in the module's.
+        self._routed_pending.append((routed, 2 * self.slots, n))
+        self._rounds_granted = sum(
+            1 for i in active_rows if grant[i])
+        return active_rows, {}, (toks, counts), t_dispatch
+
+    def _finish_rounds(self, active_rows: List[int], host,
+                       dispatch_s: float) -> None:
+        """Commit and emit a dispatch of drafting rounds: per row
+        the tokens its rounds committed, in order (one or two a
+        round), up to its budget or EOS."""
+        host_toks, host_counts = host
+        self._count_routed()
+        n = host_counts.shape[1]
+        t_chunk_end = time.time()
+        t_chunk_start = t_chunk_end - dispatch_s
+        m = self._metrics
+        emitted = committed = 0
+        row_steps = context = 0
+        for i in active_rows:
+            counts = host_counts[i]
+            total = int(counts.sum())
+            if not total:
+                continue
+            # Round r of a row that stood at L: two main positions
+            # (contexts L + 1 and L + 2, their own counted) and the
+            # module's one or two (L + 1, L + 2 less its empty slot).
+            before = self.slot_len[i] + np.concatenate(
+                [[0], np.cumsum(counts)[:-1]])
+            row_steps += 2 * n + total
+            context += int((2 * before + 3).sum() +
+                           (counts * before + counts *
+                            (counts - 1) // 2).sum())
+            committed += total
+            self.slot_len[i] = min(self.slot_len[i] + total,
+                                   self.max_seq)
+            kept = np.arange(2)[None, :] < counts[:, None]
+            emitted += self._emit_tokens(
+                i, host_toks[i][kept], t_chunk_start, t_chunk_end)
+        proposed = n * self._rounds_granted
+        accepted = committed - int((host_counts > 0).sum())
+        m['mtp_rounds'].inc(n * len(active_rows))
+        m['mtp_tokens'].inc(committed)
+        m['mtp_positions'].inc(committed)
+        m['mla_absorbed_row_steps'].inc(row_steps)
+        m['mla_absorbed_context'].inc(context)
+        if proposed:
+            m['spec_proposed'].inc(proposed)
+            self._spec_proposed_local += proposed
+        if accepted:
+            m['spec_accepted'].inc(accepted)
+        self._spec_accepted_local += accepted
+        m['spec_tokens_per_forward'].set(
+            committed / max(1, n * len(active_rows)))
+        if dispatch_s > 0:
+            m['tok_s'].set(emitted / dispatch_s)
+        self.events.append(('decode', len(active_rows)))
+        self.events.append(('rounds', len(active_rows), proposed,
+                            accepted))
+        if emitted:
+            self._count_tokens(emitted)
+
     def _launch_verify(self, active_rows: List[int],
                        drafts: Dict[int, List[int]]):
         """One speculative VERIFY dispatch: every decode-ready row
@@ -2730,9 +2989,9 @@ class BatchingEngine:
         self._mark_enqueue(t_dispatch)
         preds, accepted, self.pos, self.tokens, self.caches = \
             self._verify_fn(
-                self.params, jnp.asarray(toks, jnp.int32),
+                self.params, np.asarray(toks, np.int32),
                 self.caches, self._tables(), self.pos,
-                jnp.asarray(n_real, jnp.int32), self.config, w,
+                np.asarray(n_real, np.int32), self.config, w,
                 self.block_size, *self._adapter_args(),
                 sampling=self._verify_sampling_args(toks, n_real))
         return active_rows, drafts, (preds, accepted), t_dispatch

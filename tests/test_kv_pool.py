@@ -95,6 +95,21 @@ class TestKVBlockPool:
         assert pool.block_bytes * pool.num_blocks == pool.nbytes
 
 
+    def test_a_module_adds_an_entry_to_the_latent_group(self):
+        """A next-token-prediction module's layer keeps a cache
+        entry of its own, the latent group's last: n_layers + 1
+        entries, the bytes a token costs with it."""
+        plain = llama.get_config('tiny-latent-moe')
+        mtp = llama.get_config('tiny-latent-mtp')
+        pools = {c.name: kv_pool.KVBlockPool(c, 8, 4)
+                 for c in (plain, mtp)}
+        rows = pools[mtp.name].caches[0]
+        assert rows.shape[0] == mtp.n_layers + 1 == mtp.kv_entries
+        assert pools[plain.name].caches[0].shape[0] == plain.n_layers
+        assert pools[mtp.name].token_bytes * plain.n_layers == \
+            pools[plain.name].token_bytes * (mtp.n_layers + 1)
+
+
 class TestIndexMath:
 
     def test_read_indices_flatten_blocks(self):

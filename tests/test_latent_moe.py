@@ -725,14 +725,16 @@ def test_the_dense_bodies_and_the_verify_step_refuse(model):
     with pytest.raises(exceptions.NotSupportedError,
                        match='latent attention'):
         decode.greedy_generate(params, tokens, config, 2)
+    # The verify step has a latent body since PR 43, and the n-gram
+    # drafter an engine to ride; what is refused is a drafter the
+    # model does not have.
+    preds, accepted, *_ = decode.verify_step_paged(
+        params, tokens[:, :1], _pool(config), _tables(1),
+        jnp.asarray([0]), jnp.asarray([1]), config, 1, _BLOCK)
+    assert preds.shape == (1, 1) and int(accepted[0]) == 0
     with pytest.raises(exceptions.NotSupportedError,
-                       match='speculative decoding is not implemented'):
-        decode.verify_step_paged(
-            params, tokens[:, :1], _pool(config), _tables(1),
-            jnp.asarray([0]), jnp.asarray([1]), config, 1, _BLOCK)
-    with pytest.raises(exceptions.NotSupportedError,
-                       match='speculative decoding is not implemented'):
-        _engine(params, config, speculative=True)
+                       match='no next-token-prediction module'):
+        _engine(params, config, speculative='mtp')
 
 
 def test_config_counts_and_kinds():
@@ -876,10 +878,11 @@ class TestRecipe:
     def test_the_recipe_names_the_preset_and_refuses_speculation(
             self, monkeypatch, capsys):
         """``--help`` names the preset among the stacks only the
-        engine runs; ``--speculative on`` with it is refused at
-        start-up by ``verify_step_paged``'s own error, before any
-        weight is made; without ``--slots`` it is refused as the
-        other such stacks are."""
+        engine runs; ``--speculative mtp`` with it is refused at
+        start-up, before any weight is made, because it has no
+        module to draft with (``--speculative on`` is served since
+        PR 43: the verify step has a latent body); without
+        ``--slots`` it is refused as the other such stacks are."""
         import sys
 
         from skypilot_tpu.recipes import serve_model
@@ -888,8 +891,8 @@ class TestRecipe:
         with pytest.raises(SystemExit):
             serve_model.main()
         assert 'xing4.0-29b-a4b' in capsys.readouterr().out
-        for extra, said in ((['--slots', '2', '--speculative', 'on'],
-                             'verify_step_paged has no latent body'),
+        for extra, said in ((['--slots', '2', '--speculative', 'mtp'],
+                             'has no next-token-prediction module'),
                             ([], 'pass --slots N')):
             monkeypatch.setattr(sys, 'argv', [
                 'serve_model', '--model', 'tiny-latent-moe'] + extra)

@@ -198,13 +198,14 @@ def test_the_three_paged_bodies_agree_on_one_position(name,
 
 
 def test_the_latent_bodies_agree_on_one_position(monkeypatch):
-    """A latent layer has two bodies (``verify_step_paged`` refuses
-    the configuration by name): position p of each row from a
-    one-token ``forward_paged`` chunk, the EXPANDED form over the
-    cached latent rows, and from one step of ``decode_steps_paged``,
-    the ABSORBED form over the same rows. The same token, logits
-    equal to float32 tolerance, and the same latent row written at p
-    for every entry, dense layers' and expert layers' alike."""
+    """A latent layer has three bodies: position p of each row from
+    a one-token ``forward_paged`` chunk, the EXPANDED form over the
+    cached latent rows, from one step of ``decode_steps_paged``, the
+    ABSORBED form over the same rows, and from a width-1
+    ``verify_step_paged`` (its latent body, since PR 43). The same
+    token, logits equal to float32 tolerance, and the same latent
+    row written at p for every entry, dense layers' and expert
+    layers' alike."""
     config = llama.get_config('tiny-latent-moe')
     params = llama.init_params(config, jax.random.PRNGKey(0))
     rng = np.random.default_rng(9)
@@ -258,12 +259,18 @@ def test_the_latent_bodies_agree_on_one_position(monkeypatch):
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
     assert np.abs(want[..., :config.latent_width]).min(axis=-1).max() \
         > 0 and not want[..., config.latent_width:].any()
-    from skypilot_tpu import exceptions
-    with pytest.raises(exceptions.NotSupportedError,
-                       match='verify_step_paged has no latent body'):
+    preds, accepted, verify_pos, _, verify_pools = \
         decode.verify_step_paged(
             params, token[:, None], pools, tables, pos,
             jnp.ones((2,), jnp.int32), config, 1, _BLOCK)
+    assert np.array_equal(np.asarray(preds)[:, 0],
+                          np.asarray(toks)[:, 0])
+    assert np.asarray(accepted).tolist() == [0, 0]
+    assert np.array_equal(np.asarray(verify_pos), np.asarray(new_pos))
+    np.testing.assert_allclose(
+        np.asarray(verify_pools[0]).reshape(want.shape[0], -1,
+                                            want.shape[-1])[:, slot],
+        want, atol=2e-4, rtol=0)
 
 
 # ---------------------------------------------------------------------

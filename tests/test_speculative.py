@@ -453,6 +453,45 @@ class TestEngineExactness:
 # ---------------------------------------------------------------------
 
 
+class TestVerifyPrewarm:
+    """The constructor warms the verify step under the call its live
+    dispatch makes (``sampling=`` passed by keyword, None while every
+    row is greedy): until PR 43 it left the keyword out, which is
+    another signature, and the first live verify lowered the step
+    anew inside a request's decode window."""
+
+    def test_first_live_verify_lowers_nothing(self, loopy_setup):
+        config, params = loopy_setup
+        lowered = []
+
+        def on_event(event, duration, **kwargs):
+            del duration
+            if event == ('/jax/core/compile/'
+                         'jaxpr_to_mlir_module_duration'):
+                lowered.append(kwargs.get('fun_name'))
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        # Shapes no other test of this module builds, so that the
+        # constructor's own lowering is seen.
+        engine = BatchingEngine(params, config, slots=3, max_seq=96,
+                                steps_per_dispatch=2, draft_k=5,
+                                sampling=False, prefix_caching=False)
+        try:
+            assert 'jit(verify_step_paged)' in lowered
+            # A first request warms its prefill bucket and the host
+            # argmax; the second, the same length, then lowers
+            # nothing but what a live verify would.
+            prompt = [1, 2, 3, 1, 2, 3, 1, 2, 3, 1, 2]
+            _drain(engine.submit(prompt, 2))
+            before = len(lowered)
+            out = _drain(engine.submit(prompt[1:] + [1], 40))
+            verifies = [e for e in engine.events if e[0] == 'verify']
+            assert len(out) == 40 and verifies
+            assert lowered[before:] == []
+        finally:
+            engine.close()
+
+
 class TestSpecPrefixInteraction:
 
     def test_rejected_drafts_never_enter_registered_chains(
