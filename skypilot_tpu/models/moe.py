@@ -16,13 +16,27 @@ This layer has neither:
   here or not, times ``config.moe_routed_scale``;
 - the (token, expert) pairs whose expert this chip HOLDS
   (``config.experts_held`` = (first, count); all experts without it)
-  are sorted by expert and go through three grouped products
-  (``jax.lax.ragged_dot``; int8 expert weights are taken as codes,
-  their per-channel scales applied to each row by its expert) whose
-  cost follows the pairs routed here, in a 512-token chunk as in a
-  decode step. A pair whose expert lives on another chip adds nothing:
-  the result is this share's part of the layer, and no code stands in
-  for the absent chips or for their exchange;
+  are sorted by expert and go through three grouped products (int8
+  expert weights are taken as codes, their per-channel scales applied
+  to each row by its expert) whose cost follows the pairs routed
+  here, in a 512-token chunk as in a decode step. A pair whose expert
+  lives on another chip adds nothing: the result is this share's part
+  of the layer, and no code stands in for the absent chips or for
+  their exchange;
+- which product multiplies is ``_grouped``'s one question
+  (``ops/grouped_matmul.tiles_engage``: platform, operand types,
+  static widths). On a TPU, int8 codes (a decode step, a drafting
+  round, every prefill chunk) go through
+  ``pair_tiled_matmul``, a Pallas kernel whose row tile follows the
+  pairs a group holds and which visits the layer's hit experts
+  once; the CPU and a float expert stack keep
+  ``jax.lax.ragged_dot``, whose row tile on the TPU follows the
+  whole padded array of pairs (256 or 512 rows: 40 to 80 times the
+  work for a group of 6). Either way the product's operand is the
+  WHOLE expert stack (``LayerOf``): a kernel call's operand is a
+  buffer of its own, so a layer's slice would be a copy of it. The
+  kernel indexes (layer, group) itself; ``ragged_dot`` walks all
+  L x G groups with every group outside the layer empty;
 - the shared experts (``n_shared_experts`` gated MLPs side by side in
   the ``ws_*`` leaves) are one dense gated product whose mean is added.
 
@@ -37,6 +51,7 @@ import jax.numpy as jnp
 
 from skypilot_tpu.models import llama
 from skypilot_tpu.models.quant import matmul as _mm
+from skypilot_tpu.ops import grouped_matmul as gm
 
 Params = Dict[str, Any]
 EXPERT_LEAVES = ('w_gate', 'w_up', 'w_down')
@@ -49,8 +64,9 @@ class LayerOf(NamedTuple):
     its own: a layer's slice taken out of the stack first is a copy
     of it (268 MB of codes a product at 16 experts of 4,096 x 4,096,
     in every layer of every step, where the dense products read
-    their slice in place). The product is therefore over all L x G
-    groups, with every group outside the layer empty."""
+    their slice in place). ``ragged_dot`` is therefore over all
+    L x G groups, with every group outside the layer empty; the
+    pair-tiled kernel takes the stack and the index as they are."""
     stack: Any
     layer: jax.Array
 
@@ -99,7 +115,18 @@ def _grouped(xs: jax.Array, w, sizes: jax.Array,
     out] -> [M, out] in xs's type. Quantised ``w`` is read as int8
     codes inside the product; ``group`` [M] (each row's expert) picks
     the row's per-channel scales afterwards, which is exact for
-    per-output-channel scaling. ``w`` may be a ``LayerOf``."""
+    per-output-channel scaling. ``w`` may be a ``LayerOf``. Which
+    product multiplies the codes is ``gm.tiles_engage``'s to say,
+    from the platform, the operands' types and the static shapes."""
+    if _tiles(w, xs.dtype):
+        # The layer's own groups, from the stack where it lies.
+        stack, layer = w if isinstance(w, LayerOf) else (
+            jax.tree.map(lambda a: a[None], w), jnp.int32(0))
+        out = gm.pair_tiled_matmul(xs, stack['q'], layer, sizes)
+        scales = stack['s'].reshape(-1, *stack['s'].shape[2:])
+        group = group + layer * sizes.shape[0]
+        return (out * scales[:, 0].astype(jnp.float32)[group]
+                ).astype(xs.dtype)
     if isinstance(w, LayerOf):
         # All layers' groups end to end, this layer's alone filled.
         per_layer = sizes.shape[0]
@@ -118,6 +145,26 @@ def _grouped(xs: jax.Array, w, sizes: jax.Array,
         out = jax.lax.ragged_dot(xs, w, sizes,
                                  preferred_element_type=jnp.float32)
     return out.astype(xs.dtype)
+
+
+def _tiles(w, rows_dtype) -> bool:
+    """``gm.tiles_engage`` for rows of ``rows_dtype`` on expert
+    weights ``w`` [.., in, out]: plain, ``{'q', 's'}`` or a
+    ``LayerOf`` either."""
+    w = w.stack if isinstance(w, LayerOf) else w
+    codes = isinstance(w, dict) and 'q' in w
+    return gm.tiles_engage(*(w['q'] if codes else w).shape[-2:],
+                           codes=codes, rows_dtype=rows_dtype)
+
+
+def pairs_tiled(experts: Params, rows_dtype) -> bool:
+    """Whether the three grouped products of an expert layer go
+    through the pair-tiled kernel: the question ``_grouped`` asks, put
+    to the stacked expert leaves ``experts[name]`` [L, G, in, out] for
+    rows of ``rows_dtype``. One answer for every program of an
+    engine."""
+    return all(_tiles(experts[name], rows_dtype)
+               for name in EXPERT_LEAVES)
 
 
 def moe_layer(config: llama.LlamaConfig, h: jax.Array, lp: Params,

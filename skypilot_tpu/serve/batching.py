@@ -76,6 +76,7 @@ from skypilot_tpu import metrics as metrics_lib
 from skypilot_tpu import tpu_logging
 from skypilot_tpu import trace as trace_lib
 from skypilot_tpu.models import llama
+from skypilot_tpu.models import moe
 from skypilot_tpu.models.decode import (decode_steps_paged,
                                         forward_paged,
                                         mtp_first_paged,
@@ -573,6 +574,12 @@ def _engine_metrics():
             'the grouped products computed. A layer that drops '
             'tokens moves held / routed off experts_held / '
             'n_experts.'),
+        'moe_tiled_pairs': reg.counter(
+            'skytpu_batch_moe_tiled_pairs_total',
+            'Of the held pairs, those whose three grouped products '
+            'went through the pair-tiled kernel '
+            '(ops/grouped_matmul.tiles_engage, by the static shapes '
+            'of the dispatch\'s or chunk\'s program).'),
         'moe_busiest_pairs': reg.counter(
             'skytpu_batch_moe_busiest_expert_pairs_total',
             'Pairs of the busiest held expert, summed over layers '
@@ -1138,8 +1145,13 @@ class BatchingEngine:
             self._metrics['kv_window_blocks_total'].set(
                 self.wpool.usable_blocks)
         # Pairs the expert layers routed, as device arrays until the
-        # next emit adds them to the counters (``_count_routed``).
+        # next emit adds them to the counters (``_count_routed``),
+        # and which grouped product every program of this engine
+        # takes for them (static: platform, types, widths).
         self._routed_pending: list = []
+        self._pairs_tiled = bool(self.config.n_experts) and \
+            moe.pairs_tiled(self.params['layers'],
+                            self.params['embed'].dtype)
         from skypilot_tpu.utils import profiling as profiling_lib
         self._profiler = profiling_lib.StepProfiler('decode')
         self.thread = threading.Thread(target=self._loop, daemon=True)
@@ -2774,7 +2786,10 @@ class BatchingEngine:
                 jax.device_get(pending):
             layers, held = pairs.shape
             m['moe_routed_pairs'].inc(tokens * steps * top_k * layers)
-            m['moe_held_pairs'].inc(int(pairs.sum()))
+            held_pairs = int(pairs.sum())
+            m['moe_held_pairs'].inc(held_pairs)
+            if self._pairs_tiled:
+                m['moe_tiled_pairs'].inc(held_pairs)
             m['moe_busiest_pairs'].inc(int(pairs.max(axis=1).sum()))
             m['moe_experts_hit'].inc(int(hit_steps.sum()))
             m['moe_experts_held'].inc(layers * held * steps)

@@ -69,6 +69,25 @@ def _ephemeral_port() -> int:
         return s.getsockname()[1]
 
 
+@pytest.fixture(scope='module', autouse=True)
+def _compile_cache_as_found():
+    """A replica started in-process (``recipes/serve_model``) turns
+    JAX's persistent compilation cache on for the whole process, in a
+    directory that the six workers of a whole run share; a file the
+    worker ran later then segfaulted inside the cache's read or its
+    compile-and-write (``tests/test_batching.py``'s engines, in two of
+    four whole runs of PR 44's tree). Each file leaves the setting as
+    it found it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ('jax_enable_compilation_cache', 'jax_compilation_cache_dir')
+    was = [getattr(jax.config, name) for name in names]
+    yield
+    if [getattr(jax.config, name) for name in names] != was:
+        for name, value in zip(names, was):
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture(autouse=True)
 def _isolated_state(tmp_path, monkeypatch, request):
     """Every test gets a fresh state dir / config — except the
