@@ -408,17 +408,17 @@ def run(loaded: Dict[str, Any], seed: int, seconds: float, trace: bool,
     # ---- correct: the served tokens against the plain reference
     results: List[Dict[str, Any]] = []
     t_ref = time.perf_counter()
-    gap = check_served(
-        loaded, served.params, served.model,
-        drove['finished_in_window'] or drove['complete'], seed,
-        weight_format=None)['served']
+    gaps = check_served(loaded, served.params, served.model, drove,
+                        seed, weight_format=None)
     ref_s = time.perf_counter() - t_ref
     ok = harness.compared('compilations_in_window', compiles.inside,
                           0, results)
     ok &= harness.compared('requests_ended_wrong',
                            len(drove['failed']), 0, results)
+    ok &= harness.compared('served_tokens_missing', gaps['missing'],
+                           0, results)
     ok &= harness.compared(
-        'served_logit_gap_max', gap,
+        'served_logit_gap_max', gaps['served'],
         config['limits']['served_logit_gap_max'], results)
     harness.say(f'reference check took {ref_s:.1f} s '
                 f'(not counted in setup_s)')
@@ -428,6 +428,7 @@ def run(loaded: Dict[str, Any], seed: int, seconds: float, trace: bool,
              'steps_per_dispatch': served.engine.steps,
              'slots': served.engine.slots,
              'block_size': served.engine.block_size,
+             'experts_held': config.get('experts_held'),
              'kv_bytes': 1 if served.build.get('kv_int8') else 2,
              'weight_bytes': 1 if config['weights'] == 'int8' else 2}
     return {'correct': bool(ok), 'attempted': len(drove['tracked']),
@@ -439,58 +440,108 @@ def run(loaded: Dict[str, Any], seed: int, seconds: float, trace: bool,
             'facts': facts, 'model': served.model, 'compared': results}
 
 
+def check_sample(config: Dict[str, Any], drove: Dict[str, Any],
+                 seed: int) -> List[int]:
+    """Which requests ``correct`` compares, as indices of the mix's
+    order (of ``drove['tracked']``): of the requests that finished
+    inside the window with the last ``check_tokens`` of their tokens
+    all stamped inside it, the longest (prompt + served tokens, ties
+    by the mix's order) and ``check_requests`` - 1 more by the seed.
+    Where fewer than ``check_requests`` such requests are, every
+    request that finished inside the window stands for them, and
+    ``check_served`` counts what is missing. In a backlog most of
+    these are requests of the later waves (an index of ``slots`` or
+    more): they took a freed slot and recycled blocks and met a warm
+    prefix cache."""
+    cap, n = int(config['check_tokens']), int(config['check_requests'])
+    tracked = drove['tracked']
+    inside = {id(i) for i in drove['finished_in_window']}
+    finished = [j for j, i in enumerate(tracked) if id(i) in inside]
+    if not finished:
+        raise harness.HarnessError(
+            'no request finished inside the window: nothing to compare '
+            'with the reference')
+    late = [j for j in finished if len(tracked[j].tokens) >= cap and
+            tracked[j].times[-cap] >= drove['t_open']]
+    among = late if len(late) >= n else finished
+    harness.say(f'reference: {len(finished)} requests finished inside '
+                f'the window, {len(late)} of them with their last '
+                f'{cap} tokens inside it')
+    longest = max(among, key=lambda j: (
+        len(tracked[j].spec['prompt']) + len(tracked[j].tokens), -j))
+    rest = [j for j in among if j != longest]
+    rng = np.random.default_rng([int(seed), 0x6368])
+    return [longest] + [rest[k] for k in
+                        rng.permutation(len(rest))[:n - 1]]
+
+
 def check_served(loaded: Dict[str, Any], params, model,
-                 finished: List[_Tracked], seed: int,
+                 drove: Dict[str, Any], seed: int,
                  weight_format: Optional[str]) -> Dict[str, float]:
     """The widest gap by which a served token's logit lies below the
-    reference's best, over a sample of finished requests drawn from
-    the seed with the longest in it. With ``weight_format`` also the
-    control's reading: the same for the tokens the lower precision
-    would put first."""
+    reference's best, over the LAST ``check_tokens`` tokens of each
+    request of ``check_sample``: its longest context, and a count of
+    tokens that does not grow with the program's speed (a maximum over
+    more tokens reads higher: PERF.md section 6, PR 39 and PR 45). The
+    reference follows the whole request. ``missing`` is how many of
+    ``check_requests`` x ``check_tokens`` tokens stamped inside the
+    window were not there to compare. A request's
+    ``spec['reference']``, where the driver put one, holds what else
+    the reference needs to follow it (a sampled row's temperature and
+    seed). With ``weight_format`` also the control's reading
+    (``lower``): the same for the tokens the lower precision would put
+    first."""
     config = loaded['config']
     reference = harness.reference_for(config)
-    if not finished:
-        raise harness.HarnessError('no request finished: nothing to '
-                                   'compare with the reference')
-    n = int(config['check_requests'])
-    longest = max(finished, key=lambda i: len(i.spec['prompt']) +
-                  len(i.tokens))
-    rest = [i for i in finished if i is not longest]
-    rng = np.random.default_rng([int(seed), 0x6368])
-    picks = [longest] + [rest[j] for j in rng.permutation(
-        len(rest))[:n - 1]]
+    cap = int(config['check_tokens'])
+    picks = check_sample(config, drove, seed)
     widest = {'served': 0.0, 'lower': 0.0}
-    n_tokens = 0
-    for item in picks:
+    summed = {'served': 0.0, 'lower': 0.0}
+    counts, inside = [], 0
+    sizes = []
+    for item in (drove['tracked'][j] for j in picks):
         total = len(item.spec['prompt']) + len(item.tokens)
+        sizes.append(total)
         pad_to = next(b for b in config['check_pad_to'] if b >= total)
         served, lower = reference.served_token_gaps(
-            params, model, item.spec['prompt'], item.tokens, pad_to,
-            weight_format=weight_format)
-        widest['served'] = max(widest['served'], float(served.max()))
-        widest['lower'] = max(widest['lower'], float(lower.max()))
-        n_tokens += len(item.tokens)
-    harness.say(f'reference: {len(picks)} requests, {n_tokens} served '
-                f'tokens, longest sequence '
-                f'{len(longest.spec["prompt"]) + len(longest.tokens)}')
+            params, model, list(item.spec['prompt']), item.tokens,
+            pad_to, weight_format=weight_format,
+            **item.spec.get('reference', {}))
+        for name, gaps in (('served', served), ('lower', lower)):
+            widest[name] = max(widest[name], float(gaps[-cap:].max()))
+            summed[name] += float(gaps[-cap:].sum())
+        counts.append(len(item.tokens[-cap:]))
+        inside += sum(1 for t in item.times[-cap:]
+                      if drove['t_open'] <= t < drove['t_close'])
+    widest['missing'] = float(max(
+        0, int(config['check_requests']) * cap - inside))
+    harness.say(
+        f'reference: requests {picks} of the mix\'s order, the longest '
+        f'first, sequences {sizes}; '
+        f'tokens compared {counts} (the last {cap} of each), {inside} '
+        f'of them stamped inside the window; mean gap a token served '
+        f'{summed["served"] / sum(counts):.4g}' + (
+            f', lower {summed["lower"] / sum(counts):.4g}'
+            if weight_format else ''))
     return widest
 
 
 def control_readings(loaded: Dict[str, Any], seed: int, seconds: float,
                      rehearse: bool) -> Dict[str, Dict[str, float]]:
     """The control of this kind of cell, at the cell's own size: a
-    short window at the cell's own load, then over the same finished
-    requests the program's reading (``sound``) and the reading of the
-    reference computed at the configuration's ``control`` precision
-    (``control``), each under the name of the limit it is held to."""
+    short window at the cell's own load, then over the same requests
+    and tokens (``check_served``) the program's reading (``sound``)
+    and the reading of the reference computed at the configuration's
+    ``control`` precision (``control``), each under the name of the
+    limit it is held to."""
     served = Served(loaded, seed, rehearse)
     requests = loadgen.generator_for(served.traffic['kind'])(
         served.traffic, seed, seconds, served.model['vocab_size'])
     drove = drive(served, requests, seconds)
     served.close()
     gaps = check_served(
-        loaded, served.params, served.model,
-        drove['finished_in_window'] or drove['complete'], seed,
+        loaded, served.params, served.model, drove, seed,
         weight_format=loaded['config']['control']['weight_format'])
-    return {'sound': {'served_logit_gap_max': gaps['served']},
+    return {'sound': {'served_logit_gap_max': gaps['served'],
+                      'served_tokens_missing': gaps['missing']},
             'control': {'served_logit_gap_max': gaps['lower']}}

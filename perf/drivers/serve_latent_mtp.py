@@ -18,14 +18,12 @@ engine build, load, window and result, but for
   engine prewarms its rounds and its first draft itself);
 - ``correct``: a sampled token is held to the reference by the
   Gumbel noise of its own key
-  (``joyai_mtp_block_f32.served_token_gaps``), and the requests
-  checked are chosen by the MIX'S order: the longest of the
-  schedule's first ``slots`` requests (all admitted at once when the
-  lead-in starts) and ``check_requests - 1`` more of them by the
-  seed. What happened to finish in the window has no say: a sample
-  drawn from the finished requests follows the program's speed, and
-  refused PR 39 on a reading that was another sample's (PERF.md
-  section 7 (vi)).
+  (``joyai_mtp_block_f32.served_token_gaps``), so each request
+  carries its temperature and sampling seed for the reference
+  (``spec['reference']``); which requests and how many of their
+  tokens are compared is ``serve_engine.check_served``'s rule, the
+  same for every serving cell since PR 45 (it was this driver's own
+  before).
 
 ``build.speculative`` is the drafter: ``"mtp"`` in the file; the
 environment's ``PERF_MTP_DRAFTER=off`` builds the same engine with
@@ -129,8 +127,8 @@ class _SampledEngine:
 def sampled(requests: List[Dict[str, Any]], traffic: Dict[str, Any],
             seed: int) -> List[Dict[str, Any]]:
     """The mix's requests with its ``sampling`` arguments and each
-    one's own seed attached to the prompt, and its index in the
-    mix's order."""
+    one's own seed attached to the prompt, its index in the mix's
+    order, and what the reference needs to follow a sampled row."""
     knobs = traffic['sampling']
     for index, spec in enumerate(requests):
         prompt = _SampledPrompt(spec['prompt'])
@@ -139,6 +137,9 @@ def sampled(requests: List[Dict[str, Any]], traffic: Dict[str, Any],
             'top_p': float(knobs['top_p']),
             'seed': request_seed(seed, index)}
         spec['prompt'], spec['index'] = prompt, index
+        spec['reference'] = {
+            'temperature': prompt.sampling['temperature'],
+            'seed': prompt.sampling['seed']}
     return requests
 
 
@@ -181,82 +182,24 @@ class Served(base.Served):
         del self.engine._engine.caches
 
 
-def check_served(loaded: Dict[str, Any], params, model,
-                 finished: List[Any], seed: int,
-                 weight_format: Optional[str],
-                 tracked: Optional[List[Any]] = None
-                 ) -> Dict[str, float]:
-    """``serve_engine.check_served`` for sampled rows, over requests
-    chosen by the mix's order (see the module's docstring) among
-    ``tracked``, every request of the window whether it finished or
-    was cut at the close (what it had emitted is checked);
-    ``finished``, what ``serve_engine.run`` hands over, where that is
-    not given."""
-    config = loaded['config']
-    reference = harness.reference_for(config)
-    first = [i for i in tracked or finished
-             if i.spec['index'] < int(config['build']['slots'])
-             and i.tokens]
-    if not first:
-        raise harness.HarnessError(
-            'none of the schedule\'s first requests emitted a token: '
-            'nothing to compare with the reference')
-    n = int(config['check_requests'])
-    longest = max(first, key=lambda i: (len(i.spec['prompt']) +
-                                        i.spec['max_new'],
-                                        -i.spec['index']))
-    rest = [i for i in first if i is not longest]
-    rng = np.random.default_rng([int(seed), 0x6368])
-    picks = [longest] + [rest[j] for j in rng.permutation(
-        len(rest))[:n - 1]]
-    widest = {'served': 0.0, 'lower': 0.0}
-    n_tokens = 0
-    for item in picks:
-        total = len(item.spec['prompt']) + len(item.tokens)
-        pad_to = next(b for b in config['check_pad_to'] if b >= total)
-        knobs = item.spec['prompt'].sampling
-        served, lower = reference.served_token_gaps(
-            params, model, list(item.spec['prompt']), item.tokens,
-            pad_to, weight_format=weight_format,
-            temperature=knobs['temperature'], seed=knobs['seed'])
-        widest['served'] = max(widest['served'], float(served.max()))
-        widest['lower'] = max(widest['lower'], float(lower.max()))
-        n_tokens += len(item.tokens)
-    harness.say(
-        f'reference: requests {[i.spec["index"] for i in picks]} of '
-        f'the mix\'s order, {n_tokens} served tokens, longest '
-        f'sequence {len(longest.spec["prompt"]) + len(longest.tokens)}')
-    return widest
-
-
 def _on_this_system(fn):
     """``serve_engine.run`` and ``control_readings`` build the system
-    under test as ``serve_engine.Served``, load it through ``drive``
-    and compare through ``check_served``, by those names: run them
-    with the names bound to this module's, the load sampled under
-    the run's seed and every request it tracked kept for the
-    comparison."""
+    under test as ``serve_engine.Served`` and load it through
+    ``drive``, by those names: run them with the names bound to this
+    module's, the load sampled under the run's seed."""
     @functools.wraps(fn)
     def call(loaded, seed, *args, **kwargs):
-        theirs = base.Served, base.drive, base.check_served
-        window = {}
+        theirs = base.Served, base.drive
 
         def drive(served, requests, *a, **k):
-            drove = theirs[1](served, sampled(
+            return theirs[1](served, sampled(
                 requests, served.traffic, seed), *a, **k)
-            window['tracked'] = drove['tracked']
-            return drove
 
-        def check(*a, **k):
-            return check_served(*a, tracked=window.get('tracked'),
-                                **k)
-
-        base.Served, base.drive, base.check_served = \
-            Served, drive, check
+        base.Served, base.drive = Served, drive
         try:
             return fn(loaded, seed, *args, **kwargs)
         finally:
-            base.Served, base.drive, base.check_served = theirs
+            base.Served, base.drive = theirs
     return call
 
 

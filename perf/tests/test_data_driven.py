@@ -134,3 +134,63 @@ def test_without_a_tpu_and_without_rehearse_the_run_fails():
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout
     assert 'no accelerator' in proc.stderr
+
+
+def test_per_layer_has_one_entry_a_family_and_judged_metric():
+    """The rule that keeps the list short (PR 45): a new cell puts
+    its NAME into the ``workloads`` lists of the families it reports
+    and adds entries only for readings no family has. The builder's
+    contract admits 128 entries and refuses a 129th before any run."""
+    bench = harness.load_json(_ROOT, 'BENCHMARK.json')
+    entries = bench['per_layer']
+    assert len(entries) <= 128
+    cells = {w['name'] for w in bench['workloads']}
+    reports = {m['name']: set(m.get('workloads', cells))
+               for m in bench['end_to_end']}
+    seen = {}
+    for m in entries:
+        family = m['name'].rpartition('.')[0] or m['name']
+        twin = seen.setdefault((family, m['moves']), m['name'])
+        assert twin == m['name'], (
+            f'{twin} and {m["name"]} are one family moving '
+            f'{m["moves"]}: keep one entry and list both cells')
+        assert m.get('workloads'), m['name']
+        assert len(set(m['workloads'])) == len(m['workloads'])
+        assert set(m['workloads']) <= cells, m['name']
+        assert set(m['workloads']) <= reports[m['moves']], m['name']
+        harness.reader_for(m['name'], harness.PERF_DIR)
+
+
+def test_experts_held_is_the_configurations_own_count():
+    """``moe_busiest_over_mean`` scales by ``experts_held``, which a
+    configuration with experts states beside the model's own key for
+    the experts a replica holds (``num_experts`` where it is a share
+    of the published count, ``n_routed_experts`` where every expert
+    is held), at the real size and at the rehearsal's; the reader
+    takes it from the driver's facts and returns nothing without it."""
+    from perf.layer_metrics import moe_busiest_over_mean as reader
+    bench = harness.load_json(_ROOT, 'BENCHMARK.json')
+    stated = 0
+    for entry in bench['configs']:
+        config = harness.load_json(_ROOT, entry['file'])
+        if config.get('driver') == 'train_step':
+            continue
+        model, tiny = config['model'], config['rehearsal']
+        for held, keys in (
+                (config.get('experts_held'), model),
+                (tiny.get('config', {}).get('experts_held'),
+                 dict(model, **tiny['model']))):
+            own = keys.get('num_experts', keys.get('n_routed_experts'))
+            assert held == own, (entry['name'], held, own)
+        stated += config.get('experts_held') is not None
+    assert stated == 3
+    counts = {'skytpu_batch_moe_busiest_expert_pairs_total': 30.0,
+              'skytpu_batch_moe_held_pairs_total': 640.0}
+
+    class Registry:
+        def delta(self, name):
+            return (counts[name], 0.0) if name in counts else None
+
+    records = {'registry': Registry(), 'facts': {'experts_held': 64}}
+    assert reader.reduce(None, records) == 64 * 30.0 / 640.0
+    assert reader.reduce(None, dict(records, facts={})) is None

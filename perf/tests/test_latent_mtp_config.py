@@ -36,6 +36,20 @@ _CELL = 'serve-reason2k-mtp-backlog'
 _NAME = 'joyai-llm-flash-int8-serve-mtp'
 _CATALOG = '/opt/skills/guides/model-configs/architectures.jsonl'
 _REDUCED = {'num_hidden_layers': (40, 8)}
+# The per-layer entries the cell's name stands in (one entry a family
+# and judged metric since PR 45; the last ten are the readings the
+# cell had no entry for while the list stood at 128), and its own.
+_FAMILIES = {
+    'prefill_chunk_ms.backlog', 'iter_host_gap_ms.backlog',
+    'prefix_hit_pct.backlog', 'tokens_per_dispatch',
+    'moe_experts_hit_pct', 'moe_tiled_pairs_pct',
+    'iter_ms.backlog', 'prefill_chunks_per_iter.backlog',
+    'prefill_real_pct.backlog', 'slots_occupied_mean',
+    'kv_blocks_used_peak_pct', 'moe_busiest_over_mean',
+    'engine_idle_schedule_ms.backlog', 'engine_idle_prefill_ms.backlog',
+    'engine_idle_dispatch_ms.backlog', 'engine_idle_emit_ms.backlog'}
+_OWN = {'mtp_accept_pct', 'mtp_tokens_per_round', 'mtp_round_ms',
+        'latent_mtp_round_hbm_roofline'}
 
 
 def _file():
@@ -43,7 +57,7 @@ def _file():
                              _NAME + '.json')
 
 
-def test_reduced_lists_exactly_the_depth():
+def test_reduced_lists_exactly_the_depth(cell_stands_in_its_lists):
     config = _file()
     assert config['reduced'] == list(_REDUCED)
     for key, (published, here) in _REDUCED.items():
@@ -74,21 +88,18 @@ def test_reduced_lists_exactly_the_depth():
     assert len(bench['per_layer']) <= 128
     judged = {m['name']: m for m in bench['end_to_end']}['out_tok_s']
     assert _CELL in judged['workloads'] and judged['bound'] == 0.03
-    mine = {m['name']: m for m in bench['per_layer']
-            if m.get('workloads') == [_CELL]}
-    assert set(mine) == {
-        'mtp_accept_pct', 'mtp_tokens_per_round', 'mtp_round_ms',
-        'latent_mtp_round_hbm_roofline', 'moe_experts_hit_pct.mtp',
-        'prefix_hit_pct.mtp', 'iter_host_gap_ms.mtp',
-        'tokens_per_dispatch.mtp', 'prefill_chunk_ms.mtp'}
-    assert {m['moves'] for m in mine.values()} == {'out_tok_s'}
+    # Its decode program is ``mtp_rounds_paged``: ``mtp_round_ms``
+    # stands where the other cells have ``decode_step_ms``, and it has
+    # no view or walk counters.
+    mine = cell_stands_in_its_lists(_CELL, _FAMILIES, _OWN)
+    assert not set(mine) & {'decode_step_ms.backlog',
+                            'decode_view_pct.backlog',
+                            'decode_walk_read_pct.backlog'}
     # Ten of the eleven set-up readings: this engine never compiles
     # ``decode_steps_paged``, so ``setup_jit_decode_s`` finds nothing.
     setup = {m['name'] for m in bench['per_layer']
              if m['moves'] == 'setup_s' and _CELL in m['workloads']}
     assert len(setup) == 10 and 'setup_jit_decode_s' not in setup
-    for name in mine:
-        harness.reader_for(name, harness.PERF_DIR)
 
 
 @pytest.mark.skipif(not os.path.exists(_CATALOG),
@@ -298,8 +309,11 @@ def fresh_programs():
     jax.clear_caches()
 
 
-def test_sound_run_is_correct_and_checks_the_mixs_requests(
+def test_sound_run_is_correct_and_checks_requests_of_the_window(
         fresh_programs, capsys):
+    """The sampled cell's check is ``serve_engine.check_served``'s
+    since PR 45: three requests that finished inside the window, the
+    last ``check_tokens`` tokens of each, all stamped inside it."""
     out = _run()
     assert out['correct'], out['compared']
     assert out['attempted'] > 0 and out['failed'] == 0
@@ -307,9 +321,14 @@ def test_sound_run_is_correct_and_checks_the_mixs_requests(
     line, = [l for l in said.splitlines()
              if l.startswith('reference: requests')]
     picked = json.loads(line.split('requests ')[1].split(' of ')[0])
-    slots = harness.load_cell(_CELL, rehearse=True)[
-        'config']['build']['slots']
-    assert len(picked) == 3 and all(0 <= i < slots for i in picked)
+    config = harness.load_cell(_CELL, rehearse=True)['config']
+    cap = config['check_tokens']
+    assert len(picked) == len(set(picked)) == 3
+    assert max(picked) >= config['build']['slots']  # a later wave
+    assert f'tokens compared {[cap] * 3}' in line
+    assert f'{3 * cap} of them stamped inside the window' in line
+    assert {c['name']: c['value'] for c in out['compared']}[
+        'served_tokens_missing'] == 0
 
 
 @pytest.mark.parametrize('seed', [13, 2**31 + 5, 77])

@@ -54,6 +54,25 @@ def test_top_level_holds_every_published_key():
         assert config[key] == value, key
 
 
+def test_the_cell_stands_in_the_lists_of_its_families(
+        cell_stands_in_its_lists):
+    # One entry a family and judged metric since PR 45: the cell's
+    # name in the family's ``workloads``, its own two entries beside.
+    families = {
+        'decode_step_ms.backlog', 'prefill_chunk_ms.backlog',
+        'iter_ms.backlog', 'iter_host_gap_ms.backlog',
+        'prefill_chunks_per_iter.backlog', 'prefill_real_pct.backlog',
+        'prefix_hit_pct.backlog', 'decode_view_pct.backlog',
+        'decode_walk_read_pct.backlog', 'prefill_keys_read_pct.backlog',
+        'engine_idle_schedule_ms.backlog',
+        'engine_idle_prefill_ms.backlog',
+        'engine_idle_dispatch_ms.backlog',
+        'engine_idle_emit_ms.backlog', 'tokens_per_dispatch',
+        'slots_occupied_mean', 'kv_blocks_used_peak_pct'}
+    own = {'loop_passes_per_token', 'looped_decode_hbm_roofline'}
+    cell_stands_in_its_lists(_CELL, families, own)
+
+
 def test_looped_step_bytes_by_hand():
     model = _file()['model']
     got = looped_decode_step.looped_decode_step_bytes(

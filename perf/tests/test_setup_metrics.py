@@ -152,6 +152,8 @@ def test_the_benchmark_lists_the_eleven():
     assert sorted(m['name'] for m in mine) == sorted(_EXPECTED)
     cells = [w['name'] for w in bench['workloads']]
     serving = [c for c in cells if c.startswith('serve-')]
+    rounds = {c: harness.load_cell(c)['config']['build'].get(
+        'speculative') == 'mtp' for c in serving}
     for m in mine:
         assert (m['source'], m['better'], m['layer']) == (
             'program_counter', 'lower', 'start-up')
@@ -159,7 +161,13 @@ def test_the_benchmark_lists_the_eleven():
             'setup_before_engine_s', 'setup_engine_build_s',
             'setup_prewarm_decode_s', 'setup_jit_decode_s',
             'setup_jit_prefill_s')
-        assert m['workloads'] == (serving if engine_only else cells)
+        expected = serving if engine_only else cells
+        if m['name'] == 'setup_jit_decode_s':
+            # An engine that drafts with the model's own module
+            # decodes through ``mtp_rounds_paged`` and never compiles
+            # ``decode_steps_paged``: the reader finds nothing there.
+            expected = [c for c in serving if not rounds[c]]
+        assert m['workloads'] == expected
         assert os.path.exists(os.path.join(
             harness.PERF_DIR, 'layer_metrics', m['name'] + '.py'))
     json.dumps(mine)

@@ -39,7 +39,8 @@ def _file():
                              _NAME + '.json')
 
 
-def test_reduced_lists_exactly_depth_experts_held_and_vocabulary():
+def test_reduced_lists_exactly_depth_experts_held_and_vocabulary(
+        cell_stands_in_its_lists):
     config = _file()
     assert sorted(config['reduced']) == sorted(_REDUCED)
     for key, (published, here) in _REDUCED.items():
@@ -59,6 +60,25 @@ def test_reduced_lists_exactly_depth_experts_held_and_vocabulary():
     # No width among them.
     assert not any(k.endswith(('_dim', '_rank', '_size'))
                    and k != 'vocab_size' for k in config['reduced'])
+    # The cell's name stands in the lists of the families it reports
+    # (one entry a family and judged metric since PR 45), and its own
+    # three entries exist.
+    families = {
+        'decode_step_ms.backlog', 'prefill_chunk_ms.backlog',
+        'iter_ms.backlog', 'iter_host_gap_ms.backlog',
+        'prefill_chunks_per_iter.backlog', 'prefill_real_pct.backlog',
+        'prefix_hit_pct.backlog', 'decode_view_pct.backlog',
+        'decode_walk_read_pct.backlog',
+        'engine_idle_schedule_ms.backlog',
+        'engine_idle_prefill_ms.backlog',
+        'engine_idle_dispatch_ms.backlog',
+        'engine_idle_emit_ms.backlog', 'tokens_per_dispatch',
+        'slots_occupied_mean', 'kv_blocks_used_peak_pct',
+        'moe_experts_hit_pct', 'moe_busiest_over_mean',
+        'moe_tiled_pairs_pct'}
+    own = {'moe_held_share_pct', 'kv_window_blocks_peak_pct',
+           'window_moe_decode_hbm_roofline'}
+    cell_stands_in_its_lists(_CELL, families, own)
 
 
 @pytest.mark.skipif(not os.path.exists(_CATALOG),

@@ -5,7 +5,14 @@ program's own where the control's run produces them. The program's
 dozen sound seeds are the benchmark's own runs, each of which prints
 every number compared.
 
-    python3 -m perf.tools.limits --workload <cell> --seeds 1,2,3 --seconds 15
+    python3 -m perf.tools.limits --workload <cell> --seeds 1,2,3 --seconds 51
+
+A serving cell compares the last ``check_tokens`` tokens of requests
+that finished inside the window with all of those tokens inside it
+(``serve_engine.check_sample``), so the window has to be long enough
+for ``check_requests`` such requests: the cell's own length is, and
+``served_tokens_missing`` in the ``sound`` readings says whether a
+shorter one was.
 """
 import argparse
 import gc
